@@ -5,42 +5,42 @@ strategy; the reference has no hardware-free path at all)."""
 import os
 import sys
 
-# Hard-set (not setdefault): the session env pins JAX_PLATFORMS to the
-# real TPU backend, but tests must be deterministic and hardware-free.
+# Hard-set (not setdefault): tests are deterministic and hardware-free
+# whatever the session env says.
 os.environ["JAX_PLATFORMS"] = "cpu"
 flags = os.environ.get("XLA_FLAGS", "")
 if "xla_force_host_platform_device_count" not in flags:
     os.environ["XLA_FLAGS"] = (flags + " --xla_force_host_platform_device_count=8").strip()
 
-# The hosted-TPU environment force-prepends its platform to jax_platforms
-# even over the env var; config.update after import is authoritative.
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")
 
-# Persistent XLA compile cache for THIS process only, machine-local
-# under /tmp (same-host CPU cache is safe; the cross-host SIGILL risk
-# bench.py documents does not apply). Why: the full suite compiles
-# ~500 XLA:CPU programs in one process, and past ~90% of them the CPU
-# compiler was observed segfaulting (reproduced three times at the
-# same test; no single module triggers it — both alphabetical halves
-# pass alone). With the cache, warm runs compile almost nothing, and
-# even a crashed cold run banks every entry up to the crash, so reruns
-# self-heal past it. Deliberately jax.config-only, NOT os.environ: the
-# env var would leak into every subprocess tests spawn (serve CLI,
-# dryruns), where the cache's serialize-on-write stalled the serve
-# engine's first compile past its test's 120s timeout.
-import getpass  # noqa: E402
+# Persistent XLA compile cache for THIS process only (the helper's
+# rule: JAX_COMPILATION_CACHE_DIR wins when set; else the fixed
+# directory inside the checkout, under the suite's own lane — XLA:CPU
+# entries are AOT code for this machine and must not mix with the
+# chip's). Why: the full suite compiles ~500 XLA:CPU programs in one
+# process, and past ~90% of them the CPU compiler was observed
+# segfaulting (reproduced three times at the same test; no single
+# module triggers it — both alphabetical halves pass alone). With the
+# cache, warm runs compile almost nothing, and even a crashed cold run
+# banks every entry up to the crash, so reruns self-heal past it.
+# Deliberately jax.config-only, NOT os.environ: the env var would leak
+# into every subprocess tests spawn (serve CLI, dryruns), where the
+# cache's serialize-on-write stalled the serve engine's first compile
+# past its test's 120s timeout.
+from tpushare.utils.compile_cache import enable_compile_cache  # noqa: E402
 
-jax.config.update("jax_compilation_cache_dir",
-                  f"/tmp/tpushare-test-xla-cache-{getpass.getuser()}")
+enable_compile_cache(lane="cpu-tests")
 # Cache EVERY entry: the accumulation risk is compile count, and the
 # suite's compiles are mostly small ones the default 1s/min-size
 # thresholds would keep recompiling forever.
 jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
 jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
 
-sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import pytest  # noqa: E402
 
@@ -55,7 +55,8 @@ import pytest  # noqa: E402
 # Policy: a test module lands here iff it imports jax or spawns a
 # JAX-running subprocess.
 SLOW_MODULES = {
-    "test_adamw", "test_checkpoint", "test_convert",
+    "test_adamw", "test_checkpoint", "test_chip_smoke_rehearsal",
+    "test_convert",
     "test_distributed_2proc", "test_e2e_dryrun",
     "test_finetune_serve", "test_fsdp",
     "test_generate", "test_kv_quant", "test_lora", "test_models",

@@ -72,7 +72,13 @@ def test_sysfs_backend_vfio_layout_shared_node(tmp_path):
     for i in range(2):
         (vfio / str(i)).write_text("")
     (vfio / "vfio").write_text("")
-    be = SysfsBackend(dev_glob=str(vfio / "*"), sysfs_root=str(tmp_path / "sys"))
+    # No sysfs PCI id under this layout: without a hint the generation
+    # is unknowable, and that is an error, not a v5e default.
+    with pytest.raises(RuntimeError, match="generation"):
+        SysfsBackend(dev_glob=str(vfio / "*"),
+                     sysfs_root=str(tmp_path / "sys")).probe()
+    be = SysfsBackend(dev_glob=str(vfio / "*"), sysfs_root=str(tmp_path / "sys"),
+                      generation_hint="v5e")
     topo = be.probe()
     assert topo.chip_count == 2
     assert [c.device_path for c in topo.chips] == [str(vfio / "0"), str(vfio / "1")]
@@ -129,6 +135,7 @@ def test_sysfs_backend_ignores_non_chip_nodes(tmp_path, monkeypatch):
         dev = tmp_path / "sys" / f"accel{i}" / "device"
         dev.mkdir(parents=True)
         (dev / "numa_node").write_text("0")
+        (dev / "device").write_text("0x0062\n")
     (tmp_path / "accel9x").write_text("")
     (tmp_path / "accel_ctl").write_text("")
     monkeypatch.setattr(nativedisc, "_LIB", None)          # defeat load cache
@@ -147,6 +154,7 @@ def test_sysfs_backend_sparse_indices_preserved(tmp_path, monkeypatch):
         dev = tmp_path / "sys" / f"accel{i}" / "device"
         dev.mkdir(parents=True)
         (dev / "numa_node").write_text(str(i % 2))
+        (dev / "device").write_text("0x0062\n")
     monkeypatch.setattr(nativedisc, "_LIB", None)
     monkeypatch.setattr(nativedisc, "_LOAD_FAILED", True)
     topo = SysfsBackend(dev_glob=str(tmp_path / "accel*"),
@@ -170,6 +178,7 @@ def test_sysfs_backend_vfio_layout(tmp_path, monkeypatch):
         (vfio / str(i)).write_text("")
     monkeypatch.setattr(nativedisc, "_LIB", None)
     monkeypatch.setattr(nativedisc, "_LOAD_FAILED", True)
-    be = SysfsBackend(dev_glob=str(vfio / "*"), sysfs_root=str(tmp_path / "sys"))
+    be = SysfsBackend(dev_glob=str(vfio / "*"), sysfs_root=str(tmp_path / "sys"),
+                      generation_hint="v5e")
     assert be.available()
     assert be.probe().chip_count == 2

@@ -1,33 +1,37 @@
-"""Pin bench.py's driver-contract record shape (VERDICT r4 #2).
+"""Pin bench.py's driver-contract lines.
 
-A CPU fallback must be unmistakably non-scoring: ``credible`` forced
-false with an explicit reason, ``vs_baseline`` null, and the
-percentage restated as ``advisory_cpu_pct``. No subprocesses — these
-exercise the pure record assembly."""
+A run that cannot measure on an accelerator FAILS: one JSON line that
+names the failure, no number, exit code 1 — never a CPU rerun, never an
+older run's figure attached. The only CPU path left is the explicit
+TPUSHARE_BENCH_FORCE_CPU harness mode, and its record is labelled and
+scores nothing. No subprocesses — these exercise the record assembly
+and main()'s control flow with the probe and the measurement stubbed."""
 
 import json
+
+import pytest
 
 import bench
 
 
-def test_cpu_fallback_is_non_scoring():
+def test_forced_cpu_harness_is_labelled_and_non_scoring():
     rec = bench.final_record(42.75, "cpu", {
         "solo_variance_pct": 1.2,
         "credible": True,          # A-B-A gates passed — irrelevant on CPU
     })
     assert rec["backend"] == "cpu"
+    # No CPU number under the device metric's name.
+    assert rec["value"] is None
     assert rec["vs_baseline"] is None
     assert rec["credible"] is False
     assert rec["advisory_cpu_pct"] == 42.75
-    assert any("cpu fallback" in r for r in rec["refusal_reasons"])
-    # Driver contract fields present and JSON-serializable.
+    assert any("FORCE_CPU" in r for r in rec["refusal_reasons"])
     assert rec["metric"] == "colocated_tokens_per_sec_pct"
     assert rec["unit"] == "%"
-    assert rec["value"] == 42.75
     json.dumps(rec)
 
 
-def test_cpu_fallback_keeps_prior_refusal_reasons():
+def test_forced_cpu_harness_keeps_prior_refusal_reasons():
     rec = bench.final_record(120.0, "cpu", {
         "credible": False,
         "refusal_reasons": ["co-located/solo 120.0% > 100%"],
@@ -42,6 +46,7 @@ def test_tpu_credible_scores():
         "solo_variance_pct": 0.8,
         "credible": True,
     })
+    assert rec["value"] == 97.1
     assert rec["vs_baseline"] == round(97.1 / 95.0, 4)
     assert rec["credible"] is True
     assert "advisory_cpu_pct" not in rec
@@ -67,151 +72,147 @@ def test_windows_never_leak_into_the_driver_line():
     assert "windows" not in rec
 
 
-def test_artifact_path_never_clobbers_credible(tmp_path):
-    """A refused run's raws go to a _refused sibling when the banked
-    artifact is credible; a credible run always takes the canonical
-    path; no artifact at all -> canonical path either way."""
-    bdir = tmp_path / "benchmarks"
-    bdir.mkdir()
-    canon = str(bdir / "NORTH_STAR_TPU_r4.json")
-    # No artifact yet: both kinds take the canonical path.
-    assert bench.artifact_path(False, repo=str(tmp_path)) == canon
-    assert bench.artifact_path(True, repo=str(tmp_path)) == canon
-    # Banked credible artifact: refused runs are diverted, credible
-    # runs overwrite (newer credible evidence supersedes).
-    with open(canon, "w") as f:
-        json.dump({"credible": True, "value_pct": 99.51}, f)
-    assert bench.artifact_path(False, repo=str(tmp_path)).endswith(
-        "_refused.json")
-    assert bench.artifact_path(True, repo=str(tmp_path)) == canon
-    # Banked refused artifact: anything may overwrite it.
-    with open(canon, "w") as f:
-        json.dump({"credible": False}, f)
-    assert bench.artifact_path(False, repo=str(tmp_path)) == canon
+def test_window_raws_go_to_the_chip_tools_output_dir():
+    """Per-window raws used to overwrite (or sit beside) a record of an
+    earlier round under benchmarks/; a record is not edited, so they go
+    to chiprun_out/, which git ignores."""
+    rel = bench.os.path.relpath(bench.WINDOWS_PATH, bench.REPO)
+    assert rel.split(bench.os.sep)[0] == "chiprun_out"
+    assert not hasattr(bench, "artifact_path")
 
 
-def test_refused_record_points_at_banked_credible(tmp_path, monkeypatch):
-    """A refused/CPU record carries a clearly-labeled pointer to the
-    round's banked credible artifact (and only then)."""
+def test_refused_record_never_cites_an_older_run(tmp_path, monkeypatch):
+    """A refused (or harness) record used to carry a pointer to the
+    round's banked credible artifact; a run reports itself only."""
     bdir = tmp_path / "benchmarks"
     bdir.mkdir()
     monkeypatch.setattr(bench, "REPO", str(tmp_path))
-    # No banked artifact: no pointer.
-    rec = bench.final_record(42.0, "cpu", {})
-    assert "banked_credible_prior_run" not in rec
     with open(bdir / "NORTH_STAR_TPU_r4.json", "w") as f:
         json.dump({"credible": True, "value_pct": 99.51,
                    "solo_variance_pct": 4.54}, f)
-    rec = bench.final_record(42.0, "cpu", {})
-    assert rec["banked_credible_prior_run"]["value_pct"] == 99.51
-    # A credible on-accel run reports itself, never the pointer.
-    rec = bench.final_record(99.0, "tpu", {"credible": True})
-    assert "banked_credible_prior_run" not in rec
-    assert rec["vs_baseline"] == round(99.0 / 95.0, 4)
-    # A banked REFUSED artifact is never pointed at.
-    with open(bdir / "NORTH_STAR_TPU_r4.json", "w") as f:
-        json.dump({"credible": False, "value_pct": 94.6}, f)
-    rec = bench.final_record(42.0, "cpu", {})
-    assert "banked_credible_prior_run" not in rec
+    for rec in (bench.final_record(42.0, "cpu", {}),
+                bench.final_record(126.0, "tpu", {"credible": False}),
+                bench.failure_record("no accelerator")):
+        assert "banked_credible_prior_run" not in rec
+        assert "99.51" not in json.dumps(rec)
 
 
-def test_probe_failure_reasons_are_collected(monkeypatch):
-    """probe_backend records every failed attempt's `kind` string into
-    attempts_log, so a `backend: cpu` BENCH record is diagnosable from
-    the artifact instead of from lost stderr (VERDICT r5 #1: five
-    opaque CPU rounds). A hang triggers the triage classification
-    (recorded too) before the single long-deadline attempt."""
-    outcomes = iter([(None, "hung >10s"),
-                     (None, "rc=1: ImportError: libtpu"),
-                     ("tpu", "TPU v5e")])
+def _run_main(monkeypatch, capsys, probe, measure=None):
+    """bench.main() with the probe child and the measurement stubbed;
+    ``probe`` is _probe_once's answer, or a callable standing in for it.
+    Returns (exit code, the one stdout line parsed, measure calls)."""
+    calls = []
+    monkeypatch.delenv("TPUSHARE_BENCH_FORCE_CPU", raising=False)
     monkeypatch.setattr(bench, "_probe_once",
-                        lambda attempt_s: next(outcomes))
-    monkeypatch.setattr(bench, "triage_probe_hang",
-                        lambda: {"accel_holder_pids": [],
-                                 "libtpu_lockfile": "absent"})
-    monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-    log = []
-    triage = {}
-    backend, kind = bench.probe_backend(budget_s=1000.0,
-                                        attempts_log=log, triage=triage)
-    assert (backend, kind) == ("tpu", "TPU v5e")
-    assert log[0] == "hung >10s"
-    assert log[1].startswith("triage: ")
-    assert log[2] == "rc=1: ImportError: libtpu"
-    assert triage == {"accel_holder_pids": [],
-                      "libtpu_lockfile": "absent"}
+                        probe if callable(probe) else lambda attempt_s: probe)
+
+    def _measure(solo_env, child_env, extras=None):
+        calls.append((solo_env, child_env))
+        if measure is None:
+            raise AssertionError("measurement must not run")
+        return measure(solo_env, child_env, extras)
+
+    monkeypatch.setattr(bench, "_measure", _measure)
+    rc = bench.main()
+    out = capsys.readouterr().out.strip().splitlines()
+    assert len(out) == 1, out            # ONE stdout line, always
+    return rc, json.loads(out[0]), calls
 
 
-def test_probe_hang_is_triaged_then_one_long_attempt(monkeypatch):
-    """The r6 hang schedule: short attempt -> hang -> classify+clean
-    -> ONE long-deadline attempt -> CPU fallback. No 19-retry blind
-    loop (r5 burned the full 1500s budget on one wedge)."""
+def test_probe_failure_is_named_and_nothing_is_measured(monkeypatch,
+                                                        capsys):
+    """A probe child that exits with an error ends the run: the line
+    carries the child's own reason, and no tenant is started."""
+    rc, rec, calls = _run_main(
+        monkeypatch, capsys, (None, "rc=1: ImportError: libtpu"))
+    assert rc == 1 and rec["ok"] is False
+    assert "ImportError: libtpu" in rec["error"]
+    assert calls == []
+    assert "value" not in rec and "advisory_cpu_pct" not in rec
+
+
+def test_probe_hang_is_one_bounded_attempt(monkeypatch, capsys):
+    """A hung probe is killed at its deadline and the run fails — no
+    triage ladder, no retries, no fallback."""
     deadlines = []
 
     def fake_probe(attempt_s):
         deadlines.append(attempt_s)
         return None, f"hung >{attempt_s:.0f}s"
 
-    monkeypatch.setattr(bench, "_probe_once", fake_probe)
-    monkeypatch.setattr(
-        bench, "triage_probe_hang",
-        lambda: {"accel_holder_pids": [4242],
-                 "libtpu_lockfile": "present (device held; "
-                                    "left in place)"})
-    monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-    log = []
-    triage = {}
-    backend, _ = bench.probe_backend(budget_s=1000.0, attempts_log=log,
-                                     triage=triage)
-    assert backend == "cpu"
-    # Exactly two attempts: one short, one long — never 19.
-    assert deadlines == [10.0, 75.0]
-    assert triage["accel_holder_pids"] == [4242]
-    assert any(e.startswith("triage: ") for e in log)
-    assert log[-1].startswith("long-deadline attempt hung after triage")
+    rc, rec, calls = _run_main(monkeypatch, capsys, fake_probe)
+    assert rc == 1 and calls == []
+    assert deadlines == [bench.INIT_TIMEOUT_S]
+    assert rec["ok"] is False and "hung" in rec["error"]
 
 
-def test_triage_removes_stale_lockfile_only(tmp_path, monkeypatch):
-    """A libtpu lockfile with no /dev/accel holder is stale and gets
-    removed; with a holder it is left in place (the chip may be a live
-    tenant's)."""
-    lock = tmp_path / "libtpu_lockfile"
-    lock.write_text("")
-    monkeypatch.setenv("TPUSHARE_LIBTPU_LOCKFILE", str(lock))
-    monkeypatch.setattr(bench, "_accel_holders", lambda: [])
-    out = bench.triage_probe_hang()
-    assert out["libtpu_lockfile"].startswith("stale")
-    assert not lock.exists()
-    # Held device: the lockfile is NOT ours to remove.
-    lock.write_text("")
-    monkeypatch.setattr(bench, "_accel_holders", lambda: [1234])
-    out = bench.triage_probe_hang()
-    assert "left in place" in out["libtpu_lockfile"]
-    assert lock.exists()
-    assert out["accel_holder_pids"] == [1234]
-    # Absent lockfile classifies as absent.
-    lock.unlink()
-    monkeypatch.setattr(bench, "_accel_holders", lambda: [])
-    assert bench.triage_probe_hang()["libtpu_lockfile"] == "absent"
+def test_probe_resolving_to_cpu_is_a_failure(monkeypatch, capsys):
+    """JAX settling on the CPU is 'no accelerator', not a backend to
+    measure on (the old probe returned "cpu" on three paths and the
+    tenants then ran BERT-tiny there)."""
+    rc, rec, calls = _run_main(monkeypatch, capsys, ("cpu", "cpu"))
+    assert rc == 1 and rec["ok"] is False
+    assert "no accelerator" in rec["error"]
+    assert calls == []
+    with pytest.raises(bench.BenchFailure, match="no accelerator"):
+        bench.probe_backend()
 
 
-def test_probe_deterministic_fallback_reasons(monkeypatch):
-    """Three consecutive non-hang failures -> CPU fallback, with all
-    three reasons plus the classification in the log."""
-    monkeypatch.setattr(bench, "_probe_once",
-                        lambda attempt_s: (None, "rc=1: broken libtpu"))
-    monkeypatch.setattr(bench.time, "sleep", lambda s: None)
-    log = []
-    backend, _ = bench.probe_backend(budget_s=1000.0, attempts_log=log)
-    assert backend == "cpu"
-    assert log == ["rc=1: broken libtpu"] * 3 + [
-        "3 consecutive deterministic failures"]
+def test_unknown_device_kind_is_an_error(monkeypatch, capsys):
+    """A device the peak tables do not know used to be called v5e."""
+    rc, rec, calls = _run_main(monkeypatch, capsys,
+                               ("tpu", "TPU v9 hyper"))
+    assert rc == 1 and "TPU v9 hyper" in rec["error"]
+    assert calls == []
 
 
-def test_probe_failures_land_in_the_driver_record():
-    rec = bench.final_record(42.0, "cpu", {
-        "probe_failures": ["hung >75s"] * 19,
-    })
-    assert rec["probe_failures"] == ["hung >75s"] * 19
-    assert rec["credible"] is False
-    json.dumps(rec)
+def test_failed_measurement_is_a_failure_line_not_a_cpu_rerun(
+        monkeypatch, capsys):
+    """A tenant that cannot open the chip (or dies) fails the run with
+    its phase and message; the measurement runs ONCE — no re-probe, no
+    retry, no rerun under TPUSHARE_BENCH_FORCE_CPU."""
+    def measure(solo_env, child_env, extras):
+        raise bench.BenchFailure(
+            "co-located phase (2 tenant processes on one chip): tenant "
+            "1 of 2 never said READY; its stderr ends: TPU "
+            "initialization failed: open(/dev/vfio/2): Device or "
+            "resource busy")
+
+    rc, rec, calls = _run_main(monkeypatch, capsys,
+                               ("tpu", "TPU v5 lite"), measure)
+    assert rc == 1 and rec["ok"] is False
+    assert "co-located phase" in rec["error"]
+    assert "Device or resource busy" in rec["error"]
+    assert rec["device"] == {"platform": "tpu", "kind": "TPU v5 lite"}
+    assert len(calls) == 1
+    solo_env, child_env = calls[0]
+    assert "TPUSHARE_BENCH_FORCE_CPU" not in solo_env
+    assert solo_env["TPUSHARE_TPU_GENERATION"] == "v5e"
+    assert "value" not in rec
+
+
+def test_measured_record_names_its_device(monkeypatch, capsys, tmp_path):
+    monkeypatch.setattr(bench, "WINDOWS_PATH",
+                        str(tmp_path / "out" / "w.json"))
+
+    def measure(solo_env, child_env, extras):
+        extras.update({"credible": True, "solo_variance_pct": 1.0,
+                       "windows": {"solo_a1": {}}})
+        return 96.0
+
+    rc, rec, _ = _run_main(monkeypatch, capsys, ("tpu", "TPU v5 lite"),
+                           measure)
+    assert rc == 0
+    assert rec["device"] == {"platform": "tpu", "kind": "TPU v5 lite"}
+    assert rec["backend"] == "tpu" and rec["value"] == 96.0
+    assert "windows" not in rec
+    with open(tmp_path / "out" / "w.json") as f:
+        assert "windows" in json.load(f)
+
+
+def test_no_fallback_text_left_in_bench():
+    with open(bench.__file__) as f:
+        src = f.read()
+    assert "falling back to CPU" not in src
+    for gone in ("triage_probe_hang", "_accel_holders", "artifact_path"):
+        assert not hasattr(bench, gone)

@@ -472,10 +472,7 @@ class TestMoEInference:
         cache (the cache shards over nothing; experts shard over ep)."""
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         cfg = moe.tiny(remat=False)
         params = _params(cfg, seed=2)
         toks = _tokens(cfg, batch=2, seq=6, seed=7)
@@ -753,10 +750,7 @@ class TestMoEShardedDecode:
     def test_ep_tp_decode_matches_single_device(self, quantized):
         from functools import partial
         from jax.sharding import PartitionSpec as P
-        try:
-            from jax import shard_map
-        except ImportError:
-            from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         from tpushare.models import quant
         cfg = moe.tiny(remat=False)
         fp = _params(cfg, seed=2)

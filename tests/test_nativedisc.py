@@ -60,10 +60,15 @@ def test_probe_empty_dir_returns_none(tmp_path):
     assert nativedisc.probe(f"{tmp_path}/accel*", f"{tmp_path}/sys") is None
 
 
-def test_probe_unknown_pci_falls_back_to_v5e(tmp_path):
+def test_probe_unknown_pci_is_an_error_not_v5e(tmp_path):
+    """An unknown PCI id used to be called v5e and given 16 GiB; a
+    guessed generation advertises another chip's HBM, so it raises —
+    unless the caller supplies the generation as a hint."""
     dev, sysr = fake_tree(tmp_path, n=1, pci="0xdead")
-    topo = nativedisc.probe(f"{dev}/accel*", sysr)
-    assert topo.generation == "v5e"
+    with pytest.raises(RuntimeError, match="generation"):
+        nativedisc.probe(f"{dev}/accel*", sysr)
+    topo = nativedisc.probe(f"{dev}/accel*", sysr, generation_hint="v6e")
+    assert topo.generation == "v6e"
 
 
 def test_sysfs_backend_uses_native(tmp_path):

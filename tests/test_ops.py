@@ -522,6 +522,16 @@ class TestDecodeDispatchPolicy:
         monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
         monkeypatch.delenv(fa.DECODE_KERNEL_ENV, raising=False)
         assert fa.paged_decode_eligible(*self._paged_shapes()) is True
+        # A page must fill a Mosaic tile of its dtype: 8 rows of f32,
+        # 16 of bf16 (the daemon's default block), 32 of int8.
+        q, _ = self._paged_shapes()
+        for dtype, bs, want in ((jnp.float32, 8, True),
+                                (jnp.bfloat16, 8, False),
+                                (jnp.bfloat16, 16, True),
+                                (jnp.int8, 16, False),
+                                (jnp.int8, 32, True)):
+            pool = jnp.zeros((16, bs, 2, 128), dtype)
+            assert fa.paged_decode_eligible(q, pool) is want, (dtype, bs)
         monkeypatch.setenv(fa.DECODE_KERNEL_ENV, "0")
         assert fa.paged_decode_eligible(*self._paged_shapes()) is False
 

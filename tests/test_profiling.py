@@ -1,6 +1,7 @@
 """Profiling helpers: step timing, FLOPs accounting, MFU."""
 
 import jax.numpy as jnp
+import pytest
 
 from tpushare.models import transformer as tf
 from tpushare.utils import profiling
@@ -45,4 +46,9 @@ def test_mfu_bounds():
     flops = profiling.transformer_flops(cfg, 8, 128)
     u = profiling.mfu(flops, step_seconds=0.05, generation="v5e")
     assert 0 < u < 1
-    assert profiling.mfu(flops, 0.05, generation="unknown-chip") is None
+    # A chip that is not in the table is an error, never another
+    # chip's peak (it used to default to v5e / return None).
+    with pytest.raises(ValueError, match="unknown-chip"):
+        profiling.mfu(flops, 0.05, generation="unknown-chip")
+    with pytest.raises(ValueError, match="unknown-chip"):
+        profiling.bandwidth_utilization(1e9, 0.05, "unknown-chip")

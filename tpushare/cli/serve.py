@@ -3790,9 +3790,12 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--platform", default="",
                     choices=["", "cpu", "tpu"],
                     help="force the JAX backend (config.update wins "
-                         "over JAX_PLATFORMS, which hosted TPU "
-                         "environments may override); default: jax's "
-                         "own resolution")
+                         "over JAX_PLATFORMS). 'tpu' fails at startup "
+                         "when no chip can be opened — what an "
+                         "on-chip deployment wants; the default lets "
+                         "jax resolve the backend, which without a "
+                         "chip is the CPU. The startup line names "
+                         "the platform either way")
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--port", type=int, default=8478)
     ap.add_argument("--n-slots", type=int, default=8)
@@ -3978,9 +3981,24 @@ def main() -> int:
     args = build_parser().parse_args()
     engine = build_engine(args)
     httpd = serve(engine, args.host, args.port, daemon_threads=False)
+    import jax
+    devs = jax.devices()
+
+    def _hbm(key):
+        # Per local device; None where the backend keeps no allocator
+        # stats (the CPU).
+        return [(d.memory_stats() or {}).get(key)
+                for d in jax.local_devices()]
+
+    # The one place the daemon names its device: a deployment (and
+    # chip_smoke.py) reads platform= here to know the weights are on
+    # the chip and, under --mesh, spread over it.
     print(f"tpushare-serve on {args.host}:{httpd.server_address[1]} "
           f"({args.model_family}/{args.preset}, {args.n_slots} slots"
-          f"{', mesh ' + args.mesh if args.mesh else ''})",
+          f"{', mesh ' + args.mesh if args.mesh else ''}) "
+          f"platform={devs[0].platform} "
+          f"device_kind={devs[0].device_kind!r} devices={len(devs)} "
+          f"bytes_in_use={_hbm('bytes_in_use')}",
           flush=True)
 
     # SIGTERM (the kubelet's preemption signal) drains: refuse new
@@ -3999,6 +4017,8 @@ def main() -> int:
         # request's response bytes reach the socket before exit.
         httpd.server_close()
         engine.stop()
+        print(f"drained: peak_bytes_in_use={_hbm('peak_bytes_in_use')} "
+              f"bytes_limit={_hbm('bytes_limit')}", flush=True)
         return 0
     except KeyboardInterrupt:
         return 0
@@ -4141,6 +4161,14 @@ def build_engine(args) -> ServeEngine:
                 gang = GangLeader(num_processes,
                                   port=int(port) + 1,
                                   host=host or "0.0.0.0")
+    # After the gang bring-up above (jax.distributed initializes
+    # BEFORE the first device query), before the first compile.
+    if jax.default_backend() != "cpu":
+        # Every daemon start on the chip would otherwise compile its
+        # model from nothing. CPU daemons compile fast and XLA:CPU
+        # cache entries are machine-specific — no cache there.
+        from tpushare.utils.compile_cache import enable_compile_cache
+        enable_compile_cache()
     if args.model_family == "moe":
         from tpushare.models import moe
         moe_kv = args.kv or "rows"
@@ -4270,7 +4298,22 @@ def build_engine(args) -> ServeEngine:
         from tpushare.models import transformer as tf
         cfg = {"tiny": tf.tiny, "gemma_2b": tf.gemma_2b,
                "llama3_8b": tf.llama3_8b}[args.preset]()
-        params = tf.init_params(jax.random.PRNGKey(args.seed), cfg)
+        key = jax.random.PRNGKey(args.seed)
+        if mesh is None:
+            params = tf.init_params(key, cfg)
+        else:
+            # Born under the serving placement: unplaced, the whole
+            # tree lands on the first device, each leaf drawn in
+            # float32 first — llama3_8b's w_gate alone is 7.5 GB on a
+            # 16 GB chip that is about to hold its quarter of 16 GB of
+            # weights. Under jit with out_shardings each device draws
+            # only its own shard (threefry is partitionable: the
+            # values are those of the unplaced tree).
+            from tpushare.parallel import tree_shardings
+            params = jax.jit(
+                lambda k: tf.init_params(k, cfg),
+                out_shardings=tree_shardings(
+                    mesh, tf.param_specs(cfg)))(key)
         spec, hook, dps = None, None, None
         if args.draft_preset == "int8-self":
             from tpushare.models import quant

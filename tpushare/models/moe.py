@@ -338,12 +338,10 @@ def expert_capacity(n_tokens: int, cfg: MoEConfig,
 
 
 def _pvary(x: jnp.ndarray, axis: str) -> jnp.ndarray:
-    """Explicitly tag x as varying over ``axis`` (pcast on new jax,
-    pvary on older) — see _dropless_dispatch on why the implicit lift
-    at a varying-index gather is not sufficient."""
-    if hasattr(jax.lax, "pcast"):
-        return jax.lax.pcast(x, (axis,), to="varying")
-    return jax.lax.pvary(x, (axis,))
+    """Explicitly tag x as varying over ``axis`` — see
+    _dropless_dispatch on why the implicit lift at a varying-index
+    gather is not sufficient."""
+    return jax.lax.pcast(x, (axis,), to="varying")
 
 
 def _route_buffers(top_w: jnp.ndarray, top_i: jnp.ndarray, T: int, E: int,
@@ -1037,7 +1035,10 @@ class MoESlotServer(SpecDecodeMixin):
                  draft_layers_hook=None,
                  mesh=None, param_specs=None, draft_param_specs=None,
                  phase_timer=None):
-        from tpushare.models.serving import TokenSampler, make_placement
+        from tpushare.models.serving import (TokenSampler,
+                                             make_placement,
+                                             mesh_attn_impl)
+        attn_impl = mesh_attn_impl(mesh, attn_impl)
         # mesh: span a jax.sharding Mesh — expert stacks over ep,
         # per-expert GEMMs and attention heads over tp (param_specs;
         # int8 expert trees need quant.quant_moe_param_specs), dense
@@ -1758,10 +1759,7 @@ def make_adamw_spmd_train_step(cfg: MoEConfig, mesh, *, lr: float = 1e-3,
     (ep-sharded experts get ep-sharded moments for free). Same batch
     layout rules as make_spmd_train_step (routing='a2a' makes ep a
     data axis)."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     import functools as _ft
     from tpushare.models.training import adamw_init, opt_state_specs
     if cfg.n_experts % mesh.shape["ep"]:
@@ -1802,10 +1800,7 @@ def make_spmd_train_step(cfg: MoEConfig, mesh, *, lr: float = 1e-3):
     replicated across ep; under routing="a2a" ep is an additional data
     axis — the batch shards over ((dp, ep), sp) and the all_to_all
     exchange inside _moe_ffn carries tokens to their expert owners."""
-    try:
-        from jax import shard_map
-    except ImportError:  # pragma: no cover - older jax
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     import functools as _ft
     if cfg.n_experts % mesh.shape["ep"]:
         raise ValueError(f"ep={mesh.shape['ep']} must divide "

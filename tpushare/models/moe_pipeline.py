@@ -39,10 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from tpushare.models.moe import (
     MoEConfig, _moe_ffn, param_specs as moe_param_specs,
@@ -136,16 +133,10 @@ def moe_pipelined_lm_loss(params, inputs: jnp.ndarray,
         inflight = jax.lax.ppermute(act, pp_axis, perm)
         return inflight, outputs, aux_acc
 
-    vma = {pp_axis}
-    try:
-        vma |= set(jax.typeof(x_mb).vma)
-    except (AttributeError, TypeError):  # pragma: no cover - older jax
-        pass
+    vma = {pp_axis} | set(jax.typeof(x_mb).vma)
 
     def pvary(x):
-        if hasattr(jax.lax, "pcast"):
-            return jax.lax.pcast(x, tuple(vma), to="varying")
-        return x
+        return jax.lax.pcast(x, tuple(vma), to="varying")
 
     inflight0 = pvary(jnp.zeros((Bm, S, cfg.d_model), cfg.dtype))
     outputs0 = pvary(jnp.zeros((M, Bm, S, cfg.d_model), cfg.dtype))

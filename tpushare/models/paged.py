@@ -904,7 +904,9 @@ class PagedSlotServer(SpecDecodeMixin):
                  kv_quota=None):
         from tpushare.models.serving import (MultiLoraSlots,
                                              TokenSampler,
-                                             make_placement)
+                                             make_placement,
+                                             mesh_attn_impl)
+        attn_impl = mesh_attn_impl(mesh, attn_impl)
         # forward_fn: a transformer.forward-shaped callable with a
         # paged-cache branch — the family seam. moe.paged_forward here
         # serves the MoE LM over the SAME block pool, prefix cache,

@@ -28,10 +28,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from tpushare.models.transformer import (
     ParallelCtx, TransformerConfig, layer_windows,
@@ -93,15 +90,6 @@ def _block(x, layer, cfg: TransformerConfig, cos, sin, tp: Optional[str],
     return x + ff
 
 
-def _static_axis_size(axis: str) -> int:
-    """Mesh-axis size as a static int inside shard_map (the axis env
-    carries it; one copy of the older-jax fallback)."""
-    try:
-        return jax.lax.axis_size(axis)
-    except AttributeError:  # pragma: no cover - older jax
-        return int(jax.core.get_axis_env().axis_size(axis))
-
-
 def _local_layer_windows(cfg: TransformerConfig, pp_axis: str,
                          interleaved_v: Optional[int] = None):
     """This rank's per-layer sliding windows in STORAGE order ([L/P]
@@ -112,7 +100,7 @@ def _local_layer_windows(cfg: TransformerConfig, pp_axis: str,
     wls = layer_windows(cfg)
     if wls is None:
         return None
-    P_static = _static_axis_size(pp_axis)
+    P_static = jax.lax.axis_size(pp_axis)
     if interleaved_v is not None:
         wls = wls[jnp.asarray(
             interleaved_layer_order(cfg.n_layers, P_static, interleaved_v))]
@@ -199,16 +187,10 @@ def pipelined_lm_loss(params, inputs: jnp.ndarray, targets: jnp.ndarray,
 
     # Accumulator vma must match the loop outputs': the pipe axis plus
     # whatever the embedded microbatches vary over (dp, sp, ...).
-    vma = {pp_axis}
-    try:
-        vma |= set(jax.typeof(x_mb).vma)
-    except (AttributeError, TypeError):  # pragma: no cover - older jax
-        pass
+    vma = {pp_axis} | set(jax.typeof(x_mb).vma)
 
     def pvary(x):
-        if hasattr(jax.lax, "pcast"):
-            return jax.lax.pcast(x, tuple(vma), to="varying")
-        return x
+        return jax.lax.pcast(x, tuple(vma), to="varying")
 
     inflight0 = pvary(jnp.zeros((Bm, S, cfg.d_model), cfg.dtype))
     outputs0 = pvary(jnp.zeros((M, Bm, S, cfg.d_model), cfg.dtype))
@@ -259,14 +241,10 @@ class _ManualVJPShared:
         self.tied = cfg.tie_embeddings
         self.head_key = "embed" if self.tied else "unembed"
         self.params = params
-        self.P_static = _static_axis_size(pp_axis)
+        self.P_static = jax.lax.axis_size(pp_axis)
 
-        self.vma = {pp_axis}
-        try:
-            self.vma |= set(
-                jax.typeof(params["embed"][self.inputs_mb[0]]).vma)
-        except (AttributeError, TypeError):  # pragma: no cover - older jax
-            pass
+        self.vma = {pp_axis} | set(
+            jax.typeof(params["embed"][self.inputs_mb[0]]).vma)
 
         # CRITICAL: params that are replicated over pp/dp must be pcast
         # to varying BEFORE they enter a vjp. The vma-aware transpose
@@ -284,13 +262,7 @@ class _ManualVJPShared:
         self.tp_axis = tp_axis
 
     def pvary(self, x):
-        if not hasattr(jax.lax, "pcast"):
-            return x
-        try:
-            have = set(jax.typeof(x).vma)
-        except (AttributeError, TypeError):  # pragma: no cover
-            have = set()
-        missing = tuple(self.vma - have)
+        missing = tuple(self.vma - set(jax.typeof(x).vma))
         return jax.lax.pcast(x, missing, to="varying") if missing else x
 
     def chunk_fwd(self, x, lyrs, ws=None):

@@ -20,10 +20,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from tpushare.models.generate import sample_logits
 from tpushare.models.paged import PoolExhausted
@@ -252,6 +249,23 @@ def make_placement(mesh, cfg, param_specs=None, *, role: str = "target"):
     place = MeshPlacement(mesh, param_specs or default_param_specs(cfg))
     place.check(cfg, role=role)
     return place
+
+
+def mesh_attn_impl(mesh, attn_impl: str) -> str:
+    """The attention implementation a slot server spanning ``mesh`` may
+    dispatch to. The sharded forwards are plain jit over
+    NamedSharding-placed arrays (MeshPlacement), and JAX refuses to
+    lower a Mosaic kernel there: "Mosaic kernels cannot be
+    automatically partitioned. Please wrap the call in a shard_map."
+    The CPU lane never met this because the kernels are off on the
+    CPU; on the chip every eligible prefill and decode would raise it.
+    So over more than one device "auto" resolves to the XLA reference
+    paths, which GSPMD partitions like any other op. Running the
+    kernels per tp shard of the head axis (a shard_map round each
+    call, threaded through every copy of the block) is ROADMAP S7's."""
+    if mesh is not None and mesh.size > 1 and attn_impl == "auto":
+        return "reference"
+    return attn_impl
 
 
 def default_param_specs(cfg):

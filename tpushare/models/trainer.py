@@ -66,7 +66,8 @@ def fit(step_fn: StepFn, params: Any, opt_state: Any,
 
     Throughput telemetry: pass ``tokens_per_step`` to log tokens/sec
     over each log window (the loss read acts as the device sync), and
-    ``flops_per_step`` (+ optional ``tpu_generation``) to log MFU via
+    ``flops_per_step`` with ``tpu_generation`` (the chip whose peak MFU
+    divides by; without it no MFU is logged) to log MFU via
     utils/profiling — e.g. profiling.transformer_flops(cfg, B, S,
     training=True) for a train step with GLOBAL batch B. MFU divides
     by ``n_chips`` x one chip's peak (0 = len(jax.devices()), the
@@ -92,10 +93,11 @@ def fit(step_fn: StepFn, params: Any, opt_state: Any,
             if warmed and tokens_per_step and dt > 0 and window_steps:
                 msg += (f" | {tokens_per_step * window_steps / dt:,.0f}"
                         f" tok/s")
-            if warmed and flops_per_step and dt > 0 and window_steps:
+            if (warmed and flops_per_step and tpu_generation
+                    and dt > 0 and window_steps):
                 from tpushare.utils import profiling
                 m = profiling.mfu(flops_per_step, dt / window_steps,
-                                  tpu_generation or "v5e",
+                                  tpu_generation,
                                   n_chips=n_chips or len(jax.devices()))
                 if m is not None:
                     msg += f" | mfu {100 * m:.1f}%"

@@ -24,10 +24,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:
-    from jax import shard_map
-except ImportError:  # pragma: no cover - older jax
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from tpushare.ops.attention import NEG_INF, _expand_kv, window_keep
 
@@ -147,15 +144,10 @@ def ring_attention(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray, *,
     # e.g. the model's dp×sp×tp training step).
     vma: set = {axis_name}
     for arr in (q, k, v):
-        try:
-            vma |= set(jax.typeof(arr).vma)
-        except (AttributeError, TypeError):  # pragma: no cover - older jax
-            pass
+        vma |= set(jax.typeof(arr).vma)
 
     def pvary(x):
-        if hasattr(jax.lax, "pcast"):
-            return jax.lax.pcast(x, tuple(vma), to="varying")
-        return getattr(jax.lax, "pvary", lambda a, _: a)(x, tuple(vma))
+        return jax.lax.pcast(x, tuple(vma), to="varying")
 
     acc0 = pvary(jnp.zeros((B, H, Sq, D), jnp.float32))
     m0 = pvary(jnp.full((B, H, Sq, 1), NEG_INF, jnp.float32))

@@ -49,6 +49,22 @@ _DEFAULT_HBM = {"v5e": 16 * _GIB, "v5p": 95 * _GIB, "v4": 32 * _GIB, "v6e": 32 *
 _DEFAULT_CORES = {"v5e": 1, "v5p": 2, "v4": 2, "v6e": 1}
 
 
+def generation_from_kind(device_kind: str) -> str:
+    """TPU generation ("v5e", ...) from a PJRT ``device_kind`` ("TPU v5
+    lite"). An unknown kind is an error, never a default: every table
+    keyed by generation (HBM, cores, peak FLOP/s, HBM bandwidth) would
+    otherwise answer for a chip that is not the one in the machine."""
+    kind = device_kind.lower().replace(" ", "")
+    for gen in ("v6e", "v5p", "v5e", "v4"):
+        if gen in kind:
+            return gen
+    for alias, gen in (("v5lite", "v5e"), ("v6lite", "v6e"), ("tpuv5", "v5p")):
+        if alias in kind:
+            return gen
+    raise ValueError(f"unknown TPU device_kind {device_kind!r} "
+                     f"(known generations: {sorted(_DEFAULT_HBM)})")
+
+
 @dataclass(frozen=True)
 class Chip:
     """One physical TPU chip on this host."""
@@ -285,12 +301,17 @@ def build_topology_from_facts(indices: Sequence[int],
     """One assembly path for discovered chip facts, shared by the native
     (nativedisc) and pure-Python sysfs probes so both emit identical
     uuids/HBM/mesh for the same host. Priority: detected generation >
-    caller hint > v5e default."""
-    gen = generation or generation_hint or "v5e"
+    caller hint; neither (or one the tables do not know) is an error —
+    a guessed generation would advertise another chip's HBM."""
+    gen = generation or generation_hint or ""
+    if gen not in _DEFAULT_HBM:
+        raise RuntimeError(
+            f"cannot tell the TPU generation of {list(device_paths or indices)} "
+            f"(detected {generation!r}, hint {generation_hint!r}; known: "
+            f"{sorted(_DEFAULT_HBM)})")
     count = len(indices)
     return _build_topology(gen, count, _default_mesh(count),
-                           _DEFAULT_HBM.get(gen, 16 * _GIB),
-                           _DEFAULT_CORES.get(gen, 1),
+                           _DEFAULT_HBM[gen], _DEFAULT_CORES[gen],
                            uuid_prefix=f"tpu-{gen}-{_host_id()}",
                            numa_nodes=list(numa_nodes), indices=list(indices),
                            device_paths=(list(device_paths) if device_paths
@@ -388,18 +409,11 @@ class JaxBackend(Backend):
         devs = [d for d in jax.devices() if d.platform == "tpu"]
         if not devs:
             raise RuntimeError("no TPU devices visible to JAX")
-        gen = getattr(devs[0], "device_kind", "tpu").lower()
-        gen = {"tpu v5 lite": "v5e", "tpu v5": "v5p", "tpu v4": "v4",
-               "tpu v6 lite": "v6e"}.get(gen, re.sub(r"[^a-z0-9]+", "", gen) or "tpu")
-        hbm_per_chip = []
-        for d in devs:
-            try:
-                hbm_per_chip.append(int(d.memory_stats()["bytes_limit"]))
-            except Exception:
-                hbm_per_chip.append(_DEFAULT_HBM.get(gen, 16 * _GIB))
+        gen = generation_from_kind(devs[0].device_kind)
+        hbm_per_chip = [int(d.memory_stats()["bytes_limit"]) for d in devs]
         count = len(devs)
         return _build_topology(gen, count, _default_mesh(count), hbm_per_chip[0],
-                               _DEFAULT_CORES.get(gen, 1),
+                               _DEFAULT_CORES[gen],
                                uuid_prefix=f"tpu-{gen}-{_host_id()}",
                                hbm_per_chip=hbm_per_chip)
 

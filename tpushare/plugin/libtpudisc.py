@@ -33,7 +33,8 @@ import subprocess
 from typing import Optional
 
 from tpushare.plugin.backend import (Backend, Chip, HostTopology,
-                                     _DEFAULT_CORES, _DEFAULT_HBM, _host_id)
+                                     _DEFAULT_CORES, _DEFAULT_HBM, _host_id,
+                                     generation_from_kind)
 
 log = logging.getLogger("tpushare.libtpudisc")
 
@@ -44,16 +45,6 @@ _HELPER_CANDIDATES = (
         os.path.dirname(os.path.abspath(__file__)))), "native", "pjrtdisc"),
     "/usr/local/bin/pjrtdisc",
 )
-
-
-def _generation(device_kind: str) -> str:
-    kind = device_kind.lower().replace(" ", "")
-    for gen in ("v6e", "v5p", "v5e", "v4"):
-        if gen in kind:
-            return gen
-    if "v5lite" in kind:
-        return "v5e"
-    return "v5e"
 
 
 def find_helper() -> Optional[str]:
@@ -117,7 +108,10 @@ class LibtpuBackend(Backend):
         except json.JSONDecodeError as e:
             raise RuntimeError(f"libtpu probe emitted bad JSON: {e}")
 
-        gen = _generation(data.get("device_kind", ""))
+        try:
+            gen = generation_from_kind(data.get("device_kind", ""))
+        except ValueError as e:
+            raise RuntimeError(f"libtpu probe: {e}")
         raw = data.get("chips", [])
         if not raw:
             raise RuntimeError("libtpu probe saw zero chips")
@@ -130,14 +124,14 @@ class LibtpuBackend(Backend):
         for i, c in enumerate(raw):
             hbm = int(c.get("hbm_bytes") or 0)
             if hbm <= 0:
-                hbm = _DEFAULT_HBM.get(gen, 16 << 30)
+                hbm = _DEFAULT_HBM[gen]
             coords = tuple(c.get("coords", [i, 0, 0]))
             idx = int(c.get("index", i))
             chips.append(Chip(
                 index=idx,
                 uuid=f"tpu-{gen}-{_host_id()}-{idx}",
                 hbm_bytes=hbm,
-                cores=int(c.get("cores") or _DEFAULT_CORES.get(gen, 1)),
+                cores=int(c.get("cores") or _DEFAULT_CORES[gen]),
                 coords=coords,
                 # Allocate injects this as the tenant's DeviceSpec; the
                 # PJRT probe doesn't report node paths, so use the same
