@@ -168,6 +168,36 @@ class TestOneTransferPerTick:
         assert int(jax.device_get(srv.lengths)[s]) == 8
         assert int(srv._lengths_np[s]) == 8
 
+    @pytest.mark.parametrize("family", ("paged", "dense-rows", "moe-rows"))
+    def test_device_mask_is_a_copy_of_the_host_mirror(self, family):
+        """On the CPU backend ``jnp.asarray`` aliases a numpy buffer
+        that happens to be 64-byte aligned (half of all allocations),
+        and the servers flip ``active`` in place while a dispatch that
+        reads the device mask may still be in flight: the retirement
+        test above and the overlapped tick's bit-exactness failed now
+        and then for it. The mirror is uploaded by copy; pinned here on
+        a host mirror that IS aligned."""
+        if family == "paged":
+            srv = PagedSlotServer(TF_PARAMS, TF_CFG, n_slots=2,
+                                  n_blocks=32, block_size=4)
+        elif family == "dense-rows":
+            srv = SlotServer(TF_PARAMS, TF_CFG, n_slots=2, max_len=64)
+        else:
+            srv = moe.MoESlotServer(MOE_PARAMS, MOE_CFG, n_slots=2,
+                                    max_len=64)
+        raw = np.zeros(srv.active.size + 64, np.uint8)
+        off = -raw.ctypes.data % 64
+        srv.active = raw[off:off + srv.active.size].view(bool)
+        vocab = (MOE_CFG if family == "moe-rows" else TF_CFG).vocab_size
+        slot = srv.admit(_prompt(1, 6, vocab))
+        dev = srv._active_dev
+        assert bool(dev[slot])
+        srv.active[slot] = False            # what retirement does
+        assert bool(dev[slot]), "the device mask aliases the host mirror"
+        srv.active[slot] = True
+        srv.evict(slot)
+        assert not bool(srv._active_dev[slot])
+
 
 class TestFusedKernelPathSyncFree:
     """ISSUE 12: the fused int8 expert path (quant.fused_expert_hook
@@ -650,7 +680,18 @@ class TestTieredTickSyncFree:
                            block_size=8, prefill_chunk=8,
                            tick_token_budget=16, **kw)
 
-    def test_mixed_tier_ticks_one_transfer(self):
+    @pytest.mark.parametrize("profiled", (False, True),
+                             ids=("untraced", "profiler-on"))
+    def test_mixed_tier_ticks_one_transfer(self, profiled, tmp_path):
+        """``profiled``: under a jax.profiler session the engine's
+        spans are live (tpushare.utils.profiling.span) — they must add
+        no transfer either."""
+        from tpushare.utils.profiling import trace
+        with (trace(str(tmp_path)) if profiled
+              else contextlib.nullcontext()):
+            self._mixed_tier_ticks_one_transfer()
+
+    def _mixed_tier_ticks_one_transfer(self):
         from tpushare.cli.serve import _Request
         from tpushare.slo import TenantQuotaSpec
         eng = self._engine(

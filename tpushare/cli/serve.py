@@ -158,6 +158,28 @@ TPUSHARE_OWNERSHIP = {
 # clamps smaller values unless --prefill-chunk-force is passed.
 PREFILL_CHUNK_FLOOR = 512
 
+#: The engine thread's loop as stages, in the overlapped tick's order;
+#: a moment of the thread is in at most one, and what is in none is the
+#: remainder against its wall clock. Each is a ``tpushare.engine.<name>``
+#: span in a profiler session and a clock in /stats ``engine_thread_ms``.
+#:   preamble  chaos points, gang poll, capacity pre-reap
+#:   admit     one admission that popped a request (prefill included);
+#:             it waits for the device too: the eager pool scatter
+#:             returns when the prefill has run, the lookup reads the
+#:             prompt back, and the first token is fetched here
+#:   finalize  the deferred token fetch: the host waits for the device
+#:             (overlapped tick only)
+#:   apply     NaN scan, emission, completion, reap
+#:   schedule  admission pick, cancelled reap, budget alternation
+#:   dispatch  step_async / admit_step; in the serial tick srv.step(), so
+#:             there it contains the fetch, as an admit_step that
+#:             completes a prompt contains the first token's
+#:   plan      the next tick's pick, inside this tick's device window
+#:   journal   TOKENS records and the flush policy (journaled engines)
+#:   idle      the idle sleep
+ENGINE_STAGES = ("preamble", "admit", "finalize", "apply", "schedule",
+                 "dispatch", "plan", "journal", "idle")
+
 
 def _np_dtype(name: str):
     """Resolve a wire dtype name to numpy, falling through to
@@ -209,6 +231,11 @@ class _Request:
         self.tier = tier
         self.tenant = tenant
         self.t_submit = time.monotonic()
+        # When the admission that first PLACED the request began (a
+        # held or re-queued pop does not stamp it; a replay keeps the
+        # first life's): t_submit..t_admit is queue wait, t_admit..
+        # t_first the admission itself.
+        self.t_admit: Optional[float] = None
         self.t_first: Optional[float] = None    # first pushed token
         self.t_last: Optional[float] = None     # newest pushed token
         self.tokens: List[int] = []
@@ -744,6 +771,14 @@ class ServeEngine:
                        # and wedge-watchdog hard restarts.
                        "recovered_requests": 0, "dedup_hits": 0,
                        "resumed_streams": 0, "wedge_escalations": 0,
+                       # The client's first-token time split on the
+                       # engine's own clock: submit -> the admission
+                       # that placed the request (queue wait), and
+                       # that -> its first token (whole prompt) or
+                       # completing chunk (chunked). Once a request,
+                       # at its first life.
+                       "queue_wait_ms_sum": 0.0, "queue_wait_n": 0,
+                       "admit_ms_sum": 0.0, "admit_n": 0,
                        # Monotonic engine-loop iterations (idle ticks
                        # included): the router's liveness-of-the-loop
                        # signal — a wedged engine's ticks stop
@@ -751,6 +786,11 @@ class ServeEngine:
                        # mean "idle".
                        "ticks": 0}
         self._engine_t0 = time.monotonic()
+        # The engine thread's loop cut into stages (ENGINE_STAGES): a
+        # span each when someone traces, a cumulative clock each
+        # always. /stats engine_thread_ms / engine_thread_n.
+        from tpushare.utils.profiling import StageClock
+        self._clock = StageClock("engine", ENGINE_STAGES)
         # Typed transient-pressure exception (lazy-bound like every
         # other jax-adjacent import in this module): the admission and
         # preemption paths catch EXACTLY this — any other runtime
@@ -1766,6 +1806,7 @@ class ServeEngine:
         srv = self.srv
         jst = (self._journal.stats()
                if self._journal is not None else None)
+        clock = self._clock.snapshot()
         out = dict(self._stats)
         out.update({
             "active_slots": self.active_count(),
@@ -1918,6 +1959,18 @@ class ServeEngine:
                                  if self._overlap_tick else None),
             "host_gap_ms": (_gap_percentiles(list(self._host_gap_ms))
                             if self._overlap_tick else None),
+            # The engine thread's stage clocks (ENGINE_STAGES),
+            # cumulative from the engine's start like every counter:
+            # ms spent in and entries of each stage. Their sum against
+            # uptime is the thread's un-staged remainder. In serial
+            # mode ``dispatch`` contains the fetch and ``finalize``
+            # stays 0. ``finalize`` is not the only wait for the
+            # device: ``admit`` holds the prefill's (its eager pool
+            # scatter and first-token fetch block), so the stages but
+            # ``finalize`` and ``idle`` bound the host's work from
+            # above.
+            "engine_thread_ms": clock["ms"],
+            "engine_thread_n": clock["n"],
             # Host KV offload tier (ISSUE 18). Null-not-0 when no
             # tier is configured: an engine without a tier has no
             # offload plane, not an idle one — the router reads null
@@ -2017,6 +2070,16 @@ class ServeEngine:
             # container; _popped keeps drain()'s idle check honest
             # across the prefill (handoff atomic under _pop_lock).
             self._popped = req
+        with self._clock.stage(
+                "admit", rid=req.request_id,
+                prompt_tokens=len(req.prompt)) as sp:
+            return self._admit_guarded(req, sp)
+
+    def _admit_guarded(self, req: _Request, sp) -> bool:
+        """One popped request through admission, with the failure
+        domain of a device fault mid-admission around it. ``sp`` is
+        the admission's span (it learns ``chunked`` and
+        ``cached_tokens`` when they are known)."""
         try:
             if (int(self.srv.active.sum()) + self.srv.admitting_count
                     >= self.srv.cache.n_slots):
@@ -2029,7 +2092,7 @@ class ServeEngine:
                         req.tier, self._sched.specs)):
                     self._sched.push_front(req)
                     return False
-            return self._admit_popped(req)
+            return self._admit_popped(req, sp)
         except Exception as e:
             # A device/runtime failure mid-admission (an
             # XlaRuntimeError out of a prefill chunk or the first
@@ -2074,12 +2137,14 @@ class ServeEngine:
             with self._pop_lock:
                 self._popped = None
 
-    def _admit_popped(self, req: _Request) -> bool:
+    def _admit_popped(self, req: _Request, sp) -> bool:
         import jax.numpy as jnp
+        from tpushare.utils.profiling import span
         srv = self.srv
         if req.cancelled:               # client gave up while queued
             req.finish()
             return True
+        t_admit = time.monotonic()
         chunked = (self._prefill_chunk is not None
                    and len(req.prompt) > self._prefill_chunk)
         self._fault_admit()
@@ -2174,6 +2239,15 @@ class ServeEngine:
             # preempted to free them NOW; a 503 here would reject a
             # backlog admittable moments later.
             return self._hold_or_preempt(req)
+        # Placed. Queue wait ends where this admission began — once a
+        # request: a replay or a preempted victim keeps its first life's.
+        if req.t_admit is None:
+            req.t_admit = t_admit
+            self._stats["queue_wait_ms_sum"] += (
+                t_admit - req.t_submit) * 1e3
+            self._stats["queue_wait_n"] += 1
+        sp.set_metadata(chunked=int(chunked),
+                        cached_tokens=int(srv.last_cached_len))
         if chunked:
             req.cached_prefix = srv.last_cached_len
             self._seq += 1
@@ -2188,7 +2262,8 @@ class ServeEngine:
         self._tier_stats.bump(req.tier, "admitted")
         # The token sampled from the prompt's last logits is the first
         # emitted token (it is already the slot's pending last_token).
-        first = int(self.srv.last_token[slot, 0])
+        with span("slot.admit.first_token"):
+            first = int(self.srv.last_token[slot, 0])
         if self._tok_bad(first):
             # NaN logits at prefill (the sampler picked -1): same
             # slot-scoped failure domain as a poisoned decode tick.
@@ -2228,6 +2303,10 @@ class ServeEngine:
         if first:
             self._tier_stats.record_first_token(
                 req.tier, (req.t_first - req.t_submit) * 1e3)
+            if req.t_admit is not None:
+                self._stats["admit_ms_sum"] += (
+                    req.t_first - req.t_admit) * 1e3
+                self._stats["admit_n"] += 1
 
     def _preempt_one(self, below_rank: Optional[int] = None,
                      reserve_for: Optional[str] = None) -> bool:
@@ -2410,7 +2489,9 @@ class ServeEngine:
                 # generation's in-flight timestamp or flush its
                 # half-batched journal records.
                 self._tick_started = None
-                self._journal_tick_end()
+                if self._journal is not None:
+                    with self._clock.stage("journal"):
+                        self._journal_tick_end()
             if self._tick_deadline_ms is not None:
                 dt_ms = (time.monotonic() - t0) * 1e3
                 if dt_ms > self._tick_deadline_ms:
@@ -2737,24 +2818,20 @@ class ServeEngine:
                 self._unpark_tenant(req.tenant)
                 req.finish()
 
-    def _pick_admission(self) -> Optional[int]:
+    def _pick_admission_planned(self) -> Optional[int]:
         """The ONE admitting slot this tick advances, reaping
         cancelled admissions on the way; None when no admission is in
         flight. Tier-aware (slo.TickScheduler.pick_admission): an
         at-risk interactive admission always advances, otherwise
         tiers take weighted turns — oldest first within a tier, which
         is exactly the old oldest-first behavior when every admission
-        shares one tier."""
-        self._reap_cancelled_admissions()
-        return self._sched.pick_admission(self._admitting)
-
-    def _pick_admission_planned(self) -> Optional[int]:
-        """Overlap-mode admission pick: commit the choice precomputed
-        inside the last overlap window iff the admitting set is
-        unchanged (slot+seq identity), else recompute fresh. Either
-        way the committed rotation state matches what a fresh
-        pick_admission would have left — the plan only moves the host
-        arithmetic into the device window."""
+        shares one tier. The overlapped tick commits the choice
+        precomputed inside its last device window iff the admitting
+        set is unchanged (slot+seq identity), else recomputes fresh
+        (the serial tick has no plan and always does). Either way the
+        committed rotation state matches what a fresh pick_admission
+        would have left — the plan only moves the host arithmetic
+        into the device window."""
         self._reap_cancelled_admissions()
         plan, self._next_pick_plan = self._next_pick_plan, None
         if plan is not None and plan["admitting"] == tuple(sorted(
@@ -2817,21 +2894,23 @@ class ServeEngine:
         the token-budget alternation. The tick budget caps this chunk
         too (an admission-only tick must not smuggle a full unbounded
         chunk past the latency bound the budget promises)."""
-        self._fault_forward()       # chaos: this tick's model forward
-        self._check_superseded(gen)  # wedge hang fired above: abort
-        f0 = self.srv.device_fetches
-        tok = self.srv.admit_step(
-            slot, max_chunk_tokens=self._tick_token_budget or None)
-        self._stats["device_fetches"] += self.srv.device_fetches - f0
-        self._stats["model_forwards"] += 1
-        self._stats["work_ticks"] += 1
+        with self._clock.stage("dispatch"):
+            self._fault_forward()   # chaos: this tick's model forward
+            self._check_superseded(gen)  # wedge hang fired above: abort
+            f0 = self.srv.device_fetches
+            tok = self.srv.admit_step(
+                slot, max_chunk_tokens=self._tick_token_budget or None)
+            self._stats["device_fetches"] += self.srv.device_fetches - f0
+            self._stats["model_forwards"] += 1
+            self._stats["work_ticks"] += 1
         if tok is None:
             return
-        if self._tok_bad(tok):
-            self._quarantine_slot(slot, self._admitting,
-                                  "NaN token (poisoned prefill)")
-            return
-        self._complete_admission(slot, tok)
+        with self._clock.stage("apply"):
+            if self._tok_bad(tok):
+                self._quarantine_slot(slot, self._admitting,
+                                      "NaN token (poisoned prefill)")
+                return
+            self._complete_admission(slot, tok)
 
     def _tick(self, gen: Optional[int] = None) -> None:
         if self._overlap_tick:
@@ -2843,47 +2922,103 @@ class ServeEngine:
         """The pre-pipeline tick: schedule, dispatch, and fetch in one
         sequential pass. ``--overlap-tick off`` routes here — the
         fallback the overlapped mode must stay bit-exact against."""
-        if self._mesh_configured is not None:
-            self._fire_chip_chaos()
-            self._fire_host_chaos()
-            self._poll_gang()
-            if self._mesh_fault is not None:
-                # A chip- or host-health event landed since the last
-                # tick (POST /mesh/chip, /mesh/host, or a liaison
-                # verdict): degrade proactively, before any dispatch
-                # touches the dead shards.
-                self._reshard(self._mesh_fault)
-                return
-        admitted = True
-        while admitted and self._mesh_fault is None:
-            admitted = self._try_admit()    # drain as slots allow
-        if self._mesh_fault is not None:
-            # An admission dispatch flagged a mesh fault mid-drain:
-            # reshard NOW, before another pop lands on the broken
-            # placement; the replayed requests re-admit next tick on
-            # the rebuilt mesh.
-            self._reshard(self._mesh_fault)
+        if not self._preamble_and_admit():
             return
-        work = self._pick_admission()
-        if not self._active:
-            # No decode batch to fuse into: serial admission (one
-            # chunk per tick) is the fast path.
-            if work is not None:
-                self._advance_one_admission(work, gen)
-            elif not self._admitting:
-                if self._maybe_grow_back():
-                    return
-                time.sleep(self._idle_sleep_s)
+        with self._clock.stage("schedule"):
+            plan = self._schedule(finalized=False)
+        if plan is None or self._run_unbatched(plan, gen):
             return
-        # Reap cancelled (timed-out) requests before paying for a step.
-        for slot in [s for s, r in self._active.items() if r.cancelled]:
-            self._maybe_finish(slot, -1)
-        if not self._active:
-            return
+        _, work, room = plan
         # Fused tick: the admission's next chunk rides the decode
         # batch's forward (exactly one model forward — and still one
         # device->host transfer — per tick). `room` caps the chunk so
         # decode-rows + chunk tokens stay within the tick budget.
+        with self._clock.stage("dispatch"):
+            self._fault_forward()   # chaos: this tick's model forward
+            self._check_superseded(gen)  # wedge hang fired above: abort
+            f0 = self.srv.device_fetches
+            try:
+                out = (self.srv.step(prefill_work=work,
+                                     max_chunk_tokens=room)
+                       if work is not None else self.srv.step())
+            except (self._pool_exhausted, self._slot_cap_exceeded) as e:
+                if not self._shed_at_dispatch(e):
+                    raise
+                return
+            self._stats["steps"] += 1
+            self._stats["device_fetches"] += self.srv.device_fetches - f0
+            self._stats["model_forwards"] += 1
+            self._stats["work_ticks"] += 1
+            if work is not None:
+                self._stats["fused_ticks"] += 1
+        with self._clock.stage("apply"):
+            self._apply_step_output(out, work)
+
+    def _preamble_and_admit(self) -> bool:
+        """The head both ticks share: chaos points and the proactive
+        mesh degrade, the overlapped tick's capacity pre-reap, then
+        the admission drain (as slots allow). False when a mesh fault
+        resharded instead: the tick is over — and the in-flight
+        dispatch, as suspect as whatever flagged the fault, was
+        dropped unfetched (its answers may straddle the dead shards;
+        replay regenerates its tokens)."""
+        with self._clock.stage("preamble"):
+            if self._mesh_configured is not None:
+                self._fire_chip_chaos()
+                self._fire_host_chaos()
+                self._poll_gang()
+                if self._mesh_fault is not None:
+                    # A chip- or host-health event landed since the
+                    # last tick (POST /mesh/chip, /mesh/host, or a
+                    # liaison verdict): degrade proactively, before
+                    # any dispatch touches the dead shards.
+                    self._flush_pipeline()
+                    self._reshard(self._mesh_fault)
+                    return False
+            # Before the drain can hand a capacity-retired in-flight
+            # slot to a new request.
+            self._prereap_retired()
+        return self._drain_admissions()
+
+    def _drain_admissions(self) -> bool:
+        """Admit as slots allow. False when an admission dispatch
+        flagged a mesh fault mid-drain: reshard NOW, before another
+        pop lands on the broken placement; the replayed requests
+        re-admit next tick on the rebuilt mesh."""
+        admitted = True
+        while admitted and self._mesh_fault is None:
+            admitted = self._try_admit()
+        if self._mesh_fault is not None:
+            self._flush_pipeline()
+            self._reshard(self._mesh_fault)
+            return False
+        return True
+
+    def _schedule(self, finalized: bool):
+        """What this tick dispatches, decided on serial-equivalent
+        state (the previous tick is fully applied), for both ticks:
+        ``("admit", slot, None)`` a serial admission chunk with its
+        own forward — the no-active-decodes fast path, and the
+        decode-starved half of the token-budget alternation;
+        ``("step", work, room)`` a decode step, ``work`` the admitting
+        slot whose next chunk (capped at ``room`` tokens) rides it;
+        ``("idle", None, None)``; or None: nothing this tick.
+        ``finalized`` (overlapped tick only) gates the serial
+        admission forward: a tick that already paid the finalize fetch
+        defers it one tick, keeping the one-fetch-per-tick invariant
+        airtight instead of merely average."""
+        work = self._pick_admission_planned()
+        if not self._active:
+            if work is not None:
+                return None if finalized else ("admit", work, None)
+            if self._admitting or self._maybe_grow_back():
+                return None
+            return ("idle", None, None)
+        # Reap cancelled (timed-out) requests before paying for a step.
+        for slot in [s for s, r in self._active.items() if r.cancelled]:
+            self._maybe_finish(slot, -1)
+        if not self._active:
+            return None
         room = None
         if work is not None and self._tick_token_budget:
             room = self._tick_token_budget - len(self._active)
@@ -2897,51 +3032,59 @@ class ServeEngine:
                 choice = self._sched.alternation(self._admitting[work],
                                                  self._active)
                 if choice is None:
+                    if finalized and self._admit_turn:
+                        # Admission's turn, but this tick already paid
+                        # the finalize fetch: hold the turn untoggled
+                        # and run the chunk next tick (which dispatches
+                        # nothing else).
+                        return None
                     choice = "admit" if self._admit_turn else "decode"
                     self._admit_turn = not self._admit_turn
                 if choice == "admit":
-                    self._advance_one_admission(work, gen)
-                    return
+                    # finalized: the at-risk claim stands next tick
+                    return None if finalized else ("admit", work, None)
                 work, room = None, None
-        self._fault_forward()       # chaos: this tick's model forward
-        self._check_superseded(gen)  # wedge hang fired above: abort
-        f0 = self.srv.device_fetches
-        try:
-            out = (self.srv.step(prefill_work=work,
-                                 max_chunk_tokens=room)
-                   if work is not None else self.srv.step())
-        except self._pool_exhausted as e:
-            # Pool exhausted by concurrent decode growth (admission does
-            # not reserve max_tokens worth of blocks, by design — that
-            # would waste most of the pool). Shed ONE victim and retry
-            # next tick rather than 503ing every in-flight request.
-            # Typed catch: any OTHER RuntimeError is a device/runtime
-            # failure and belongs to the quarantine path in _loop.
-            if self._preempt_one():
-                self._stats["engine_errors"] += 1
-                self._stats["last_error"] = f"preempt: {e}"
-                return
-            raise
-        except self._slot_cap_exceeded as e:
-            # ONE slot's block table is full: a per-slot ceiling, not
-            # a device fault. Retire exactly that request at its
-            # tokens-so-far (the paged analog of dense max_len
-            # retirement) — preempting or quarantining the batch over
-            # one sequence's ceiling would punish the innocents.
+        return ("step", work, room)
+
+    def _run_unbatched(self, plan, gen: Optional[int]) -> bool:
+        """The two plans that need no decode batch: a serial admission
+        chunk, or the idle sleep. False for a ``step`` plan."""
+        kind, work, _ = plan
+        if kind == "admit":
+            self._advance_one_admission(work, gen)
+        elif kind == "idle":
+            with self._clock.stage("idle"):
+                time.sleep(self._idle_sleep_s)
+        return kind != "step"
+
+    def _shed_at_dispatch(self, e: Exception) -> bool:
+        """Pool pressure raised host-side at dispatch, before anything
+        is in flight; False where it could not be shed and the caller
+        re-raises. Typed: any OTHER RuntimeError is a
+        device/runtime failure and belongs to the quarantine path in
+        _loop_once. PoolExhausted — concurrent decode growth
+        (admission does not reserve max_tokens worth of blocks, by
+        design: that would waste most of the pool): shed ONE victim
+        and retry next tick rather than 503ing every in-flight
+        request. SlotCapacityExceeded — ONE slot's block table is
+        full, a per-slot ceiling and not a device fault: retire
+        exactly that request at its tokens-so-far (the paged analog of
+        dense max_len retirement); preempting or quarantining the
+        batch over one sequence's ceiling would punish the
+        innocents."""
+        if isinstance(e, self._slot_cap_exceeded):
             req = self._active.pop(e.slot, None)
             self._safe_evict(e.slot)
             self._stats["last_error"] = str(e)
-            if req is not None:
-                self._finish_completed(req)
-                return
-            raise                       # not ours: a real engine bug
-        self._stats["steps"] += 1
-        self._stats["device_fetches"] += self.srv.device_fetches - f0
-        self._stats["model_forwards"] += 1
-        self._stats["work_ticks"] += 1
-        if work is not None:
-            self._stats["fused_ticks"] += 1
-        self._apply_step_output(out, work)
+            if req is None:
+                return False            # not ours: a real engine bug
+            self._finish_completed(req)
+            return True
+        if not self._preempt_one():
+            return False
+        self._stats["engine_errors"] += 1
+        self._stats["last_error"] = f"preempt: {e}"
+        return True
 
     def _apply_step_output(self, out, work: Optional[int],
                            retired=None) -> None:
@@ -3059,28 +3202,7 @@ class ServeEngine:
                            _PendingTick, then precompute the next
                            pick inside the freshly opened window
         """
-        if self._mesh_configured is not None:
-            self._fire_chip_chaos()
-            self._fire_host_chaos()
-            self._poll_gang()
-            if self._mesh_fault is not None:
-                # A chip- or host-health event landed since the last
-                # tick: degrade proactively — and drop the in-flight
-                # dispatch unfetched (its answers may straddle the
-                # dead shards; replay regenerates its tokens).
-                self._flush_pipeline()
-                self._reshard(self._mesh_fault)
-                return
-        self._prereap_retired()
-        admitted = True
-        while admitted and self._mesh_fault is None:
-            admitted = self._try_admit()    # drain as slots allow
-        if self._mesh_fault is not None:
-            # An admission dispatch flagged a mesh fault mid-drain:
-            # reshard NOW — the in-flight dispatch is as suspect as
-            # the admission that failed.
-            self._flush_pipeline()
-            self._reshard(self._mesh_fault)
+        if not self._preamble_and_admit():
             return
         q0 = self._stats["quarantines"]
         finalized = self._finalize_pending()
@@ -3093,12 +3215,7 @@ class ServeEngine:
             # quarantined: a replayed request re-admits at the NEXT
             # tick's drain, keeping the recovery tick itself at the
             # one transfer the sync-free invariant allows.
-            admitted = True
-            while admitted and self._mesh_fault is None:
-                admitted = self._try_admit()
-            if self._mesh_fault is not None:
-                self._flush_pipeline()
-                self._reshard(self._mesh_fault)
+            if not self._drain_admissions():
                 return
         self._schedule_and_dispatch(gen, finalized)
 
@@ -3137,135 +3254,94 @@ class ServeEngine:
             # — drop it unfetched.
             self._pipeline_flushes += 1
             return False
-        stale = frozenset(
-            s for s, req in pend.slot_reqs.items()
-            if (self._active.get(s) is not req
-                and self._admitting.get(s) is not req
-                and s not in pend.retired))
-        f1 = self.srv.device_fetches
-        try:
-            out = pend.step.finalize(stale)
-        except BaseException:
-            # The deferred fetch surfaced the dispatch's device fault.
-            # Pre-reaped retired rows live in no store the quarantine
-            # sweep can see — replay them here, then let the fault
-            # take the normal quarantine path for everyone else.
-            for req in pend.retired.values():
-                self._stats["quarantines"] += 1
-                self._tier_stats.bump(req.tier, "quarantined")
-                self._unpark_tenant(req.tenant)
-                self._replay_or_503(req,
-                                    "device fault at pipeline finalize")
-            raise
-        self._stats["steps"] += 1
-        # Fetch accounting joins the two halves of the split tick:
-        # the dispatch-side delta (zero on the async path; the eager
-        # monkeypatch fallback pays there) plus the finalize fetch —
-        # admission transfers in between stay excluded, exactly as
-        # the serial tick excludes them.
-        self._stats["device_fetches"] += (
-            pend.dispatch_fetches + (self.srv.device_fetches - f1))
-        self._apply_step_output(out, pend.work, retired=pend.retired)
+        with self._clock.stage("finalize"):
+            stale = frozenset(
+                s for s, req in pend.slot_reqs.items()
+                if (self._active.get(s) is not req
+                    and self._admitting.get(s) is not req
+                    and s not in pend.retired))
+            f1 = self.srv.device_fetches
+            try:
+                out = pend.step.finalize(stale)
+            except BaseException:
+                # The deferred fetch surfaced the dispatch's device
+                # fault. Pre-reaped retired rows live in no store the
+                # quarantine sweep can see — replay them here, then
+                # let the fault take the normal quarantine path for
+                # everyone else.
+                for req in pend.retired.values():
+                    self._stats["quarantines"] += 1
+                    self._tier_stats.bump(req.tier, "quarantined")
+                    self._unpark_tenant(req.tenant)
+                    self._replay_or_503(
+                        req, "device fault at pipeline finalize")
+                raise
+        with self._clock.stage("apply"):
+            self._stats["steps"] += 1
+            # Fetch accounting joins the two halves of the split tick:
+            # the dispatch-side delta (zero on the async path; the
+            # eager monkeypatch fallback pays there) plus the finalize
+            # fetch — admission transfers in between stay excluded,
+            # exactly as the serial tick excludes them.
+            self._stats["device_fetches"] += (
+                pend.dispatch_fetches + (self.srv.device_fetches - f1))
+            self._apply_step_output(out, pend.work, retired=pend.retired)
         self._gap_anchor = time.monotonic()
         return True
 
     def _schedule_and_dispatch(self, gen: Optional[int],
                                finalized: bool) -> None:
-        """Stages 4+5. State is serial-equivalent here — the previous
-        tick is fully applied — so every decision matches what the
-        serial engine would choose. ``finalized`` gates the serial
-        admission forward: a tick that already paid the finalize fetch
-        defers it one tick, keeping the one-fetch-per-tick invariant
-        airtight instead of merely average."""
-        work = self._pick_admission_planned()
-        if not self._active:
+        """Stages 4+5: the pick (_schedule), then the dispatch with
+        its fetch left owing, then the next tick's pick precomputed
+        inside the freshly opened device window."""
+        with self._clock.stage("schedule"):
+            plan = self._schedule(finalized)
+        if plan is None or self._run_unbatched(plan, gen):
+            return
+        _, work, room = plan
+        with self._clock.stage("dispatch"):
+            self._fault_forward()   # chaos: this tick's model forward
+            self._check_superseded(gen)  # wedge hang fired above: abort
+            slot_reqs = dict(self._active)
             if work is not None:
-                if finalized:
-                    return
-                self._advance_one_admission(work, gen)
-            elif not self._admitting:
-                if self._maybe_grow_back():
-                    return
-                time.sleep(self._idle_sleep_s)
-            return
-        # Reap cancelled (timed-out) requests before paying for a step.
-        for slot in [s for s, r in self._active.items() if r.cancelled]:
-            self._maybe_finish(slot, -1)
-        if not self._active:
-            return
-        room = None
-        if work is not None and self._tick_token_budget:
-            room = self._tick_token_budget - len(self._active)
-            if room < self._chunk_gran:
-                choice = self._sched.alternation(self._admitting[work],
-                                                 self._active)
-                if choice is None:
-                    if finalized and self._admit_turn:
-                        # Admission's turn, but this tick already paid
-                        # the finalize fetch: hold the turn untoggled
-                        # and run the chunk next tick (which dispatches
-                        # nothing else).
-                        return
-                    choice = "admit" if self._admit_turn else "decode"
-                    self._admit_turn = not self._admit_turn
-                if choice == "admit":
-                    if finalized:
-                        return          # at-risk claim stands next tick
-                    self._advance_one_admission(work, gen)
-                    return
-                work, room = None, None
-        self._fault_forward()       # chaos: this tick's model forward
-        self._check_superseded(gen)  # wedge hang fired above: abort
-        slot_reqs = dict(self._active)
-        if work is not None:
-            slot_reqs[work] = self._admitting[work]
-        f0 = self.srv.device_fetches
-        # Instance-level step overrides (chaos/unit tests monkeypatch
-        # eng.srv.step) see exactly the serial call — eagerly, with
-        # exceptions raising at dispatch — and their output rides the
-        # pipeline pre-fetched.
-        eager = ("step" in vars(self.srv)
-                 or not hasattr(self.srv, "step_async"))
-        try:
-            if eager:
-                from tpushare.models.serving import PendingStep
-                out = (self.srv.step(prefill_work=work,
-                                     max_chunk_tokens=room)
-                       if work is not None else self.srv.step())
-                pstep = PendingStep.done(out)
-            else:
-                pstep = (self.srv.step_async(prefill_work=work,
-                                             max_chunk_tokens=room)
-                         if work is not None
-                         else self.srv.step_async())
-        except self._pool_exhausted as e:
-            # Same shed-one-victim contract as the serial tick (see
-            # _tick_serial): these raise host-side at dispatch, so the
-            # pipeline holds nothing suspect.
-            if self._preempt_one():
-                self._stats["engine_errors"] += 1
-                self._stats["last_error"] = f"preempt: {e}"
+                slot_reqs[work] = self._admitting[work]
+            f0 = self.srv.device_fetches
+            # Instance-level step overrides (chaos/unit tests
+            # monkeypatch eng.srv.step) see exactly the serial call —
+            # eagerly, with exceptions raising at dispatch — and their
+            # output rides the pipeline pre-fetched.
+            eager = ("step" in vars(self.srv)
+                     or not hasattr(self.srv, "step_async"))
+            try:
+                if eager:
+                    from tpushare.models.serving import PendingStep
+                    out = (self.srv.step(prefill_work=work,
+                                         max_chunk_tokens=room)
+                           if work is not None else self.srv.step())
+                    pstep = PendingStep.done(out)
+                else:
+                    pstep = (self.srv.step_async(prefill_work=work,
+                                                 max_chunk_tokens=room)
+                             if work is not None
+                             else self.srv.step_async())
+            except (self._pool_exhausted, self._slot_cap_exceeded) as e:
+                # These raise host-side at dispatch, so the pipeline
+                # holds nothing suspect.
+                if not self._shed_at_dispatch(e):
+                    raise
                 return
-            raise
-        except self._slot_cap_exceeded as e:
-            req = self._active.pop(e.slot, None)
-            self._safe_evict(e.slot)
-            self._stats["last_error"] = str(e)
-            if req is not None:
-                self._finish_completed(req)
-                return
-            raise                       # not ours: a real engine bug
-        self._dispatch_seq += 1
-        self._pending_tick = _PendingTick(
-            pstep, engine_gen=self._engine_gen,
-            tick_id=self._dispatch_seq, slot_reqs=slot_reqs,
-            work=work, dispatch_fetches=self.srv.device_fetches - f0)
-        self._stats["model_forwards"] += 1
-        self._stats["work_ticks"] += 1
-        if work is not None:
-            self._stats["fused_ticks"] += 1
+            self._dispatch_seq += 1
+            self._pending_tick = _PendingTick(
+                pstep, engine_gen=self._engine_gen,
+                tick_id=self._dispatch_seq, slot_reqs=slot_reqs,
+                work=work, dispatch_fetches=self.srv.device_fetches - f0)
+            self._stats["model_forwards"] += 1
+            self._stats["work_ticks"] += 1
+            if work is not None:
+                self._stats["fused_ticks"] += 1
         self._record_host_gap()
-        self._plan_next_pick()
+        with self._clock.stage("plan"):
+            self._plan_next_pick()
 
     def _flush_pipeline(self) -> None:
         """Abandon the in-flight dispatch WITHOUT its fetch: its
@@ -3280,10 +3356,14 @@ class ServeEngine:
         self._pipeline_flushes += 1
 
     def _record_host_gap(self) -> None:
-        """One host-gap sample: finalize done -> this dispatch
-        launched, the host-side scheduling span the overlap hides.
-        Plain monotonic deltas into a bounded ring (no PhaseTimer —
-        its barriers are the syncs the hot loop must never make)."""
+        """One host-gap sample: the previous tick's finalize applied
+        -> this tick's dispatch call RETURNED. So a sample holds the
+        ``schedule`` stage and the whole ``dispatch`` stage (block
+        growth, the launch, the eager sampler), not only the
+        scheduling the overlap hides; the stage clocks give the two
+        apart. Plain monotonic deltas into a bounded ring (no
+        PhaseTimer — its barriers are the syncs the hot loop must
+        never make)."""
         anchor, self._gap_anchor = self._gap_anchor, None
         if anchor is None:
             return
@@ -3318,6 +3398,8 @@ def chip_to_device(chip: int) -> int:
 
 
 def make_handler(engine: ServeEngine, timeout_s: float):
+    from tpushare.utils.profiling import span
+
     class Handler(BaseHTTPRequestHandler):
         def log_message(self, *a):           # quiet by default
             pass
@@ -3369,8 +3451,9 @@ def make_handler(engine: ServeEngine, timeout_s: float):
                 if eid is not None:
                     frame += b"id: %d\n" % eid
                 frame += b"data: " + json.dumps(obj).encode() + b"\n\n"
-                self.wfile.write(frame)
-                self.wfile.flush()
+                with span("http.write", rid=req.request_id):
+                    self.wfile.write(frame)
+                    self.wfile.flush()
 
             sent = max(0, int(from_n))
             deadline = time.time() + timeout_s
@@ -3479,6 +3562,88 @@ def make_handler(engine: ServeEngine, timeout_s: float):
                 return
             engine.note_resumed()
             self._stream(req, from_n=from_n, resume=True)
+
+        def _accept(self, sp):
+            """A completion request from its body to the engine's
+            queue: (request, stream, attached), or None once an error
+            was answered. ``sp`` is the ``http.accept`` span around
+            this call; it learns the request's id, the one the engine's
+            admit span and every ``http.write`` of the request
+            carry."""
+            try:
+                n = int(self.headers.get("Content-Length", 0))
+                body = json.loads(self.rfile.read(n) or b"{}")
+                if not isinstance(body, dict):
+                    raise ValueError("body must be a JSON object")
+                prompt = body["prompt"]
+                vocab = engine.srv.cfg.vocab_size
+                if (not isinstance(prompt, list) or not prompt
+                        or not all(isinstance(t, int)
+                                   and 0 <= t < vocab for t in prompt)):
+                    raise ValueError(
+                        "prompt must be a non-empty list of token ids "
+                        f"in [0, {vocab})")
+                mt = body.get("max_tokens", 16)
+                if (not isinstance(mt, int) or mt < 1
+                        or mt > engine.max_tokens_cap):
+                    raise ValueError(
+                        f"max_tokens must be an int in "
+                        f"[1, {engine.max_tokens_cap}]")
+                eos = body.get("eos")
+                if eos is not None and not isinstance(eos, int):
+                    raise ValueError("eos must be an int token id")
+                adapter = body.get("adapter", -1)
+                if isinstance(adapter, bool) or not isinstance(
+                        adapter, int):
+                    # bool subclasses int: {"adapter": true} would
+                    # silently select adapter 1 — another tenant.
+                    raise ValueError("adapter must be an int bank "
+                                     "index (-1 = base model)")
+                stream = bool(body.get("stream", False))
+                # SLO identity: "tier" orders the request against the
+                # rest of the traffic (unknown names 400 — a typo'd
+                # tier silently landing in the default would be an
+                # unasked-for SLO downgrade); "tenant" is the KV-quota
+                # accounting principal.
+                tier = parse_tier(body.get("tier"),
+                                  getattr(engine, "default_tier",
+                                          DEFAULT_TIER),
+                                  specs=getattr(engine, "tier_specs",
+                                                None))
+                tenant = body.get("tenant", "default")
+                if not isinstance(tenant, str) or not tenant:
+                    raise ValueError(
+                        "tenant must be a non-empty string")
+                req = _Request(prompt, mt, eos, adapter,
+                               tier=tier, tenant=tenant)
+                req.idem_key = (self.headers.get("Idempotency-Key")
+                                or None)
+            except (KeyError, ValueError, TypeError,
+                    json.JSONDecodeError) as e:
+                self._json(400, {"error": str(e)})
+                return None
+            # Exactly-once admission (r15): an Idempotency-Key that
+            # already names a request RE-ATTACHES to it — live or
+            # completed — instead of double-executing; the same key
+            # with a different prompt is a 409 (a client bug, not a
+            # retry). getattr: test fakes implement only submit().
+            reg = getattr(engine, "register_or_attach", None)
+            attached = conflict = False
+            if reg is not None:
+                req, attached, conflict = reg(req)
+            if conflict:
+                self._json(409, {
+                    "error": "Idempotency-Key reuse with a different "
+                             "prompt (a retry must resend the same "
+                             "request)"})
+                return None
+            sp.set_metadata(rid=req.request_id)
+            if not attached and not engine.submit(req):
+                if reg is not None:     # never accepted: the key must
+                    engine.deregister(req)  # not pin a request that
+                self._json(429, {"error": "queue full, retry later"})
+                return None             # will never run
+            return req, stream, attached
 
         def do_POST(self):
             if self.path == "/mesh/chip":
@@ -3598,78 +3763,11 @@ def make_handler(engine: ServeEngine, timeout_s: float):
             if self.path != "/v1/completions":
                 self._json(404, {"error": "not found"})
                 return
-            try:
-                n = int(self.headers.get("Content-Length", 0))
-                body = json.loads(self.rfile.read(n) or b"{}")
-                if not isinstance(body, dict):
-                    raise ValueError("body must be a JSON object")
-                prompt = body["prompt"]
-                vocab = engine.srv.cfg.vocab_size
-                if (not isinstance(prompt, list) or not prompt
-                        or not all(isinstance(t, int)
-                                   and 0 <= t < vocab for t in prompt)):
-                    raise ValueError(
-                        "prompt must be a non-empty list of token ids "
-                        f"in [0, {vocab})")
-                mt = body.get("max_tokens", 16)
-                if (not isinstance(mt, int) or mt < 1
-                        or mt > engine.max_tokens_cap):
-                    raise ValueError(
-                        f"max_tokens must be an int in "
-                        f"[1, {engine.max_tokens_cap}]")
-                eos = body.get("eos")
-                if eos is not None and not isinstance(eos, int):
-                    raise ValueError("eos must be an int token id")
-                adapter = body.get("adapter", -1)
-                if isinstance(adapter, bool) or not isinstance(
-                        adapter, int):
-                    # bool subclasses int: {"adapter": true} would
-                    # silently select adapter 1 — another tenant.
-                    raise ValueError("adapter must be an int bank "
-                                     "index (-1 = base model)")
-                stream = bool(body.get("stream", False))
-                # SLO identity: "tier" orders the request against the
-                # rest of the traffic (unknown names 400 — a typo'd
-                # tier silently landing in the default would be an
-                # unasked-for SLO downgrade); "tenant" is the KV-quota
-                # accounting principal.
-                tier = parse_tier(body.get("tier"),
-                                  getattr(engine, "default_tier",
-                                          DEFAULT_TIER),
-                                  specs=getattr(engine, "tier_specs",
-                                                None))
-                tenant = body.get("tenant", "default")
-                if not isinstance(tenant, str) or not tenant:
-                    raise ValueError(
-                        "tenant must be a non-empty string")
-                req = _Request(prompt, mt, eos, adapter,
-                               tier=tier, tenant=tenant)
-                req.idem_key = (self.headers.get("Idempotency-Key")
-                                or None)
-            except (KeyError, ValueError, TypeError,
-                    json.JSONDecodeError) as e:
-                self._json(400, {"error": str(e)})
+            with span("http.accept") as sp:
+                accepted = self._accept(sp)
+            if accepted is None:
                 return
-            # Exactly-once admission (r15): an Idempotency-Key that
-            # already names a request RE-ATTACHES to it — live or
-            # completed — instead of double-executing; the same key
-            # with a different prompt is a 409 (a client bug, not a
-            # retry). getattr: test fakes implement only submit().
-            reg = getattr(engine, "register_or_attach", None)
-            attached = conflict = False
-            if reg is not None:
-                req, attached, conflict = reg(req)
-            if conflict:
-                self._json(409, {
-                    "error": "Idempotency-Key reuse with a different "
-                             "prompt (a retry must resend the same "
-                             "request)"})
-                return
-            if not attached and not engine.submit(req):
-                if reg is not None:     # never accepted: the key must
-                    engine.deregister(req)  # not pin a request that
-                self._json(429, {"error": "queue full, retry later"})
-                return                  # will never run
+            req, stream, attached = accepted
             if stream:
                 # An attached stream is a read-only view: its dropped
                 # connection/timeout must never cancel a generation

@@ -1108,6 +1108,7 @@ class MoESlotServer(SpecDecodeMixin):
         self.last_token = jnp.zeros((n_slots, 1), jnp.int32)
         self.active = np.zeros(n_slots, dtype=bool)       # host truth
         self._active_dev = jnp.zeros((n_slots,), bool)    # device mirror
+        # Uploaded by copy, never aliased (see PagedSlotServer.__init__).
         self._admissions: Dict[int, Dict[str, Any]] = {}  # chunked
         # Row-level prefix cache: the dense-row idiom of the paged
         # server's block prefix cache. ONE retained (prompt, row)
@@ -1204,7 +1205,7 @@ class MoESlotServer(SpecDecodeMixin):
         nxt = self._sampler.pick(last_logits)[0].astype(jnp.int32)
         self.last_token = self.last_token.at[slot, 0].set(nxt)
         self.active[slot] = True
-        self._active_dev = jnp.asarray(self.active)
+        self._active_dev = jnp.array(self.active)
 
     def _cached_prefix_len(self, prompt_np: np.ndarray) -> int:
         """Longest usable cached-prefix length: common prefix with the
@@ -1484,7 +1485,7 @@ class MoESlotServer(SpecDecodeMixin):
                 self.active[slot] = False   # next write would be OOB
                 retired = True
         if retired:
-            self._active_dev = jnp.asarray(self.active)
+            self._active_dev = jnp.array(self.active)
 
         def _finalize(invalid):
             self.device_fetches += 1
@@ -1618,7 +1619,7 @@ class MoESlotServer(SpecDecodeMixin):
             self.active[slot] = True
         elif st["in_cache"]:
             self._track_admit_frontier(slot, st)
-        self._active_dev = jnp.asarray(self.active)
+        self._active_dev = jnp.array(self.active)
         out_slots = decode_slots + ([slot] if final else [])
 
         def _finalize(invalid):
@@ -1691,7 +1692,7 @@ class MoESlotServer(SpecDecodeMixin):
     def evict(self, slot: int) -> None:
         self._admissions.pop(slot, None)   # cancel mid-chunked admit
         self.active[slot] = False
-        self._active_dev = jnp.asarray(self.active)
+        self._active_dev = jnp.array(self.active)
         self.lengths = self.lengths.at[slot].set(0)
         self._lengths_np[slot] = 0
 

@@ -37,6 +37,7 @@ import numpy as np
 from tpushare.models.transformer import TransformerConfig, forward
 from tpushare.parallel.multihost import addressable_fetch, host_scalar
 from tpushare.router.chainkeys import chain_keys
+from tpushare.utils.profiling import span
 
 
 class SlotCapacityExceeded(RuntimeError):
@@ -840,29 +841,42 @@ def _prefill_chunk(params, prompt: jnp.ndarray, cfg: TransformerConfig,
     kvq = cache.pool_k_scale is not None
     final = end >= S
     pad_len = (comp_len - done) if final else chunk
-    padded = jnp.zeros((pad_len,), prompt.dtype
-                       ).at[:end - done].set(prompt[done:end])
-    if prefill_fn is None:
-        logits, row = forward(params, padded[None, :], cfg, cache=row,
-                              pos_offset=done)
-    else:
-        logits, row = prefill_fn(params, padded[None, :], cache=row,
-                                 pos_offset=done)
-    start_blk = done // bs
-    end_blk = n_blk if final else end // bs
-    ids = cache.block_table[slot][start_blk:end_blk]
-    L = row["k"].shape[0]
-    n_fresh = end_blk - start_blk
-    updates = {}
-    for pf, rk_ in _row_pairs(kvq):
-        r = row[rk_][:, 0, start_blk * bs:end_blk * bs]
-        r = r.reshape(L, n_fresh, bs, *r.shape[2:])
-        if pf.endswith("_scale"):
-            from tpushare.models.quant import scales_to_pool_layout
-            r = scales_to_pool_layout(r)    # -> [L, fb, Hkv_pad, bs]
-        updates[pf] = getattr(cache, pf).at[:, ids].set(r)
-    last = logits[0, S - 1 - done] if final else None
+    with span("slot.admit.prefill"):
+        padded = jnp.zeros((pad_len,), prompt.dtype
+                           ).at[:end - done].set(prompt[done:end])
+        if prefill_fn is None:
+            logits, row = forward(params, padded[None, :], cfg,
+                                  cache=row, pos_offset=done)
+        else:
+            logits, row = prefill_fn(params, padded[None, :], cache=row,
+                                     pos_offset=done)
+    with span("slot.admit.scatter"):
+        start_blk = done // bs
+        end_blk = n_blk if final else end // bs
+        ids = cache.block_table[slot][start_blk:end_blk]
+        L = row["k"].shape[0]
+        n_fresh = end_blk - start_blk
+        updates = {}
+        for pf, rk_ in _row_pairs(kvq):
+            r = row[rk_][:, 0, start_blk * bs:end_blk * bs]
+            r = r.reshape(L, n_fresh, bs, *r.shape[2:])
+            if pf.endswith("_scale"):
+                from tpushare.models.quant import scales_to_pool_layout
+                r = scales_to_pool_layout(r)    # -> [L, fb, Hkv_pad, bs]
+            updates[pf] = getattr(cache, pf).at[:, ids].set(r)
+        last = logits[0, S - 1 - done] if final else None
     return last, dataclasses.replace(cache, **updates), row
+
+
+def _program(name: str, fn, **kw):
+    """``functools.partial(fn, **kw)`` under a stable ``__name__``, so
+    the jitted program is ``jit_<name>`` in a trace's ``XLA Modules``
+    (a bare partial is ``jit__unknown``, and decode, prefill and fused
+    tick cannot be told apart). The names are read by
+    tpubench/readers/program_trace.py."""
+    part = functools.partial(fn, **kw)
+    part.__name__ = name
+    return part
 
 
 class PagedSlotServer(SpecDecodeMixin):
@@ -979,6 +993,10 @@ class PagedSlotServer(SpecDecodeMixin):
         self.prefix_prompt_tokens = 0       # cumulative admitted tokens
         self.active = np.zeros(n_slots, dtype=bool)       # host truth
         self._active_dev = jnp.zeros((n_slots,), bool)    # device mirror
+        # The mirror is uploaded by COPY (jnp.array, never jnp.asarray):
+        # on the CPU backend asarray may alias the numpy buffer, and
+        # ``active`` is mutated in place while a dispatch that reads
+        # the device mask can still be in flight.
         self._admissions: Dict[int, Dict[str, Any]] = {}  # chunked admits
         # Per-tenant KV-block quotas (tpushare.slo.quota.KvQuota; None
         # = unquota'd pool). The server is the ledger's single writer:
@@ -1003,18 +1021,21 @@ class PagedSlotServer(SpecDecodeMixin):
         # and nothing else holds a pool reference — DN601/DN602 police
         # exactly this surface); a PagedCache snapshot from before a
         # tick was already invalidated by the host-mirror contract.
-        self._decode = jax.jit(functools.partial(
+        self._decode = jax.jit(_program(
+            "paged_decode",
             decode_core, cfg=cfg, block_size=block_size,
             attn_impl=attn_impl, layers_hook=layers_hook,
             mlora_scale=mlora_scale, forward_fn=forward_fn),
             donate_argnums=(2, 3))
-        self._prefill = jax.jit(functools.partial(
+        self._prefill = jax.jit(_program(
+            "paged_prefill",
             base_fwd, cfg=cfg, attn_impl=attn_impl,
             layers_hook=layers_hook, mlora_scale=mlora_scale))
         # The multi-token paged forward (verify_core) is also the
         # fused engine tick's dispatch: decode rows contribute 1 token
         # each, the admitting slot its next chunk — one weight stream.
-        self._verify = jax.jit(functools.partial(
+        self._verify = jax.jit(_program(
+            "paged_fused",
             verify_core, cfg=cfg, attn_impl=attn_impl,
             layers_hook=layers_hook, mlora_scale=mlora_scale,
             forward_fn=forward_fn),
@@ -1084,12 +1105,14 @@ class PagedSlotServer(SpecDecodeMixin):
             # same hook).
             dfwd_fn = (forward_fn if draft_forward_fn is None
                        else draft_forward_fn)
-            self._draft_decode = jax.jit(functools.partial(
+            self._draft_decode = jax.jit(_program(
+                "draft_paged_decode",
                 decode_core, cfg=draft_cfg, block_size=block_size,
                 attn_impl=attn_impl, layers_hook=draft_layers_hook,
                 mlora_scale=mlora_scale, forward_fn=dfwd_fn),
                 donate_argnums=(2, 3))
-            self._draft_prefill = jax.jit(functools.partial(
+            self._draft_prefill = jax.jit(_program(
+                "draft_paged_prefill",
                 forward if dfwd_fn is None else dfwd_fn,
                 cfg=draft_cfg, attn_impl=attn_impl,
                 layers_hook=draft_layers_hook, mlora_scale=mlora_scale))
@@ -1097,7 +1120,8 @@ class PagedSlotServer(SpecDecodeMixin):
             # forward mirrors the decode tokens' draft KV AND writes
             # the admission chunk's draft KV (same batch as the
             # target's fused forward — logits discarded).
-            self._draft_verify = jax.jit(functools.partial(
+            self._draft_verify = jax.jit(_program(
+                "draft_paged_fused",
                 verify_core, cfg=draft_cfg, attn_impl=attn_impl,
                 layers_hook=draft_layers_hook, mlora_scale=mlora_scale,
                 forward_fn=dfwd_fn),
@@ -1212,96 +1236,98 @@ class PagedSlotServer(SpecDecodeMixin):
         if prompt.ndim != 1:
             raise ValueError("admit takes a single unbatched prompt")
         self._ml.validate(adapter)
-        candidates = [s for s in range(self.cache.n_slots)
-                      if not self.active[s] and s not in self._admissions]
-        if not candidates:
-            # Slot pressure is the same transient class as pool
-            # pressure for the engine's hold-and-retry path.
-            raise PoolExhausted("no free slots")
-        slot = candidates[0]
-        if self._ml.enabled:
-            self._ml.set(slot, adapter)
-        prefill_fn = self._ml.wrap_prefill(self._prefill, adapter)
-        # A slot that retired at capacity (deactivated in step()) still
-        # owns its blocks so they stay readable; reclaim them before
-        # reuse or they would leak — admit() wipes the table row
-        # without touching the free list. release() degenerates to
-        # evict() when no prefix bookkeeping exists, and plain evict()
-        # on a cache with published blocks would free them while still
-        # indexed (silent KV corruption) — so the server always
-        # releases.
-        if (self.cache.host_table()[slot] >= 0).any():
-            self._refund_slot(slot)
-            self.cache = release(self.cache, slot)
-        prompt_np = np.asarray(prompt)
-        S = int(prompt_np.shape[0])
-        bs = self.cache.block_size
-        tenant = tenant or "default"
-        if self.prefix_cache:
-            # Hash once: S//bs keys cover both the admit match
-            # ((S-1)//bs of them) and the publish (S//bs). Salted by
-            # adapter id: KV under different adapters must not share.
-            salt = (b"adapter:%d" % adapter) if self._ml.enabled else b""
-            keys = _chain_keys(prompt_np, bs, S // bs, salt=salt)
-            self.cache, cached_len, blocks = admit_prefix(
-                self.cache, slot, prompt_np, keys=keys)
-            self.last_cached_len = cached_len
-            self.prefix_hit_tokens += cached_len
-            self.prefix_prompt_tokens += S
-        else:
-            self.cache = admit(self.cache, slot, S)
-            cached_len, keys, blocks = 0, None, None
-        if self.kv_quota is not None:
-            # Enforce on the FRESH allocation only (prefix hits share
-            # blocks already paid for by their first writer). The
-            # verdict runs after the alloc because only the alloc
-            # knows how much of the prompt the prefix cache covered —
-            # and the reserve-floor check must see the POST-admission
-            # pool: a prefix hit pins zero-ref LRU blocks that a
-            # pre-allocation snapshot still counts as claimable, which
-            # would let a large-hit admission dig into other tenants'
-            # guaranteed floors undetected. admit_verdict subtracts
-            # ``need``, so handing it post-state + fresh makes its
-            # comparison exactly "claimable after this admission".
-            # A refusal rolls the host-side reservation back intact.
-            # Promoted host-tier landings count as cached_len for
-            # prefill purposes but are FRESH device allocations the
-            # tenant pays for — only genuinely shared device-resident
-            # hits are free (their first writer already paid).
-            promoted = (self.cache.host_tier.last_promoted_n
-                        if (self.prefix_cache
-                            and self.cache.host_tier is not None)
-                        else 0)
-            fresh = blocks_needed(S + 1, bs) - cached_len // bs \
-                + promoted
-            verdict = self.kv_quota.admit_verdict(
-                tenant, fresh, reclaimable_blocks(self.cache) + fresh)
-            if verdict is not None:
-                kind, msg = verdict
+        with span("slot.admit.lookup"):
+            candidates = [s for s in range(self.cache.n_slots)
+                          if not self.active[s] and s not in self._admissions]
+            if not candidates:
+                # Slot pressure is the same transient class as pool
+                # pressure for the engine's hold-and-retry path.
+                raise PoolExhausted("no free slots")
+            slot = candidates[0]
+            if self._ml.enabled:
+                self._ml.set(slot, adapter)
+            prefill_fn = self._ml.wrap_prefill(self._prefill, adapter)
+            # A slot that retired at capacity (deactivated in step()) still
+            # owns its blocks so they stay readable; reclaim them before
+            # reuse or they would leak — admit() wipes the table row
+            # without touching the free list. release() degenerates to
+            # evict() when no prefix bookkeeping exists, and plain evict()
+            # on a cache with published blocks would free them while still
+            # indexed (silent KV corruption) — so the server always
+            # releases.
+            if (self.cache.host_table()[slot] >= 0).any():
+                self._refund_slot(slot)
                 self.cache = release(self.cache, slot)
-                if self.prefix_cache:
-                    self.prefix_hit_tokens -= cached_len
-                    self.prefix_prompt_tokens -= S
-                raise QuotaExceeded(msg, kind=kind, tenant=tenant,
-                                    need=fresh)
-            self.kv_quota.charge(tenant, fresh)
-            self._slot_charge[slot] = fresh
-        self._slot_tenant[slot] = tenant
-        if self.prefix_cache and self.cache.host_tier is not None:
-            # Record this tenant as the quota principal of every
-            # freshly-allocated block — a later demotion charges the
-            # host-tier byte ledger against it.
-            n_matched = (cached_len // bs
-                         - self.cache.host_tier.last_promoted_n)
-            for b in blocks[n_matched:]:
-                self.cache.owners[int(b)] = tenant
+            prompt_np = np.asarray(prompt)
+            S = int(prompt_np.shape[0])
+            bs = self.cache.block_size
+            tenant = tenant or "default"
+            if self.prefix_cache:
+                # Hash once: S//bs keys cover both the admit match
+                # ((S-1)//bs of them) and the publish (S//bs). Salted by
+                # adapter id: KV under different adapters must not share.
+                salt = (b"adapter:%d" % adapter) if self._ml.enabled else b""
+                keys = _chain_keys(prompt_np, bs, S // bs, salt=salt)
+                self.cache, cached_len, blocks = admit_prefix(
+                    self.cache, slot, prompt_np, keys=keys)
+                self.last_cached_len = cached_len
+                self.prefix_hit_tokens += cached_len
+                self.prefix_prompt_tokens += S
+            else:
+                self.cache = admit(self.cache, slot, S)
+                cached_len, keys, blocks = 0, None, None
+            if self.kv_quota is not None:
+                # Enforce on the FRESH allocation only (prefix hits share
+                # blocks already paid for by their first writer). The
+                # verdict runs after the alloc because only the alloc
+                # knows how much of the prompt the prefix cache covered —
+                # and the reserve-floor check must see the POST-admission
+                # pool: a prefix hit pins zero-ref LRU blocks that a
+                # pre-allocation snapshot still counts as claimable, which
+                # would let a large-hit admission dig into other tenants'
+                # guaranteed floors undetected. admit_verdict subtracts
+                # ``need``, so handing it post-state + fresh makes its
+                # comparison exactly "claimable after this admission".
+                # A refusal rolls the host-side reservation back intact.
+                # Promoted host-tier landings count as cached_len for
+                # prefill purposes but are FRESH device allocations the
+                # tenant pays for — only genuinely shared device-resident
+                # hits are free (their first writer already paid).
+                promoted = (self.cache.host_tier.last_promoted_n
+                            if (self.prefix_cache
+                                and self.cache.host_tier is not None)
+                            else 0)
+                fresh = blocks_needed(S + 1, bs) - cached_len // bs \
+                    + promoted
+                verdict = self.kv_quota.admit_verdict(
+                    tenant, fresh, reclaimable_blocks(self.cache) + fresh)
+                if verdict is not None:
+                    kind, msg = verdict
+                    self.cache = release(self.cache, slot)
+                    if self.prefix_cache:
+                        self.prefix_hit_tokens -= cached_len
+                        self.prefix_prompt_tokens -= S
+                    raise QuotaExceeded(msg, kind=kind, tenant=tenant,
+                                        need=fresh)
+                self.kv_quota.charge(tenant, fresh)
+                self._slot_charge[slot] = fresh
+            self._slot_tenant[slot] = tenant
+            if self.prefix_cache and self.cache.host_tier is not None:
+                # Record this tenant as the quota principal of every
+                # freshly-allocated block — a later demotion charges the
+                # host-tier byte ledger against it.
+                n_matched = (cached_len // bs
+                             - self.cache.host_tier.last_promoted_n)
+                for b in blocks[n_matched:]:
+                    self.cache.owners[int(b)] = tenant
         chunk = chunk_tokens if chunk_tokens else S
         # Round UP to block alignment: rounding down would split even a
         # whole-prompt admit of a non-aligned prompt into two dispatches
         # (and a second compile key) for no reason.
         chunk = max(bs, -(-chunk // bs) * bs)
-        row, comp_len, n_blk = _admission_row(
-            self.cfg, self.cache, slot, S, cached_len)
+        with span("slot.admit.row"):
+            row, comp_len, n_blk = _admission_row(
+                self.cfg, self.cache, slot, S, cached_len)
         st = {
             "prompt": prompt, "prompt_np": prompt_np, "done": cached_len,
             "chunk": chunk, "keys": keys, "blocks": blocks,
@@ -1327,8 +1353,10 @@ class PagedSlotServer(SpecDecodeMixin):
             # degrades acceptance over the promoted span, never
             # correctness — the same tradeoff the donated-pool
             # recovery path already accepts.
-            st["drow"], st["dcomp_len"], _ = _admission_row(
-                self.draft_cfg, self._draft_view(), slot, S, cached_len)
+            with span("slot.admit.row"):
+                st["drow"], st["dcomp_len"], _ = _admission_row(
+                    self.draft_cfg, self._draft_view(), slot, S,
+                    cached_len)
             st["draft_prefill_fn"] = self._ml.wrap_prefill(
                 self._draft_prefill, adapter)
         self._admissions[slot] = st
@@ -1365,12 +1393,13 @@ class PagedSlotServer(SpecDecodeMixin):
             # the serial row from the pool (one gather — exactly what
             # _admission_row does for a prefix hit of length `done`,
             # which fused chunks effectively are).
-            st["row"], st["comp_len"], _ = _admission_row(
-                self.cfg, self.cache, slot, S, st["done"])
-            if self.speculative:
-                st["drow"], st["dcomp_len"], _ = _admission_row(
-                    self.draft_cfg, self._draft_view(), slot, S,
-                    st["done"])
+            with span("slot.admit.row"):
+                st["row"], st["comp_len"], _ = _admission_row(
+                    self.cfg, self.cache, slot, S, st["done"])
+                if self.speculative:
+                    st["drow"], st["dcomp_len"], _ = _admission_row(
+                        self.draft_cfg, self._draft_view(), slot, S,
+                        st["done"])
             st["row_stale"] = False
         end = min(S, st["done"] + chunk)
         done0 = st["done"]
@@ -1403,12 +1432,15 @@ class PagedSlotServer(SpecDecodeMixin):
         if self.prefix_cache:
             publish_prefix(self.cache, st["blocks"], st["prompt_np"],
                            keys=st["keys"])
-        nxt = self._sampler.pick(last_logits[None, :])[0].astype(jnp.int32)
-        self.last_token = self.last_token.at[slot, 0].set(nxt)
+        with span("slot.sample"):
+            nxt = self._sampler.pick(
+                last_logits[None, :])[0].astype(jnp.int32)
+            self.last_token = self.last_token.at[slot, 0].set(nxt)
         self.active[slot] = True
-        self._active_dev = jnp.asarray(self.active)
+        self._active_dev = jnp.array(self.active)
         self.device_fetches += 1
-        tok = int(host_scalar(nxt))
+        with span("slot.admit.first_token"):
+            tok = int(host_scalar(nxt))
         if tier is not None:
             tier.estimator.observe_prefill(
                 end - done0, time.perf_counter() - t0)
@@ -1540,40 +1572,46 @@ class PagedSlotServer(SpecDecodeMixin):
             return self._spec_step_async()
         if not self.active.any():
             return PendingStep.done({})
-        self._grow_active()
-        mkw = ({"mlora_idx": self._ml.dev} if self._ml.enabled else {})
-        logits, pool_k, pool_v, pks, pvs, lengths = self._pools_dispatch(
-            self._decode,
-            self.params, self.last_token, self.cache.pool_k,
-            self.cache.pool_v, self.cache.block_table,
-            self.cache.lengths, self._active_dev,
-            pool_k_scale=self.cache.pool_k_scale,
-            pool_v_scale=self.cache.pool_v_scale, **mkw)
-        # Rebind the donated pools IMMEDIATELY: between the dispatch
-        # and this replace, self.cache.pool_k/pool_v name deleted
-        # buffers (donate_argnums), and any raise in that window would
-        # leave the server holding them.
-        self.cache = dataclasses.replace(
-            self.cache, pool_k=pool_k, pool_v=pool_v, lengths=lengths,
-            pool_k_scale=pks, pool_v_scale=pvs)
-        nxt = self._sampler.pick(logits[:, 0]).astype(jnp.int32)
-        self.last_token = jnp.where(self._active_dev[:, None],
-                                    nxt[:, None], self.last_token)
-        # Host mirror advances by the same +1-per-active-slot the
-        # device lengths just did — the tick's ONE transfer is the
-        # token fetch itself.
-        lnp = self.cache.host_lengths()
-        lnp[self.active] += 1
-        slots = [int(s) for s in np.nonzero(self.active)[0]]
-        # Capacity retirement reads only the host mirror — decided at
-        # dispatch, exactly the serial tick's criterion.
-        hit_cap = False
-        for slot in slots:
-            if int(lnp[slot]) >= self.slot_capacity:
-                self.active[slot] = False
-                hit_cap = True
-        if hit_cap:
-            self._active_dev = jnp.asarray(self.active)
+        with span("slot.grow"):
+            self._grow_active()
+        with span("slot.launch"):
+            mkw = ({"mlora_idx": self._ml.dev} if self._ml.enabled
+                   else {})
+            logits, pool_k, pool_v, pks, pvs, lengths = \
+                self._pools_dispatch(
+                    self._decode,
+                    self.params, self.last_token, self.cache.pool_k,
+                    self.cache.pool_v, self.cache.block_table,
+                    self.cache.lengths, self._active_dev,
+                    pool_k_scale=self.cache.pool_k_scale,
+                    pool_v_scale=self.cache.pool_v_scale, **mkw)
+            # Rebind the donated pools IMMEDIATELY: between the
+            # dispatch and this replace, self.cache.pool_k/pool_v name
+            # deleted buffers (donate_argnums), and any raise in that
+            # window would leave the server holding them.
+            self.cache = dataclasses.replace(
+                self.cache, pool_k=pool_k, pool_v=pool_v,
+                lengths=lengths, pool_k_scale=pks, pool_v_scale=pvs)
+        with span("slot.sample"):
+            nxt = self._sampler.pick(logits[:, 0]).astype(jnp.int32)
+            self.last_token = jnp.where(self._active_dev[:, None],
+                                        nxt[:, None], self.last_token)
+        with span("slot.mirror"):
+            # Host mirror advances by the same +1-per-active-slot the
+            # device lengths just did — the tick's ONE transfer is the
+            # token fetch itself.
+            lnp = self.cache.host_lengths()
+            lnp[self.active] += 1
+            slots = [int(s) for s in np.nonzero(self.active)[0]]
+            # Capacity retirement reads only the host mirror — decided
+            # at dispatch, exactly the serial tick's criterion.
+            hit_cap = False
+            for slot in slots:
+                if int(lnp[slot]) >= self.slot_capacity:
+                    self.active[slot] = False
+                    hit_cap = True
+            if hit_cap:
+                self._active_dev = jnp.array(self.active)
 
         def _finalize(invalid):
             self.device_fetches += 1
@@ -1619,66 +1657,74 @@ class PagedSlotServer(SpecDecodeMixin):
                                       gran=self.cache.block_size)
         if width == 0:
             return self.step_async()    # budget left no chunk room
-        self._grow_active()
-        toks = fused_token_batch(self.last_token, st["prompt"],
-                                 done, end, width, slot)
-        pos = self.cache.lengths.at[slot].set(done)
-        # The admitting slot must WRITE (its table row is reserved);
-        # decode rows write their one real token; everything else
-        # routes to the trash block.
-        wmask = self._active_dev.at[slot].set(True)
-        mkw = ({"mlora_idx": self._ml.dev} if self._ml.enabled else {})
-        logits, pk, pv, pks, pvs = self._pools_dispatch(
-            self._verify,
-            self.params, toks, self.cache.pool_k, self.cache.pool_v,
-            self.cache.block_table, pos, wmask,
-            pool_k_scale=self.cache.pool_k_scale,
-            pool_v_scale=self.cache.pool_v_scale, **mkw)
-        # Rebind donated pools immediately (see step()); lengths are
-        # not donated, so computing the advance after the replace is
-        # identical.
-        lengths = self.cache.lengths + self._active_dev.astype(jnp.int32)
-        self.cache = dataclasses.replace(
-            self.cache, pool_k=pk, pool_v=pv, lengths=lengths,
-            pool_k_scale=pks, pool_v_scale=pvs)
-        if self.speculative:
-            # One draft forward: decode rows mirror their pending
-            # token's draft KV (a skipped write would leave a hole
-            # every later draft step attends), the admitting row
-            # advances the draft chunk — same batch, logits dropped.
-            _, self._dpk, self._dpv, _, _ = self._pools_dispatch(
-                self._draft_verify,
-                self.draft_params, toks, self._dpk, self._dpv,
-                self.cache.block_table, pos, wmask, **mkw)
+        with span("slot.grow"):
+            self._grow_active()
+        with span("slot.launch"):
+            toks = fused_token_batch(self.last_token, st["prompt"],
+                                     done, end, width, slot)
+            pos = self.cache.lengths.at[slot].set(done)
+            # The admitting slot must WRITE (its table row is
+            # reserved); decode rows write their one real token;
+            # everything else routes to the trash block.
+            wmask = self._active_dev.at[slot].set(True)
+            mkw = ({"mlora_idx": self._ml.dev} if self._ml.enabled
+                   else {})
+            logits, pk, pv, pks, pvs = self._pools_dispatch(
+                self._verify,
+                self.params, toks, self.cache.pool_k, self.cache.pool_v,
+                self.cache.block_table, pos, wmask,
+                pool_k_scale=self.cache.pool_k_scale,
+                pool_v_scale=self.cache.pool_v_scale, **mkw)
+            # Rebind donated pools immediately (see step()); lengths
+            # are not donated, so computing the advance after the
+            # replace is identical.
+            lengths = (self.cache.lengths
+                       + self._active_dev.astype(jnp.int32))
+            self.cache = dataclasses.replace(
+                self.cache, pool_k=pk, pool_v=pv, lengths=lengths,
+                pool_k_scale=pks, pool_v_scale=pvs)
+            if self.speculative:
+                # One draft forward: decode rows mirror their pending
+                # token's draft KV (a skipped write would leave a hole
+                # every later draft step attends), the admitting row
+                # advances the draft chunk — same batch, logits
+                # dropped.
+                _, self._dpk, self._dpv, _, _ = self._pools_dispatch(
+                    self._draft_verify,
+                    self.draft_params, toks, self._dpk, self._dpv,
+                    self.cache.block_table, pos, wmask, **mkw)
         st["done"] = end
         st["row_stale"] = True
         final = end >= S
-        if final:
-            # Admission pick before the decode pick: matches the
-            # serial engine order on the sampler's key stream.
-            first = self._sampler.pick(logits[slot:slot + 1,
-                                             S - 1 - done]
-                                       ).astype(jnp.int32)
-        nxt = self._sampler.pick(logits[:, 0]).astype(jnp.int32)
-        self.last_token = jnp.where(self._active_dev[:, None],
-                                    nxt[:, None], self.last_token)
-        lnp = self.cache.host_lengths()
-        lnp[self.active] += 1
-        decode_slots = [int(s) for s in np.nonzero(self.active)[0]]
-        for s in decode_slots:
-            if int(lnp[s]) >= self.slot_capacity:
-                self.active[s] = False
-        if final:
-            # Activation is dispatch-side device work: the slot's
-            # first token stays on device (first[0] indexes the
-            # device array, no fetch) until finalize.
-            del self._admissions[slot]
-            if self.prefix_cache:
-                publish_prefix(self.cache, st["blocks"],
-                               st["prompt_np"], keys=st["keys"])
-            self.last_token = self.last_token.at[slot, 0].set(first[0])
-            self.active[slot] = True
-        self._active_dev = jnp.asarray(self.active)
+        with span("slot.sample"):
+            if final:
+                # Admission pick before the decode pick: matches the
+                # serial engine order on the sampler's key stream.
+                first = self._sampler.pick(logits[slot:slot + 1,
+                                                 S - 1 - done]
+                                           ).astype(jnp.int32)
+            nxt = self._sampler.pick(logits[:, 0]).astype(jnp.int32)
+            self.last_token = jnp.where(self._active_dev[:, None],
+                                        nxt[:, None], self.last_token)
+        with span("slot.mirror"):
+            lnp = self.cache.host_lengths()
+            lnp[self.active] += 1
+            decode_slots = [int(s) for s in np.nonzero(self.active)[0]]
+            for s in decode_slots:
+                if int(lnp[s]) >= self.slot_capacity:
+                    self.active[s] = False
+            if final:
+                # Activation is dispatch-side device work: the slot's
+                # first token stays on device (first[0] indexes the
+                # device array, no fetch) until finalize.
+                del self._admissions[slot]
+                if self.prefix_cache:
+                    publish_prefix(self.cache, st["blocks"],
+                                   st["prompt_np"], keys=st["keys"])
+                self.last_token = self.last_token.at[slot, 0].set(
+                    first[0])
+                self.active[slot] = True
+            self._active_dev = jnp.array(self.active)
         out_slots = decode_slots + ([slot] if final else [])
 
         def _finalize(invalid):
@@ -1802,7 +1848,7 @@ class PagedSlotServer(SpecDecodeMixin):
         prefix bookkeeping exists). Safe mid-admission: the chunk
         state is dropped with the blocks."""
         self.active[slot] = False
-        self._active_dev = jnp.asarray(self.active)
+        self._active_dev = jnp.array(self.active)
         self._admissions.pop(slot, None)
         if self._ml.enabled:
             self._ml.reset(slot)

@@ -25,6 +25,7 @@ from jax import shard_map
 from tpushare.models.generate import sample_logits
 from tpushare.models.paged import PoolExhausted
 from tpushare.parallel.multihost import addressable_fetch, host_scalar
+from tpushare.utils.profiling import span
 from tpushare.models.transformer import (
     _chunked_prefill_loop,
     ParallelCtx, TransformerConfig, forward, init_cache, param_specs,
@@ -488,7 +489,7 @@ class MultiLoraSlots:
 
     def set(self, slot: int, adapter: int) -> None:
         self._host[slot] = adapter
-        self.dev = jnp.asarray(self._host)
+        self.dev = jnp.array(self._host)
 
     def reset(self, slot: int) -> None:
         self.set(slot, -1)
@@ -541,7 +542,10 @@ class PendingStep:
                 out = {s: t for s, t in out.items() if s not in invalid}
             return out
         fn, self._fn = self._fn, None
-        return fn(frozenset(invalid))
+        # The tick's one device->host transfer: the host waits here
+        # for the device (every slot server's deferred fetch).
+        with span("slot.fetch"):
+            return fn(frozenset(invalid))
 
 
 class SlotServer:
@@ -618,6 +622,7 @@ class SlotServer:
         self.last_token = jnp.zeros((n_slots, 1), jnp.int32)
         self.active = np.zeros(n_slots, dtype=bool)       # host truth
         self._active_dev = jnp.zeros((n_slots,), bool)    # device mirror
+        # Uploaded by copy, never aliased (see PagedSlotServer.__init__).
         self._admissions: Dict[int, Dict[str, Any]] = {}  # chunked
         # Sampling config (temperature 0 = greedy, the default).
         self._sampler = TokenSampler(temperature, top_k, top_p, seed)
@@ -683,7 +688,7 @@ class SlotServer:
         nxt = self._pick(last_logits[None, :])[0].astype(jnp.int32)
         self.last_token = self.last_token.at[slot, 0].set(nxt)
         self.active[slot] = True
-        self._active_dev = jnp.asarray(self.active)
+        self._active_dev = jnp.array(self.active)
         return slot
 
     def _claim_slot(self, prompt: jnp.ndarray) -> int:
@@ -810,7 +815,7 @@ class SlotServer:
         nxt = self._pick(last)[0].astype(jnp.int32)
         self.last_token = self.last_token.at[slot, 0].set(nxt)
         self.active[slot] = True
-        self._active_dev = jnp.asarray(self.active)
+        self._active_dev = jnp.array(self.active)
         self.device_fetches += 1
         return int(host_scalar(nxt))
 
@@ -862,7 +867,7 @@ class SlotServer:
                 self.active[slot] = False
                 hit_cap = True
         if hit_cap:
-            self._active_dev = jnp.asarray(self.active)
+            self._active_dev = jnp.array(self.active)
 
         def _finalize(invalid):
             self.device_fetches += 1
@@ -947,7 +952,7 @@ class SlotServer:
             self._lengths_np[slot] = S
             self.last_token = self.last_token.at[slot, 0].set(first[0])
             self.active[slot] = True
-        self._active_dev = jnp.asarray(self.active)
+        self._active_dev = jnp.array(self.active)
         out_slots = decode_slots + ([slot] if final else [])
 
         def _finalize(invalid):
@@ -969,7 +974,7 @@ class SlotServer:
     def evict(self, slot: int) -> None:
         self._admissions.pop(slot, None)   # cancel mid-chunked admit
         self.active[slot] = False
-        self._active_dev = jnp.asarray(self.active)
+        self._active_dev = jnp.array(self.active)
         self.lengths = self.lengths.at[slot].set(0)
         self._lengths_np[slot] = 0
         if self._ml.enabled:

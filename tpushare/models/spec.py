@@ -392,7 +392,7 @@ class SpecDecodeMixin:
                     self.active[slot] = False
                     retired = True
             if retired:
-                self._active_dev = jnp.asarray(self.active)
+                self._active_dev = jnp.array(self.active)
             return out
 
         return PendingStep(_finalize, slots=slots)

@@ -7,6 +7,14 @@ steady-state step timer, and model FLOPs accounting so benchmarks can
 report MFU (model FLOPs utilization) against the chip's peak — the
 number that tells you whether co-located tenants are compute-starved
 or just HBM-bound.
+
+The serving path traces itself with two things from here. ``span``
+names a stretch of host code in whatever profiler session is running
+(``trace()`` above, or anyone's ``jax.profiler.start_trace``), on the
+device trace's clock; ``StageClock`` cuts one thread's loop into named
+stages, each a span plus an always-on cumulative clock that ``/stats``
+shows. Neither takes a flag: spans are on when someone traces, clocks
+always.
 """
 
 from __future__ import annotations
@@ -71,6 +79,50 @@ def trace(log_dir: str):
         jax.profiler.stop_trace()
 
 
+#: every span of the program carries this prefix in a trace
+SPAN_PREFIX = "tpushare."
+
+
+def span(name: str, **args):
+    """A host span ``tpushare.<name>`` in the running profiler session
+    (``/host:CPU``, the calling thread's line, the device trace's
+    clock); ``args`` become the event's stats, and more can be added
+    before it closes with ``.set_metadata(**args)``. With no session on
+    entering costs one flag test. Never a barrier, never a device
+    call."""
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name, **args)
+
+
+class StageClock:
+    """One thread's loop cut into named stages that do not overlap:
+    ``with clock.stage(name):`` is the span ``<prefix>.<name>`` plus
+    the elapsed ``time.monotonic()`` added to ``ms[name]`` and one to
+    ``n[name]``. Whatever the thread does under no stage shows as the
+    remainder against its wall clock. One writer (the owning thread)
+    and no lock: every stage is named up front so the dicts never
+    change size, and ``snapshot()`` hands other threads a copy."""
+
+    def __init__(self, prefix: str, stages):
+        self._prefix = prefix + "."
+        self.ms = {name: 0.0 for name in stages}
+        self.n = {name: 0 for name in stages}
+
+    @contextlib.contextmanager
+    def stage(self, name: str, **args):
+        t0 = time.monotonic()
+        try:
+            with span(self._prefix + name, **args) as sp:
+                yield sp
+        finally:
+            self.ms[name] += (time.monotonic() - t0) * 1e3
+            self.n[name] += 1
+
+    def snapshot(self) -> dict:
+        """{"ms": {stage: cumulative ms}, "n": {stage: entries}}."""
+        return {"ms": {k: round(v, 3) for k, v in self.ms.items()},
+                "n": dict(self.n)}
+
+
 def time_step(fn: Callable, *args, warmup: int = 2, iters: int = 10,
               **kwargs) -> float:
     """Median wall-clock seconds of ``fn(*args)`` at steady state."""
@@ -131,7 +183,10 @@ def time_step_chained(body: Callable, init, *consts, k_lo: int = 16,
 
 #: PhaseTimer phase name for the host-side scheduling gap of an
 #: overlapped engine tick: finalize-of-tick-N-1 done -> tick N's
-#: dispatch launched. The serving loop itself never attaches a
+#: dispatch call RETURNED, so a sample holds the dispatch itself
+#: (block growth, the launch, the eager sampler) as well as the
+#: scheduling before it; the ``schedule`` and ``dispatch`` stage clocks
+#: give the two apart. The serving loop itself never attaches a
 #: PhaseTimer (measurement mode only — see the class docstring); it
 #: records raw monotonic deltas and summarizes them with
 #: ``gap_percentiles`` below. Benches that DO attach a timer charge
