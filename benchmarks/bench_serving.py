@@ -95,6 +95,8 @@ def main() -> int:
             pk = pool_f.astype(cfg.dtype)
             pv, pks, pvs = pk, None, None
         del pool_f
+        # the stored page holds its kv heads merged (paged.PagedCache)
+        pk = pv = pk.reshape(L, nb_, bs, Hkv * Dh)
 
         # params ride as a const ARGUMENT: closure capture bakes the
         # 5 GB tree into the lowered module as constants and the

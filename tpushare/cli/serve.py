@@ -1602,7 +1602,7 @@ class ServeEngine:
         import numpy as np
         if not self._has_pool:
             return {"block_size": None, "blocks": {}}
-        from tpushare.models.paged import _row_pairs
+        from tpushare.models.paged import read_block
         out: Dict[str, Any] = {}
         for kh in keys_hex:
             try:
@@ -1617,12 +1617,9 @@ class ServeEngine:
                     blk = cache.index.get(key)
                     if blk is None:
                         break
-                    kvq = cache.pool_k_scale is not None
                     try:
                         import jax
-                        data = jax.device_get(
-                            {pf: getattr(cache, pf)[:, blk]
-                             for pf, _ in _row_pairs(kvq)})
+                        data = jax.device_get(read_block(cache, blk))
                         break
                     except Exception:   # donated mid-read: retry
                         data = None
@@ -1656,16 +1653,12 @@ class ServeEngine:
         import urllib.parse
 
         import numpy as np
-        from tpushare.models.paged import _row_pairs
+        from tpushare.models.paged import block_nbytes, block_shapes
         cache = self.srv.cache
-        kvq = cache.pool_k_scale is not None
-        fields = [pf for pf, _ in _row_pairs(kvq)]
-        shapes, dtypes, block_bytes = {}, {}, 0
-        for pf in fields:
-            pool = getattr(cache, pf)
-            shapes[pf] = tuple(pool.shape[:1] + pool.shape[2:])
-            dtypes[pf] = str(pool.dtype)
-            block_bytes += int(np.prod(shapes[pf])) * pool.dtype.itemsize
+        shapes = block_shapes(cache)
+        fields = list(shapes)
+        dtypes = {pf: str(getattr(cache, pf).dtype) for pf in fields}
+        block_bytes = block_nbytes(cache)
         est = self._host_tier.estimator
         if est.decide("net", block_bytes * len(keys_hex),
                       cache.block_size * len(keys_hex)) == "recompute":

@@ -685,12 +685,14 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray, cfg: MoEConfig, *,
     Dh = cfg.head_dim
     use_cache = cache is not None
     # Paged decode (the transformer.forward contract): the cache dict
-    # carries block-pool slices ({"pool_k": [L,nb,bs,Hkv,Dh], "pool_v",
-    # "table": [B,mb], "active": [B]}) instead of dense rows. KV is the
-    # ONLY MoE cache (routing re-decides per token), so the block pool
-    # ports unchanged: each layer scatters into its pool slice and
-    # attends through the table (pallas paged kernel on TPU, per-layer
-    # gathered view elsewhere). No kv_quant/multi-LoRA branches here —
+    # carries the stacked block pools ({"pool_k": [L,nb,bs,Hkv*Dh],
+    # "pool_v", "table": [B,mb], "active": [B]}) instead of dense rows.
+    # KV is the ONLY MoE cache (routing re-decides per token), so the
+    # block pool ports unchanged: the stacks are the layer loop's
+    # carry, each layer scatters its rows at [l, blk, off] in place and
+    # attends through the table at layer l (pallas paged kernel on TPU,
+    # one gather of the slots' blocks elsewhere) — no layer is sliced
+    # out or restacked. No kv_quant/multi-LoRA branches here —
     # those are dense-LM features (paged.PagedSlotServer rejects them
     # under a forward_fn override).
     paged = use_cache and "pool_k" in cache
@@ -700,6 +702,9 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray, cfg: MoEConfig, *,
     ragged = use_cache and jnp.asarray(pos_offset).ndim == 1
     if paged and not ragged:
         raise ValueError("paged cache requires ragged decode (pos [B])")
+    if paged and phase_timer is not None:
+        raise ValueError("phase_timer measures the dense-row cache "
+                         "only (paged_forward never passes one)")
     pg_active = (jnp.asarray(cache["active"])
                  if paged and "active" in cache
                  else (jnp.ones((B,), bool) if paged else None))
@@ -734,7 +739,9 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray, cfg: MoEConfig, *,
     else:
         kv_mask = None
 
-    def block(x, layer, lk=None, lv=None):
+    def block(x, layer, lk=None, lv=None, l=None):
+        # Paged: lk/lv are the whole stacked pools and ``l`` the layer
+        # to write and read; dense rows: this layer's slices.
         pt = phase_timer
         if layers_hook is not None:
             layer = layers_hook(layer)
@@ -755,17 +762,19 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray, cfg: MoEConfig, *,
             # block — the same guard as transformer.forward's paged
             # branches), then attend straight off the pool. S == 1 is
             # ragged decode, S > 1 the multi-token speculative verify.
-            bs_pg = lk.shape[1]
+            bs_pg = lk.shape[2]
             mb = cache["table"].shape[1]
-            trash = lk.shape[0] - 1
+            trash = lk.shape[1] - 1
             table = cache["table"]
             bi = jnp.minimum(positions // bs_pg, mb - 1)       # [B, S]
             entry = jnp.take_along_axis(table, bi, 1)          # [B, S]
             blk = jnp.where(pg_active[:, None] & (entry >= 0)
                             & (positions < mb * bs_pg), entry, trash)
             off = positions % bs_pg
-            lk = lk.at[blk, off].set(k.astype(lk.dtype))
-            lv = lv.at[blk, off].set(v.astype(lv.dtype))
+            lk = lk.at[l, blk, off].set(
+                k.reshape(B, S, Hkv * Dh).astype(lk.dtype))
+            lv = lv.at[l, blk, off].set(
+                v.reshape(B, S, Hkv * Dh).astype(lv.dtype))
             from tpushare.ops.flash_attention import (
                 paged_decode_eligible, paged_flash_decode,
                 paged_flash_verify, paged_verify_eligible)
@@ -774,15 +783,16 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray, cfg: MoEConfig, *,
             kernel = (paged_flash_decode if S == 1
                       else paged_flash_verify)
             if (attn_impl != "reference"
-                    and eligible(q, lk, max_ctx=mb * bs_pg)):
+                    and eligible(q, lk, max_ctx=mb * bs_pg,
+                                 stacked=True)):
                 # Pages stream from HBM once per slot per step; the
                 # fallback below re-materializes the whole slot view
                 # per layer (the eligibility policy notes).
-                attn = kernel(q, lk, lv, table, pos)
+                attn = kernel(q, lk, lv, table, pos, layer=l)
             else:
                 safe = jnp.where(table >= 0, table, trash)
-                kd = lk[safe].reshape(B, mb * bs_pg, Hkv, Dh)
-                vd = lv[safe].reshape(B, mb * bs_pg, Hkv, Dh)
+                kd = lk[l, safe].reshape(B, mb * bs_pg, Hkv, Dh)
+                vd = lv[l, safe].reshape(B, mb * bs_pg, Hkv, Dh)
                 pg_mask = (jnp.arange(mb * bs_pg)[None, None, :]
                            <= positions[:, :, None])           # [B,S,M]
                 attn = attention(q, kd, vd, causal=False,
@@ -832,13 +842,12 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray, cfg: MoEConfig, *,
         # between phases (a mark inside a scan body would be traced
         # away). Bit-compatible with the scan — same per-layer ops on
         # the same slices; only the loop carrier differs.
-        kk, vv = ("pool_k", "pool_v") if paged else ("k", "v")
         aux_l, nk_l, nv_l = [], [], []
         for li in range(cfg.n_layers):
             layer_i = {k: v[li] for k, v in params["layers"].items()}
             if use_cache:
-                x, aux, lk, lv = block(x, layer_i, cache[kk][li],
-                                       cache[vv][li])
+                x, aux, lk, lv = block(x, layer_i, cache["k"][li],
+                                       cache["v"][li])
                 nk_l.append(lk)
                 nv_l.append(lv)
             else:
@@ -850,14 +859,24 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray, cfg: MoEConfig, *,
             # The re-stack is a measurement-loop artifact (the scan
             # carries layers in place) — keep it out of unembed.
             phase_timer.mark("kv_stack", block_on=(nk, nv))
+    elif paged:
+        # The stacked pools are the CARRY and the layer index rides xs
+        # with the weights (transformer.forward's paged scan): each
+        # layer's scatter updates the carried buffer in place.
+        def body(carry, xs):
+            layer, l = xs
+            x, aux, nk, nv = block(carry[0], layer, *carry[1:], l)
+            return (x, nk, nv), aux
+        (x, nk, nv), aux_per_layer = jax.lax.scan(
+            body, (x, cache["pool_k"], cache["pool_v"]),
+            (params["layers"], jnp.arange(cfg.n_layers)))
     elif use_cache:
         def body(x, xs):
             layer, lk, lv = xs
             x, aux, lk, lv = block(x, layer, lk, lv)
             return x, (aux, lk, lv)
-        kk, vv = ("pool_k", "pool_v") if paged else ("k", "v")
         x, (aux_per_layer, nk, nv) = jax.lax.scan(
-            body, x, (params["layers"], cache[kk], cache[vv]))
+            body, x, (params["layers"], cache["k"], cache["v"]))
     else:
         def body(x, layer):
             x, aux, _, _ = block(x, layer)

@@ -9,17 +9,21 @@ tokens, not slots×max_len, so a tenant fits more concurrent sequences
 into its HBM share.
 
 Design (TPU-first):
-- Pool: [L, n_blocks, block_size, Hkv, Dh] per K/V — static shapes.
+- Pool: [L, n_blocks, block_size, Hkv*Dh] per K/V — static shapes; a
+  page holds its kv heads merged, the shape the paged kernels read.
 - Block table: [n_slots, max_blocks] int32 pool indices; host-side
   free-list decides allocation (admit/evict), device code only ever
   sees static-shaped gathers/scatters.
 - Decode: one jitted step writes each active slot's new KV into
-  (block_table[slot, t // bs], t % bs) via scatter and attends
-  straight off the pool through forward()'s paged-cache branch: the
-  pallas paged-attention kernel on TPU (block table rides scalar
+  (layer, block_table[slot, t // bs], t % bs) of the stacked pool via
+  scatter and attends straight off it through forward()'s paged-cache
+  branch: the stacks are the layer loop's carry and are donated into
+  the step, so the rows land in place and no layer of the pool is
+  sliced out, restacked or copied. The pallas paged-attention kernel on
+  TPU reads the stack at its layer (layer and block table ride scalar
   prefetch into the BlockSpec index_map — pages are DMA'd from HBM
-  once, nothing is gathered into a dense view), a per-layer gathered
-  view with the ragged kv_mask elsewhere.
+  once, nothing is gathered into a dense view); elsewhere one gather
+  of the slots' blocks with the ragged kv_mask.
 """
 
 from __future__ import annotations
@@ -100,7 +104,12 @@ class QuotaExceeded(PoolExhausted):
 @dataclasses.dataclass
 class PagedCache:
     """Pool + table state (a pytree; host mutates table via methods)."""
-    pool_k: jnp.ndarray        # [L, n_blocks, bs, Hkv, Dh]
+    # A page holds its kv heads merged, [bs, Hkv*Dh]: the shape the
+    # paged kernels read, so the forward hands them the stack as it
+    # lies (a split [.., Hkv, Dh] minor pair is a relayout of the whole
+    # layer on the chip). Whoever wants heads apart reshapes what is
+    # small: gathered rows, one block's payload (block_shapes).
+    pool_k: jnp.ndarray        # [L, n_blocks, bs, Hkv*Dh]
     pool_v: jnp.ndarray
     block_table: jnp.ndarray   # [n_slots, max_blocks] int32 (-1 = none)
     lengths: jnp.ndarray       # [n_slots] int32
@@ -159,6 +168,9 @@ class PagedCache:
     # so stale entries are bounded by the pool size and never read
     # (demotion reads an entry the moment alloc reclaims it).
     owners: Dict[int, str] = dataclasses.field(default_factory=dict)
+    # kv heads of one page (the pool shape keeps only Hkv*Dh): what a
+    # block's payload is split by on its way out of the pool.
+    kv_heads: int = 1
 
     @property
     def n_slots(self) -> int:
@@ -199,8 +211,8 @@ def init_paged_cache(cfg: TransformerConfig, *, n_slots: int,
     (shared blocks carry their scale rows along). Reads take the
     gathered-view path (transformer.py paged+kvq note)."""
     mb = max_blocks_per_slot or n_blocks
-    shape = (cfg.n_layers, n_blocks, block_size, cfg.n_kv_heads,
-             cfg.head_dim)
+    shape = (cfg.n_layers, n_blocks, block_size,
+             cfg.n_kv_heads * cfg.head_dim)
     kv_dtype = jnp.int8 if kv_quant else cfg.dtype
     if kv_quant:
         from tpushare.models.quant import kv_scale_pad
@@ -220,6 +232,7 @@ def init_paged_cache(cfg: TransformerConfig, *, n_slots: int,
                       if kv_quant else None),
         table_np=np.full((n_slots, mb), -1, np.int32),
         lengths_np=np.zeros((n_slots,), np.int64),
+        kv_heads=cfg.n_kv_heads,
     )
 
 
@@ -363,12 +376,7 @@ def _demote_block(cache: PagedCache, blk: int) -> bool:
     if tier is None or key is None:
         return False
     bs = cache.block_size
-    kvq = cache.pool_k_scale is not None
-    nbytes = 0
-    for pf, _ in _row_pairs(kvq):
-        pool = getattr(cache, pf)
-        shape = pool.shape[:1] + pool.shape[2:]     # [L, *block row]
-        nbytes += int(np.prod(shape)) * pool.dtype.itemsize
+    nbytes = block_nbytes(cache)
     if tier.estimator.decide("d2h", nbytes, bs) == "recompute":
         return False
     if tier.fault_demote is not None:
@@ -378,8 +386,7 @@ def _demote_block(cache: PagedCache, blk: int) -> bool:
             tier.demote_failures += 1
             return False
     t0 = time.perf_counter()
-    data = jax.device_get({pf: getattr(cache, pf)[:, blk]
-                           for pf, _ in _row_pairs(kvq)})
+    data = jax.device_get(read_block(cache, blk))
     tier.estimator.observe_transfer("d2h", nbytes,
                                     time.perf_counter() - t0)
     return tier.put(key, data, tenant=cache.owners.get(blk),
@@ -507,10 +514,8 @@ def _land_promoted(cache: PagedCache, keys: List[bytes],
     them) stack device-side for free; host-sourced entries pay their
     upload here, timed as the estimator's h2d observation."""
     tier = cache.host_tier
-    kvq = cache.pool_k_scale is not None
-    fields = [pf for pf, _ in _row_pairs(kvq)]
-    shapes = {pf: getattr(cache, pf).shape[:1]
-              + getattr(cache, pf).shape[2:] for pf in fields}
+    shapes = block_shapes(cache)
+    fields = list(shapes)
     datas = []
     for key in keys:
         data, _staged = tier.take_promote(key)
@@ -532,7 +537,9 @@ def _land_promoted(cache: PagedCache, keys: List[bytes],
         stacked = jnp.stack([jnp.asarray(d[pf]) for d in datas],
                             axis=1)             # [L, n, *block row]
         stacked_leaves.append(stacked)
-        updates[pf] = getattr(cache, pf).at[:, ids].set(stacked)
+        pool = getattr(cache, pf)
+        updates[pf] = pool.at[:, ids].set(
+            stacked.reshape(pool.shape[0], n, *pool.shape[2:]))
     if host_bytes:
         # Wait on the uploads (NOT the scatters) so the h2d rate the
         # crossover policy cites is the transfer, not queue luck.
@@ -767,6 +774,33 @@ def _row_pairs(kvq: bool):
     return pairs
 
 
+def block_shapes(cache: PagedCache) -> Dict[str, Tuple[int, ...]]:
+    """Shape of ONE block's payload per pool leaf — what the host tier
+    holds and the /kv/blocks wire carries: KV leaves [L, bs, Hkv, Dh]
+    (the pool stores a page's heads merged; a payload keeps them
+    apart, as it always was), scale leaves [L, Hkv_pad, bs]."""
+    out = {}
+    for pf, _ in _row_pairs(cache.pool_k_scale is not None):
+        L, _, *row = getattr(cache, pf).shape
+        if not pf.endswith("_scale"):
+            row = [row[0], cache.kv_heads, row[1] // cache.kv_heads]
+        out[pf] = (L, *row)
+    return out
+
+
+def block_nbytes(cache: PagedCache) -> int:
+    """Bytes of one block's payload over every pool leaf."""
+    return sum(int(np.prod(shape)) * getattr(cache, pf).dtype.itemsize
+               for pf, shape in block_shapes(cache).items())
+
+
+def read_block(cache: PagedCache, blk: int) -> Dict[str, jnp.ndarray]:
+    """One block's payload off the pools, still on the device (the
+    caller's device_get is the transfer)."""
+    return {pf: getattr(cache, pf)[:, blk].reshape(shape)
+            for pf, shape in block_shapes(cache).items()}
+
+
 def _admission_row(cfg: TransformerConfig, cache: PagedCache, slot: int,
                    S: int, cached_len: int):
     """The dense row cache one admission computes into, with the
@@ -813,8 +847,10 @@ def _admission_row(cfg: TransformerConfig, cache: PagedCache, slot: int,
                 # [L, nb, Hkv_pad, bs]; the row cache wants
                 # [L, cached_len, Hkv].
                 g = pool_scales_to_rows(g, Hkv)
+            # KV pages hold their heads merged; the row keeps them
+            # apart ([L, cached_len, Hkv, Dh]).
             row[rk_] = row[rk_].at[:, 0, :cached_len].set(
-                g.reshape(L, cached_len, *g.shape[3:]))
+                g.reshape(L, cached_len, *row[rk_].shape[3:]))
     return row, comp_len, n_blk
 
 
@@ -859,7 +895,7 @@ def _prefill_chunk(params, prompt: jnp.ndarray, cfg: TransformerConfig,
         updates = {}
         for pf, rk_ in _row_pairs(kvq):
             r = row[rk_][:, 0, start_blk * bs:end_blk * bs]
-            r = r.reshape(L, n_fresh, bs, *r.shape[2:])
+            r = r.reshape(L, n_fresh, bs, -1)   # a page: [bs, Hkv*Dh]
             if pf.endswith("_scale"):
                 from tpushare.models.quant import scales_to_pool_layout
                 r = scales_to_pool_layout(r)    # -> [L, fb, Hkv_pad, bs]
@@ -978,8 +1014,8 @@ class PagedSlotServer(SpecDecodeMixin):
         if self._placement is not None:
             self.cache = dataclasses.replace(
                 self.cache,
-                pool_k=self._placement.place_kv(self.cache.pool_k),
-                pool_v=self._placement.place_kv(self.cache.pool_v))
+                pool_k=self._placement.place_pool(self.cache.pool_k),
+                pool_v=self._placement.place_pool(self.cache.pool_v))
         # Device->host transfers made by the tick paths (step/
         # _spec_step/_fused_tick/admit_step completions) — the /stats
         # observability counter for the one-fetch-per-host invariant.
@@ -1011,11 +1047,14 @@ class PagedSlotServer(SpecDecodeMixin):
         self.last_token = jnp.zeros((n_slots, 1), jnp.int32)
         # layers_hook: per-layer transform seam (quant.dequant_hook
         # for int8 params).
-        # donate_argnums=(2, 3): the KV pools are DONATED into every
-        # jitted tick dispatch — each tick writes at most B block rows
-        # into pools that can be many GiB (sharded: the dominant
-        # per-device resident), so an undonated step would hold two
-        # full pool generations live across every dispatch. The old
+        # donate_argnums=(2, 3) and the int8 pools' scale leaves by
+        # name: the KV pools are DONATED into every jitted tick
+        # dispatch — each tick writes at most B block rows into pools
+        # that can be many GiB (sharded: the dominant per-device
+        # resident). The forward carries the stacks through its layer
+        # loop and writes them in place, so the returned pools ARE the
+        # donated buffers: a step holds one pool generation and copies
+        # none (tests/test_paged_inplace.py holds it to that). The old
         # arrays are dead the moment the call returns (the tick
         # methods rebind self.cache/self._dpk to the returned pools
         # and nothing else holds a pool reference — DN601/DN602 police
@@ -1026,7 +1065,8 @@ class PagedSlotServer(SpecDecodeMixin):
             decode_core, cfg=cfg, block_size=block_size,
             attn_impl=attn_impl, layers_hook=layers_hook,
             mlora_scale=mlora_scale, forward_fn=forward_fn),
-            donate_argnums=(2, 3))
+            donate_argnums=(2, 3),
+            donate_argnames=("pool_k_scale", "pool_v_scale"))
         self._prefill = jax.jit(_program(
             "paged_prefill",
             base_fwd, cfg=cfg, attn_impl=attn_impl,
@@ -1039,7 +1079,8 @@ class PagedSlotServer(SpecDecodeMixin):
             verify_core, cfg=cfg, attn_impl=attn_impl,
             layers_hook=layers_hook, mlora_scale=mlora_scale,
             forward_fn=forward_fn),
-            donate_argnums=(2, 3))
+            donate_argnums=(2, 3),
+            donate_argnames=("pool_k_scale", "pool_v_scale"))
         # Speculative decoding over the paged pools: a draft LM drafts
         # gamma tokens per slot, the target verifies the whole block in
         # ONE weight stream — and unlike the dense speculative loop
@@ -1083,7 +1124,7 @@ class PagedSlotServer(SpecDecodeMixin):
             self.draft_params = draft_params
             self.draft_cfg = draft_cfg
             dshape = (draft_cfg.n_layers, n_blocks, block_size,
-                      draft_cfg.n_kv_heads, draft_cfg.head_dim)
+                      draft_cfg.n_kv_heads * draft_cfg.head_dim)
             self._dpk = jnp.zeros(dshape, draft_cfg.dtype)
             self._dpv = jnp.zeros(dshape, draft_cfg.dtype)
             if self._placement is not None:
@@ -1095,8 +1136,8 @@ class PagedSlotServer(SpecDecodeMixin):
                 dplace = make_placement(mesh, draft_cfg,
                                         draft_param_specs, role="draft")
                 self.draft_params = dplace.place_params(draft_params)
-                self._dpk = dplace.place_kv(self._dpk)
-                self._dpv = dplace.place_kv(self._dpv)
+                self._dpk = dplace.place_pool(self._dpk)
+                self._dpv = dplace.place_pool(self._dpv)
             # draft_layers_hook: the quantized-self-speculation seam —
             # pass quant.dequant_hook(cfg) with an int8 quantize_params
             # tree of the TARGET as the draft: the draft is the
@@ -1110,7 +1151,8 @@ class PagedSlotServer(SpecDecodeMixin):
                 decode_core, cfg=draft_cfg, block_size=block_size,
                 attn_impl=attn_impl, layers_hook=draft_layers_hook,
                 mlora_scale=mlora_scale, forward_fn=dfwd_fn),
-                donate_argnums=(2, 3))
+                donate_argnums=(2, 3),
+                donate_argnames=("pool_k_scale", "pool_v_scale"))
             self._draft_prefill = jax.jit(_program(
                 "draft_paged_prefill",
                 forward if dfwd_fn is None else dfwd_fn,
@@ -1125,7 +1167,8 @@ class PagedSlotServer(SpecDecodeMixin):
                 verify_core, cfg=draft_cfg, attn_impl=attn_impl,
                 layers_hook=draft_layers_hook, mlora_scale=mlora_scale,
                 forward_fn=dfwd_fn),
-                donate_argnums=(2, 3))
+                donate_argnums=(2, 3),
+                donate_argnames=("pool_k_scale", "pool_v_scale"))
             # temperature > 0: proposals are SAMPLED from the draft's
             # filtered law and verified with the stochastic rejection
             # rule (spec.spec_accept_core) — every emitted token's
@@ -1167,12 +1210,12 @@ class PagedSlotServer(SpecDecodeMixin):
         frees them instead of parking garbage on the LRU)."""
         c = self.cache
         repl = {}
-        for pf in ("pool_k", "pool_v"):
+        for pf, _ in _row_pairs(c.pool_k_scale is not None):
             arr = getattr(c, pf)
             if arr.is_deleted():
                 new = jnp.zeros(arr.shape, arr.dtype)
                 if self._placement is not None:
-                    new = self._placement.place_kv(new)
+                    new = self._placement.place_pool(new)
                 repl[pf] = new
         if repl:
             for blk in list(c.lru):
@@ -1187,7 +1230,7 @@ class PagedSlotServer(SpecDecodeMixin):
                 if arr.is_deleted():
                     new = jnp.zeros(arr.shape, arr.dtype)
                     if self._placement is not None:
-                        new = self._placement.place_kv(new)
+                        new = self._placement.place_pool(new)
                     setattr(self, attr, new)
 
     def admit(self, prompt: jnp.ndarray, adapter: int = -1,

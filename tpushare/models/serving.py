@@ -182,10 +182,12 @@ def make_moe_decoder(cfg, mesh: Mesh, *, quantized: bool = False):
 
 
 def paged_pool_specs() -> P:
-    """Paged KV pool PartitionSpec: [L, n_blocks, bs, Hkv, Dh], kv
-    heads over tp (same head split as cache_specs; block tables and
-    lengths stay replicated — they are tiny int32 control state)."""
-    return P(None, None, None, "tp", None)
+    """Paged KV pool PartitionSpec: [L, n_blocks, bs, Hkv*Dh], kv
+    heads over tp: a page holds its heads merged, head-major, so
+    splitting the merged axis tp ways is the same head split as
+    cache_specs (tp divides Hkv); block tables and lengths stay
+    replicated — they are tiny int32 control state."""
+    return P(None, None, None, "tp")
 
 
 def make_tp_paged_decoder(cfg: TransformerConfig, mesh: Mesh, *,
@@ -287,8 +289,8 @@ class MeshPlacement:
     Weights place per the family's param_specs (tensor-parallel dense
     attention/MLP; expert x tensor-parallel MoE — experts over ``ep``,
     per-expert GEMMs over ``tp``). KV storage — dense rows
-    [L, B, S, Hkv, Dh] AND paged pools [L, nb, bs, Hkv, Dh] share the
-    trailing (Hkv, Dh) layout — splits the kv-head axis over ``tp``
+    [L, B, S, Hkv, Dh] AND paged pools [L, nb, bs, Hkv*Dh] (heads
+    merged, head-major) — splits the kv heads over ``tp``
     (cache_specs / paged_pool_specs, the same head split the shard_map
     decoder factories use). Control state (block tables, lengths,
     token buffers, active masks) stays replicated: every mutation is
@@ -309,11 +311,12 @@ class MeshPlacement:
     def __init__(self, mesh, param_specs_tree):
         self.mesh = mesh
         self._pspecs = param_specs_tree
-        # THE kv-head split, not a copy of it: paged_pool_specs() is
-        # the one home of the pool layout and cache_specs() shares the
-        # same index-3 head axis for dense rows — a layout change
-        # there must move this placement with it.
-        self.kv = NamedSharding(mesh, paged_pool_specs())
+        # THE kv-head split, not a copy of it: cache_specs() is the
+        # one home of the dense rows' layout ([L, B, S, Hkv, Dh]) and
+        # paged_pool_specs() of the pool's ([L, nb, bs, Hkv*Dh]) — a
+        # layout change there must move this placement with it.
+        self.kv = NamedSharding(mesh, cache_specs()["k"])
+        self.pool = NamedSharding(mesh, paged_pool_specs())
 
     @property
     def shape(self) -> Dict[str, int]:
@@ -349,9 +352,12 @@ class MeshPlacement:
         return shard_tree(params, self.mesh, self._pspecs)
 
     def place_kv(self, tree):
-        """Place KV leaves (dense row dicts or bare pool arrays) on the
-        kv-head split."""
+        """Place dense-row KV leaves on the kv-head split."""
         return jax.device_put(tree, self.kv)
+
+    def place_pool(self, pool):
+        """Place a paged KV pool on the same head split."""
+        return jax.device_put(pool, self.pool)
 
 
 def bucket_len(n: int, floor: int = 16) -> int:

@@ -283,11 +283,15 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray,
     Dh = cfg.head_dim
     pos = jnp.asarray(pos_offset)
     ragged = pos.ndim == 1
-    # Paged decode: cache carries block-pool slices instead of dense
-    # rows ({"pool_k": [L,nb,bs,Hkv,D], "pool_v", "table": [B,mb],
-    # "active": [B]}). Attention runs straight off the pool (pallas
-    # paged kernel on TPU; per-layer gathered view elsewhere) — the
-    # pool is never materialized as one [L,B,mb*bs,...] dense cache.
+    # Paged decode: cache carries the stacked block pools instead of
+    # dense rows ({"pool_k": [L,nb,bs,Hkv*D], "pool_v", "table": [B,mb],
+    # "active": [B]}). The stacks are the layer loop's CARRY: layer l
+    # writes its new rows at [l, blk, off] of the stack (a scatter on a
+    # loop-carried buffer, in place) and attention reads the stack at
+    # layer l (pallas paged kernel on TPU; one gather of the slots'
+    # blocks elsewhere) — no layer of the pool is sliced out or
+    # restacked, and the pool is never materialized as one
+    # [L,B,mb*bs,...] dense cache.
     paged = cache is not None and "pool_k" in cache
     # Ragged multi-token (S > 1 with per-sequence offsets) is supported
     # by BOTH cache layouts: the paged branch (speculative verify) and,
@@ -338,8 +342,10 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray,
     # block body — the window enters the mask as a traced scalar.
     wls = layer_windows(cfg)
 
-    def block(x, layer, lk_cache, lv_cache, lk_s, lv_s, w):
+    def block(x, layer, lk_cache, lv_cache, lk_s, lv_s, w, l=None):
         # lk_s/lv_s: per-(pos, head) scales when kvq, else None.
+        # Paged: the four cache leaves are the whole stacks and ``l``
+        # the layer to write and read; dense rows: this layer's slices.
         layer = dict(layer)
         ml = layer.pop("_mlora", None)       # [NA, ...] per-layer slice
         if layers_hook is not None:
@@ -380,6 +386,43 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray,
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
 
+        def wr_pg(c, rows):
+            """Write ``rows`` [..., Hkv, Dh] at [l, blk, off] of the
+            stacked pool ``c`` [L, nb, bs, Hkv*Dh] — the paged
+            branches' one write, in place on the loop's carry."""
+            return c.at[l, blk, off].set(
+                rows.reshape(*rows.shape[:-2], -1).astype(c.dtype))
+
+        def wr_pg_scale(c, s):
+            """Scales ``s`` [..., Hkv] into column [l, blk, :, off] of
+            the scale stack, stored in the kernel page layout
+            [L, nb, Hkv_pad, bs] (heads zero-padded). Element writes:
+            a column written as a window ([l, blk, :, off]) has XLA
+            transpose the whole stack there and back."""
+            hp = c.shape[2]
+            sp = jnp.zeros((*s.shape[:-1], hp), jnp.float32
+                           ).at[..., :Hkv].set(s)
+            return c.at[l, blk[..., None], jnp.arange(hp),
+                        off[..., None]].set(sp)
+
+        def slot_views(table, trash):
+            """The gathered fallback's K and V [B, mb*bs, Hkv, Dh]:
+            every slot's blocks of layer ``l`` in ONE gather off the
+            stack (unallocated entries read the trash block, which
+            the position mask hides)."""
+            safe = jnp.where(table >= 0, table, trash)
+            views = []
+            for c, c_s in ((lk_cache, lk_s), (lv_cache, lv_s)):
+                g = c[l, safe].reshape(B, -1, Hkv, Dh)
+                if kvq:
+                    from tpushare.models.quant import (
+                        kv_dequantize, pool_scales_to_rows)
+                    rows_s = pool_scales_to_rows(c_s[l, safe], Hkv)
+                    g = kv_dequantize(g, rows_s.reshape(B, -1, Hkv),
+                                      cfg.dtype)
+                views.append(g)
+            return views
+
         if paged and S > 1:
             # Multi-token ragged paged step (speculative verify: the
             # target scores a gamma+1 candidate block per slot in ONE
@@ -389,9 +432,9 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray,
             # no scalar q_offset can express ragged multi-token
             # causality, hence the 3D kv_mask. No pallas path: Sq>1
             # verify is compute-shaped, XLA handles it.
-            bs_pg = lk_cache.shape[1]
+            bs_pg = lk_cache.shape[2]
             mb = cache["table"].shape[1]
-            trash = lk_cache.shape[0] - 1
+            trash = lk_cache.shape[1] - 1
             table = cache["table"]
             pos_grid = pos[:, None] + jnp.arange(S)[None, :]   # [B, S]
             bi = jnp.minimum(pos_grid // bs_pg, mb - 1)
@@ -403,49 +446,29 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray,
                             & (pos_grid < mb * bs_pg), entry, trash)
             off = pos_grid % bs_pg
             if kvq:
-                from tpushare.models.quant import (kv_dequantize,
-                                                   pool_scales_to_rows)
-                hp = lk_s.shape[1]
-                wr = lambda c, x: c.at[blk, off].set(x)
-
-                def wr_s(c, s):             # s [B, S, Hkv]
-                    sp = jnp.zeros((B, S, hp), jnp.float32
-                                   ).at[..., :Hkv].set(s)
-                    return c.at[blk, :, off].set(sp)
                 lk_cache, lv_cache, lk_s, lv_s = _kvq_write(
-                    wr, wr_s, k, v)
+                    wr_pg, wr_pg_scale, k, v)
             else:
-                lk_cache = lk_cache.at[blk, off].set(
-                    k.astype(lk_cache.dtype))
-                lv_cache = lv_cache.at[blk, off].set(
-                    v.astype(lv_cache.dtype))
+                lk_cache = wr_pg(lk_cache, k)
+                lv_cache = wr_pg(lv_cache, v)
             from tpushare.ops.flash_attention import (
                 paged_flash_verify, paged_verify_eligible)
             if (attn_impl != "reference"
                     and paged_verify_eligible(q, lk_cache,
                                               quantized=kvq,
-                                              max_ctx=mb * bs_pg)):
+                                              max_ctx=mb * bs_pg,
+                                              stacked=True)):
                 # Pages stream from HBM once per slot per round; the
                 # fallback below re-materializes the whole slot view
                 # per layer (paged_verify_eligible policy note).
                 attn = paged_flash_verify(
-                    q, lk_cache, lv_cache, table, pos,
+                    q, lk_cache, lv_cache, table, pos, layer=l,
                     scale=cfg.attn_scale, window=w,
                     attn_softcap=cfg.attn_softcap,
                     **({"k_scale": lk_s, "v_scale": lv_s} if kvq
                        else {}))
             else:
-                safe = jnp.where(table >= 0, table, trash)
-                if kvq:
-                    ks_r = pool_scales_to_rows(lk_s[safe], Hkv)
-                    vs_r = pool_scales_to_rows(lv_s[safe], Hkv)
-                    kd = kv_dequantize(lk_cache[safe], ks_r, cfg.dtype
-                                       ).reshape(B, mb * bs_pg, Hkv, Dh)
-                    vd = kv_dequantize(lv_cache[safe], vs_r, cfg.dtype
-                                       ).reshape(B, mb * bs_pg, Hkv, Dh)
-                else:
-                    kd = lk_cache[safe].reshape(B, mb * bs_pg, Hkv, Dh)
-                    vd = lv_cache[safe].reshape(B, mb * bs_pg, Hkv, Dh)
+                kd, vd = slot_views(table, trash)
                 k_pos = jnp.arange(mb * bs_pg)
                 kv_mask3 = k_pos[None, None, :] <= pos_grid[..., None]
                 if w is not None:
@@ -461,9 +484,9 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray,
             # slot's current block (inactive slots write to the trash
             # block — their table entries may name live blocks another
             # step must not clobber), then attend through the table.
-            bs_pg = lk_cache.shape[1]
+            bs_pg = lk_cache.shape[2]
             mb = cache["table"].shape[1]
-            trash = lk_cache.shape[0] - 1
+            trash = lk_cache.shape[1] - 1
             table = cache["table"]
             bi = jnp.minimum(pos // bs_pg, mb - 1)
             entry = jnp.take_along_axis(table, bi[:, None], 1)[:, 0]
@@ -473,56 +496,31 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray,
                             & (pos < mb * bs_pg), entry, trash)
             off = pos % bs_pg
             if kvq:
-                from tpushare.models.quant import kv_dequantize
-                wr = lambda c, x: c.at[blk, off].set(x)
-                # Scale pool is stored in the kernel page layout
-                # [nb, Hkv_pad, bs]: one row-write per (block, offset)
-                # column, heads zero-padded — no pool transpose here.
-                hp = lk_s.shape[1]
-
-                def wr_s(c, s):             # s [B, Hkv]
-                    sp = jnp.zeros((B, hp), jnp.float32
-                                   ).at[:, :Hkv].set(s)
-                    return c.at[blk, :, off].set(sp)
                 lk_cache, lv_cache, lk_s, lv_s = _kvq_write(
-                    wr, wr_s, k[:, 0], v[:, 0])
+                    wr_pg, wr_pg_scale, k[:, 0], v[:, 0])
             else:
-                lk_cache = lk_cache.at[blk, off].set(
-                    k[:, 0].astype(lk_cache.dtype))
-                lv_cache = lv_cache.at[blk, off].set(
-                    v[:, 0].astype(lv_cache.dtype))
+                lk_cache = wr_pg(lk_cache, k[:, 0])
+                lv_cache = wr_pg(lv_cache, v[:, 0])
             from tpushare.ops.flash_attention import (
                 paged_decode_eligible, paged_flash_decode)
             if (attn_impl != "reference"
                     and paged_decode_eligible(q, lk_cache,
                                               quantized=kvq,
-                                              max_ctx=mb * bs_pg)):
+                                              max_ctx=mb * bs_pg,
+                                              stacked=True)):
                 # Int8 pools take the same kernel with scale pages
                 # (in-kernel dequant after the DMA) when the slot
                 # capacity clears the measured crossover (~8k ctx);
                 # shorter contexts take the gathered fallback below
                 # (paged_decode_eligible policy note).
                 attn = paged_flash_decode(
-                    q, lk_cache, lv_cache, table, pos,
+                    q, lk_cache, lv_cache, table, pos, layer=l,
                     scale=cfg.attn_scale, window=w,
                     attn_softcap=cfg.attn_softcap,
                     **({"k_scale": lk_s, "v_scale": lv_s} if kvq
                        else {}))
             else:
-                safe = jnp.where(table >= 0, table, trash)
-                if kvq:
-                    from tpushare.models.quant import pool_scales_to_rows
-                    ks_r = pool_scales_to_rows(lk_s[safe], Hkv)
-                    vs_r = pool_scales_to_rows(lv_s[safe], Hkv)
-                    kd = kv_dequantize(lk_cache[safe], ks_r,
-                                       cfg.dtype
-                                       ).reshape(B, mb * bs_pg, Hkv, Dh)
-                    vd = kv_dequantize(lv_cache[safe], vs_r,
-                                       cfg.dtype
-                                       ).reshape(B, mb * bs_pg, Hkv, Dh)
-                else:
-                    kd = lk_cache[safe].reshape(B, mb * bs_pg, Hkv, Dh)
-                    vd = lv_cache[safe].reshape(B, mb * bs_pg, Hkv, Dh)
+                kd, vd = slot_views(table, trash)
                 kv_mask = jnp.arange(mb * bs_pg)[None, :] <= pos[:, None]
                 if w is not None:
                     kv_mask &= window_keep(
@@ -677,32 +675,41 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray,
             return x, None
         x, _ = jax.lax.scan(body, x, (params["layers"], wls))
         new_cache = None
+    elif paged:
+        # The stacked pools (and their scale stacks) are the CARRY, the
+        # layer index rides xs with the weights: each layer's scatter
+        # updates the carried buffer in place, and with the pools
+        # donated into the jitted step (paged.PagedSlotServer) the
+        # returned stacks are the argument buffers themselves. As scan
+        # xs/ys a layer is sliced out, updated as a copy and restacked:
+        # pool-sized copies every step, whatever is live.
+        def body(carry, xs):
+            layer, w, l = xs
+            return block(carry[0], layer, *carry[1:], w, l), None
+        (x, pk, pv, pks, pvs), _ = jax.lax.scan(
+            body, (x, cache["pool_k"], cache["pool_v"],
+                   cache.get("pool_k_scale"), cache.get("pool_v_scale")),
+            (params["layers"], wls, jnp.arange(cfg.n_layers)))
+        new_cache = dict(cache, pool_k=pk, pool_v=pv)
+        if kvq:
+            new_cache.update(pool_k_scale=pks, pool_v_scale=pvs)
     elif kvq:
         def body(x, xs):
             layer, lk, lv, lks, lvs, w = xs
             x, lk, lv, lks, lvs = block(x, layer, lk, lv, lks, lvs, w)
             return x, (lk, lv, lks, lvs)
-        kk, vv = ("pool_k", "pool_v") if paged else ("k", "v")
         x, (ck, cv, cks, cvs) = jax.lax.scan(
-            body, x, (params["layers"], cache[kk], cache[vv],
-                      cache[kk + "_scale"], cache[vv + "_scale"], wls))
-        new_cache = dict(cache)
-        new_cache.update({kk: ck, vv: cv, kk + "_scale": cks,
-                          vv + "_scale": cvs})
-        if not paged:
-            new_cache = {k2: new_cache[k2] for k2 in
-                         ("k", "v", "k_scale", "v_scale")}
+            body, x, (params["layers"], cache["k"], cache["v"],
+                      cache["k_scale"], cache["v_scale"], wls))
+        new_cache = {"k": ck, "v": cv, "k_scale": cks, "v_scale": cvs}
     else:
         def body(x, xs):
             layer, lk, lv, w = xs
             x, lk, lv, _, _ = block(x, layer, lk, lv, None, None, w)
             return x, (lk, lv)
-        ck_in = cache["pool_k"] if paged else cache["k"]
-        cv_in = cache["pool_v"] if paged else cache["v"]
         x, (ck, cv) = jax.lax.scan(
-            body, x, (params["layers"], ck_in, cv_in, wls))
-        new_cache = (dict(cache, pool_k=ck, pool_v=cv) if paged
-                     else {"k": ck, "v": cv})
+            body, x, (params["layers"], cache["k"], cache["v"], wls))
+        new_cache = {"k": ck, "v": cv}
 
     if last_logit_only:
         # Prefill only needs the last position's logits: slicing before

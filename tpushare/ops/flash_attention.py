@@ -657,8 +657,45 @@ def flash_decode(q: jnp.ndarray, k: jnp.ndarray, v: jnp.ndarray,
     return out[:, :g].reshape(B, Hkv * g, D)[:, None].reshape(B, 1, H, D)
 
 
-def _paged_decode_kernel(table_ref, pos_ref, win_ref, q_ref, k_ref, v_ref,
-                         *rest, scale: float,
+def _stacked_pages(pool_k, pool_v, k_scale, v_scale, layer, D: int):
+    """The paged kernels' one view of the KV pool: pages
+    [L, nb, bs, Hkv*D] (the shape models/paged.py stores, so a whole
+    stacked pool is passed as it lies and ``layer`` picks the layer in
+    the index_map — no layer slice exists in HBM), scale pages
+    [L, nb, Hkv_pad, bs], and the layer as a scalar-prefetch operand.
+    ``layer=None`` takes one layer's pool [nb, bs, Hkv, D] (scales
+    [nb, Hkv_pad, bs]) as a stack of one. Returns
+    (pages_k, pages_v, k_scale, v_scale, layer [1] int32, nb, bs, Hkv)."""
+    if layer is None:
+        nb, bs, Hkv, D2 = pool_k.shape
+        assert D2 == D, (pool_k.shape, D)
+        pool_k = pool_k.reshape(1, nb, bs, Hkv * D)
+        pool_v = pool_v.reshape(1, nb, bs, Hkv * D)
+        if k_scale is not None:
+            k_scale, v_scale = k_scale[None], v_scale[None]
+        layer = 0
+    _, nb, bs, HD = pool_k.shape
+    assert HD % D == 0 and pool_v.shape == pool_k.shape, (
+        pool_k.shape, pool_v.shape, D)
+    assert bs % 8 == 0, f"block_size {bs} must be a multiple of 8"
+    return (pool_k, pool_v, k_scale, v_scale,
+            jnp.asarray(layer, jnp.int32).reshape(1), nb, bs, HD // D)
+
+
+def _scale_pages(k_scale, v_scale, L: int, nb: int, bs: int, Hkv: int):
+    """The two int8 scale operands of a paged kernel, checked against
+    the one page layout [L, nb, Hkv_pad, bs] (quant.scales_to_pool_layout;
+    stored so at init by models/paged.py)."""
+    from tpushare.models.quant import kv_scale_pad
+    want = (L, nb, kv_scale_pad(Hkv), bs)   # one padding rule with the pool
+    assert k_scale.shape == want == v_scale.shape, (
+        f"scale pools must be pre-laid-out [L, nb, Hkv_pad, bs] = {want}"
+        f", got {k_scale.shape}")
+    return [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
+
+
+def _paged_decode_kernel(table_ref, pos_ref, win_ref, layer_ref, q_ref,
+                         k_ref, v_ref, *rest, scale: float,
                          softcap: Optional[float], hkv: int, g_pad: int,
                          n_pages: int, quantized: bool = False):
     # One decode step over a block-table-paged KV pool. Grid (B, pages):
@@ -668,7 +705,8 @@ def _paged_decode_kernel(table_ref, pos_ref, win_ref, q_ref, k_ref, v_ref,
     # gathered-view fallback in transformer.py's paged branch pays).
     # Each grid step DMAs
     # exactly one page [bs, Hkv*D]; all kv heads are processed in a
-    # static unroll so page bytes stream from HBM once.
+    # static unroll so page bytes stream from HBM once. ``layer_ref``
+    # (which layer of the stacked pool) is read by the index_maps only.
     #
     # quantized=True: k/v pages are int8 and two extra scale refs
     # ([1, Hkv_pad, bs] f32 — bs on the lane dim, the layout Mosaic
@@ -739,18 +777,24 @@ def paged_flash_decode(q: jnp.ndarray, pool_k: jnp.ndarray,
                        window=None, attn_softcap: Optional[float] = None,
                        k_scale: Optional[jnp.ndarray] = None,
                        v_scale: Optional[jnp.ndarray] = None,
+                       layer=None,
                        interpret: bool = False) -> jnp.ndarray:
     """Ragged decode attention straight off a paged KV pool.
 
-    q [B, 1, H, D]; pool_k/pool_v [n_blocks, bs, Hkv, D] (one layer's
-    pool, models/paged.py layout); table [B, max_blocks] int32 pool
+    q [B, 1, H, D]; pool_k/pool_v either the whole stacked pool
+    [L, n_blocks, bs, Hkv*D] as models/paged.py stores it, with
+    ``layer`` (traced scalar OK) the layer to read — the forward's
+    call: the stack is the layer loop's carry and no layer of it is
+    ever sliced out — or, with ``layer=None``, one layer's pool
+    [n_blocks, bs, Hkv, D]; table [B, max_blocks] int32 pool
     indices (-1 = unallocated); pos [B] — slot b attends pool positions
     <= pos[b] through its block table (the new token's KV must already
     be scattered at pos[b]). Unallocated table entries are clamped to
     page 0 and masked by ``pos``, so they are never attended.
 
     Int8 pools: pass ``k_scale``/``v_scale`` [n_blocks, Hkv_pad, bs]
-    (the models/paged.py kv_quant pools store scales in exactly this
+    (stacked: [L, n_blocks, Hkv_pad, bs]; the models/paged.py kv_quant
+    pools store scales in exactly this
     page layout from init — quant.scales_to_pool_layout; bs on the
     lane dim because Mosaic rejects a short minor axis) — pages stream
     from HBM as int8 and dequantize on the VPU after the DMA, halving
@@ -768,9 +812,9 @@ def paged_flash_decode(q: jnp.ndarray, pool_k: jnp.ndarray,
     """
     B, Sq, H, D = q.shape
     assert Sq == 1, "paged_flash_decode is the Sq==1 path"
-    nb, bs, Hkv, D2 = pool_k.shape
-    assert D2 == D and H % Hkv == 0, (pool_k.shape, q.shape)
-    assert bs % 8 == 0, f"block_size {bs} must be a multiple of 8"
+    kp, vp, k_scale, v_scale, layer_s, nb, bs, Hkv = _stacked_pages(
+        pool_k, pool_v, k_scale, v_scale, layer, D)
+    assert H % Hkv == 0, (pool_k.shape, q.shape)
     quantized = k_scale is not None
     mb = table.shape[1]
     g = H // Hkv
@@ -781,42 +825,35 @@ def paged_flash_decode(q: jnp.ndarray, pool_k: jnp.ndarray,
     qp = jnp.zeros((B, Hkv * g_pad, D), q.dtype)
     for h in range(Hkv):                          # static, Hkv is small
         qp = qp.at[:, h * g_pad:h * g_pad + g].set(q4[:, h])
-    kp = pool_k.reshape(nb, bs, Hkv * D)
-    vp = pool_v.reshape(nb, bs, Hkv * D)
     table_s = jnp.asarray(table, jnp.int32)
     pos_s = jnp.asarray(pos, jnp.int32).reshape(B)
     win = jnp.asarray(0 if window is None else window,
                       jnp.int32).reshape(1)
 
-    def q_index(b, kb, table_ref, pos_ref, win_ref):
+    def q_index(b, kb, table_ref, pos_ref, win_ref, layer_ref):
         return (b, 0, 0)
 
-    def kv_index(b, kb, table_ref, pos_ref, win_ref):
+    def kv_index(b, kb, table_ref, pos_ref, win_ref, layer_ref):
         # Page-level DMA skip: clamp the page index into the slot's
         # live range [lo, hi) so pages past pos[b] (and before the
         # sliding window) repeat an already-fetched page and the copy
         # is elided — halves KV read traffic at random fill levels.
         lo, hi = _kv_live_range(pos_ref[b], win_ref[0], bs, mb)
-        return (jnp.maximum(table_ref[b, jnp.clip(kb, lo, hi - 1)], 0),
+        return (layer_ref[0],
+                jnp.maximum(table_ref[b, jnp.clip(kb, lo, hi - 1)], 0),
                 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, Hkv * g_pad, D), q_index),
-        pl.BlockSpec((1, bs, Hkv * D), kv_index),
-        pl.BlockSpec((1, bs, Hkv * D), kv_index),
+        pl.BlockSpec((None, 1, bs, Hkv * D), kv_index),
+        pl.BlockSpec((None, 1, bs, Hkv * D), kv_index),
     ]
     operands = [qp, kp, vp]
     if quantized:
-        from tpushare.models.quant import kv_scale_pad
-        hkv_pad = kv_scale_pad(Hkv)     # one padding rule with the pool
-        assert k_scale.shape == (nb, hkv_pad, bs) == v_scale.shape, (
-            f"scale pools must be pre-laid-out [nb, Hkv_pad, bs] = "
-            f"{(nb, hkv_pad, bs)} (quant.scales_to_pool_layout; stored "
-            f"so at init by models/paged.py), got {k_scale.shape}")
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
-        in_specs += [pl.BlockSpec((1, hkv_pad, bs), kv_index),
-                     pl.BlockSpec((1, hkv_pad, bs), kv_index)]
+        operands += _scale_pages(k_scale, v_scale, kp.shape[0], nb, bs,
+                                 Hkv)
+        in_specs += [pl.BlockSpec((None, 1) + operands[-1].shape[2:],
+                                  kv_index)] * 2
 
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel,
@@ -824,7 +861,7 @@ def paged_flash_decode(q: jnp.ndarray, pool_k: jnp.ndarray,
                           softcap=attn_softcap, hkv=Hkv, g_pad=g_pad,
                           n_pages=mb, quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B, mb),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, Hkv * g_pad, D), q_index),
@@ -836,13 +873,13 @@ def paged_flash_decode(q: jnp.ndarray, pool_k: jnp.ndarray,
         ),
         out_shape=_sds((B, Hkv * g_pad, D), q.dtype, q, pool_k, pool_v),
         interpret=interpret,
-    )(table_s, pos_s, win, *operands)
+    )(table_s, pos_s, win, layer_s, *operands)
     out4 = out.reshape(B, Hkv, g_pad, D)[:, :, :g]
     return out4.reshape(B, 1, H, D)
 
 
-def _paged_verify_kernel(table_ref, pos_ref, win_ref, q_ref, k_ref, v_ref,
-                         *rest, scale: float,
+def _paged_verify_kernel(table_ref, pos_ref, win_ref, layer_ref, q_ref,
+                         k_ref, v_ref, *rest, scale: float,
                          softcap: Optional[float], hkv: int, sq: int,
                          gq_pad: int, n_pages: int,
                          quantized: bool = False):
@@ -924,13 +961,15 @@ def paged_flash_verify(q: jnp.ndarray, pool_k: jnp.ndarray,
                        window=None, attn_softcap: Optional[float] = None,
                        k_scale: Optional[jnp.ndarray] = None,
                        v_scale: Optional[jnp.ndarray] = None,
+                       layer=None,
                        interpret: bool = False) -> jnp.ndarray:
     """Speculative-verify attention straight off a paged KV pool.
 
     q [B, Sq, H, D] — slot b's Sq candidate tokens at positions
     pos[b]..pos[b]+Sq-1, whose KV must already be scattered into the
     pool; per-row causality (row s attends <= pos[b]+s) rides inside
-    the kernel. Everything else (pool layout, int8 scale pages,
+    the kernel. Everything else (the stacked pool and ``layer``, or
+    one layer's pool; int8 scale pages,
     page-level DMA skip, bs constraints) matches paged_flash_decode —
     this is its Sq>1 sibling, with candidates folded into the
     query-row dimension so each page still streams from HBM exactly
@@ -945,9 +984,9 @@ def paged_flash_verify(q: jnp.ndarray, pool_k: jnp.ndarray,
     has a cell of its own."""
     B, Sq, H, D = q.shape
     assert Sq > 1, "Sq == 1 is paged_flash_decode"
-    nb, bs, Hkv, D2 = pool_k.shape
-    assert D2 == D and H % Hkv == 0, (pool_k.shape, q.shape)
-    assert bs % 8 == 0, f"block_size {bs} must be a multiple of 8"
+    kp, vp, k_scale, v_scale, layer_s, nb, bs, Hkv = _stacked_pages(
+        pool_k, pool_v, k_scale, v_scale, layer, D)
+    assert H % Hkv == 0, (pool_k.shape, q.shape)
     quantized = k_scale is not None
     mb = table.shape[1]
     g = H // Hkv
@@ -961,41 +1000,35 @@ def paged_flash_verify(q: jnp.ndarray, pool_k: jnp.ndarray,
     qp = jnp.zeros((B, Hkv * gq_pad, D), q.dtype)
     for h in range(Hkv):                          # static, Hkv is small
         qp = qp.at[:, h * gq_pad:h * gq_pad + gq].set(q5[:, h])
-    kp = pool_k.reshape(nb, bs, Hkv * D)
-    vp = pool_v.reshape(nb, bs, Hkv * D)
     table_s = jnp.asarray(table, jnp.int32)
     pos_s = jnp.asarray(pos, jnp.int32).reshape(B)
     win = jnp.asarray(0 if window is None else window,
                       jnp.int32).reshape(1)
 
-    def q_index(b, kb, table_ref, pos_ref, win_ref):
+    def q_index(b, kb, table_ref, pos_ref, win_ref, layer_ref):
         return (b, 0, 0)
 
-    def kv_index(b, kb, table_ref, pos_ref, win_ref):
+    def kv_index(b, kb, table_ref, pos_ref, win_ref, layer_ref):
         # Page-level DMA skip over the union of the Sq rows' live
         # ranges: bottom from the oldest query (pos), top from the
         # newest (pos + Sq - 1).
         lo, _ = _kv_live_range(pos_ref[b], win_ref[0], bs, mb)
         _, hi = _kv_live_range(pos_ref[b] + Sq - 1, win_ref[0], bs, mb)
-        return (jnp.maximum(table_ref[b, jnp.clip(kb, lo, hi - 1)], 0),
+        return (layer_ref[0],
+                jnp.maximum(table_ref[b, jnp.clip(kb, lo, hi - 1)], 0),
                 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, Hkv * gq_pad, D), q_index),
-        pl.BlockSpec((1, bs, Hkv * D), kv_index),
-        pl.BlockSpec((1, bs, Hkv * D), kv_index),
+        pl.BlockSpec((None, 1, bs, Hkv * D), kv_index),
+        pl.BlockSpec((None, 1, bs, Hkv * D), kv_index),
     ]
     operands = [qp, kp, vp]
     if quantized:
-        from tpushare.models.quant import kv_scale_pad
-        hkv_pad = kv_scale_pad(Hkv)     # one padding rule with the pool
-        assert k_scale.shape == (nb, hkv_pad, bs) == v_scale.shape, (
-            f"scale pools must be pre-laid-out [nb, Hkv_pad, bs] = "
-            f"{(nb, hkv_pad, bs)}, got {k_scale.shape}")
-        operands += [k_scale.astype(jnp.float32),
-                     v_scale.astype(jnp.float32)]
-        in_specs += [pl.BlockSpec((1, hkv_pad, bs), kv_index),
-                     pl.BlockSpec((1, hkv_pad, bs), kv_index)]
+        operands += _scale_pages(k_scale, v_scale, kp.shape[0], nb, bs,
+                                 Hkv)
+        in_specs += [pl.BlockSpec((None, 1) + operands[-1].shape[2:],
+                                  kv_index)] * 2
 
     out = pl.pallas_call(
         functools.partial(_paged_verify_kernel,
@@ -1003,7 +1036,7 @@ def paged_flash_verify(q: jnp.ndarray, pool_k: jnp.ndarray,
                           softcap=attn_softcap, hkv=Hkv, sq=Sq,
                           gq_pad=gq_pad, n_pages=mb, quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=4,
             grid=(B, mb),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, Hkv * gq_pad, D), q_index),
@@ -1015,7 +1048,7 @@ def paged_flash_verify(q: jnp.ndarray, pool_k: jnp.ndarray,
         ),
         out_shape=_sds((B, Hkv * gq_pad, D), q.dtype, q, pool_k, pool_v),
         interpret=interpret,
-    )(table_s, pos_s, win, *operands)
+    )(table_s, pos_s, win, layer_s, *operands)
     out5 = out.reshape(B, Hkv, gq_pad, D)[:, :, :gq]
     out5 = out5.reshape(B, Hkv, g, Sq, D).transpose(0, 3, 1, 2, 4)
     return out5.reshape(B, Sq, H, D)
@@ -1047,9 +1080,19 @@ def _paged_kernel_policy_ok(quantized: bool,
     return policy
 
 
+def _page_dims(pool, D: int, stacked: bool):
+    """(bs, Hkv, D of the page) of one layer's pool [nb, bs, Hkv, D] or,
+    ``stacked``, of the stored pool [L, nb, bs, Hkv*D]."""
+    if stacked:
+        bs, HD = pool.shape[2:]
+        return (bs, HD // D, D) if HD % D == 0 else (bs, 1, 0)
+    return pool.shape[1:]
+
+
 def paged_verify_eligible(q: jnp.ndarray, pool: jnp.ndarray,
                           quantized: bool = False,
-                          max_ctx: Optional[int] = None) -> bool:
+                          max_ctx: Optional[int] = None,
+                          stacked: bool = False) -> bool:
     """Dispatch predicate for paged_flash_verify. The XLA alternative
     is the multi-token gathered fallback (transformer.py's paged Sq>1
     branch), which materializes the whole [B, mb*bs, ...] slot view
@@ -1067,7 +1110,7 @@ def paged_verify_eligible(q: jnp.ndarray, pool: jnp.ndarray,
     if _paged_kernel_policy_ok(quantized, max_ctx) is not True:
         return False
     B, Sq, H, D = q.shape
-    nb, bs, Hkv, D2 = pool.shape
+    bs, Hkv, D2 = _page_dims(pool, D, stacked)
     return (1 < Sq <= 16 and D % 128 == 0
             and bs % _sublanes(pool.dtype) == 0
             and D2 == D and H % Hkv == 0)
@@ -1078,7 +1121,8 @@ PAGED_Q8_KERNEL_MIN_CTX = 8192
 
 def paged_decode_eligible(q: jnp.ndarray, pool: jnp.ndarray,
                           quantized: bool = False,
-                          max_ctx: Optional[int] = None) -> bool:
+                          max_ctx: Optional[int] = None,
+                          stacked: bool = False) -> bool:
     """Auto-dispatch predicate for paged_flash_decode. On by default
     for bf16 pools (unlike decode_eligible): the XLA alternative is
     the gathered dense-view fallback, which the on-chip measurement
@@ -1097,6 +1141,6 @@ def paged_decode_eligible(q: jnp.ndarray, pool: jnp.ndarray,
     if _paged_kernel_policy_ok(quantized, max_ctx) is False:
         return False
     B, Sq, H, D = q.shape
-    nb, bs, Hkv, D2 = pool.shape
+    bs, Hkv, D2 = _page_dims(pool, D, stacked)
     return (Sq == 1 and D % 128 == 0 and bs % _sublanes(pool.dtype) == 0
             and D2 == D and H % Hkv == 0)
