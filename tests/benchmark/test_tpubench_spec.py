@@ -49,7 +49,15 @@ def test_configs():
             body = json.load(f)
         assert body["source"] == c["source"]
         assert body["reduced"] == c["reduced"]
-        assert body["family"] in ("dense", "moe")
+        # a family is a file under families/ that exposes what the
+        # harness asks of one, not a name on a list
+        assert spec.NAME_RE.match(body["family"])
+        assert os.path.exists(f"{spec.HERE}/families/{body['family']}.py")
+        family = spec.family(body["family"])
+        for name in spec.FAMILY_EXPOSES:
+            assert hasattr(family, name), (body["family"], name)
+        assert isinstance(family.HELD_POSITIONS, int)
+        assert isinstance(family.MODEL_FAMILY, str)
         for k in ("n_slots", "n_blocks", "block_size", "prefill_chunk", "kv"):
             assert k in body["engine"] and k in body["engine_why"]
 

@@ -12,6 +12,8 @@ from __future__ import annotations
 
 from typing import Any, Dict
 
+from tpubench import spec
+
 #: Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 393 TOP/s
 #: int8, 16 GB HBM2e at 819 GB/s, 1,600 Gbit/s interconnect per chip.
 PEAKS: Dict[str, Dict[str, Any]] = {
@@ -37,25 +39,11 @@ def peaks_for(device_kind: str) -> Dict[str, Any]:
             f"source; there is no default.") from None
 
 
-_BYTES = {"bfloat16": 2, "float32": 4, "float16": 2}
+DTYPE_BYTES = {"bfloat16": 2, "float32": 4, "float16": 2}
 
 
 def forward_weight_bytes(config: Dict[str, Any]) -> int:
-    """Bytes of weights one forward must read from HBM, from the shapes
-    alone: every layer's attention and feed-forward matrices once (all
-    experts of a sparse layer: a batch of a dozen tokens and more
-    touches every one of 8 experts, and the psum dispatch reads them
-    regardless), the two norms, the final norm and the output head.
-    The embedding is a gather of a few rows and is left out. No cache
-    traffic, no activations: this is the floor, not an estimate."""
-    b = _BYTES[config.get("torch_dtype", "bfloat16")]
-    d = config["hidden_size"]
-    hd = config.get("head_dim") or d // config["num_attention_heads"]
-    q = config["num_attention_heads"] * hd
-    kv = config["num_key_value_heads"] * hd
-    ff = 3 * d * config["intermediate_size"]
-    n_exp = config.get("num_local_experts", 0)
-    per_layer = d * (q + 2 * kv) + q * d + 2 * d
-    per_layer += (n_exp * ff + d * n_exp) if n_exp else ff
-    head = d * config["vocab_size"] + d
-    return b * (config["num_hidden_layers"] * per_layer + head)
+    """Bytes of weights one forward must read from HBM: the count of the
+    configuration's family (``families/<family>.py``), from the shapes
+    alone."""
+    return spec.family(config["family"]).forward_weight_bytes(config)

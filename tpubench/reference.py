@@ -1,4 +1,10 @@
-"""The plain reference that decides ``correct``.
+"""What every family's check shares, and the plain reference of two.
+
+``relative_error``, ``verdict``, the router-tie margin and the check's
+default sizes are every family's. The equations below are those of
+``families/dense.py`` and ``families/moe.py``, which re-export
+``forward_with_margins``; another family brings its own (in its file
+under ``families/``, or in one it imports).
 
 A float32 ``jax.numpy`` forward of the Mistral block and of the Mixtral
 block, written from the published descriptions (Mistral 7B,
@@ -32,63 +38,30 @@ from typing import Any, Dict, List, Sequence
 import jax
 import jax.numpy as jnp
 
-# Relative error, against the largest |logit| of the reference, that the
-# served logits may show. Both sides hold the same bf16 weights; the
-# system keeps activations and the residual stream in bf16 and pads,
-# pages and batches, the reference runs float32 throughout. bf16 rounds
-# to 2^-8 about twice a layer, a random walk in the residual stream.
-#
-# Dense: 0.005 x sqrt(layers), 0.020 at 16 layers: 1.55 times the largest
-# of the 50 positions the v5e measured over 25 runs (0.0084 to 0.0129,
-# PR 22; PR 21 measured 0.0040 over 18 layers of Gemma-2B, whose norms
-# differ). A wrong mask, offset, block index or rotary layout shows as
-# O(1). What int8 shows is measured in tests/benchmark/
-# test_tpubench_reference.py at toy widths, the int8 error alone: int8
-# weights 0.016 to 0.020 over two layers, which fails; an int8 cache
-# 0.0045, which this bound does not see.
-#
-# Mixtral: a token whose second and third router logits are closer than
-# bf16 can tell takes another expert than the float32 reference does.
-# That is no error of the system, and it moves that position's logits by
-# a quarter of their scale and more. So the reference also returns, for
-# every position, the smallest gap between its second and third router
-# logit over the layers, in units of the spread of that position's
-# router logits, and a checked position under ROUTER_TIE_MARGIN is held
-# only to TIE_TOLERANCE (finite, the right scale); every other checked
-# position is held to MOE_TOLERANCE, each one, no median. Measured on
-# the v5e over 13 runs, 78 positions (PR 22): 6 positions between 0.239
-# and 0.665, 72 between 0.0085 and 0.0356, nothing in between. The
-# margins of 36 of those positions (six seeds, all six flips among
-# them), computed afterwards by this file on the CPU from the same
-# seeds: the six that flipped 0.0029 to 0.0226, those that did not
-# 0.0023 and up, the two kinds mixed below 0.04 (PERF.md, Findings). The
-# margin is 3.5 times the largest that flipped; it excuses about half of
-# all positions, so the check takes prompts until HELD_POSITIONS are
-# held. A flip at an EARLIER position reaches a checked one only through
-# attention, one key among 300: that is the spread from 0.009 to 0.036
-# among the positions that did not flip themselves (16 dense layers:
-# 0.008 to 0.013), and no margin of the checked position sees it. The
-# bound is 1.5 times the largest of the 72. It does not see int8
-# experts: the test beside the dense one measures 0.015 to 0.028 for
-# them at toy widths, under this bound.
-DENSE_TOLERANCE_PER_SQRT_LAYER = 5e-3
-MOE_TOLERANCE = 5.5e-2
+# A family's own limits, and what they were set from, are in
+# ``families/<family>.py``. The check compares relative error against the
+# largest |logit| of the reference. Where a model routes, a token whose last chosen and first
+# unchosen router logits are closer than bf16 can tell takes another
+# expert than the float32 reference does. That is no error of the
+# system, and it moves that position's logits by a quarter of their
+# scale and more. So a reference returns, for every position, the
+# smallest such gap over the layers, in units of the spread of that
+# position's router logits (infinite where nothing routes), and a
+# checked position under ROUTER_TIE_MARGIN is held only to TIE_TOLERANCE
+# (finite, the right scale); every other checked position is held to
+# the family's tolerance, each one, no median. The margins of 36
+# positions of the 8-expert top-2 cell (six seeds, all six flips among
+# them; v5e, PR 22), computed afterwards by this file on the CPU from
+# the same seeds: the six that flipped 0.0029 to 0.0226, those that did
+# not 0.0023 and up, the two kinds mixed below 0.04 (PERF.md, Findings).
+# The margin is 3.5 times the largest that flipped; it excuses about
+# half of all positions there, so the check takes prompts until the
+# family's HELD_POSITIONS are held, MAX_CHECK_PROMPTS at most.
 ROUTER_TIE_MARGIN = 8e-2
 TIE_TOLERANCE = 1.0
-
-
-def tolerance(family: str, n_layers: int) -> float:
-    if family == "moe":
-        return MOE_TOLERANCE
-    return DENSE_TOLERANCE_PER_SQRT_LAYER * n_layers ** 0.5
-
-
-#: Checked positions that must be held to the family's tolerance (two a
-#: seeded prompt: its last position from prefill, the first decode
-#: step). The sparse family takes prompts until it has four that no
-#: router tie excuses: four prompts as a rule, MAX_CHECK_PROMPTS at most.
-HELD_POSITIONS = {"dense": 2, "moe": 4}
 MAX_CHECK_PROMPTS = 12
+#: The check's prompt length where ``configs/<name>.json`` gives no
+#: ``check_prompt_tokens`` of its own (``system.check_tokens``).
 CHECK_PROMPT_TOKENS = 300
 
 _F32 = jnp.float32
@@ -211,16 +184,18 @@ def relative_error(got, want) -> float:
 
 
 def verdict(errors: List[float], margins: List[float], finite: bool,
-            family: str, n_layers: int) -> Dict[str, Any]:
-    """Every position the router does not excuse within the family's
-    tolerance, every excused one within TIE_TOLERANCE, all finite, and
-    enough positions held."""
-    tol = tolerance(family, n_layers)
+            tolerance: float, held_positions: int) -> Dict[str, Any]:
+    """Every position the router does not excuse within ``tolerance``,
+    every excused one within TIE_TOLERANCE, all finite, and
+    ``held_positions`` or more held. Both numbers are the family's
+    (``families/<family>.py``: ``tolerance(config)``, ``HELD_POSITIONS``)."""
     held = [e for e, m in zip(errors, margins) if m >= ROUTER_TIE_MARGIN]
     tied = [e for e, m in zip(errors, margins) if m < ROUTER_TIE_MARGIN]
-    ok = (finite and len(held) >= HELD_POSITIONS[family]
-          and all(e <= tol for e in held)
+    ok = (finite and len(held) >= held_positions
+          and all(e <= tolerance for e in held)
           and all(e <= TIE_TOLERANCE for e in tied))
     return {"ok": bool(ok), "max_held_rel_err": max(held, default=None),
-            "tolerance": tol, "held": len(held), "router_ties": len(tied),
+            "tolerance": tolerance, "held": len(held),
+            "held_positions": held_positions, "router_ties": len(tied),
+            "max_tied_rel_err": max(tied, default=None),
             "rel_errs": errors, "router_margins": margins, "finite": finite}

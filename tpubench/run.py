@@ -3,7 +3,8 @@
 
 One process holds the cell's chips: it draws the weights from the seed,
 builds the engine and the HTTP daemon the way ``tpushare-serve`` does,
-checks the engine's logits against ``tpubench/reference.py``, starts the
+checks the engine's logits against the reference of the configuration's
+family (``tpubench/families/``), starts the
 load generator as a child that never touches JAX, and reduces what came
 back. No chip, too few chips, or a device that is not in the peak
 table: a non-zero exit and no result. ``--rehearse`` (a flag of this
@@ -265,7 +266,7 @@ def main(argv=None) -> int:
     httpd = None
     try:
         correct = system.check_correct(cell, sut, a.seed, log)
-        system.warm_growth(engine)
+        system.warm(cell, engine)
         if a.trace:
             system.annotate(engine)
         from tpushare.cli.serve import serve
@@ -353,6 +354,14 @@ def main(argv=None) -> int:
     if a.trace and red is not None:
         line["breakdown"] = {"device_ops": red["device_ops"],
                              "idle_gaps": red["idle_gaps"]}
+    # Every number ``correct`` rests on beside its limit, last on the line
+    # and last on standard error: what the driver's record keeps of a run
+    # that is not correct.
+    line["compared"] = dict(system.compared(correct), compiled_in_window={
+        "value": n_in_window, "limit": None if a.rehearse else 0})
+    for name, c in line["compared"].items():
+        print(f"tpubench compared {name}: {c['value']} (limit "
+              f"{c['limit']})", file=sys.stderr, flush=True)
     with open(os.path.join(out_dir, "last_line.json"), "w") as f:
         json.dump(line, f)
     print(json.dumps(line), flush=True)

@@ -3,7 +3,12 @@
 ``BENCHMARK.json`` names cells, configurations and metrics; everything
 that belongs to one of them sits in a file found by that name:
 
-  configs/<config>.json      widths as published, the cut, engine sizes
+  configs/<config>.json      widths as published, the cut, engine sizes,
+                             and the ``family`` that runs it
+  families/<family>.py       how the program is configured and drawn for
+                             such a configuration, its plain reference
+                             and that reference's limits, the bytes one
+                             forward must read
   traffic/<mix>.json         generator name, its parameters, the loop
   generators/<name>.py       one general generator per file
   layers/<metric>.json       one per-layer metric: layer, reader, args
@@ -97,6 +102,22 @@ def load_cell(name: str, *, rehearse: bool = False) -> Cell:
                 config=config, traffic=traffic, engine=engine,
                 rate_rps=cell_file.get("rate_rps"),
                 end_to_end=e2e, per_layer=per, rehearse=rehearse)
+
+
+#: What ``families/<family>.py`` exposes (``families/__init__.py`` says
+#: what each is); ``warm_growth(engine)`` besides, where the family's
+#: cache is not the one ``system.warm_growth`` knows.
+FAMILY_EXPOSES = ("program_config", "init_params", "MODEL_FAMILY",
+                  "forward_with_margins", "tolerance", "HELD_POSITIONS",
+                  "forward_weight_bytes")
+
+
+def family(name: str):
+    """The family module ``families/<name>.py``, by a configuration's
+    ``family`` key."""
+    if not NAME_RE.match(name):
+        raise ValueError(f"bad family name {name!r}")
+    return importlib.import_module(f"tpubench.families.{name}")
 
 
 def generator(name: str):

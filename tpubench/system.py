@@ -3,40 +3,34 @@
 From the program this takes ``init_params``, ``ServeEngine``,
 ``cli.serve.serve`` and the compile-cache helper: everything
 ``build_engine`` adds to a preset lookup. The rest of this file is the
-benchmark's own: the correctness check against ``reference.py`` and the
-host spans of the traced run.
+benchmark's own: the correctness check against the family's reference and
+the host spans of the traced run. What differs from one family of model
+to another is in ``families/<family>.py``, found by the configuration's
+``family`` key; nothing here names one.
 """
 
 from __future__ import annotations
 
+import math
 import time
-import types
 from typing import Any, Dict, List
 
-from tpubench import reference
+from tpubench import reference, spec
 from tpubench.spec import Cell
 
 
+def family_of(cell: Cell):
+    """``families/<family>.py`` of the cell's configuration."""
+    return spec.family(cell.config["family"])
+
+
 def program_config(cell: Cell):
-    """The program's config object from the published keys."""
+    """The program's config object, as the configuration's family builds
+    it from the whole configuration file."""
     import jax.numpy as jnp
     c = cell.config
     dtype = {"bfloat16": jnp.bfloat16, "float32": jnp.float32}[c["torch_dtype"]]
-    if c["family"] == "moe":
-        from tpushare.models.convert import moe_config_from_hf
-        hf = types.SimpleNamespace(**{k: v for k, v in c.items()
-                                      if not isinstance(v, (dict, list))})
-        return moe_config_from_hf(hf, dtype=dtype)
-    from tpushare.models.transformer import TransformerConfig
-    return TransformerConfig(
-        vocab_size=c["vocab_size"], d_model=c["hidden_size"],
-        n_layers=c["num_hidden_layers"], n_heads=c["num_attention_heads"],
-        n_kv_heads=c["num_key_value_heads"],
-        head_dim=c.get("head_dim") or c["hidden_size"] // c["num_attention_heads"],
-        d_ff=c["intermediate_size"], rope_base=float(c["rope_theta"]),
-        norm_eps=float(c["rms_norm_eps"]), act=c["hidden_act"],
-        tie_embeddings=bool(c["tie_word_embeddings"]),
-        sliding_window=c.get("sliding_window"), dtype=dtype, remat=False)
+    return family_of(cell).program_config(c, dtype)
 
 
 def build(cell: Cell, seed: int, log) -> Dict[str, Any]:
@@ -44,12 +38,9 @@ def build(cell: Cell, seed: int, log) -> Dict[str, Any]:
     engine at the cell's sizes. One chip: a cell over a mesh brings its
     placement here in the PR that measures it."""
     import jax
-    c, e = cell.config, cell.engine
+    e = cell.engine
     cfg = program_config(cell)
-    if c["family"] == "moe":
-        from tpushare.models import moe as family
-    else:
-        from tpushare.models import transformer as family
+    family = family_of(cell)
     key = jax.random.PRNGKey(seed)
     t0 = time.monotonic()
     params = jax.jit(lambda k: family.init_params(k, cfg))(key)
@@ -57,7 +48,7 @@ def build(cell: Cell, seed: int, log) -> Dict[str, Any]:
     t1 = time.monotonic()
     from tpushare.cli.serve import ServeEngine
     engine = ServeEngine(
-        params, cfg, model_family=c["family"], kv=e.get("kv"),
+        params, cfg, model_family=family.MODEL_FAMILY, kv=e.get("kv"),
         n_slots=e["n_slots"], n_blocks=e["n_blocks"],
         block_size=e["block_size"],
         max_blocks_per_slot=e.get("max_blocks_per_slot"),
@@ -78,21 +69,30 @@ def check_prompt(seed: int, k: int, n: int, vocab: int) -> List[int]:
     return out
 
 
+def check_tokens(cell: Cell) -> int:
+    """The check's prompt length: the configuration's own
+    ``check_prompt_tokens`` (a window or a selector that 300 tokens
+    would never leave or fill), else ``reference.CHECK_PROMPT_TOKENS``;
+    either way no longer than a slot holds with the decoded token."""
+    return min(cell.config.get("check_prompt_tokens",
+                               reference.CHECK_PROMPT_TOKENS),
+               cell.engine["block_size"]
+               * (cell.engine.get("max_blocks_per_slot")
+                  or cell.engine["n_blocks"]) - 2)
+
+
 def check_correct(cell: Cell, system: Dict[str, Any], seed: int, log
                   ) -> Dict[str, Any]:
     """Admit a seeded prompt and decode one step through the engine's
     own slot server, before the engine thread starts; compare the logits
-    it sampled from with the reference's full forward."""
+    it sampled from with the full forward of the family's reference."""
     import jax.numpy as jnp
     srv = system["engine"].srv
-    family = cell.config["family"]
+    family = family_of(cell)
     vocab = cell.config["vocab_size"]
-    n_tok = min(reference.CHECK_PROMPT_TOKENS,
-                cell.engine["block_size"]
-                * (cell.engine.get("max_blocks_per_slot")
-                   or cell.engine["n_blocks"]) - 2)
+    n_tok = check_tokens(cell)
     errors, margins, finite = [], [], True
-    need = reference.HELD_POSITIONS[family]
+    need = family.HELD_POSITIONS
     for k in range(reference.MAX_CHECK_PROMPTS):
         if sum(m >= reference.ROUTER_TIE_MARGIN for m in margins) >= need:
             break
@@ -107,16 +107,39 @@ def check_correct(cell: Cell, system: Dict[str, Any], seed: int, log
         finally:
             srv._sampler.pick = pick
         srv.evict(slot)
-        want, margin = reference.forward_with_margins(
+        want, margin = family.forward_with_margins(
             system["params"], prompt + [tok1], cell.config)
         for got, at in ((seen[0][0], n_tok - 1), (seen[1][slot], n_tok)):
             finite = finite and bool(jnp.isfinite(got).all())
             errors.append(reference.relative_error(got, want[at]))
             margins.append(float(margin[at]))
-    out = reference.verdict(errors, margins, finite, family,
-                            cell.config["num_hidden_layers"])
-    log("correctness vs tpubench/reference.py: " + repr(out))
+    out = reference.verdict(errors, margins, finite,
+                            family.tolerance(cell.config), need)
+    log(f"correctness vs the reference of tpubench/families/"
+        f"{cell.config['family']}.py: " + repr(out))
     return out
+
+
+def compared(out: Dict[str, Any]) -> Dict[str, Dict[str, Any]]:
+    """The numbers of ``check_correct``'s verdict, each beside its limit:
+    the worst held position under the family's tolerance, the worst
+    excused one under TIE_TOLERANCE, and at least ``held_positions``
+    held. A number that is not finite goes as text: the line stays JSON."""
+    def num(v):
+        return v if v is None or math.isfinite(v) else repr(v)
+    return {
+        "max_held_rel_err": {"value": num(out["max_held_rel_err"]),
+                             "limit": out["tolerance"]},
+        "max_tied_rel_err": {"value": num(out["max_tied_rel_err"]),
+                             "limit": reference.TIE_TOLERANCE},
+        "held": {"value": out["held"], "limit": out["held_positions"]},
+    }
+
+
+def warm(cell: Cell, engine) -> None:
+    """The family's ``warm_growth`` where it has one, else the one
+    below."""
+    getattr(family_of(cell), "warm_growth", warm_growth)(engine)
 
 
 def warm_growth(engine) -> None:
