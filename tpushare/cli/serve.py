@@ -598,6 +598,29 @@ class ServeEngine:
                     draft_layers_hook=draft_layers_hook,
                     mesh=f_mesh, param_specs=param_specs,
                     draft_param_specs=draft_param_specs))
+        elif model_family == "latent":
+            # Latent attention with a key selector and windowed layers
+            # (models/latent.py): the paged pool, block tables, prefix
+            # cache and tick of the dense family, its own two pools and
+            # programs underneath.
+            if kv == "rows":
+                raise ValueError("model_family='latent' serves over the "
+                                 "paged pool (kv='paged' is its only "
+                                 "KV layout)")
+            from tpushare.models.latent import LatentSlotServer
+
+            def factory(f_params, f_draft, f_mesh, f_quota):
+                return LatentSlotServer(
+                    f_params, cfg, n_slots=n_slots, n_blocks=n_blocks,
+                    block_size=block_size,
+                    max_blocks_per_slot=max_blocks_per_slot,
+                    prefix_cache=use_prefix,
+                    temperature=temperature, top_k=top_k, top_p=top_p,
+                    seed=seed, kv_quota=f_quota,
+                    # refused there, loudly, where given
+                    kv_quant=kv_quant, multi_lora=multi_lora,
+                    layers_hook=layers_hook, speculative_draft=f_draft,
+                    mesh=f_mesh)
         elif model_family != "dense":
             raise ValueError(f"unknown model_family {model_family!r}")
         else:
@@ -744,7 +767,9 @@ class ServeEngine:
         self._chunk_gran = getattr(self.srv.cache, "block_size", 1)
         self._admitting: Dict[int, _Request] = {}   # tpushare: owner[engine]
         self._idle_sleep_s = idle_sleep_s
-        self.max_tokens_cap = 4096
+        # what one request may ask for: never under the 4,096 it always
+        # was, and as much as a slot holds where slots are longer
+        self.max_tokens_cap = max(4096, getattr(self.srv, "slot_capacity", 0))
         self._seq = 0
         self._stats = {"requests": 0, "completed": 0, "rejected": 0,
                        "preempted": 0, "chunked_admits": 0, "steps": 0,
@@ -2006,6 +2031,24 @@ class ServeEngine:
                         "reclaimable_blocks": None,
                         "live_blocks": None,
                         "pool_free_frac": None})
+        # What only the latent family counts (models/latent.py
+        # family_stats): keys the selector saw and kept, latent rows the
+        # slots hold by layer kind and those behind every window to
+        # come, assignments that reached the held experts. Null for the
+        # other families (null-not-zero: they have no selector, window
+        # or expert share, not an idle one).
+        fam = srv.family_stats() if hasattr(srv, "family_stats") else {}
+        out.update({
+            "select_keys_seen": fam.get("select_keys_seen"),
+            "select_keys_kept": fam.get("select_keys_kept"),
+            "latent_rows_live": fam.get("latent_rows_live"),
+            "window_rows_dead": fam.get("window_rows_dead"),
+            "latent_row_bytes": fam.get("latent_row_bytes"),
+            "expert_assign_local": fam.get("expert_assign_local"),
+            "expert_tokens": fam.get("expert_tokens"),
+            "expert_load": fam.get("expert_load"),
+            "expert_load_max": fam.get("expert_load_max"),
+        })
         if srv.speculative:
             # Mean tokens per (slot, round) in [1, gamma×horizon+1] is
             # the live acceptance signal: 1.0 = speculation buying

@@ -121,6 +121,10 @@ class PagedCache:
     # hot step never transposes the pool; None for full precision.
     pool_k_scale: Optional[jnp.ndarray] = None
     pool_v_scale: Optional[jnp.ndarray] = None
+    # A third kind of row under the same block table, for a family that
+    # caches one (latent: the selector's keys of the full layers,
+    # [n_full, n_blocks, bs, index_dim]); None for every other.
+    pool_x: Optional[jnp.ndarray] = None
     # Prefix-cache bookkeeping (host-side, all empty unless the prefix
     # path is used). A *published* block holds the KV of one full block
     # of some prompt whose entire token chain up to that block is the
@@ -211,8 +215,15 @@ def init_paged_cache(cfg: TransformerConfig, *, n_slots: int,
     (shared blocks carry their scale rows along). Reads take the
     gathered-view path (transformer.py paged+kvq note)."""
     mb = max_blocks_per_slot or n_blocks
-    shape = (cfg.n_layers, n_blocks, block_size,
-             cfg.n_kv_heads * cfg.head_dim)
+    # A family whose layers cache rows of more than one shape says what
+    # its pools hold (latent.LatentConfig.pool_shapes); all of them lie
+    # under the one block table.
+    shape_x = None
+    if hasattr(cfg, "pool_shapes"):
+        shape_k, shape_v, shape_x = cfg.pool_shapes(n_blocks, block_size)
+    else:
+        shape_k = shape_v = (cfg.n_layers, n_blocks, block_size,
+                             cfg.n_kv_heads * cfg.head_dim)
     kv_dtype = jnp.int8 if kv_quant else cfg.dtype
     if kv_quant:
         from tpushare.models.quant import kv_scale_pad
@@ -220,8 +231,8 @@ def init_paged_cache(cfg: TransformerConfig, *, n_slots: int,
         scale_shape = (cfg.n_layers, n_blocks,
                        kv_scale_pad(cfg.n_kv_heads), block_size)
     return PagedCache(
-        pool_k=jnp.zeros(shape, kv_dtype),
-        pool_v=jnp.zeros(shape, kv_dtype),
+        pool_k=jnp.zeros(shape_k, kv_dtype),
+        pool_v=jnp.zeros(shape_v, kv_dtype),
         block_table=jnp.full((n_slots, mb), -1, jnp.int32),
         lengths=jnp.zeros((n_slots,), jnp.int32),
         block_size=block_size,
@@ -230,6 +241,7 @@ def init_paged_cache(cfg: TransformerConfig, *, n_slots: int,
                       if kv_quant else None),
         pool_v_scale=(jnp.zeros(scale_shape, jnp.float32)
                       if kv_quant else None),
+        pool_x=(jnp.zeros(shape_x, kv_dtype) if shape_x else None),
         table_np=np.full((n_slots, mb), -1, np.int32),
         lengths_np=np.zeros((n_slots,), np.int64),
         kv_heads=cfg.n_kv_heads,
@@ -765,12 +777,14 @@ def prefill_suffix_into(params, prompt: jnp.ndarray,
     return last, cache
 
 
-def _row_pairs(kvq: bool):
+def _row_pairs(cache: PagedCache):
     """(pool field, row-cache key) for every leaf the gather/scatter
     moves; scale leaves (no trailing Dh axis) reshape generically."""
     pairs = [("pool_k", "k"), ("pool_v", "v")]
-    if kvq:
+    if cache.pool_k_scale is not None:
         pairs += [("pool_k_scale", "k_scale"), ("pool_v_scale", "v_scale")]
+    if cache.pool_x is not None:
+        pairs.append(("pool_x", "x"))
     return pairs
 
 
@@ -780,7 +794,7 @@ def block_shapes(cache: PagedCache) -> Dict[str, Tuple[int, ...]]:
     (the pool stores a page's heads merged; a payload keeps them
     apart, as it always was), scale leaves [L, Hkv_pad, bs]."""
     out = {}
-    for pf, _ in _row_pairs(cache.pool_k_scale is not None):
+    for pf, _ in _row_pairs(cache):
         L, _, *row = getattr(cache, pf).shape
         if not pf.endswith("_scale"):
             row = [row[0], cache.kv_heads, row[1] // cache.kv_heads]
@@ -828,18 +842,19 @@ def _admission_row(cfg: TransformerConfig, cache: PagedCache, slot: int,
     if kvq:
         from tpushare.models.quant import init_cache_q8
         row = init_cache_q8(cfg, 1, comp_len)
+    elif hasattr(cfg, "init_row_cache"):
+        row = cfg.init_row_cache(1, comp_len)   # the family's own rows
     else:
         from tpushare.models.transformer import init_cache
         row = init_cache(cfg, 1, comp_len)
     # Device-side table slices: no host sync on the admit path (the
     # non-prefix case never needs host values; the gather below is a
     # device gather either way).
-    L = row["k"].shape[0]
     Hkv = cfg.n_kv_heads
     if cached_blk:
         from tpushare.models.quant import pool_scales_to_rows
         blk_ids = cache.block_table[slot][:cached_blk]
-        for pf, rk_ in _row_pairs(kvq):
+        for pf, rk_ in _row_pairs(cache):
             pool = getattr(cache, pf)
             g = pool[:, blk_ids]             # [L, cached_blk, bs, ...]
             if pf.endswith("_scale"):
@@ -850,14 +865,21 @@ def _admission_row(cfg: TransformerConfig, cache: PagedCache, slot: int,
             # KV pages hold their heads merged; the row keeps them
             # apart ([L, cached_len, Hkv, Dh]).
             row[rk_] = row[rk_].at[:, 0, :cached_len].set(
-                g.reshape(L, cached_len, *row[rk_].shape[3:]))
+                g.reshape(g.shape[0], cached_len, *row[rk_].shape[3:]))
     return row, comp_len, n_blk
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _scatter_blocks_donated(pool, ids, rows):
+    """``pool.at[:, ids].set(rows)`` on the pool's own buffer: the eager
+    form returns a copy, and a pool is gigabytes."""
+    return pool.at[:, ids].set(rows)
 
 
 def _prefill_chunk(params, prompt: jnp.ndarray, cfg: TransformerConfig,
                    cache: PagedCache, slot: int, row, done: int, end: int,
                    n_blk: int, comp_len: int, chunk: int,
-                   prefill_fn=None):
+                   prefill_fn=None, inplace: bool = False):
     """Forward prompt positions [done, end) against the admission row
     (which already holds [0, done) — no pool re-gather) and scatter
     this chunk's block rows to the pool. Returns
@@ -871,10 +893,15 @@ def _prefill_chunk(params, prompt: jnp.ndarray, cfg: TransformerConfig,
     padded-forward bytes — including the masked garbage KV the padded
     tail writes into the last block, which decode's length mask never
     attends and the first decode scatter at position S overwrites.
+
+    ``inplace``: scatter into the pools' own buffers (donated; between
+    the first leaf's scatter and the returned cache, ``cache`` names
+    deleted buffers, so the caller rebinds at once and recovers the pools
+    if this raises). Off, every leaf's scatter copies its pool, and
+    old and new pools are alive together until the caller rebinds.
     """
     S = int(prompt.shape[0])
     bs = cache.block_size
-    kvq = cache.pool_k_scale is not None
     final = end >= S
     pad_len = (comp_len - done) if final else chunk
     with span("slot.admit.prefill"):
@@ -890,16 +917,17 @@ def _prefill_chunk(params, prompt: jnp.ndarray, cfg: TransformerConfig,
         start_blk = done // bs
         end_blk = n_blk if final else end // bs
         ids = cache.block_table[slot][start_blk:end_blk]
-        L = row["k"].shape[0]
         n_fresh = end_blk - start_blk
         updates = {}
-        for pf, rk_ in _row_pairs(kvq):
+        for pf, rk_ in _row_pairs(cache):
             r = row[rk_][:, 0, start_blk * bs:end_blk * bs]
-            r = r.reshape(L, n_fresh, bs, -1)   # a page: [bs, Hkv*Dh]
+            # a page: [bs, Hkv*Dh]; a leaf has its own count of layers
+            r = r.reshape(r.shape[0], n_fresh, bs, -1)
             if pf.endswith("_scale"):
                 from tpushare.models.quant import scales_to_pool_layout
                 r = scales_to_pool_layout(r)    # -> [L, fb, Hkv_pad, bs]
-            updates[pf] = getattr(cache, pf).at[:, ids].set(r)
+            updates[pf] = (_scatter_blocks_donated(getattr(cache, pf), ids, r)
+                           if inplace else getattr(cache, pf).at[:, ids].set(r))
         last = logits[0, S - 1 - done] if final else None
     return last, dataclasses.replace(cache, **updates), row
 
@@ -1178,6 +1206,18 @@ class PagedSlotServer(SpecDecodeMixin):
             # bit-exact greedy match rule. Both core sets were built
             # by _spec_init above.
 
+    #: the serial admission of a family whose weights and pools leave no
+    #: room beside them (latent.LatentSlotServer turns it on and guards
+    #: the call in its ``admit_step``): the block scatter donates the
+    #: pools (``_prefill_chunk``) where the eager one copies a pool a
+    #: leaf, and the dense row waits for the first SERIAL chunk
+    #: (``admit_step`` builds it where ``row_stale``) where admit_start
+    #: builds one a pending admission, which a fused admission never
+    #: reads. Off here: the admission the dense and Mixtral cells were
+    #: measured with; making it every family's is a perf_opt PR's, with
+    #: their cells measured (PERF.md section 7).
+    lean_admission = False
+
     @property
     def slot_capacity(self) -> int:
         return self.cache.max_blocks * self.cache.block_size
@@ -1210,7 +1250,7 @@ class PagedSlotServer(SpecDecodeMixin):
         frees them instead of parking garbage on the LRU)."""
         c = self.cache
         repl = {}
-        for pf, _ in _row_pairs(c.pool_k_scale is not None):
+        for pf, _ in _row_pairs(c):
             arr = getattr(c, pf)
             if arr.is_deleted():
                 new = jnp.zeros(arr.shape, arr.dtype)
@@ -1369,8 +1409,11 @@ class PagedSlotServer(SpecDecodeMixin):
         # (and a second compile key) for no reason.
         chunk = max(bs, -(-chunk // bs) * bs)
         with span("slot.admit.row"):
-            row, comp_len, n_blk = _admission_row(
-                self.cfg, self.cache, slot, S, cached_len)
+            if self.lean_admission:
+                row, comp_len, n_blk = None, 0, blocks_needed(S + 1, bs)
+            else:
+                row, comp_len, n_blk = _admission_row(
+                    self.cfg, self.cache, slot, S, cached_len)
         st = {
             "prompt": prompt, "prompt_np": prompt_np, "done": cached_len,
             "chunk": chunk, "keys": keys, "blocks": blocks,
@@ -1380,7 +1423,7 @@ class PagedSlotServer(SpecDecodeMixin):
             # block table; the serial admission row then lags the
             # pool and must be re-gathered before the next serial
             # chunk (admit_step checks this flag).
-            "row_stale": False,
+            "row_stale": self.lean_admission,
         }
         if self.speculative:
             # The draft's admission row shares the block table; its
@@ -1455,7 +1498,8 @@ class PagedSlotServer(SpecDecodeMixin):
         last_logits, self.cache, st["row"] = _prefill_chunk(
             self.params, st["prompt"], self.cfg, self.cache, slot,
             st["row"], st["done"], end, st["n_blk"], st["comp_len"],
-            chunk, prefill_fn=st["prefill_fn"])
+            chunk, prefill_fn=st["prefill_fn"],
+            inplace=self.lean_admission)
         if self.speculative:
             # The draft needs prompt KV too, chunked the same way.
             _, dview, st["drow"] = _prefill_chunk(
@@ -1681,9 +1725,7 @@ class PagedSlotServer(SpecDecodeMixin):
 
     def _fused_tick_async(self, slot: int,
                           max_chunk_tokens: Optional[int]):
-        from tpushare.models.serving import (PendingStep,
-                                             fused_chunk_span,
-                                             fused_token_batch)
+        from tpushare.models.serving import PendingStep, fused_chunk_span
         st = self._admissions[slot]
         if not self.active.any():
             # No decode batch to fuse into: serial admission is the
@@ -1702,51 +1744,18 @@ class PagedSlotServer(SpecDecodeMixin):
             return self.step_async()    # budget left no chunk room
         with span("slot.grow"):
             self._grow_active()
+        final = end >= S
         with span("slot.launch"):
-            toks = fused_token_batch(self.last_token, st["prompt"],
-                                     done, end, width, slot)
-            pos = self.cache.lengths.at[slot].set(done)
-            # The admitting slot must WRITE (its table row is
-            # reserved); decode rows write their one real token;
-            # everything else routes to the trash block.
-            wmask = self._active_dev.at[slot].set(True)
-            mkw = ({"mlora_idx": self._ml.dev} if self._ml.enabled
-                   else {})
-            logits, pk, pv, pks, pvs = self._pools_dispatch(
-                self._verify,
-                self.params, toks, self.cache.pool_k, self.cache.pool_v,
-                self.cache.block_table, pos, wmask,
-                pool_k_scale=self.cache.pool_k_scale,
-                pool_v_scale=self.cache.pool_v_scale, **mkw)
-            # Rebind donated pools immediately (see step()); lengths
-            # are not donated, so computing the advance after the
-            # replace is identical.
-            lengths = (self.cache.lengths
-                       + self._active_dev.astype(jnp.int32))
-            self.cache = dataclasses.replace(
-                self.cache, pool_k=pk, pool_v=pv, lengths=lengths,
-                pool_k_scale=pks, pool_v_scale=pvs)
-            if self.speculative:
-                # One draft forward: decode rows mirror their pending
-                # token's draft KV (a skipped write would leave a hole
-                # every later draft step attends), the admitting row
-                # advances the draft chunk — same batch, logits
-                # dropped.
-                _, self._dpk, self._dpv, _, _ = self._pools_dispatch(
-                    self._draft_verify,
-                    self.draft_params, toks, self._dpk, self._dpv,
-                    self.cache.block_table, pos, wmask, **mkw)
+            nxt_logits, first_logits = self._fused_forward(
+                slot, st, done, end, width, final)
         st["done"] = end
         st["row_stale"] = True
-        final = end >= S
         with span("slot.sample"):
             if final:
                 # Admission pick before the decode pick: matches the
                 # serial engine order on the sampler's key stream.
-                first = self._sampler.pick(logits[slot:slot + 1,
-                                                 S - 1 - done]
-                                           ).astype(jnp.int32)
-            nxt = self._sampler.pick(logits[:, 0]).astype(jnp.int32)
+                first = self._sampler.pick(first_logits).astype(jnp.int32)
+            nxt = self._sampler.pick(nxt_logits).astype(jnp.int32)
             self.last_token = jnp.where(self._active_dev[:, None],
                                         nxt[:, None], self.last_token)
         with span("slot.mirror"):
@@ -1785,6 +1794,52 @@ class PagedSlotServer(SpecDecodeMixin):
             return out
 
         return PendingStep(_finalize, slots=out_slots)
+
+    def _fused_forward(self, slot: int, st, done: int, end: int,
+                       width: int, final: bool):
+        """The fused tick's one forward: the decode rows' pending tokens
+        and prompt[done:end) of the admitting slot through the pools,
+        which are rebound here. Returns (the decode rows' logits [B, V],
+        the logits after the prompt's last token [1, V] where the chunk
+        completes it, else None). The seam a family with its own fused
+        program overrides (latent.LatentSlotServer)."""
+        from tpushare.models.serving import fused_token_batch
+        S = int(st["prompt_np"].shape[0])
+        toks = fused_token_batch(self.last_token, st["prompt"],
+                                 done, end, width, slot)
+        pos = self.cache.lengths.at[slot].set(done)
+        # The admitting slot must WRITE (its table row is
+        # reserved); decode rows write their one real token;
+        # everything else routes to the trash block.
+        wmask = self._active_dev.at[slot].set(True)
+        mkw = ({"mlora_idx": self._ml.dev} if self._ml.enabled
+               else {})
+        logits, pk, pv, pks, pvs = self._pools_dispatch(
+            self._verify,
+            self.params, toks, self.cache.pool_k, self.cache.pool_v,
+            self.cache.block_table, pos, wmask,
+            pool_k_scale=self.cache.pool_k_scale,
+            pool_v_scale=self.cache.pool_v_scale, **mkw)
+        # Rebind donated pools immediately (see step()); lengths
+        # are not donated, so computing the advance after the
+        # replace is identical.
+        lengths = (self.cache.lengths
+                   + self._active_dev.astype(jnp.int32))
+        self.cache = dataclasses.replace(
+            self.cache, pool_k=pk, pool_v=pv, lengths=lengths,
+            pool_k_scale=pks, pool_v_scale=pvs)
+        if self.speculative:
+            # One draft forward: decode rows mirror their pending
+            # token's draft KV (a skipped write would leave a hole
+            # every later draft step attends), the admitting row
+            # advances the draft chunk — same batch, logits
+            # dropped.
+            _, self._dpk, self._dpv, _, _ = self._pools_dispatch(
+                self._draft_verify,
+                self.draft_params, toks, self._dpk, self._dpv,
+                self.cache.block_table, pos, wmask, **mkw)
+        return (logits[:, 0],
+                logits[slot:slot + 1, S - 1 - done] if final else None)
 
     # -- speculation hooks (models/spec.py SpecDecodeMixin owns the
     # round driver; these supply the paged mechanics) -----------------
