@@ -482,6 +482,148 @@ class TestPagedFlashDecode:
                          window=24, softcap=25.0)
         np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
 
+    # -- the loop over live groups (PR 29): a group is
+    # DECODE_GROUP_KEYS // bs pages, 16 of 16 rows, 8 of 32 ------------
+
+    def _live_case(self, pos, mb, bs=16, window=None, H=4, Hkv=2, D=128,
+                   seed=11, poison=False):
+        """Slots at ``pos`` over a pool drawn at random, each slot's
+        table naming a page of its own for every page up to pos[b]
+        and -1 beyond. ``poison``: a second copy of the pools in which
+        every page no slot may attend (unnamed, or wholly behind the
+        window) holds NaN. Returns (q, pool_k, pool_v, table, pos) or,
+        poisoned, (..., poisoned_k, poisoned_v)."""
+        rng = np.random.default_rng(seed)
+        B = len(pos)
+        need = [p // bs + 1 for p in pos]
+        nb = sum(need) + 3
+        pk, pv = (rng.normal(size=(nb, bs, Hkv, D)).astype(np.float32)
+                  for _ in range(2))
+        table = np.full((B, mb), -1, np.int32)
+        pages = iter(rng.permutation(nb - 1))
+        live = set()
+        for b in range(B):
+            table[b, :need[b]] = [next(pages) for _ in range(need[b])]
+            first = 0 if window is None else max(
+                0, (pos[b] - window + 1) // bs)
+            live.update(int(x) for x in table[b, first:need[b]])
+        q = jnp.asarray(rng.normal(size=(B, 1, H, D)), jnp.float32)
+        out = (q, jnp.asarray(pk), jnp.asarray(pv), jnp.asarray(table),
+               jnp.asarray(pos, jnp.int32))
+        if poison:
+            dead = [i for i in range(nb) if i not in live]
+            for a in (pk.copy(), pv.copy()):
+                a[dead] = np.nan
+                out += (jnp.asarray(a),)
+        return out
+
+    @pytest.mark.parametrize("kw", [
+        # 17 pages a slot at a group of 8: the last group holds one
+        dict(bs=32, mb=17, pos=[543, 260, 17]),
+        dict(bs=16, mb=20, pos=[0, 300, 0]),
+        # a page's last row and the next page's first, at a page's
+        # edge and at a group's (255 | 256)
+        dict(bs=16, mb=40, pos=[15, 16, 255, 256]),
+        # the table all -1 but page 0
+        dict(bs=16, mb=20, pos=[9, 200]),
+        # a window inside one group (601..700 of 512..767; 401..500)
+        dict(bs=16, mb=48, pos=[700, 500], window=100),
+        # a window over three groups (241..760; 81..600)
+        dict(bs=16, mb=48, pos=[760, 600], window=520),
+        dict(bs=16, mb=40, pos=[611, 90, 300], attn_softcap=20.0),
+    ], ids=["mb_17_at_a_group_of_8", "pos_0", "page_and_group_edges",
+            "table_unallocated_but_page_0", "window_inside_one_group",
+            "window_over_three_groups", "softcap_over_three_groups"])
+    def test_live_groups_match_gathered_reference(self, kw):
+        from tpushare.ops.flash_attention import paged_flash_decode
+        window, softcap = kw.get("window"), kw.get("attn_softcap")
+        q, pk, pv, table, pos = self._live_case(
+            kw["pos"], kw["mb"], kw["bs"], window)
+        if kw["pos"] == [9, 200]:
+            assert (np.asarray(table[0, 1:]) == -1).all()
+        got = paged_flash_decode(q, pk, pv, table, pos, window=window,
+                                 attn_softcap=softcap, interpret=True)
+        want = self._ref(q, pk, pv, table, pos, window=window,
+                         softcap=softcap)
+        np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+
+    @pytest.mark.parametrize("window", [None, 300])
+    def test_pages_outside_the_live_range_are_never_read(self, window):
+        """Every page no slot may attend holds NaN; 0 x NaN in the
+        value product would show."""
+        from tpushare.ops.flash_attention import paged_flash_decode
+        q, pk, pv, table, pos, bad_k, bad_v = self._live_case(
+            [530, 0, 270, 40], mb=40, window=window, poison=True)
+        assert np.isnan(np.asarray(bad_k)).any()
+        got = paged_flash_decode(q, bad_k, bad_v, table, pos,
+                                 window=window, interpret=True)
+        assert np.isfinite(np.asarray(got)).all()
+        want = self._ref(q, pk, pv, table, pos, window=window)
+        np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+
+    def test_int8_pages_over_three_groups(self):
+        """Scale pages copied beside their int8 pages, 20 pages of 32
+        rows at a group of 8."""
+        from tpushare.models.quant import (
+            kv_dequantize, kv_quantize, scales_to_pool_layout)
+        from tpushare.ops.flash_attention import paged_flash_decode
+        q, pk, pv, table, pos = self._live_case([639, 0, 300, 31],
+                                                mb=20, bs=32)
+        qk, sk = kv_quantize(pk)
+        qv, sv = kv_quantize(pv)
+        got = paged_flash_decode(q, qk, qv, table, pos, window=400,
+                                 k_scale=scales_to_pool_layout(sk),
+                                 v_scale=scales_to_pool_layout(sv),
+                                 interpret=True)
+        want = self._ref(q, kv_dequantize(qk, sk, jnp.float32),
+                         kv_dequantize(qv, sv, jnp.float32), table, pos,
+                         window=400)
+        np.testing.assert_allclose(got, want, rtol=2e-3, atol=2e-3)
+
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_stacked_pool_with_a_traced_layer_in_a_scan(self, dtype):
+        """The forward's call: the stack [L, nb, bs, Hkv*D] as it lies,
+        ``layer`` the scan's counter. bf16 pages take the MXU-exact
+        path (q.K in bf16, p split in three bf16 terms)."""
+        from tpushare.ops.flash_attention import paged_flash_decode
+        q, pk, pv, table, pos = self._live_case([530, 0, 270], mb=40)
+        L = 3
+        rng = np.random.default_rng(2)
+        sk, sv = (jnp.asarray(rng.normal(size=(L,) + pk.shape),
+                              jnp.float32).astype(dtype) for _ in range(2))
+        q = q.astype(dtype)
+        nb, bs, Hkv, D = pk.shape
+
+        def layer(carry, l):
+            return carry, paged_flash_decode(
+                q, sk.reshape(L, nb, bs, Hkv * D),
+                sv.reshape(L, nb, bs, Hkv * D), table, pos, layer=l,
+                interpret=True)
+        _, got = jax.jit(lambda: jax.lax.scan(layer, 0, jnp.arange(L)))()
+        tol = 2e-3 if dtype == jnp.float32 else 2e-2
+        for l in range(L):
+            want = self._ref(q, sk[l], sv[l], table, pos)
+            np.testing.assert_allclose(
+                np.asarray(got[l], np.float32),
+                np.asarray(want, np.float32), rtol=tol, atol=tol)
+
+    def test_the_exact_value_product_rounds_no_probability(self):
+        """p in three bf16 terms against bf16 V is the float32 product
+        of p and V (to float32 summation), where p cast to bf16 is a
+        hundred times off."""
+        from tpushare.ops.flash_attention import _pv_exact
+        rng = np.random.default_rng(4)
+        p = jnp.asarray(rng.uniform(size=(8, 256)), jnp.float32)
+        v = jnp.asarray(rng.normal(size=(256, 128)), jnp.bfloat16)
+        want = np.asarray(p, np.float64) @ np.asarray(
+            v.astype(jnp.float32), np.float64)
+        got = np.asarray(_pv_exact(p, v), np.float64)
+        rounded = np.asarray(p.astype(jnp.bfloat16).astype(jnp.float32),
+                             np.float64) @ np.asarray(
+            v.astype(jnp.float32), np.float64)
+        assert np.abs(got - want).max() < 2e-5
+        assert np.abs(rounded - want).max() > 1e-3
+
 
 class TestDecodeDispatchPolicy:
     """VERDICT r2 item 2: the measured-on-chip evidence has XLA's fused
@@ -556,6 +698,15 @@ class TestDecodeDispatchPolicy:
         # No capacity information -> conservative fallback.
         assert fa.paged_decode_eligible(*self._paged_shapes(),
                                         quantized=True) is False
+        # Scale pages narrower than a lane tile cannot be copied out of
+        # HBM one a page (Mosaic, PR 29): pages of 32 or 64 rows take
+        # the fallback even when forced.
+        q, _ = self._paged_shapes()
+        for bs, want in ((32, False), (64, False), (128, True),
+                         (256, True)):
+            pool = jnp.zeros((16, bs, 2, 128), jnp.int8)
+            assert fa.paged_decode_eligible(
+                q, pool, quantized=True, max_ctx=long) is want, bs
         # Env forces win over the heuristic in both directions.
         monkeypatch.setenv(fa.DECODE_KERNEL_ENV, "1")
         assert fa.paged_decode_eligible(*self._paged_shapes(),

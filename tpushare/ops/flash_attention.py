@@ -102,6 +102,15 @@ def _kv_live_range(p, w, blk: int, n_blocks: int):
     return lo, hi
 
 
+def _live_groups(p, w, bs: int, n_pages: int, group: int):
+    """(first page, end page, first group, end group) of a slot at
+    position ``p``: its live pages [lo, hi) of ``bs`` rows and the
+    groups of ``group`` pages that hold one. The paged decode kernel's
+    loop runs groups [g_lo, g_hi) and nothing else: what a slot costs."""
+    lo, hi = _kv_live_range(p, w, bs, n_pages)
+    return lo, hi, lo // group, (hi + group - 1) // group
+
+
 def _fa_kernel(q_off_ref, k_off_ref, win_ref, q_ref, k_ref, v_ref, o_ref,
                *ml_refs, scale: float, block_k: int, causal: bool,
                partial: bool, softcap: Optional[float] = None):
@@ -694,79 +703,190 @@ def _scale_pages(k_scale, v_scale, L: int, nb: int, bs: int, Hkv: int):
     return [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
 
 
+#: Keys one loop step of the paged decode kernel covers. Swept on a v5e
+#: at the benchmark's three pools (bf16 pages of 16 rows, PERF.md §6,
+#: PR 29): 128 keys a step cost 73 / 132 / 92 us a call (chat, docqa,
+#: Mixtral), 256 cost 62 / 106 / 75, 512 cost 64 / 106 / 73 — level
+#: from 256 up, where a step's copies (1 MiB) already hide its matmuls;
+#: 256 holds the double buffer at 2 MiB of VMEM.
+DECODE_GROUP_KEYS = 256
+
+
+#: VMEM the kernel's double buffer (two halves, K and V) may take.
+DECODE_BUFFER_BYTES = 4 * 1024 * 1024
+
+
+def _decode_group_pages(bs: int, mb: int, row_bytes: int) -> int:
+    """Pages one loop step of the paged decode kernel covers, from what
+    the call can see: DECODE_GROUP_KEYS keys' worth of ``bs``-row pages,
+    fewer where a row of all kv heads (``row_bytes``) is so wide that
+    four tiles of them would pass DECODE_BUFFER_BYTES, never more than
+    a slot has, never under one (``mb`` need not divide by it)."""
+    keys = min(DECODE_GROUP_KEYS, DECODE_BUFFER_BYTES // (4 * row_bytes))
+    return max(1, min(mb, keys // bs))
+
+
+def _pv_exact(p, v):
+    """p [rows, T] float32 times v [T, D] as stored, in float32.
+
+    Pages that are bf16 in HBM meet the MXU as bf16, never widened on
+    the VPU: p is split into three bf16 terms (8 + 8 + 8 bits: all of a
+    float32's mantissa), the terms ride ONE matmul as four blocks of
+    rows (the fourth zero, so the stack is whole bf16 tiles) against
+    the same V tile, and the three partial products, each exact in
+    float32, are summed: p is not rounded. Any other page dtype
+    (float32 pools, dequantized int8) takes a float32 matmul."""
+    dims = (((1,), (0,)), ((), ()))
+    if v.dtype != jnp.bfloat16:
+        return jax.lax.dot_general(p, v.astype(jnp.float32), dims,
+                                   preferred_element_type=jnp.float32)
+    rows = p.shape[0]
+    hi = p.astype(jnp.bfloat16).astype(jnp.float32)
+    mid = (p - hi).astype(jnp.bfloat16).astype(jnp.float32)
+    terms = jnp.concatenate([hi, mid, p - hi - mid, jnp.zeros_like(p)])
+    out = jax.lax.dot_general(terms.astype(jnp.bfloat16), v, dims,
+                              preferred_element_type=jnp.float32)
+    return out[:rows] + out[rows:2 * rows] + out[2 * rows:3 * rows]
+
+
 def _paged_decode_kernel(table_ref, pos_ref, win_ref, layer_ref, q_ref,
-                         k_ref, v_ref, *rest, scale: float,
+                         k_hbm, v_hbm, *rest, scale: float,
                          softcap: Optional[float], hkv: int, g_pad: int,
-                         n_pages: int, quantized: bool = False):
-    # One decode step over a block-table-paged KV pool. Grid (B, pages):
-    # the page for (slot b, page kb) is chosen by the scalar-prefetched
-    # block table inside the BlockSpec index_map — the pool is never
-    # gathered into a dense [B, S, ...] view in HBM (the tax the
-    # gathered-view fallback in transformer.py's paged branch pays).
-    # Each grid step DMAs
-    # exactly one page [bs, Hkv*D]; all kv heads are processed in a
-    # static unroll so page bytes stream from HBM once. ``layer_ref``
-    # (which layer of the stacked pool) is read by the index_maps only.
+                         group: int, n_pages: int,
+                         quantized: bool = False):
+    # One decode step over a block-table-paged KV pool. Grid (B,): one
+    # grid step a slot, and inside it a loop over the slot's LIVE groups
+    # of ``group`` pages (_kv_live_range: pages past pos[b] or behind
+    # the window cost no step, no copy and no compute). The pools stay
+    # in HBM (k_hbm / v_hbm [L, nb, bs, Hkv*D], never gathered into a
+    # dense [B, S, ...] view, never sliced by layer): a group's live
+    # pages are copied, one DMA a page through the scalar-prefetched
+    # block table, into one VMEM tile [group*bs, Hkv*D] of a double
+    # buffer, and while a group is computed the next one in line (the
+    # slot's next group, or the next slot's first) is already on its
+    # way. Each live page streams from HBM once; all kv heads are
+    # processed from the one tile in a static unroll.
     #
-    # quantized=True: k/v pages are int8 and two extra scale refs
-    # ([1, Hkv_pad, bs] f32 — bs on the lane dim, the layout Mosaic
-    # accepts) ride between v_ref and the output; pages dequantize on
-    # the VPU after the DMA, so HBM traffic — decode's roofline — is
-    # halved while the softmax/matmul math is unchanged.
+    # quantized=True: k/v pages are int8 and their scale pages
+    # ([L, nb, Hkv_pad, bs] f32 — bs on the lane dim, the layout Mosaic
+    # accepts) are copied beside them; pages dequantize on the VPU
+    # after the DMA, so HBM traffic — decode's roofline — is halved
+    # while the softmax/matmul math is unchanged.
     if quantized:
-        ks_ref, vs_ref, o_ref, acc_ref, m_ref, l_ref = rest
+        (ks_hbm, vs_hbm, o_ref, k_buf, v_buf, ks_buf, vs_buf, sem,
+         turn_ref) = rest
     else:
-        o_ref, acc_ref, m_ref, l_ref = rest
-    bs = k_ref.shape[1]
+        o_ref, k_buf, v_buf, sem, turn_ref = rest
+        ks_buf = vs_buf = None
+    bs = k_buf.shape[1] // group
+    T = group * bs
     D = q_ref.shape[2]
     b = pl.program_id(0)
-    kb = pl.program_id(1)
-    p = pos_ref[b]
+    n_slots = pl.num_programs(0)
+    layer = layer_ref[0]
     window = win_ref[0]
     w_eff = jnp.where(window > 0, window, jnp.int32(2 ** 30))
 
-    @pl.when(kb == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+    def live(slot):
+        return _live_groups(pos_ref[slot], window, bs, n_pages, group)
 
-    run = jnp.logical_and(kb * bs <= p, (kb + 1) * bs > p - w_eff + 1)
+    def page_copies(slot, lo, hi, g, buf, start: bool):
+        """Start, or wait for, the copies of the live pages (of
+        ``slot``'s [lo, hi)) of its group ``g`` into half ``buf`` of
+        the double buffer. Page j lands on rows (j - g*group)*bs of the
+        tile, so a key's row is its position less the group's first."""
+        first = jnp.maximum(lo, g * group)
 
-    @pl.when(run)
-    def _compute():
-        k_pos = (kb * bs
-                 + jax.lax.broadcasted_iota(jnp.int32, (g_pad, bs), 1))
-        keep = jnp.logical_and(k_pos <= p, k_pos > p - w_eff)
-        for h in range(hkv):                      # static unroll
-            sl = slice(h * g_pad, (h + 1) * g_pad)
-            qh = q_ref[0, sl, :].astype(jnp.float32) * scale
-            ks = k_ref[0, :, h * D:(h + 1) * D].astype(jnp.float32)
-            vs = v_ref[0, :, h * D:(h + 1) * D].astype(jnp.float32)
+        def one(i, _):
+            at = first + i - g * group
+            blk = jnp.maximum(table_ref[slot, first + i], 0)
+            rows = pl.ds(pl.multiple_of(at * bs, bs), bs)
+            pairs = [(k_hbm, k_buf.at[buf, rows]),
+                     (v_hbm, v_buf.at[buf, rows])]
             if quantized:
-                ks = ks * ks_ref[0, h, :][:, None]    # [bs, 1] row scales
-                vs = vs * vs_ref[0, h, :][:, None]
-            s = jax.lax.dot_general(qh, ks, (((1,), (1,)), ((), ())),
-                                    preferred_element_type=jnp.float32)
-            if softcap is not None:
-                s = softcap * jnp.tanh(s / softcap)
-            s = jnp.where(keep, s, NEG_INF)
-            m = m_ref[sl, :1]
-            l = l_ref[sl, :1]
-            m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-            pexp = jnp.where(s > NEG_INF / 2, jnp.exp(s - m_new), 0.0)
-            alpha = jnp.exp(m - m_new)
-            l_new = l * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
-            acc_ref[sl, :] = acc_ref[sl, :] * alpha + jax.lax.dot_general(
-                pexp, vs, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
-            m_ref[sl, :] = jnp.broadcast_to(m_new, (g_pad, m_ref.shape[1]))
-            l_ref[sl, :] = jnp.broadcast_to(l_new, (g_pad, l_ref.shape[1]))
+                pairs += [(ks_hbm, ks_buf.at[buf, at]),
+                          (vs_hbm, vs_buf.at[buf, at])]
+            for src, dst in pairs:
+                dma = pltpu.make_async_copy(src.at[layer, blk], dst,
+                                            sem.at[buf])
+                dma.start() if start else dma.wait()
 
-    @pl.when(kb == n_pages - 1)
-    def _finalize():
-        l = l_ref[:, :1]
-        o_ref[0] = (acc_ref[...] / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+        jax.lax.fori_loop(0, jnp.minimum(hi, (g + 1) * group) - first,
+                          one, None)
+
+    lo, hi, g_lo, g_hi = live(b)
+
+    @pl.when(b == 0)
+    def _first():
+        # Tile rows no copy ever writes are multiplied by p = 0: they
+        # must hold finite values (0 x NaN would poison the product).
+        v_buf[...] = jnp.zeros_like(v_buf)
+        if quantized:
+            vs_buf[...] = jnp.zeros_like(vs_buf)
+        turn_ref[0] = 0
+        page_copies(b, lo, hi, g_lo, 0, start=True)
+
+    p = pos_ref[b]
+    rows_all = hkv * g_pad
+    heads = [slice(h * g_pad, (h + 1) * g_pad) for h in range(hkv)]
+
+    def tile(buf_ref, scale_ref, buf, h):
+        """Head h of the group's tile, [T, D]: as stored, or int8
+        dequantized by its pages' row scales."""
+        x = buf_ref[buf, :, h * D:(h + 1) * D]
+        if not quantized:
+            return x
+        return jnp.concatenate([
+            x[i * bs:(i + 1) * bs].astype(jnp.float32)
+            * scale_ref[buf, i, h, :][:, None]          # [bs, 1] row scales
+            for i in range(group)])
+
+    def step(g, carry):
+        acc, m, l, buf = carry
+        # Next in line: this slot's next group, else the next slot's
+        # first (nothing after the last slot's last).
+        last = g == g_hi - 1
+        nslot = jnp.where(last, jnp.minimum(b + 1, n_slots - 1), b)
+        nlo, nhi, ng_lo, _ = live(nslot)
+
+        @pl.when(jnp.logical_not(jnp.logical_and(last, b == n_slots - 1)))
+        def _prefetch():
+            page_copies(nslot, nlo, nhi, jnp.where(last, ng_lo, g + 1),
+                        1 - buf, start=True)
+
+        page_copies(b, lo, hi, g, buf, start=False)
+        k_pos = g * T + jax.lax.broadcasted_iota(jnp.int32, (rows_all, T), 1)
+        keep = jnp.logical_and(k_pos <= p, k_pos > p - w_eff)
+        s = []
+        for h in range(hkv):                      # static unroll
+            qh, kh = q_ref[0, heads[h], :], tile(k_buf, ks_buf, buf, h)
+            if not qh.dtype == kh.dtype == jnp.bfloat16:
+                # bf16 x bf16 is exact in the MXU's float32; any other
+                # pair is widened first
+                qh, kh = qh.astype(jnp.float32), kh.astype(jnp.float32)
+            s.append(jax.lax.dot_general(
+                qh, kh, (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32))
+        s = jnp.concatenate(s) * scale                      # [rows_all, T]
+        if softcap is not None:
+            s = softcap * jnp.tanh(s / softcap)
+        s = jnp.where(keep, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
+        pexp = jnp.where(keep, jnp.exp(s - m_new), 0.0)
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(pexp, axis=-1, keepdims=True)
+        pv = jnp.concatenate([
+            _pv_exact(pexp[heads[h]], tile(v_buf, vs_buf, buf, h))
+            for h in range(hkv)])                           # [rows_all, D]
+        return acc * alpha + pv, m_new, l, 1 - buf
+
+    acc, _, l, buf = jax.lax.fori_loop(
+        g_lo, g_hi, step,
+        (jnp.zeros((rows_all, D), jnp.float32),
+         jnp.full((rows_all, 1), NEG_INF, jnp.float32),
+         jnp.zeros((rows_all, 1), jnp.float32), turn_ref[0]))
+    turn_ref[0] = buf
+    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=(
@@ -792,23 +912,28 @@ def paged_flash_decode(q: jnp.ndarray, pool_k: jnp.ndarray,
     be scattered at pos[b]). Unallocated table entries are clamped to
     page 0 and masked by ``pos``, so they are never attended.
 
+    What a call costs follows the LIVE pages, not the table's width:
+    the kernel takes one grid step a slot and, inside it, one loop step
+    a group of _decode_group_pages pages of the slot's live range
+    (pos[b] and ``window``); a page nobody may attend is neither
+    copied nor computed, and a live page is DMA'd from HBM exactly once
+    per slot.
+
     Int8 pools: pass ``k_scale``/``v_scale`` [n_blocks, Hkv_pad, bs]
     (stacked: [L, n_blocks, Hkv_pad, bs]; the models/paged.py kv_quant
     pools store scales in exactly this
     page layout from init — quant.scales_to_pool_layout; bs on the
     lane dim because Mosaic rejects a short minor axis) — pages stream
     from HBM as int8 and dequantize on the VPU after the DMA, halving
-    decode's KV page traffic. The scale pages ride the same
-    block-table index_map. r3 measured the kernel BEHIND XLA's fused
-    int8 gather at 4k ctx and ahead from 8k up (1.22-1.81x) with a
-    per-call whole-pool scale transpose inside the timed region
-    (ADVICE r3); that transpose now happens once at pool init, so the
-    dispatch crossover (paged_decode_eligible) is conservative until
-    re-measured.
+    decode's KV page traffic; the scale pages are copied beside their
+    pages. r3 measured the one-page-a-step kernel BEHIND XLA's fused
+    int8 gather at 4k ctx and ahead from 8k up (1.22-1.81x); the
+    dispatch crossover (paged_decode_eligible) is that measurement's
+    until the grouped kernel's is taken.
 
-    bs >= 8 required (sublane tile); >= 128 recommended for MXU-shaped
-    score tiles — decode is KV-bandwidth-bound either way and each page
-    is DMA'd from HBM exactly once per slot.
+    bs must be whole tiles of the pages' dtype (paged_decode_eligible:
+    16 rows of bf16, 32 of int8); the score tile is a group of pages
+    wide whatever bs is, so small pages cost nothing but their DMAs.
     """
     B, Sq, H, D = q.shape
     assert Sq == 1, "paged_flash_decode is the Sq==1 path"
@@ -817,60 +942,52 @@ def paged_flash_decode(q: jnp.ndarray, pool_k: jnp.ndarray,
     assert H % Hkv == 0, (pool_k.shape, q.shape)
     quantized = k_scale is not None
     mb = table.shape[1]
+    group = _decode_group_pages(bs, mb, Hkv * D * kp.dtype.itemsize)
     g = H // Hkv
     g_pad = max(8, -(-g // 8) * 8)
 
     # Head h = kvh*g + j: [B,H,D] -> groups on the sublane dim.
-    q4 = q[:, 0].reshape(B, Hkv, g, D)
-    qp = jnp.zeros((B, Hkv * g_pad, D), q.dtype)
-    for h in range(Hkv):                          # static, Hkv is small
-        qp = qp.at[:, h * g_pad:h * g_pad + g].set(q4[:, h])
+    qp = jnp.pad(q[:, 0].reshape(B, Hkv, g, D),
+                 ((0, 0), (0, 0), (0, g_pad - g), (0, 0)))
+    qp = qp.reshape(B, Hkv * g_pad, D)
     table_s = jnp.asarray(table, jnp.int32)
     pos_s = jnp.asarray(pos, jnp.int32).reshape(B)
     win = jnp.asarray(0 if window is None else window,
                       jnp.int32).reshape(1)
 
-    def q_index(b, kb, table_ref, pos_ref, win_ref, layer_ref):
+    def q_index(b, table_ref, pos_ref, win_ref, layer_ref):
         return (b, 0, 0)
 
-    def kv_index(b, kb, table_ref, pos_ref, win_ref, layer_ref):
-        # Page-level DMA skip: clamp the page index into the slot's
-        # live range [lo, hi) so pages past pos[b] (and before the
-        # sliding window) repeat an already-fetched page and the copy
-        # is elided — halves KV read traffic at random fill levels.
-        lo, hi = _kv_live_range(pos_ref[b], win_ref[0], bs, mb)
-        return (layer_ref[0],
-                jnp.maximum(table_ref[b, jnp.clip(kb, lo, hi - 1)], 0),
-                0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, Hkv * g_pad, D), q_index),
-        pl.BlockSpec((None, 1, bs, Hkv * D), kv_index),
-        pl.BlockSpec((None, 1, bs, Hkv * D), kv_index),
-    ]
+    in_hbm = pl.BlockSpec(memory_space=pltpu.HBM)
+    in_specs = [pl.BlockSpec((1, Hkv * g_pad, D), q_index), in_hbm, in_hbm]
     operands = [qp, kp, vp]
+    scratch = [pltpu.VMEM((2, group * bs, Hkv * D), kp.dtype)] * 2
     if quantized:
         operands += _scale_pages(k_scale, v_scale, kp.shape[0], nb, bs,
                                  Hkv)
-        in_specs += [pl.BlockSpec((None, 1) + operands[-1].shape[2:],
-                                  kv_index)] * 2
+        in_specs += [in_hbm] * 2
+        scratch += [pltpu.VMEM((2, group) + operands[-1].shape[2:],
+                               jnp.float32)] * 2
 
     out = pl.pallas_call(
         functools.partial(_paged_decode_kernel,
                           scale=D ** -0.5 if scale is None else scale,
                           softcap=attn_softcap, hkv=Hkv, g_pad=g_pad,
-                          n_pages=mb, quantized=quantized),
+                          group=group, n_pages=mb, quantized=quantized),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
-            grid=(B, mb),
+            grid=(B,),
             in_specs=in_specs,
             out_specs=pl.BlockSpec((1, Hkv * g_pad, D), q_index),
-            scratch_shapes=[
-                pltpu.VMEM((Hkv * g_pad, D), jnp.float32),
-                pltpu.VMEM((Hkv * g_pad, 128), jnp.float32),
-                pltpu.VMEM((Hkv * g_pad, 128), jnp.float32),
+            scratch_shapes=scratch + [
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
             ],
         ),
+        # the double buffer's turn and its copy in flight pass from one
+        # slot's grid step to the next: the steps run in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         out_shape=_sds((B, Hkv * g_pad, D), q.dtype, q, pool_k, pool_v),
         interpret=interpret,
     )(table_s, pos_s, win, layer_s, *operands)
@@ -975,13 +1092,12 @@ def paged_flash_verify(q: jnp.ndarray, pool_k: jnp.ndarray,
     query-row dimension so each page still streams from HBM exactly
     once per slot per round.
 
-    Deliberately NOT unified with the decode kernel yet, despite
-    decode being the sq=1 case: paged_flash_decode's implementation is
-    the one the default dispatch runs on the chip (chip_smoke.py
-    compares it with its reference every run), and routing it through
-    this opt-in body would put an unmeasured kernel on the default
-    path. Unify (decode delegating with sq=1) once the verify kernel
-    has a cell of its own."""
+    Deliberately NOT unified with the decode kernel, despite decode
+    being the sq=1 case: this opt-in body still takes one grid step a
+    page of the table (what paged_flash_decode did until PR 29, at
+    0.28 us a dead step), and no cell measures it. Give it the decode
+    kernel's loop over live groups once the verify kernel has a cell
+    of its own."""
     B, Sq, H, D = q.shape
     assert Sq > 1, "Sq == 1 is paged_flash_decode"
     kp, vp, k_scale, v_scale, layer_s, nb, bs, Hkv = _stacked_pages(
@@ -1137,10 +1253,18 @@ def paged_decode_eligible(q: jnp.ndarray, pool: jnp.ndarray,
     grows with context while the kernel streams pages once. Default:
     kernel iff ``max_ctx`` (the slot capacity mb*bs) >=
     PAGED_Q8_KERNEL_MIN_CTX; TPUSHARE_DECODE_KERNEL=1/0 forces
-    either way."""
+    either way. Int8 pages of fewer than 128 rows take the fallback
+    whatever the policy: their scale pages are narrower than a lane
+    tile (below)."""
     if _paged_kernel_policy_ok(quantized, max_ctx) is False:
         return False
     B, Sq, H, D = q.shape
     bs, Hkv, D2 = _page_dims(pool, D, stacked)
+    if quantized and bs % 128:
+        # the kernel copies a page's scale page [Hkv_pad, bs] out of HBM
+        # on its own, and Mosaic slices an HBM array by whole lane tiles
+        # only ("Slice shape along dimension 3 must be aligned to tiling
+        # (128)", compiled for a described v5e, PR 29)
+        return False
     return (Sq == 1 and D % 128 == 0 and bs % _sublanes(pool.dtype) == 0
             and D2 == D and H % Hkv == 0)
