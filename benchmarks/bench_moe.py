@@ -1,7 +1,7 @@
 """MoE serving-level decode throughput: dense-dispatch vs dropless.
 
-Times ONE full-model MoE ragged decode step (models/moe.forward — the
-exact jitted call MoESlotServer.step dispatches) at serving shapes,
+Times ONE full-model MoE ragged decode step (models/moe.forward) at
+serving shapes, over dense KV rows and over the paged pool,
 with the chained scan-differenced methodology
 (profiling.time_step_chained docstring) so host dispatch cancels out
 of the number. Two routing rows tell the MoE decode story:
@@ -29,12 +29,12 @@ parity) before a TPU run banks numbers.
 
 At decode batch (T = n_slots tokens/step) both routings are expected
 to sit at the weight-streaming roofline — all E experts' weights must
-cross HBM once per step regardless of routing — which is the
-measurement that justifies MoESlotServer's "dense KV rows, no paged
-pools" scoping (moe.MoESlotServer docstring), and is exactly why the
-int8 row should approach 2x: halving the streamed bytes halves a
+cross HBM once per step regardless of routing — which is exactly why
+the int8 row should approach 2x: halving the streamed bytes halves a
 bandwidth-bound step. A prefill row (T = B*S tokens) is where
-dropless' FLOP advantage can actually show.
+dropless' FLOP advantage can actually show. None of these rows is a
+number of record: the serving path is measured by `tpubench`
+(PERF.md).
 
 Prints one JSON row per configuration. Usage:
   python benchmarks/bench_moe.py [--slots 8] [--ctx 2048] [--layers 8]
@@ -332,7 +332,7 @@ def main() -> int:
         del os.environ[q8_expert.Q8_EXPERT_KERNEL_ENV]
     emit(row)
 
-    # Paged-KV family (the --kv paged serving path): the SAME full-model
+    # Paged-KV family (the serving path): the SAME full-model
     # ragged decode step at equal batch/context, but KV lives in the
     # block pool and attention goes through the block table
     # (moe.forward's paged branch — pallas paged kernel on TPU, gathered
@@ -406,45 +406,6 @@ def main() -> int:
              "active": active},
             lengths, int(lengths_np.sum())),
     })
-
-    # Per-slot speculative decoding: int8-self draft (the target's own
-    # rounding) vs the plain server, same host-driven loop both sides
-    # (bench_serving's spec-row methodology — wall-clock over rounds,
-    # accept_rate = emitted tokens per slot-round over gamma+1).
-    from tpushare.models import quant
-
-    from specloop import run_serving_loop, spec_row_fields
-
-    cfg = moe.MoEConfig(routing="psum", **base)   # best decode config
-    params = moe.init_params(jax.random.PRNGKey(0), cfg)
-    qdraft = quant.quantize_params(params, cfg)
-    gamma, rounds = 3, 16
-    plen = 48 if on_tpu else 16
-    # Worst-case emission at full acceptance: gamma+1 per round incl.
-    # the untimed warm step — no mid-run retirement or spec->plain
-    # fallback may skew the timing.
-    need = plen + (gamma + 1) * (rounds + 2)
-    max_len = 1 << (need - 1).bit_length()
-    rng = np.random.default_rng(5)
-    prompts = [jnp.asarray(r, jnp.int32) for r in
-               rng.integers(0, cfg.vocab_size, (B, plen))]
-
-    def make(spec: bool):
-        kw = dict(n_slots=B, max_len=max_len)
-        if spec:
-            kw.update(speculative_draft=(qdraft, cfg), gamma=gamma,
-                      draft_layers_hook=quant.dequant_hook(cfg))
-        return lambda: moe.MoESlotServer(params, cfg, **kw)
-
-    plain_tps, _, _ = run_serving_loop(make(False), prompts, rounds)
-    spec_tps, per_round, extras = run_serving_loop(make(True), prompts,
-                                                   rounds)
-    emit(dict({
-        "metric": "moe_spec_decode_tokens_per_sec",
-        "mode": "int8_self_draft",
-        "backend": backend, "slots": B, "prompt_tokens": plen,
-    }, **spec_row_fields(spec_tps, plain_tps, per_round, gamma,
-                         extras=extras)))
 
     # Rows go to stdout only, each naming its backend.
     return 0

@@ -12,9 +12,10 @@ hardware-free:
      (speculative_generate(model="moe")) — bit-exact greedy, the
      draft only buys speed;
   4. serve the int8 tree from ONE tpushare-serve HTTP daemon
-     (model_family="moe"): two requests share a system prompt, the
-     second reports its cached prefix (row-level prefix cache), and
-     both streams match moe.generate.
+     (model_family="moe", the paged pool): two requests share a
+     system prompt of one whole block, the second reports it as its
+     cached prefix (block-granular prefix cache), and both streams
+     match moe.generate.
 
 Run: python demo/e2e_moe_serve.py   (forces the CPU backend itself
 with jax.config.update, which wins over JAX_PLATFORMS)
@@ -88,7 +89,7 @@ def main() -> int:
     # 4. Serve the int8 tree over HTTP.
     from tpushare.cli.serve import ServeEngine, serve
     engine = ServeEngine(qp, cfg, model_family="moe", n_slots=2,
-                         max_len=48, layers_hook=hook,
+                         n_blocks=16, block_size=16, layers_hook=hook,
                          idle_sleep_s=0.001)
     httpd = serve(engine, host="127.0.0.1", port=0, timeout_s=120.0)
     port = httpd.server_address[1]
@@ -102,14 +103,16 @@ def main() -> int:
         return r.status, json.loads(r.read())
 
     try:
-        system = [int(t) for t in toks[0][:8]]
+        # One whole block: the prefix cache shares blocks, not tokens.
+        system = [int(t) for t in np.random.default_rng(1).integers(
+            0, cfg.vocab_size, 16)]
         s1, o1 = post({"prompt": system + [3, 1], "max_tokens": 4})
         s2, o2 = post({"prompt": system + [9, 9, 9], "max_tokens": 4})
         assert s1 == 200 and s2 == 200, (o1, o2)
-        assert o2["cached_prefix"] == 8, o2
+        assert o2["cached_prefix"] == 16, o2
         ref = moe.generate(qp, jnp.asarray([system + [9, 9, 9]]), cfg,
                            max_new_tokens=4, layers_hook=hook)
-        assert o2["tokens"] == [int(t) for t in ref[0, 11:]]
+        assert o2["tokens"] == [int(t) for t in ref[0, 19:]]
         print(f"[4] HTTP daemon (int8, prefix cache): 2nd request "
               f"reused {o2['cached_prefix']} shared prompt tokens; "
               f"streams match moe.generate")

@@ -65,7 +65,7 @@ SLOW_MODULES = {
     "test_moe_pipeline", "test_ops", "test_paged", "test_parallel",
     "test_pipeline",
     "test_prefix_cache", "test_serve",
-    "test_profiling", "test_quant", "test_serving", "test_slot_server",
+    "test_profiling", "test_quant", "test_serving",
     "test_speculative", "test_trainer", "test_transformer",
 }
 

@@ -28,7 +28,7 @@ TF_PARAMS = tf.init_params(jax.random.PRNGKey(0), TF_CFG)
 MOE_CFG = moe.tiny(remat=False)
 MOE_PARAMS = moe.init_params(jax.random.PRNGKey(0), MOE_CFG)
 
-FAMILIES = ("dense", "moe_rows", "moe_paged")
+FAMILIES = ("dense", "moe_chunked", "moe_paged")
 
 
 def make_engine(family, **kw):
@@ -37,13 +37,15 @@ def make_engine(family, **kw):
     if family == "dense":
         return ServeEngine(TF_PARAMS, TF_CFG, n_slots=2, n_blocks=48,
                            block_size=8, max_blocks_per_slot=12, **kw)
-    if family == "moe_rows":
+    if family == "moe_chunked":
+        # Blocks of 4 and chunks of 4: every prompt over 4 tokens is a
+        # chunked admission riding fused ticks, on the sparse family.
         return ServeEngine(MOE_PARAMS, MOE_CFG, model_family="moe",
-                           n_slots=2, max_len=128, **kw)
+                           n_slots=2, n_blocks=96, block_size=4,
+                           prefill_chunk=4, **kw)
     if family == "moe_paged":
         return ServeEngine(MOE_PARAMS, MOE_CFG, model_family="moe",
-                           kv="paged", n_slots=2, n_blocks=48,
-                           block_size=8, **kw)
+                           n_slots=2, n_blocks=48, block_size=8, **kw)
     raise AssertionError(family)
 
 

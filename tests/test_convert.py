@@ -170,7 +170,9 @@ def test_mixtral_generate_and_serving_compose():
     prompt = jnp.asarray([[5, 17, 90, 3, 41]])
     out = moe.generate(params, prompt, cfg, max_new_tokens=6)
     assert out.shape == (1, 11)
-    srv = moe.MoESlotServer(params, cfg, n_slots=2, max_len=16)
+    from tpushare.models.paged import PagedSlotServer
+    srv = PagedSlotServer(params, cfg, n_slots=2, n_blocks=8,
+                          block_size=4, forward_fn=moe.paged_forward)
     s = srv.admit(prompt[0])
     got = [int(srv.last_token[s, 0])]
     for _ in range(5):

@@ -284,11 +284,21 @@ def test_stage_clock_and_span_cost_nothing_they_should_not():
     assert (time.perf_counter() - t0) / 2000 < 50e-6
 
 
+@pytest.mark.parametrize("family", ("dense", "moe"))
 @pytest.mark.parametrize("draft", (False, True), ids=("target", "draft"))
-def test_the_paged_programs_carry_stable_names(draft):
-    kw = ({"speculative_draft": (PARAMS, CFG), "gamma": 2} if draft else {})
-    srv = PagedSlotServer(PARAMS, CFG, n_slots=2, n_blocks=16,
-                          block_size=8, **kw)
+def test_the_paged_programs_carry_stable_names(draft, family):
+    """The three program names the trace readers key on, whichever
+    forward function the one slot server runs."""
+    if family == "moe":
+        from tpushare.models import moe
+        cfg = moe.tiny(remat=False)
+        params = moe.init_params(jax.random.PRNGKey(0), cfg)
+        fkw, init_cache = {"forward_fn": moe.paged_forward}, moe.init_cache
+    else:
+        cfg, params, fkw, init_cache = CFG, PARAMS, {}, tf.init_cache
+    kw = ({"speculative_draft": (params, cfg), "gamma": 2} if draft else {})
+    srv = PagedSlotServer(params, cfg, n_slots=2, n_blocks=16,
+                          block_size=8, **fkw, **kw)
     pre = "draft_" if draft else ""
     c = srv.cache
     toks = jnp.zeros((2, 1), jnp.int32)
@@ -296,14 +306,14 @@ def test_the_paged_programs_carry_stable_names(draft):
     decode = srv._draft_decode if draft else srv._decode
     fused = srv._draft_verify if draft else srv._verify
     prefill = srv._draft_prefill if draft else srv._prefill
-    text = decode.lower(PARAMS, toks, c.pool_k, c.pool_v, c.block_table,
+    text = decode.lower(params, toks, c.pool_k, c.pool_v, c.block_table,
                         c.lengths, active).as_text()
     assert f"module @jit_{pre}paged_decode " in text
-    text = fused.lower(PARAMS, jnp.zeros((2, 8), jnp.int32), c.pool_k,
+    text = fused.lower(params, jnp.zeros((2, 8), jnp.int32), c.pool_k,
                        c.pool_v, c.block_table, c.lengths,
                        active).as_text()
     assert f"module @jit_{pre}paged_fused " in text
-    row = tf.init_cache(CFG, 1, 16)
-    text = prefill.lower(PARAMS, jnp.zeros((1, 16), jnp.int32), cache=row,
+    row = init_cache(cfg, 1, 16)
+    text = prefill.lower(params, jnp.zeros((1, 16), jnp.int32), cache=row,
                          pos_offset=0).as_text()
     assert f"module @jit_{pre}paged_prefill " in text

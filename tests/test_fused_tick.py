@@ -1,9 +1,9 @@
 """Fused prefill+decode engine tick: while an admission is in flight
 with active decode slots, each tick issues exactly ONE model forward
 (the chunk rides the decode batch — no second weight stream) and the
-fused path is bit-exact vs the serial admit_step oracle for all three
-server families (dense SlotServer, PagedSlotServer, MoESlotServer),
-including their speculative variants and the engine integration."""
+fused path is bit-exact vs the serial admit_step oracle for the slot
+server under both families' forward functions, with int8 pools, the
+prefix cache and speculation, and through the engine."""
 
 import jax
 import jax.numpy as jnp
@@ -13,8 +13,7 @@ import pytest
 from tpushare.models import moe, quant
 from tpushare.models import transformer as tf
 from tpushare.models.paged import PagedSlotServer
-from tpushare.models.serving import (SlotServer, fused_chunk_span,
-                                     fused_token_batch)
+from tpushare.models.serving import fused_chunk_span, fused_token_batch
 
 TF_CFG = tf.tiny(remat=False)
 TF_PARAMS = tf.init_params(jax.random.PRNGKey(0), TF_CFG)
@@ -58,10 +57,6 @@ def _drive(srv, long_prompt, fused, ticks=8, chunk=8):
 
 
 FAMILIES = {
-    "dense": lambda: SlotServer(TF_PARAMS, TF_CFG, n_slots=3,
-                                max_len=96),
-    "dense_kvq": lambda: SlotServer(TF_PARAMS, TF_CFG, n_slots=3,
-                                    max_len=96, kv_quant=True),
     "paged": lambda: PagedSlotServer(TF_PARAMS, TF_CFG, n_slots=3,
                                      n_blocks=64, block_size=4),
     "paged_prefix": lambda: PagedSlotServer(
@@ -73,10 +68,12 @@ FAMILIES = {
     "paged_moe": lambda: PagedSlotServer(
         MOE_PARAMS, MOE_CFG, n_slots=3, n_blocks=64, block_size=4,
         forward_fn=moe.paged_forward),
-    "moe": lambda: moe.MoESlotServer(MOE_PARAMS, MOE_CFG, n_slots=3,
-                                     max_len=96),
-    "moe_spec": lambda: moe.MoESlotServer(
-        MOE_PARAMS, MOE_CFG, n_slots=3, max_len=96,
+    "paged_kvq": lambda: PagedSlotServer(
+        TF_PARAMS, TF_CFG, n_slots=3, n_blocks=64, block_size=4,
+        kv_quant=True),
+    "paged_moe_spec": lambda: PagedSlotServer(
+        MOE_PARAMS, MOE_CFG, n_slots=3, n_blocks=96, block_size=4,
+        forward_fn=moe.paged_forward,
         speculative_draft=(MOE_QDRAFT, MOE_CFG), gamma=2,
         draft_layers_hook=quant.dequant_hook(MOE_CFG)),
 }
@@ -173,12 +170,9 @@ class TestDispatchCount:
         return counts
 
     @pytest.mark.parametrize("family,fwd_names", [
-        ("dense", ("_decode", "_prefill", "_prefill_last")),
-        ("paged", ("_decode", "_prefill", "_verify")),
-        ("paged_spec", ("_decode", "_prefill", "_verify")),
-        ("moe", ("_fwd",)),
-        ("moe_spec", ("_fwd",)),
-    ])
+        (family, ("_decode", "_prefill", "_verify")) for family in
+        ("paged", "paged_kvq", "paged_spec", "paged_moe",
+         "paged_moe_spec")])
     def test_one_forward_per_fused_tick(self, family, fwd_names):
         srv = FAMILIES[family]()
         srv.admit(_prompt(1, 6, (MOE_CFG if "moe" in family
@@ -226,26 +220,21 @@ class TestFusedHelpers:
         """The tick budget bounds SERIAL chunks too (the
         admission-only half of the engine's budget alternation must
         not smuggle a full unbounded chunk past the latency bound)."""
-        # Dense and MoE cap at the exact token count (granule 1).
-        for family, vocab in (("dense", TF_CFG.vocab_size),
-                              ("moe", MOE_CFG.vocab_size)):
+        # Rounds down to block alignment with a one-block floor.
+        for family, vocab in (("paged", TF_CFG.vocab_size),
+                              ("paged_moe", MOE_CFG.vocab_size)):
             srv = FAMILIES[family]()
             slot = srv.admit_start(_prompt(7, 21, vocab),
                                    chunk_tokens=16)
-            assert srv.admit_step(slot, max_chunk_tokens=3) is None
-            assert srv._admissions[slot]["done"] == 3, family
-        # Paged rounds down to block alignment with a one-block floor.
-        srv = FAMILIES["paged"]()
-        slot = srv.admit_start(_prompt(7, 21), chunk_tokens=16)
-        assert srv.admit_step(slot, max_chunk_tokens=7) is None
-        assert srv._admissions[slot]["done"] == 4      # one 4-block
-        assert srv.admit_step(slot, max_chunk_tokens=2) is None
-        assert srv._admissions[slot]["done"] == 8      # floor: 1 block
+            assert srv.admit_step(slot, max_chunk_tokens=7) is None
+            assert srv._admissions[slot]["done"] == 4      # one 4-block
+            assert srv.admit_step(slot, max_chunk_tokens=2) is None
+            assert srv._admissions[slot]["done"] == 8      # floor: 1 block
 
     def test_step_rejects_unknown_prefill_work(self):
-        for family in ("dense", "paged", "moe"):
+        for family in ("paged", "paged_moe"):
             srv = FAMILIES[family]()
-            srv.admit(_prompt(1, 6, (MOE_CFG if family == "moe"
+            srv.admit(_prompt(1, 6, (MOE_CFG if "moe" in family
                                      else TF_CFG).vocab_size))
             with pytest.raises((ValueError, KeyError)):
                 srv.step(prefill_work=2)

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tpushare.models import moe
 from tpushare.models import transformer as tf
 from tpushare.models.kvtier import CHANNELS, CrossoverEstimator, HostKvTier
 from tpushare.models.paged import PagedSlotServer
@@ -24,20 +25,28 @@ from tpushare.slo.quota import KvQuota, parse_quota_spec
 
 CFG = tf.tiny(remat=False)
 PARAMS = tf.init_params(jax.random.PRNGKey(0), CFG)
+MOE_CFG = moe.tiny(remat=False)
+MOE_PARAMS = moe.init_params(jax.random.PRNGKey(0), MOE_CFG)
 BS = 4
+FAMILIES = ("dense", "moe")
 
 
-def _prompt(seed, n):
+def _prompt(seed, n, family="dense"):
+    vocab = (MOE_CFG if family == "moe" else CFG).vocab_size
     rng = np.random.default_rng(seed)
-    return jnp.asarray(rng.integers(0, CFG.vocab_size, n), jnp.int32)
+    return jnp.asarray(rng.integers(0, vocab, n), jnp.int32)
 
 
-def _mk(tier=None, n_blocks=16, **kw):
+def _mk(tier=None, n_blocks=16, family="dense", **kw):
     kw.setdefault("n_slots", 2)
     kw.setdefault("block_size", BS)
     kw.setdefault("max_blocks_per_slot", 8)
     kw.setdefault("prefix_cache", True)
-    srv = PagedSlotServer(PARAMS, CFG, n_blocks=n_blocks, **kw)
+    if family == "moe":
+        srv = PagedSlotServer(MOE_PARAMS, MOE_CFG, n_blocks=n_blocks,
+                              forward_fn=moe.paged_forward, **kw)
+    else:
+        srv = PagedSlotServer(PARAMS, CFG, n_blocks=n_blocks, **kw)
     if tier is not None:
         srv.cache.host_tier = tier
     return srv
@@ -260,11 +269,12 @@ def _force_transfer(tier):
     return tier
 
 
-def _roundtrip(tier, n_decode=6, **server_kw):
+def _roundtrip(tier, n_decode=6, family="dense", **server_kw):
     """Warm prompt A, evict, thrash the pool with fillers until A's
     blocks demote, re-admit A. Returns (oracle tokens, tier tokens,
     the tier, the server)."""
-    a = _prompt(1, 13)
+    a = _prompt(1, 13, family)
+    server_kw["family"] = family
     # Oracle: pool big enough that nothing is ever reclaimed.
     big = _mk(None, n_blocks=64, **server_kw)
     slot = big.admit(a)
@@ -275,7 +285,7 @@ def _roundtrip(tier, n_decode=6, **server_kw):
     _decode(srv, slot, n_decode)
     srv.evict(slot)                     # A's chain parks on the LRU
     for seed in range(3, 7):            # thrash: reclaim demotes A
-        f = srv.admit(_prompt(seed, 13))
+        f = srv.admit(_prompt(seed, 13, family))
         srv.evict(f)
     slot = srv.admit(a)                 # promote from the host tier
     got = _decode(srv, slot, n_decode)
@@ -283,9 +293,10 @@ def _roundtrip(tier, n_decode=6, **server_kw):
 
 
 class TestDemotePromoteRoundtrip:
-    def test_dense_roundtrip_bit_exact(self):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_roundtrip_bit_exact(self, family):
         tier = _force_transfer(HostKvTier(32 << 20))
-        want, got, srv = _roundtrip(tier)
+        want, got, srv = _roundtrip(tier, family=family)
         assert got == want
         snap = tier.snapshot()
         assert snap["demotions"] > 0, "thrash never demoted"
@@ -298,37 +309,6 @@ class TestDemotePromoteRoundtrip:
         cx = snap["crossover"]
         assert cx["channels"]["d2h"]["transfers"] > 1
         assert cx["channels"]["h2d"]["transfers"] > 1
-
-    def test_moe_paged_roundtrip_bit_exact(self):
-        from tpushare.models import moe
-        mcfg = moe.tiny(remat=False)
-        mparams = moe.init_params(jax.random.PRNGKey(0), mcfg)
-        tier = _force_transfer(HostKvTier(32 << 20))
-        a = jnp.asarray(np.random.default_rng(2).integers(
-            0, mcfg.vocab_size, 13), jnp.int32)
-
-        def mk(t, nb):
-            s = PagedSlotServer(mparams, mcfg, n_slots=2, n_blocks=nb,
-                                block_size=BS, max_blocks_per_slot=8,
-                                prefix_cache=True,
-                                forward_fn=moe.paged_forward)
-            if t is not None:
-                s.cache.host_tier = t
-            return s
-
-        big = mk(None, 64)
-        want = _decode(big, big.admit(a), 6)
-        srv = mk(tier, 10)
-        slot = srv.admit(a)
-        _decode(srv, slot, 6)
-        srv.evict(slot)
-        for seed in range(3, 7):
-            srv.evict(srv.admit(jnp.asarray(
-                np.random.default_rng(seed).integers(
-                    0, mcfg.vocab_size, 13), jnp.int32)))
-        got = _decode(srv, srv.admit(a), 6)
-        assert got == want
-        assert tier.snapshot()["promotions"] > 0
 
     def test_speculative_roundtrip_bit_exact(self):
         """Promotion restores TARGET KV only (the draft prefix over a
@@ -349,25 +329,27 @@ class TestDemotePromoteRoundtrip:
         assert got == want
         assert tier.snapshot()["promotions"] > 0
 
-    def test_failed_promotion_recomputes_token_exact(self):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_failed_promotion_recomputes_token_exact(self, family):
         tier = _force_transfer(HostKvTier(32 << 20))
 
         def boom():
             raise RuntimeError("injected promote fault")
         tier.fault_promote = boom
-        want, got, srv = _roundtrip(tier)
+        want, got, srv = _roundtrip(tier, family=family)
         assert got == want              # recompute fallback, bit-exact
         snap = tier.snapshot()
         assert snap["promotions"] == 0
         assert snap["promote_failures"] > 0
 
-    def test_chaos_demote_fault_degrades_to_eviction(self):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_chaos_demote_fault_degrades_to_eviction(self, family):
         tier = _force_transfer(HostKvTier(32 << 20))
 
         def boom():
             raise RuntimeError("injected demote fault")
         tier.fault_demote = boom
-        want, got, srv = _roundtrip(tier)
+        want, got, srv = _roundtrip(tier, family=family)
         assert got == want              # plain eviction + recompute
         snap = tier.snapshot()
         assert snap["demotions"] == 0

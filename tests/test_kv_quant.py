@@ -2,7 +2,7 @@
 
 Pins: requant-idempotence (unwritten rows never drift), prefill+decode
 parity against the full-precision cache within int8 tolerance, the
-~2x/4x storage shrink, and SlotServer(kv_quant=True) end-to-end.
+~2x/4x storage shrink, and PagedSlotServer(kv_quant=True) end-to-end.
 """
 
 import jax
@@ -11,7 +11,7 @@ import numpy as np
 
 from tpushare.models import quant
 from tpushare.models import transformer as tf
-from tpushare.models.serving import SlotServer
+from tpushare.models.paged import PagedSlotServer
 
 CFG = tf.tiny(remat=False)
 
@@ -161,8 +161,8 @@ def test_slot_server_kv_quant_end_to_end():
                for n in (7, 12)]
     outs = {}
     for kvq in (False, True):
-        srv = SlotServer(params, CFG, n_slots=2, max_len=32,
-                         kv_quant=kvq)
+        srv = PagedSlotServer(params, CFG, n_slots=2, n_blocks=24,
+                              block_size=4, kv_quant=kvq)
         slots = [srv.admit(p) for p in prompts]
         toks = {s: [] for s in slots}
         for _ in range(5):
@@ -170,14 +170,18 @@ def test_slot_server_kv_quant_end_to_end():
                 toks[s].append(t)
         outs[kvq] = [toks[s] for s in slots]
         if kvq:
-            assert set(srv.cache) == {"k", "v", "k_scale", "v_scale"}
-            assert srv.cache["k"].dtype == jnp.int8
-    # Chunked admit (the q8 row cache crosses multiple forward()
+            assert srv.cache.pool_k.dtype == jnp.int8
+            assert srv.cache.pool_k_scale is not None
+    # Chunked admit (the q8 admission row crosses multiple forward()
     # calls — previously-quantized rows coexist with each chunk's new
     # writes): first decode step must match the unchunked q8 admit.
-    chunked = SlotServer(params, CFG, n_slots=2, max_len=32,
-                         kv_quant=True, prefill_chunk=4)
-    c_slots = [chunked.admit(p) for p in prompts]
+    chunked = PagedSlotServer(params, CFG, n_slots=2, n_blocks=24,
+                              block_size=4, kv_quant=True)
+    c_slots = []
+    for p in prompts:
+        c_slots.append(chunked.admit_start(p, chunk_tokens=4))
+        while chunked.admit_step(c_slots[-1]) is None:
+            pass
     c_first = chunked.step()
     for i, cs in enumerate(c_slots):
         assert outs[True][i][0] == c_first[cs]

@@ -504,66 +504,6 @@ class TestMoEInference:
                                    rtol=2e-4, atol=2e-4)
 
 
-class TestMoESlotServer:
-    """Continuous batching for MoE: per-slot streams must equal
-    moe.generate on the same prompt (ragged slots never cross-talk),
-    slots recycle after evict, and capacity retires cleanly."""
-
-    def test_slot_streams_match_generate(self):
-        params = _params()
-        rng = np.random.default_rng(11)
-        p0 = jnp.asarray(rng.integers(0, CFG.vocab_size, 9))
-        p1 = jnp.asarray(rng.integers(0, CFG.vocab_size, 5))
-        srv = moe.MoESlotServer(params, CFG, n_slots=3, max_len=32)
-        s0, s1 = srv.admit(p0), srv.admit(p1)
-        got = {s0: [int(srv.last_token[s0, 0])],
-               s1: [int(srv.last_token[s1, 0])]}
-        for _ in range(6):
-            out = srv.step()
-            for s, t in out.items():
-                got[s].append(t)
-        for s, p in ((s0, p0), (s1, p1)):
-            want = moe.generate(params, p[None, :], CFG,
-                                max_new_tokens=7)[0, p.shape[0]:]
-            assert got[s] == [int(t) for t in want], s
-
-    def test_evict_recycles_slot(self):
-        params = _params()
-        srv = moe.MoESlotServer(params, CFG, n_slots=1, max_len=32)
-        s = srv.admit(jnp.asarray([3, 1, 4, 1, 5]))
-        srv.step()
-        srv.evict(s)
-        assert not srv.active.any()
-        p2 = jnp.asarray([2, 7, 1, 8])
-        s2 = srv.admit(p2)
-        got = [int(srv.last_token[s2, 0])]
-        for _ in range(4):
-            got.extend(srv.step().values())
-        want = moe.generate(params, p2[None, :], CFG,
-                            max_new_tokens=5)[0, 4:]
-        assert got == [int(t) for t in want]
-
-    def test_capacity_retires_cleanly(self):
-        params = _params()
-        srv = moe.MoESlotServer(params, CFG, n_slots=1, max_len=18)
-        s = srv.admit(jnp.asarray([3, 1, 4, 1, 5]))
-        steps = 0
-        while srv.active[s] and steps < 40:
-            srv.step()
-            steps += 1
-        assert not srv.active[s]
-        assert int(srv.lengths[s]) <= srv.max_len
-
-    def test_admit_guards(self):
-        params = _params()
-        srv = moe.MoESlotServer(params, CFG, n_slots=1, max_len=16)
-        with pytest.raises(ValueError, match="max_len"):
-            srv.admit(jnp.asarray(list(range(16))))
-        srv.admit(jnp.asarray([1, 2, 3]))
-        with pytest.raises(RuntimeError, match="free"):
-            srv.admit(jnp.asarray([4, 5]))
-
-
 class TestMoEInt8:
     """Int8 expert weights through forward's layers_hook seam:
     quant._QUANT_KEYS already names w_gate/w_up/w_down and its
@@ -619,30 +559,6 @@ class TestMoEInt8:
         agree = float(jnp.mean((got[:, 16:] == want[:, 16:]).astype(
             jnp.float32)))
         assert agree >= 0.75, f"int8 MoE greedy agreement {agree}"
-
-    def test_quantized_slot_server_matches_quantized_generate(self):
-        # The server must be bit-exact vs generate ON THE SAME int8
-        # params (int8 vs fp drift is bounded by the TV test; the
-        # serving engine itself must add zero error).
-        from tpushare.models import quant
-        params = _params()
-        qp = quant.quantize_params(params, CFG)
-        hook = quant.dequant_hook(CFG)
-        rng = np.random.default_rng(13)
-        p0 = jnp.asarray(rng.integers(0, CFG.vocab_size, 9))
-        p1 = jnp.asarray(rng.integers(0, CFG.vocab_size, 5))
-        srv = moe.MoESlotServer(qp, CFG, n_slots=3, max_len=32,
-                                layers_hook=hook)
-        s0, s1 = srv.admit(p0), srv.admit(p1)
-        got = {s0: [int(srv.last_token[s0, 0])],
-               s1: [int(srv.last_token[s1, 0])]}
-        for _ in range(6):
-            for s, t in srv.step().items():
-                got[s].append(t)
-        for s, p in ((s0, p0), (s1, p1)):
-            want = moe.generate(qp, p[None, :], CFG, max_new_tokens=7,
-                                layers_hook=hook)[0, p.shape[0]:]
-            assert got[s] == [int(t) for t in want], s
 
 
 class TestMoESpeculative:
@@ -792,220 +708,6 @@ class TestMoEShardedDecode:
                                    rtol=2e-4, atol=2e-4)
 
 
-class TestMoEChunkedAdmit:
-    """Chunked admission on the MoE server: prefill-continuation
-    chunks into the slot's own dense row, so chunked == whole
-    admission bit-exactly; cancel frees the slot; the bucket-padded
-    final chunk falls back near max_len instead of letting a clamped
-    dynamic_update_slice corrupt earlier rows."""
-
-    def _streams(self, srv, slots, n):
-        got = {s: [int(srv.last_token[s, 0])] for s in slots}
-        for _ in range(n):
-            for s, t in srv.step().items():
-                if s in got:
-                    got[s].append(t)
-        return got
-
-    def test_chunked_matches_whole_admit(self):
-        params = _params()
-        rng = np.random.default_rng(21)
-        prompt = jnp.asarray(rng.integers(0, CFG.vocab_size, 13))
-        whole = moe.MoESlotServer(params, CFG, n_slots=2, max_len=32)
-        sw = whole.admit(prompt)
-        chunked = moe.MoESlotServer(params, CFG, n_slots=2, max_len=32)
-        sc = chunked.admit_start(prompt, chunk_tokens=4)
-        assert chunked.admitting_count == 1
-        steps = 0
-        while chunked.admit_step(sc) is None:
-            steps += 1
-        assert steps == 3                    # 13 tokens / 4-chunks
-        assert chunked.admitting_count == 0
-        a = self._streams(whole, [sw], 6)[sw]
-        b = self._streams(chunked, [sc], 6)[sc]
-        assert a == b
-
-    def test_decode_interleaves_with_admission(self):
-        # An active stream keeps decoding between another slot's
-        # chunks, and both final streams match whole-admit servers.
-        params = _params()
-        rng = np.random.default_rng(22)
-        p0 = jnp.asarray(rng.integers(0, CFG.vocab_size, 5))
-        p1 = jnp.asarray(rng.integers(0, CFG.vocab_size, 11))
-        srv = moe.MoESlotServer(params, CFG, n_slots=2, max_len=32)
-        s0 = srv.admit(p0)
-        s1 = srv.admit_start(p1, chunk_tokens=4)
-        got0 = [int(srv.last_token[s0, 0])]
-        first1 = None
-        while first1 is None:
-            got0.append(srv.step()[s0])      # decode between chunks
-            first1 = srv.admit_step(s1)
-        got1 = [first1]
-        for _ in range(4):
-            out = srv.step()
-            got0.append(out[s0])
-            got1.append(out[s1])
-        ref = moe.MoESlotServer(params, CFG, n_slots=2, max_len=32)
-        r0, r1 = ref.admit(p0), ref.admit(p1)
-        want = self._streams(ref, [r0, r1], len(got0) - 1)
-        assert got0 == want[r0][:len(got0)]
-        assert got1 == want[r1][:len(got1)]
-
-    def test_admitting_slot_is_not_free_and_evict_cancels(self):
-        params = _params()
-        srv = moe.MoESlotServer(params, CFG, n_slots=1, max_len=32)
-        s = srv.admit_start(jnp.asarray([1, 2, 3, 4, 5]),
-                            chunk_tokens=2)
-        with pytest.raises(RuntimeError, match="free"):
-            srv.admit(jnp.asarray([7, 8]))
-        srv.evict(s)                        # cancel mid-admission
-        assert srv.admitting_count == 0
-        s2 = srv.admit(jnp.asarray([7, 8]))  # slot is reusable
-        assert s2 == s
-
-    def test_final_chunk_near_max_len_is_exact(self):
-        # S chosen so the bucket-padded final chunk would spill past
-        # max_len: the fallback must keep parity with whole admit.
-        params = _params()
-        rng = np.random.default_rng(23)
-        # chunk=16, max_len=24, S=19: final chunk done=16, residual 3
-        # buckets to 16, done+16=32 > 24 -> the fallback MUST fire
-        # (with chunk below the bucket floor it never can).
-        S, max_len = 19, 24
-        prompt = jnp.asarray(rng.integers(0, CFG.vocab_size, S))
-        whole = moe.MoESlotServer(params, CFG, n_slots=1,
-                                  max_len=max_len)
-        sw = whole.admit(prompt)
-        chunked = moe.MoESlotServer(params, CFG, n_slots=1,
-                                    max_len=max_len)
-        sc = chunked.admit_start(prompt, chunk_tokens=16)
-        while chunked.admit_step(sc) is None:
-            pass
-        assert int(whole.last_token[sw, 0]) == int(
-            chunked.last_token[sc, 0])
-
-
-class TestMoEPrefixCache:
-    """Row-level prefix cache: a new admit reuses the longest common
-    prefix of the retained row (KV is causal, so prefix rows are
-    continuation-independent) and must be bit-identical to a cold
-    admit."""
-
-    def _stream(self, srv, slot, n):
-        got = [int(srv.last_token[slot, 0])]
-        for _ in range(n):
-            got.append(srv.step()[slot])
-        return got
-
-    def test_shared_prefix_reused_and_bit_exact(self):
-        params = _params()
-        rng = np.random.default_rng(31)
-        system = rng.integers(0, CFG.vocab_size, 10)
-        p1 = jnp.asarray(np.concatenate([system,
-                                         rng.integers(0, 256, 3)]))
-        p2 = jnp.asarray(np.concatenate([system,
-                                         rng.integers(0, 256, 4)]))
-        warm = moe.MoESlotServer(params, CFG, n_slots=2, max_len=32,
-                                 prefix_cache=True)
-        s1 = warm.admit(p1)
-        assert warm.last_cached_len == 0           # cold registry
-        s2 = warm.admit(p2)
-        assert warm.last_cached_len == 10          # the system prompt
-        assert warm.prefix_hit_tokens == 10
-        cold = moe.MoESlotServer(params, CFG, n_slots=2, max_len=32)
-        c2 = cold.admit(p2)
-        a = self._stream(warm, s2, 6)
-        b = self._stream(cold, c2, 6)
-        assert a == b
-
-    def test_prefix_capped_below_full_prompt(self):
-        # Re-admitting the SAME prompt must still forward its last
-        # token (the admit samples from those logits): cap at S-1.
-        params = _params()
-        prompt = jnp.asarray([5, 4, 3, 2, 1, 0, 9])
-        srv = moe.MoESlotServer(params, CFG, n_slots=2, max_len=32,
-                                prefix_cache=True)
-        s1 = srv.admit(prompt)
-        s2 = srv.admit(prompt)
-        assert srv.last_cached_len == 6            # S-1, not S
-        cold = moe.MoESlotServer(params, CFG, n_slots=2, max_len=32)
-        assert (self._stream(srv, s2, 5)
-                == self._stream(cold, cold.admit(prompt), 5))
-        assert int(srv.last_token[s1, 0]) == int(srv.last_token[s2, 0])
-
-    def test_divergent_prompt_partial_hit(self):
-        params = _params()
-        rng = np.random.default_rng(33)
-        base = rng.integers(0, CFG.vocab_size, 8)
-        p1 = jnp.asarray(base)
-        p2_np = base.copy(); p2_np[5] = (p2_np[5] + 1) % CFG.vocab_size
-        p2 = jnp.asarray(np.concatenate([p2_np,
-                                         rng.integers(0, 256, 2)]))
-        srv = moe.MoESlotServer(params, CFG, n_slots=2, max_len=32,
-                                prefix_cache=True)
-        srv.admit(p1)
-        s2 = srv.admit(p2)
-        assert srv.last_cached_len == 5            # up to the edit
-        cold = moe.MoESlotServer(params, CFG, n_slots=2, max_len=32)
-        assert (self._stream(srv, s2, 5)
-                == self._stream(cold, cold.admit(p2), 5))
-
-    def test_chunked_admit_composes_with_prefix_cache(self):
-        # A warm chunked admit starts at the cached prefix (fewer
-        # chunks) and reports the reuse; the stream is bit-exact vs a
-        # cold server.
-        params = _params()
-        rng = np.random.default_rng(34)
-        system = rng.integers(0, CFG.vocab_size, 9)
-        p1 = jnp.asarray(system)
-        p2 = jnp.asarray(np.concatenate([system,
-                                         rng.integers(0, 256, 4)]))
-        srv = moe.MoESlotServer(params, CFG, n_slots=2, max_len=32,
-                                prefix_cache=True)
-        srv.admit(p1)
-        s2 = srv.admit_start(p2, chunk_tokens=4)
-        assert srv.last_cached_len == 9
-        steps = 1
-        while srv.admit_step(s2) is None:
-            steps += 1
-        assert steps == 1                  # 4 remaining tokens: 1 chunk
-        cold = moe.MoESlotServer(params, CFG, n_slots=2, max_len=32)
-        c2 = cold.admit(p2)
-        assert (self._stream(srv, s2, 6)
-                == self._stream(cold, c2, 6))
-        # Completed chunked admits feed the registry too.
-        p3 = jnp.asarray(np.concatenate([np.asarray(p2),
-                                         rng.integers(0, 256, 2)]))
-        srv.evict(s2)
-        srv.admit(p3)
-        assert srv.last_cached_len == 13   # p2's full length
-
-    def test_warm_widths_stay_bucketed_near_max_len(self):
-        # The warm suffix keeps its power-of-two width by reusing
-        # LESS prefix when the padded end would spill past max_len —
-        # compile variants must not scale with distinct prefix
-        # lengths (review catch). S=23, p=20, max_len=24: bucket(3)=4
-        # fits (20+4=24); S=23, p=21: bucket(2)=2 fits; S=23 with a
-        # 16-bucket residual shrinks p instead of compiling width 3.
-        params = _params()
-        rng = np.random.default_rng(35)
-        base = rng.integers(0, CFG.vocab_size, 13)
-        p1 = jnp.asarray(base)
-        p2 = jnp.asarray(np.concatenate([base,
-                                         rng.integers(0, 256, 10)]))
-        # S=23, cached p=13 -> bucket_len(10)=16, 13+16=29 > 24 ->
-        # p shrinks to 24-16=8; parity must hold with partial reuse.
-        srv = moe.MoESlotServer(params, CFG, n_slots=2, max_len=24,
-                                prefix_cache=True)
-        srv.admit(p1)
-        s2 = srv.admit(p2)
-        assert srv.last_cached_len == 8      # shrunk, still bucketed
-        # S=23 at max_len=24: room for exactly one decode step.
-        cold = moe.MoESlotServer(params, CFG, n_slots=2, max_len=24)
-        assert (self._stream(srv, s2, 1)
-                == self._stream(cold, cold.admit(p2), 1))
-
-
 class TestMoERaggedMultiToken:
     """forward's ragged mode with S > 1 (speculative verify): scoring
     a candidate block at per-row offsets must equal teacher-forced
@@ -1034,132 +736,3 @@ class TestMoERaggedMultiToken:
                                        np.asarray(lg[:, 0]),
                                        rtol=2e-5, atol=2e-5)
             lens = lens + 1
-
-
-class TestMoESpecServer:
-    """Per-slot speculative decoding in MoESlotServer: streams are
-    bit-exact vs the plain server for ANY draft (the draft only buys
-    speed), slots accept independently (no lockstep), and the server
-    falls back to plain ticks near max_len."""
-
-    def _drain(self, srv, slots, want_n):
-        got = {s: [int(srv.last_token[s, 0])] for s in slots}
-        while any(len(got[s]) < want_n for s in slots):
-            out = srv.step()
-            if not out:
-                break
-            for s, toks in out.items():
-                if s in got:
-                    got[s].extend(toks if isinstance(toks, list)
-                                  else [toks])
-        return {s: v[:want_n] for s, v in got.items()}
-
-    def _plain_ref(self, params, prompts, n):
-        srv = moe.MoESlotServer(params, CFG, n_slots=len(prompts),
-                                max_len=64)
-        slots = [srv.admit(p) for p in prompts]
-        got = {s: [int(srv.last_token[s, 0])] for s in slots}
-        for _ in range(n - 1):
-            for s, t in srv.step().items():
-                got[s].append(t)
-        return [got[s] for s in slots]
-
-    @pytest.mark.parametrize("draft_seed,label", [
-        (0, "int8-self"), (7, "mismatched")])
-    def test_streams_exact_vs_plain(self, draft_seed, label):
-        from tpushare.models import quant
-        params = _params()
-        if label == "int8-self":
-            draft = (quant.quantize_params(params, CFG), CFG)
-            hook = quant.dequant_hook(CFG)
-        else:
-            draft = (moe.init_params(jax.random.PRNGKey(7), CFG), CFG)
-            hook = None
-        rng = np.random.default_rng(51)
-        prompts = [jnp.asarray(rng.integers(0, CFG.vocab_size, n))
-                   for n in (6, 9)]
-        srv = moe.MoESlotServer(params, CFG, n_slots=2, max_len=64,
-                                speculative_draft=draft, gamma=3,
-                                draft_layers_hook=hook)
-        slots = [srv.admit(p) for p in prompts]
-        got = self._drain(srv, slots, 10)
-        want = self._plain_ref(params, prompts, 10)
-        for s, w in zip(slots, want):
-            assert got[s] == w, s
-
-    def test_int8_self_accepts_more_than_one_per_round(self):
-        from tpushare.models import quant
-        params = _params()
-        srv = moe.MoESlotServer(
-            params, CFG, n_slots=1, max_len=64,
-            speculative_draft=(quant.quantize_params(params, CFG), CFG),
-            gamma=3, draft_layers_hook=quant.dequant_hook(CFG))
-        s = srv.admit(jnp.asarray([3, 1, 4, 1, 5, 9, 2, 6]))
-        out = srv.step()
-        assert isinstance(out[s], list)
-        # int8-self = the target's own rounding: acceptance is high.
-        assert len(out[s]) >= 2
-
-    def test_spec_rounds_then_plain_fallback_at_capacity(self):
-        # len 8, max_len 13, gamma 3: spec rounds run while
-        # lengths <= 9, then the server crosses into plain ticks on
-        # the SAME slot — the transition (and retirement landing at
-        # max_len) is the boundary a guard regression would break.
-        # A MISMATCHED draft keeps acceptance near zero, so rounds
-        # advance ~1 token and cannot jump straight to max_len the
-        # way a full-acceptance int8-self draft can.
-        params = _params()
-        prompt = jnp.asarray([5, 4, 3, 2, 1, 0, 9, 8])
-        srv = moe.MoESlotServer(
-            params, CFG, n_slots=1, max_len=13,
-            speculative_draft=(moe.init_params(jax.random.PRNGKey(7),
-                                               CFG), CFG),
-            gamma=3)
-        s = srv.admit(prompt)
-        got = [int(srv.last_token[s, 0])]
-        saw_spec = saw_plain = False
-        while srv.active[s]:
-            out = srv.step()
-            t = out.get(s)
-            if t is None:
-                break
-            if isinstance(t, list):
-                saw_spec = True
-                got.extend(t)
-            else:
-                saw_plain = True
-                got.append(t)
-        assert saw_spec and saw_plain      # both regimes exercised
-        assert int(jax.device_get(srv.lengths)[s]) == 13
-        plain = self._plain_ref(params, [prompt], len(got))[0]
-        assert got == plain[:len(got)]
-
-    def test_composes_with_prefix_cache_and_chunked(self):
-        from tpushare.models import quant
-        params = _params()
-        rng = np.random.default_rng(53)
-        system = rng.integers(0, CFG.vocab_size, 8)
-        p1 = jnp.asarray(system)
-        p2 = jnp.asarray(np.concatenate([system,
-                                         rng.integers(0, 256, 5)]))
-        srv = moe.MoESlotServer(
-            params, CFG, n_slots=2, max_len=64, prefix_cache=True,
-            speculative_draft=(quant.quantize_params(params, CFG), CFG),
-            gamma=3, draft_layers_hook=quant.dequant_hook(CFG))
-        srv.admit(p1)
-        s2 = srv.admit_start(p2, chunk_tokens=4)
-        assert srv.last_cached_len == 8
-        while srv.admit_step(s2) is None:
-            pass
-        got = self._drain(srv, [s2], 8)[s2]
-        want = self._plain_ref(params, [p2], 8)[0]
-        assert got == want
-
-    def test_temperature_rejected(self):
-        from tpushare.models import quant
-        params = _params()
-        with pytest.raises(ValueError, match="greedy"):
-            moe.MoESlotServer(
-                params, CFG, n_slots=1, max_len=16, temperature=0.7,
-                speculative_draft=(quant.quantize_params(params, CFG),
-                                   CFG))

@@ -1,5 +1,5 @@
 """Multi-LoRA serving: per-slot adapters in one batched decode
-(forward's _mlora activation-path delta + SlotServer integration)."""
+(forward's _mlora activation-path delta + PagedSlotServer integration)."""
 
 import jax
 import jax.numpy as jnp
@@ -7,7 +7,8 @@ import numpy as np
 
 from tpushare.models import lora
 from tpushare.models import transformer as tf
-from tpushare.models.serving import SlotServer
+from tpushare.models.generate import generate
+from tpushare.models.paged import PagedSlotServer
 
 CFG = tf.tiny(remat=False)
 
@@ -46,58 +47,33 @@ def test_activation_delta_matches_weight_merge():
     np.testing.assert_array_equal(np.asarray(base), np.asarray(off))
 
 
-def test_slot_server_serves_three_tenants_one_batch():
+def test_server_serves_three_tenants_one_batch():
+    """PagedSlotServer(multi_lora=...): two adapters and the base
+    model in ONE batched decode; the base slot's stream is
+    generate()'s."""
     params = tf.init_params(jax.random.PRNGKey(3), CFG)
     ad7, l7, p7 = _teach(params, 7, seed=11)
     ad42, l42, p42 = _teach(params, 42, seed=13)
     assert l7 < 0.5 and l42 < 0.5
     bank = lora.stack_adapters([ad7, ad42])
-
     rng = np.random.default_rng(5)
-    prompts = [p7, p42,
-               jnp.asarray(rng.integers(0, CFG.vocab_size, 8))]
-    srv = SlotServer(params, CFG, n_slots=3, max_len=32,
-                     multi_lora=bank)
-    s0 = srv.admit(prompts[0], adapter=0)
-    s1 = srv.admit(prompts[1], adapter=1)
-    s2 = srv.admit(prompts[2])                 # base model
-    streams = {s0: [], s1: [], s2: []}
+    base_prompt = jnp.asarray(rng.integers(0, CFG.vocab_size, 8))
+    srv = PagedSlotServer(params, CFG, n_slots=3, n_blocks=32,
+                          block_size=8, max_blocks_per_slot=4,
+                          multi_lora=bank)
+    s0 = srv.admit(p7, adapter=0)
+    s1 = srv.admit(p42, adapter=1)
+    s2 = srv.admit(base_prompt)                # base model
+    streams = {s0: [], s1: [], s2: [int(srv.last_token[s2, 0])]}
     for _ in range(4):
         for s, t in srv.step().items():
             streams[s].append(t)
     # Each tenant follows ITS adapter inside one batched decode.
     assert streams[s0].count(7) >= 3, streams[s0]
     assert streams[s1].count(42) >= 3, streams[s1]
-    # The base slot matches a plain server exactly.
-    ref = SlotServer(params, CFG, n_slots=1, max_len=32)
-    r = ref.admit(prompts[2])
-    ref_stream = [ref.step()[r] for _ in range(4)]
-    assert streams[s2] == ref_stream
-
-
-def test_paged_server_multi_lora_matches_slot_server():
-    """PagedSlotServer(multi_lora=...) serves the same per-slot
-    adapters as SlotServer — one batched decode, paged storage."""
-    from tpushare.models.paged import PagedSlotServer
-    params = tf.init_params(jax.random.PRNGKey(3), CFG)
-    ad7, _, p7 = _teach(params, 7, seed=11)
-    ad42, _, p42 = _teach(params, 42, seed=13)
-    bank = lora.stack_adapters([ad7, ad42])
-    srv = PagedSlotServer(params, CFG, n_slots=3, n_blocks=32,
-                          block_size=8, max_blocks_per_slot=4,
-                          multi_lora=bank)
-    s0 = srv.admit(p7, adapter=0)
-    s1 = srv.admit(p42, adapter=1)
-    s2 = srv.admit(p7)                     # base model
-    streams = {s0: [], s1: [], s2: []}
-    for _ in range(4):
-        for s, t in srv.step().items():
-            streams[s].append(t)
-    assert streams[s0].count(7) >= 3, streams[s0]
-    assert streams[s1].count(42) >= 3, streams[s1]
-    ref = SlotServer(params, CFG, n_slots=1, max_len=32)
-    r = ref.admit(p7)
-    assert streams[s2] == [ref.step()[r] for _ in range(4)]
+    # The base slot matches the plain model exactly.
+    ref = generate(params, base_prompt[None, :], CFG, max_new_tokens=5)
+    assert streams[s2] == [int(t) for t in ref[0, 8:]]
     import pytest
     with pytest.raises(ValueError, match="out of range"):
         srv.admit(p7, adapter=5)
@@ -107,7 +83,6 @@ def test_prefix_cache_isolated_per_adapter():
     """Adapters change the KV a prompt produces (wv targets) — the
     SAME tokens under DIFFERENT adapters must never share blocks,
     while the same adapter still hits."""
-    from tpushare.models.paged import PagedSlotServer
     params = tf.init_params(jax.random.PRNGKey(5), CFG)
     ad, _, _ = _teach(params, 9, seed=19, steps=10)
     bank = lora.stack_adapters([ad, ad])
@@ -130,7 +105,6 @@ def test_triple_composition_prefix_kvq_multilora():
     prefix caching + per-slot adapters. Hits stay adapter-isolated,
     storage stays int8, and a taught adapter still emits its task
     token through the composed pipeline."""
-    from tpushare.models.paged import PagedSlotServer
     params = tf.init_params(jax.random.PRNGKey(3), CFG)
     ad7, _, p7 = _teach(params, 7, seed=11)
     bank = lora.stack_adapters([ad7, ad7])
@@ -168,8 +142,8 @@ def test_adapter_slot_resets_on_evict():
     params = tf.init_params(jax.random.PRNGKey(4), CFG)
     ad, _, _ = _teach(params, 9, seed=17, steps=10)
     bank = lora.stack_adapters([ad])
-    srv = SlotServer(params, CFG, n_slots=2, max_len=32,
-                     multi_lora=bank)
+    srv = PagedSlotServer(params, CFG, n_slots=2, n_blocks=16,
+                          block_size=8, multi_lora=bank)
     p = jnp.asarray(np.random.default_rng(7).integers(
         0, CFG.vocab_size, 6))
     s = srv.admit(p, adapter=0)
@@ -185,15 +159,16 @@ def test_admit_rejects_out_of_range_adapter():
     params = tf.init_params(jax.random.PRNGKey(6), CFG)
     bank = lora.stack_adapters(
         [lora.init_lora(jax.random.PRNGKey(8), CFG, 2)] * 2)
-    srv = SlotServer(params, CFG, n_slots=2, max_len=32,
-                     multi_lora=bank)
+    srv = PagedSlotServer(params, CFG, n_slots=2, n_blocks=16,
+                          block_size=8, multi_lora=bank)
     p = jnp.asarray(np.random.default_rng(9).integers(
         0, CFG.vocab_size, 5))
     with pytest.raises(ValueError, match="out of range"):
         srv.admit(p, adapter=2)
     with pytest.raises(ValueError, match="out of range"):
         srv.admit(p, adapter=-2)
-    plain = SlotServer(params, CFG, n_slots=2, max_len=32)
+    plain = PagedSlotServer(params, CFG, n_slots=2, n_blocks=16,
+                            block_size=8)
     with pytest.raises(ValueError, match="not set"):
         plain.admit(p, adapter=0)
 

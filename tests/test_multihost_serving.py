@@ -66,7 +66,7 @@ def _mk_dense(mesh, n_proc=1, **kw):
 def _mk_moe(mesh, n_proc=1, **kw):
     kw.setdefault("chaos_spec", "")
     return serve_mod.ServeEngine(
-        MOE_PARAMS, MOE_CFG, model_family="moe", kv="paged",
+        MOE_PARAMS, MOE_CFG, model_family="moe",
         n_slots=4, n_blocks=128, block_size=4, idle_sleep_s=0.0,
         prefill_chunk=8, mesh=mesh, num_processes=n_proc, **kw)
 

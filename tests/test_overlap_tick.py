@@ -28,7 +28,7 @@ import pytest
 from tpushare.chaos import InjectedXlaRuntimeError
 from tpushare.cli import serve as serve_mod
 from tpushare.cli.serve import ServeEngine, _Request
-from tpushare.models import moe
+from tpushare.models import moe, quant
 from tpushare.models import transformer as tf
 from tpushare.slo import TenantQuotaSpec
 from test_sync_free import count_transfers
@@ -39,7 +39,7 @@ MOE_CFG = moe.tiny(remat=False)
 MOE_PARAMS = moe.init_params(jax.random.PRNGKey(0), MOE_CFG)
 
 FAMILIES = ("dense", "dense-kvq", "paged", "paged-spec", "paged-moe",
-            "moe-rows")
+            "paged-moe-spec")
 
 
 def make_engine(family, *, overlap, **kw):
@@ -65,11 +65,16 @@ def make_engine(family, *, overlap, **kw):
                            gamma=2, spec_horizon=2, **kw)
     if family == "paged-moe":
         return ServeEngine(MOE_PARAMS, MOE_CFG, model_family="moe",
-                           kv="paged", n_slots=2, n_blocks=48,
+                           n_slots=2, n_blocks=48,
                            block_size=8, prefill_chunk=8, **kw)
-    if family == "moe-rows":
-        return ServeEngine(MOE_PARAMS, MOE_CFG, model_family="moe",
-                           n_slots=2, max_len=128, **kw)
+    if family == "paged-moe-spec":
+        return ServeEngine(
+            MOE_PARAMS, MOE_CFG, model_family="moe", n_slots=2,
+            n_blocks=48, block_size=8,
+            speculative_draft=(quant.quantize_params(MOE_PARAMS,
+                                                     MOE_CFG), MOE_CFG),
+            draft_layers_hook=quant.dequant_hook(MOE_CFG),
+            gamma=2, **kw)
     raise AssertionError(family)
 
 

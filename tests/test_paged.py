@@ -1,5 +1,7 @@
 """Paged KV cache: block-table decode must match the dense-cache
-ragged decode; pool accounting reclaims blocks on evict."""
+ragged decode; pool accounting reclaims blocks on evict. The slot
+server over it is held in tests/test_slot_server.py, under both
+families' forward functions."""
 
 import jax
 import jax.numpy as jnp
@@ -178,162 +180,3 @@ def test_inactive_slots_keep_length_and_blocks():
     after = np.asarray(cache.pool_k)
     for b in slot1_blocks:
         np.testing.assert_array_equal(after[:, b], pool_before[:, b])
-
-
-class TestPagedSlotServer:
-    def _prompts(self):
-        params = tf.init_params(jax.random.PRNGKey(0), CFG)
-        rng = np.random.default_rng(11)
-        p1 = jnp.asarray(rng.integers(0, CFG.vocab_size, (6,)))
-        p2 = jnp.asarray(rng.integers(0, CFG.vocab_size, (9,)))
-        return params, p1, p2
-
-    def test_matches_independent_generation(self):
-        from tpushare.models.generate import generate
-        params, p1, p2 = self._prompts()
-        server = paged.PagedSlotServer(params, CFG, n_slots=4, n_blocks=24,
-                                       block_size=4, max_blocks_per_slot=6)
-        s1, s2 = server.admit(p1), server.admit(p2)
-        new_tokens = {s1: [], s2: []}
-        first = {s1: int(server.last_token[s1, 0]),
-                 s2: int(server.last_token[s2, 0])}
-        for _ in range(4):
-            for slot, tok in server.step().items():
-                new_tokens[slot].append(tok)
-        for prompt, slot in ((p1, s1), (p2, s2)):
-            ref = generate(params, prompt[None, :], CFG, max_new_tokens=5)
-            ref_new = [int(t) for t in np.asarray(ref[0, prompt.shape[0]:])]
-            assert [first[slot]] + new_tokens[slot] == ref_new
-
-    def test_evict_reclaims_pool_blocks(self):
-        params, p1, p2 = self._prompts()
-        server = paged.PagedSlotServer(params, CFG, n_slots=2, n_blocks=5,
-                                       block_size=4, max_blocks_per_slot=4)
-        s1 = server.admit(p1)                 # 6+1 tokens -> 2 of 4 usable
-        used = server.cache.live_blocks()
-        with pytest.raises(RuntimeError, match="exhausted"):
-            server.admit(p2)                  # 9+1 -> 3 blocks, only 2 free
-        server.evict(s1)
-        assert server.cache.live_blocks() == 0
-        s2 = server.admit(p2)
-        assert s2 in (0, 1) and server.cache.live_blocks() >= used
-
-    def test_retires_at_capacity(self):
-        params, p1, _ = self._prompts()
-        server = paged.PagedSlotServer(params, CFG, n_slots=1, n_blocks=8,
-                                       block_size=4, max_blocks_per_slot=2)
-        s = server.admit(p1)                  # length 6, capacity 8
-        server.step()                         # 7
-        out = server.step()                   # 8 == capacity -> retired
-        assert s in out
-        assert not server.active[s]
-        assert server.step() == {}
-
-    def test_reuse_of_retired_slot_reclaims_blocks(self):
-        # A slot that retired at capacity keeps its blocks (readable
-        # until evict); admitting into it must return them to the pool,
-        # not leak them (free + live == n_blocks - 1 trash block).
-        params, p1, _ = self._prompts()
-        server = paged.PagedSlotServer(params, CFG, n_slots=1, n_blocks=8,
-                                       block_size=4, max_blocks_per_slot=2)
-        total = 8 - 1
-        for _ in range(3):
-            server.admit(p1)                  # reuses the retired slot
-            while server.active[0]:
-                server.step()
-            assert len(server.cache.free) + server.cache.live_blocks() == total
-
-    def test_grow_exhaustion_keeps_free_list_intact(self):
-        # Two slots crossing a block boundary with one free block: the
-        # shortfall must raise without popping (no leaked blocks).
-        params, p1, _ = self._prompts()
-        # block_size 4: admit length 3 -> need 1 block; lengths hit 4
-        # after one step -> both slots need a second block same step.
-        pa = p1[:3]
-        server = paged.PagedSlotServer(params, CFG, n_slots=2, n_blocks=4,
-                                       block_size=4, max_blocks_per_slot=2)
-        server.admit(pa)
-        server.admit(pa)                      # 2 live, 1 free (1 trash)
-        assert len(server.cache.free) == 1
-        server.step()                         # lengths 3 -> 4 (block full)
-        with pytest.raises(RuntimeError, match="exhausted"):
-            server.step()                     # both need block 1, one free
-        assert len(server.cache.free) == 1    # nothing leaked
-
-
-class TestChunkedAdmission:
-    """vLLM-style chunked prefill: admit_start/admit_step must produce
-    bit-identical KV and tokens to a whole-prompt admit."""
-
-    def _mk(self, prefix_cache=False):
-        import jax
-        from tpushare.models import transformer as tf
-        from tpushare.models.paged import PagedSlotServer
-        cfg = tf.tiny(remat=False)
-        params = tf.init_params(jax.random.PRNGKey(0), cfg)
-        return cfg, params, lambda: PagedSlotServer(
-            params, cfg, n_slots=2, n_blocks=32, block_size=4,
-            prefix_cache=prefix_cache)
-
-    def test_chunked_matches_whole_admit(self):
-        import jax.numpy as jnp
-        import numpy as np
-        cfg, params, mk = self._mk()
-        rng = np.random.default_rng(5)
-        prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, 19), jnp.int32)
-
-        whole = mk()
-        s0 = whole.admit(prompt)
-        want = [int(whole.last_token[s0, 0])]
-        for _ in range(5):
-            want.append(whole.step()[s0])
-
-        chunked = mk()
-        slot = chunked.admit_start(prompt, chunk_tokens=8)
-        steps = 0
-        tok = None
-        while tok is None:
-            tok = chunked.admit_step(slot)
-            steps += 1
-        assert steps == 3                   # 19 tokens / 8-aligned chunks
-        got = [tok]
-        for _ in range(5):
-            got.append(chunked.step()[slot])
-        assert got == want
-
-    def test_chunked_with_prefix_cache_publishes(self):
-        import jax.numpy as jnp
-        import numpy as np
-        cfg, params, mk = self._mk(prefix_cache=True)
-        rng = np.random.default_rng(6)
-        shared = [int(t) for t in rng.integers(0, cfg.vocab_size, 12)]
-        p1 = jnp.asarray(shared + [1, 2, 3], jnp.int32)
-        p2 = jnp.asarray(shared + [4, 5, 6, 7], jnp.int32)
-        srv = mk()
-        slot = srv.admit_start(p1, chunk_tokens=4)
-        while srv.admit_step(slot) is None:
-            pass
-        assert srv.last_cached_len == 0
-        # the chunked admission PUBLISHED its full blocks:
-        s2 = srv.admit(p2)
-        assert srv.last_cached_len == 12
-        # and the sharing is correct: greedy continuations are finite
-        out = srv.step()
-        assert set(out) == {slot, s2}
-
-    def test_evict_mid_admission_reclaims_blocks(self):
-        import jax.numpy as jnp
-        import numpy as np
-        cfg, params, mk = self._mk()
-        rng = np.random.default_rng(7)
-        prompt = jnp.asarray(rng.integers(0, cfg.vocab_size, 16), jnp.int32)
-        srv = mk()
-        free0 = len(srv.cache.free)
-        slot = srv.admit_start(prompt, chunk_tokens=4)
-        assert srv.admitting_count == 1
-        assert len(srv.cache.free) < free0
-        srv.admit_step(slot)                # one chunk in
-        srv.evict(slot)
-        assert srv.admitting_count == 0
-        assert len(srv.cache.free) == free0
-        assert not srv.active[slot]

@@ -1,143 +1,19 @@
 """Automatic prefix caching over the paged pool (models/paged.py).
 
-First-principles checks: a prefix hit must be *bit-identical* KV reuse
-(same generated tokens as the uncached server), sharing must actually
-reduce unique pool blocks, retention must survive eviction, and pool
-pressure must reclaim only zero-ref published blocks — never a block a
-live slot still references.
+The cache-level checks: pool pressure eats a parked chain leaf first,
+and release keeps the reference counts. What a slot server shows of
+the prefix cache (bit-identical reuse, fewer unique blocks, retention
+across eviction, reclaim of zero-ref blocks only) is held in
+tests/test_slot_server.py::TestPrefixCache under both families.
 """
 
-import jax
-import jax.numpy as jnp
 import numpy as np
-import pytest
 
 from tpushare.models import paged
 from tpushare.models import transformer as tf
 
 CFG = tf.tiny(remat=False)
 BS = 4
-
-
-def _mk(params, prefix_cache, n_blocks=24):
-    return paged.PagedSlotServer(
-        params, CFG, n_slots=2, n_blocks=n_blocks, block_size=BS,
-        max_blocks_per_slot=8, prefix_cache=prefix_cache)
-
-
-def _prompts(rng):
-    prefix = rng.integers(0, CFG.vocab_size, 8)
-    a = np.concatenate([prefix, rng.integers(0, CFG.vocab_size, 5)])
-    b = np.concatenate([prefix, rng.integers(0, CFG.vocab_size, 3)])
-    return jnp.asarray(a), jnp.asarray(b)
-
-
-def _unique_live(cache):
-    ids = np.asarray(cache.block_table)
-    return len({int(x) for x in ids.ravel() if int(x) >= 0})
-
-
-def test_prefix_sharing_matches_plain_server():
-    params = tf.init_params(jax.random.PRNGKey(0), CFG)
-    a, b = _prompts(np.random.default_rng(7))
-    streams = {}
-    for pc in (False, True):
-        srv = _mk(params, pc)
-        sa, sb = srv.admit(a), srv.admit(b)
-        # Block accounting before decode growth kicks in:
-        if pc:
-            # b shares the two full 4-token prefix blocks of a.
-            assert srv.last_cached_len == 8
-            assert _unique_live(srv.cache) == 5   # 4 + (3 - 2 shared)
-        else:
-            assert _unique_live(srv.cache) == 7   # 4 + 3, no sharing
-        toks = {sa: [], sb: []}
-        for _ in range(4):
-            for slot, t in srv.step().items():
-                toks[slot].append(t)
-        streams[pc] = (toks[sa], toks[sb])
-    assert streams[False] == streams[True]
-
-
-def test_identical_prompt_caps_at_recomputing_tail():
-    params = tf.init_params(jax.random.PRNGKey(1), CFG)
-    prompt = jnp.asarray(np.random.default_rng(3).integers(
-        0, CFG.vocab_size, 12))
-    srv = _mk(params, True)
-    s0 = srv.admit(prompt)
-    first = [srv.step()[s0] for _ in range(3)]
-    s1 = srv.admit(prompt)
-    # S=12, bs=4: full blocks 0..2 published, but matching stops at
-    # (S-1)//bs = 2 blocks so the last token is always recomputed.
-    assert srv.last_cached_len == 8
-    # Same prompt, same params, greedy: identical continuation.
-    later = []
-    for _ in range(3):
-        later.append(srv.step()[s1])
-    assert later == first
-
-
-def test_retention_survives_eviction():
-    params = tf.init_params(jax.random.PRNGKey(2), CFG)
-    prompt = jnp.asarray(np.random.default_rng(5).integers(
-        0, CFG.vocab_size, 10))
-    srv = _mk(params, True)
-    s0 = srv.admit(prompt)
-    srv.step()
-    srv.evict(s0)
-    assert len(srv.cache.lru) > 0       # published blocks parked, not freed
-    s1 = srv.admit(prompt)
-    assert srv.last_cached_len == 8     # hit straight off the LRU
-    assert srv.step()[s1] >= 0
-
-
-def test_pool_pressure_reclaims_only_zero_ref():
-    params = tf.init_params(jax.random.PRNGKey(3), CFG)
-    rng = np.random.default_rng(11)
-    # Pool sized so the second distinct admit must reclaim the first
-    # prompt's parked blocks: 8 usable blocks (9 - trash), prompts of
-    # 13 tokens need 4 blocks each.
-    srv = _mk(params, True, n_blocks=9)
-    p1 = jnp.asarray(rng.integers(0, CFG.vocab_size, 13))
-    p2 = jnp.asarray(rng.integers(0, CFG.vocab_size, 13))
-    s0 = srv.admit(p1)
-    srv.evict(s0)
-    parked = set(srv.cache.lru)
-    assert parked
-    s1 = srv.admit(p2)                  # takes the 4 remaining free
-    s2 = srv.admit(p1)                  # hits p1's parked blocks
-    assert srv.last_cached_len == 12    # all 3 published blocks of p1
-    # Now every block is owned; a third distinct prompt cannot fit.
-    srv.evict(s1)
-    srv.evict(s2)
-    p3 = jnp.asarray(rng.integers(0, CFG.vocab_size, 13))
-    s3 = srv.admit(p3)                  # reclaims under pressure
-    # Reclaimed blocks were unpublished: their index entries are gone.
-    for blk in np.asarray(srv.cache.block_table[s3]):
-        assert int(blk) not in srv.cache.lru
-    live = {int(x) for x in np.asarray(srv.cache.block_table[s3])
-            if int(x) >= 0}
-    for b in live:
-        assert srv.cache.refs[b] >= 1
-
-
-def test_shared_blocks_never_written_by_decode():
-    params = tf.init_params(jax.random.PRNGKey(4), CFG)
-    rng = np.random.default_rng(13)
-    # S = 8, a multiple of bs: the shareable blocks end exactly at the
-    # slot's write frontier — the adversarial case for copy-on-write.
-    prompt = jnp.asarray(rng.integers(0, CFG.vocab_size, 8))
-    srv = _mk(params, True)
-    s0 = srv.admit(prompt)
-    s1 = srv.admit(prompt)
-    assert srv.last_cached_len == 4     # (S-1)//bs = 1 full block shared
-    shared = int(np.asarray(srv.cache.block_table[s1, 0]))
-    assert shared == int(np.asarray(srv.cache.block_table[s0, 0]))
-    before = np.asarray(srv.cache.pool_k[:, shared])
-    for _ in range(6):                  # decode across a block boundary
-        srv.step()
-    after = np.asarray(srv.cache.pool_k[:, shared])
-    np.testing.assert_array_equal(before, after)
 
 
 def test_reclaim_consumes_chains_leaf_first():

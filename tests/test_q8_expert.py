@@ -25,6 +25,7 @@ import numpy as np
 import pytest
 
 from tpushare.models import moe, quant
+from tpushare.models.paged import PagedSlotServer
 from tpushare.ops import q8_expert as qe
 from tpushare.utils import profiling
 
@@ -63,6 +64,13 @@ def _prompt(seed, n, vocab=None):
     rng = np.random.default_rng(seed)
     return jnp.asarray(rng.integers(0, vocab or CFG.vocab_size, n),
                        jnp.int32)
+
+
+def _server(params, cfg, **kw):
+    """The slot server over the sparse family's forward."""
+    return PagedSlotServer(params, cfg, n_slots=2, n_blocks=32,
+                           block_size=4, forward_fn=moe.paged_forward,
+                           **kw)
 
 
 class TestKernelInterpreterParity:
@@ -330,14 +338,13 @@ class TestFusedVsDequantHook:
                                    **LOGITS_TOL)
 
     def test_served_stream_bit_exact(self):
-        # The MoESlotServer path (admit + ragged decode ticks): the
+        # The slot server's path (admit + ragged decode ticks): the
         # engine-visible token stream must not change when the fused
         # hook replaces the dequant hook.
         streams = {}
         for name, hook in (("dequant", quant.dequant_hook(CFG)),
                            ("fused", quant.fused_expert_hook(CFG))):
-            srv = moe.MoESlotServer(QPARAMS, CFG, n_slots=2,
-                                    max_len=64, layers_hook=hook)
+            srv = _server(QPARAMS, CFG, layers_hook=hook)
             srv.admit(_prompt(11, 7))
             srv.admit(_prompt(12, 5))
             toks = []
@@ -393,8 +400,7 @@ class TestKernelThroughServingPath:
         streams = {}
         for name, hook in (("fused", quant.fused_expert_hook(CFG128)),
                            ("dequant", quant.dequant_hook(CFG128))):
-            srv = moe.MoESlotServer(QPARAMS128, CFG128, n_slots=2,
-                                    max_len=64, layers_hook=hook)
+            srv = _server(QPARAMS128, CFG128, layers_hook=hook)
             srv.admit(_prompt(52, 7, CFG128.vocab_size))
             streams[name] = [sorted(srv.step().items())
                              for _ in range(8)]
@@ -413,10 +419,9 @@ class TestShardedFusedServing:
     def _stream(self, mesh):
         from tpushare.parallel import make_mesh
         specs = quant.quant_moe_param_specs(CFG) if mesh else None
-        srv = moe.MoESlotServer(
-            QPARAMS, CFG, n_slots=2, max_len=64,
-            layers_hook=quant.fused_expert_hook(CFG),
-            mesh=mesh, param_specs=specs)
+        srv = _server(QPARAMS, CFG,
+                      layers_hook=quant.fused_expert_hook(CFG),
+                      mesh=mesh, param_specs=specs)
         srv.admit(_prompt(21, 6))
         srv.admit(_prompt(22, 9))
         out = []
@@ -444,10 +449,9 @@ class TestShardedFusedServing:
         def stream(mesh):
             specs = (quant.quant_moe_param_specs(CFG128) if mesh
                      else None)
-            srv = moe.MoESlotServer(
-                QPARAMS128, CFG128, n_slots=2, max_len=48,
-                layers_hook=quant.fused_expert_hook(CFG128),
-                mesh=mesh, param_specs=specs)
+            srv = _server(QPARAMS128, CFG128,
+                          layers_hook=quant.fused_expert_hook(CFG128),
+                          mesh=mesh, param_specs=specs)
             srv.admit(_prompt(23, 5, CFG128.vocab_size))
             return [sorted(srv.step().items()) for _ in range(6)]
 
@@ -547,20 +551,6 @@ class TestPhaseTimerSeam:
                                       generation="v5e", on_chip=True)
         assert on["attn"]["pct_of_roofline"] is not None
         assert on["dispatch"]["pct_of_roofline"] is None  # 0-byte
-
-    def test_server_phase_timer_stream_unchanged(self):
-        pt = profiling.PhaseTimer()
-        streams = {}
-        for name, timer in (("off", None), ("on", pt)):
-            srv = moe.MoESlotServer(
-                QPARAMS, CFG, n_slots=2, max_len=64,
-                layers_hook=quant.fused_expert_hook(CFG),
-                phase_timer=timer)
-            srv.admit(_prompt(41, 6))
-            streams[name] = [sorted(srv.step().items())
-                             for _ in range(6)]
-        assert streams["on"] == streams["off"]
-        assert pt.snapshot()                       # phases measured
 
 
 def test_analysis_q8_seam_clean():

@@ -962,8 +962,9 @@ def test_cli_sigterm_drains_and_exits_zero():
 
 class TestMoEServe:
     """model_family="moe": the HTTP daemon serves the MoE LM through
-    the same engine scaffolding (queue/drain/SSE), with paged-only
-    flags rejected loudly and streams matching moe.generate."""
+    the same engine and slot server as the dense one (the paged pool
+    under moe.paged_forward), with dense-only options rejected loudly
+    and streams matching moe.generate."""
 
     @pytest.fixture(scope="class")
     def moe_server(self):
@@ -972,8 +973,8 @@ class TestMoEServe:
         params = quant.quantize_params(
             moe.init_params(jax.random.PRNGKey(0), cfg), cfg)
         engine = serve_mod.ServeEngine(
-            params, cfg, model_family="moe", n_slots=2, max_len=48,
-            prefix_cache=False, idle_sleep_s=0.001,
+            params, cfg, model_family="moe", n_slots=2, n_blocks=32,
+            block_size=4, prefix_cache=False, idle_sleep_s=0.001,
             layers_hook=quant.dequant_hook(cfg))
         httpd = serve_mod.serve(engine, host="127.0.0.1", port=0,
                                 timeout_s=120.0)
@@ -1016,13 +1017,10 @@ class TestMoEServe:
         status, body = _get(port, "/stats")
         assert status == 200
         assert body["n_slots"] == 2
-        # Dense rows: no pool exists, so the counters are null (NOT 0 —
-        # an autoscaler keyed on pool exhaustion must not read an idle
-        # MoE server as permanently exhausted) and the family/layout
-        # tags say why.
-        assert body["free_blocks"] is None
-        assert body["live_blocks"] is None
-        assert body["model_family"] == "moe" and body["kv"] == "rows"
+        # The sparse family's default is the paged pool: real counters.
+        assert body["free_blocks"] + body["live_blocks"] \
+            + body["reclaimable_blocks"] == 31
+        assert body["model_family"] == "moe" and body["kv"] == "paged"
         assert "speculative" not in body
         status, _ = _get(port, "/healthz")
         assert status == 200
@@ -1034,20 +1032,17 @@ class TestMoEServe:
         from tpushare.models import moe
         cfg = moe.tiny(remat=False)
         params = moe.init_params(jax.random.PRNGKey(0), cfg)
-        eng = serve_mod.ServeEngine(params, cfg, model_family="moe",
-                                    n_slots=1, max_len=16)
-        assert eng.stats()["n_slots"] == 1
+        eng = serve_mod.ServeEngine(params, cfg, model_family="moe")
+        assert eng.stats()["n_slots"] == 8
+        assert eng.stats()["free_blocks"] == 255
 
-    def test_paged_only_options_rejected(self):
+    def test_dense_only_options_rejected(self):
         from tpushare.models import moe
         cfg = moe.tiny(remat=False)
         params = moe.init_params(jax.random.PRNGKey(0), cfg)
         with pytest.raises(ValueError, match="does not support"):
             serve_mod.ServeEngine(params, cfg, model_family="moe",
                                   kv_quant=True)
-        with pytest.raises(ValueError, match="does not support"):
-            serve_mod.ServeEngine(params, cfg, model_family="moe",
-                                  max_blocks_per_slot=4)
         with pytest.raises(ValueError, match="model_family"):
             serve_mod.ServeEngine(params, cfg, model_family="nope")
 
@@ -1065,8 +1060,8 @@ class TestMoEServe:
         out = {}
         for chunk in (None, 4):
             engine = serve_mod.ServeEngine(
-                params, cfg, model_family="moe", n_slots=2, max_len=32,
-                prefill_chunk=chunk, idle_sleep_s=0.001)
+                params, cfg, model_family="moe", n_slots=2, n_blocks=32,
+                block_size=4, prefill_chunk=chunk, idle_sleep_s=0.001)
             httpd = serve_mod.serve(engine, host="127.0.0.1", port=0,
                                     timeout_s=120.0)
             try:
@@ -1101,8 +1096,8 @@ class TestMoEServe:
                     gamma=3,
                     draft_layers_hook=quant.dequant_hook(cfg))
             engine = serve_mod.ServeEngine(
-                params, cfg, model_family="moe", n_slots=2, max_len=48,
-                idle_sleep_s=0.001, **kw)
+                params, cfg, model_family="moe", n_slots=2, n_blocks=64,
+                block_size=4, idle_sleep_s=0.001, **kw)
             httpd = serve_mod.serve(engine, host="127.0.0.1", port=0,
                                     timeout_s=120.0)
             try:

@@ -1,6 +1,6 @@
 """Sharded multi-chip ServeEngine (ISSUE 7): the slot servers span a
 NamedSharding mesh — tensor-parallel dense, expert x tensor-parallel
-MoE, KV pools/rows split on the kv-head axis — and every decode
+MoE, KV pools split on the kv-head axis — and every decode
 stream, chunked admission, fused tick, and greedy speculation round is
 BIT-EXACT vs the single-chip engine (the correctness oracle: placement
 alone makes the same jitted code compile SPMD, so tokens must not
@@ -17,7 +17,6 @@ import pytest
 from tpushare.models import moe, quant
 from tpushare.models import transformer as tf
 from tpushare.models.paged import PagedSlotServer
-from tpushare.models.serving import SlotServer
 from tpushare.parallel import make_mesh, parse_mesh_spec, serving_mesh
 
 pytestmark = pytest.mark.skipif(
@@ -46,10 +45,6 @@ def _prompt(seed, n, vocab):
 
 # mesh=None is the single-chip oracle; mesh=mk_mesh() the sharded run.
 FAMILIES = {
-    "dense_tp": (
-        lambda mesh: SlotServer(TF_PARAMS, TF_CFG, n_slots=3,
-                                max_len=96, mesh=mesh),
-        _mesh_tp, TF_CFG),
     "paged_tp": (
         lambda mesh: PagedSlotServer(TF_PARAMS, TF_CFG, n_slots=3,
                                      n_blocks=64, block_size=4,
@@ -85,10 +80,6 @@ FAMILIES = {
             draft_layers_hook=quant.dequant_hook(MOE_CFG), mesh=mesh,
             draft_param_specs=(quant.quant_moe_param_specs(MOE_CFG)
                                if mesh is not None else None)),
-        _mesh_eptp, MOE_CFG),
-    "moe_rows_eptp": (
-        lambda mesh: moe.MoESlotServer(MOE_PARAMS, MOE_CFG, n_slots=3,
-                                       max_len=96, mesh=mesh),
         _mesh_eptp, MOE_CFG),
 }
 
@@ -201,7 +192,7 @@ class TestShardedEngine:
     def _run(self, mesh, **kw):
         from tpushare.cli import serve as serve_mod
         eng = serve_mod.ServeEngine(
-            MOE_PARAMS, MOE_CFG, model_family="moe", kv="paged",
+            MOE_PARAMS, MOE_CFG, model_family="moe",
             n_slots=4, n_blocks=128, block_size=4, idle_sleep_s=0.0,
             prefill_chunk=8, mesh=mesh, **kw)
         reqs = [serve_mod._Request(list(p), 5, None)
@@ -315,7 +306,7 @@ class TestElasticShrink:
 
         def mk(mesh):
             return serve_mod.ServeEngine(
-                MOE_PARAMS, MOE_CFG, model_family="moe", kv="paged",
+                MOE_PARAMS, MOE_CFG, model_family="moe",
                 n_slots=4, n_blocks=128, block_size=4,
                 idle_sleep_s=0.0, prefill_chunk=8, mesh=mesh,
                 max_reshards=5)
@@ -347,7 +338,7 @@ class TestElasticShrink:
     def test_grow_back_after_recovery(self):
         from tpushare.cli import serve as serve_mod
         eng = serve_mod.ServeEngine(
-            MOE_PARAMS, MOE_CFG, model_family="moe", kv="paged",
+            MOE_PARAMS, MOE_CFG, model_family="moe",
             n_slots=4, n_blocks=128, block_size=4, idle_sleep_s=0.0,
             mesh=_mesh_eptp(), max_reshards=5)
         self._drive_engine(eng, [[5, 9, 12, 3]], shrink_at=2, dev=3)
@@ -363,7 +354,7 @@ class TestElasticShrink:
         assert st["num_devices"] == 4
         # The regrown engine still serves, token-exact vs oracle.
         oracle = serve_mod.ServeEngine(
-            MOE_PARAMS, MOE_CFG, model_family="moe", kv="paged",
+            MOE_PARAMS, MOE_CFG, model_family="moe",
             n_slots=4, n_blocks=128, block_size=4, idle_sleep_s=0.0)
         want = self._drive_engine(oracle, [[7, 7, 3]])
         assert self._drive_engine(eng, [[7, 7, 3]]) == want
@@ -461,14 +452,15 @@ class TestPlacementValidation:
             pytest.skip("needs 6 forced devices for ep=3,tp=2")
         mesh = make_mesh({"ep": 3, "tp": 2}, devices=jax.devices()[:6])
         with pytest.raises(ValueError, match="n_experts"):
-            moe.MoESlotServer(MOE_PARAMS, MOE_CFG, n_slots=2,
-                              max_len=32, mesh=mesh)
+            PagedSlotServer(MOE_PARAMS, MOE_CFG, n_slots=2,
+                            n_blocks=16, block_size=4,
+                            forward_fn=moe.paged_forward, mesh=mesh)
 
     def test_ep_rejected_for_dense(self):
         mesh = make_mesh({"ep": 2}, devices=jax.devices()[:2])
         with pytest.raises(ValueError, match="expert-parallel"):
-            SlotServer(TF_PARAMS, TF_CFG, n_slots=2, max_len=32,
-                       mesh=mesh)
+            PagedSlotServer(TF_PARAMS, TF_CFG, n_slots=2, n_blocks=16,
+                            block_size=4, mesh=mesh)
 
     def test_non_serving_axes_rejected(self):
         mesh = make_mesh({"dp": 2}, devices=jax.devices()[:2])
@@ -547,12 +539,12 @@ class TestCliMesh:
 
     def test_moe_paged_mesh_serves_end_to_end(self, monkeypatch):
         """The acceptance demo path: tpushare-serve --mesh tp=2,ep=2
-        --model-family moe --kv paged builds a sharded engine that
+        --model-family moe builds a sharded engine that
         serves a request end-to-end."""
         from tpushare.cli import serve as serve_mod
         eng = self._engine_from_argv(
             monkeypatch, "--mesh", "tp=2,ep=2",
-            "--model-family", "moe", "--kv", "paged")
+            "--model-family", "moe")
         st = eng.stats()
         assert st["mesh_shape"] == {"ep": 2, "tp": 2}
         assert st["num_devices"] == 4
@@ -686,7 +678,7 @@ class TestMeshFaultClassification:
         until that chip recovers too."""
         from tpushare.cli import serve as serve_mod
         eng = serve_mod.ServeEngine(
-            MOE_PARAMS, MOE_CFG, model_family="moe", kv="paged",
+            MOE_PARAMS, MOE_CFG, model_family="moe",
             n_slots=2, n_blocks=64, block_size=4, idle_sleep_s=0.0,
             mesh=_mesh_eptp(), max_reshards=5)
         eng.chip_event(3, False)

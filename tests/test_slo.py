@@ -555,14 +555,22 @@ class TestEngineTiers:
             tenant_quotas={"acme": TenantQuotaSpec(2, 32)})
         assert q_eng.stats()["tenants"]["acme"]["reserve"] == 2
 
-    def test_rows_family_rejects_quotas(self):
+    def test_moe_family_meters_a_tenant_quota(self):
+        """The one slot server meters the sparse family's blocks like
+        the dense one's: a prompt over the tenant's ceiling is a 429,
+        one under it is served and charged."""
         from tpushare.models import moe
         cfg = moe.tiny(remat=False)
         params = moe.init_params(jax.random.PRNGKey(0), cfg)
-        with pytest.raises(ValueError, match="block pool"):
-            ServeEngine(params, cfg, model_family="moe", n_slots=2,
-                        max_len=64,
-                        tenant_quotas={"a": TenantQuotaSpec(0, 4)})
+        eng = ServeEngine(params, cfg, model_family="moe", n_slots=2,
+                          n_blocks=16, block_size=4, idle_sleep_s=0.0,
+                          tenant_quotas={"acme": TenantQuotaSpec(0, 2)})
+        small = _Request([1, 2, 3], 2, None, tenant="acme")
+        big = _Request(list(range(1, 14)), 2, None, tenant="acme")
+        drive(eng, [small, big])
+        assert small.error is None and len(small.tokens) == 2
+        assert big.status == 429 and "KV-block ceiling" in big.error
+        assert eng.stats()["tenants"]["acme"]["ceiling"] == 2
 
     def test_tier_http_contract(self):
         from tpushare.cli import serve as serve_mod

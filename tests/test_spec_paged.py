@@ -2,13 +2,16 @@
 speculative_draft): every emitted token must be EXACTLY what greedy
 non-speculative decoding produces — the draft model affects speed,
 never output — with per-slot ragged acceptance (no dense-loop lockstep),
-composing with prefix caching and int8 KV pools."""
+composing with prefix caching and int8 KV pools. The server-level
+properties are held under both forward functions the server runs
+(``family``: the dense LM, the sparse one via moe.paged_forward)."""
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from tpushare.models import moe, quant
 from tpushare.models import transformer as tf
 from tpushare.models.paged import PagedSlotServer
 
@@ -16,18 +19,36 @@ CFG = tf.tiny(remat=False)
 PARAMS = tf.init_params(jax.random.PRNGKey(0), CFG)
 DRAFT_SAME = (PARAMS, CFG)                    # self-draft: 100% accept
 DRAFT_OTHER = (tf.init_params(jax.random.PRNGKey(9), CFG), CFG)
+MOE_CFG = moe.tiny(remat=False)
+MOE_PARAMS = moe.init_params(jax.random.PRNGKey(0), MOE_CFG)
+
+FAMILIES = ("dense", "moe")
+# family -> (params, cfg, server kwargs, self-draft, mismatched draft)
+FAMILY = {
+    "dense": (PARAMS, CFG, {}, DRAFT_SAME, DRAFT_OTHER),
+    "moe": (MOE_PARAMS, MOE_CFG, {"forward_fn": moe.paged_forward},
+            (MOE_PARAMS, MOE_CFG),
+            (moe.init_params(jax.random.PRNGKey(9), MOE_CFG), MOE_CFG)),
+}
 
 
-def _prompt(seed, n):
+def _prompt(seed, n, family="dense"):
     rng = np.random.default_rng(seed)
-    return jnp.asarray(rng.integers(0, CFG.vocab_size, n), jnp.int32)
+    return jnp.asarray(
+        rng.integers(0, FAMILY[family][1].vocab_size, n), jnp.int32)
 
 
-def _mk(spec=None, **kw):
+def _mk(spec=None, family="dense", **kw):
+    """``spec``: a (params, cfg) draft, or "same" / "other" for the
+    family's own self-draft and mismatched draft."""
+    params, cfg, fkw, same, other = FAMILY[family]
+    spec = {"same": same, "other": other}.get(spec, spec) \
+        if isinstance(spec, str) else spec
     kw.setdefault("n_slots", 2)
     kw.setdefault("n_blocks", 32)
     kw.setdefault("block_size", 4)
-    return PagedSlotServer(PARAMS, CFG, speculative_draft=spec, **kw)
+    return PagedSlotServer(params, cfg, speculative_draft=spec, **fkw,
+                           **kw)
 
 
 def _greedy_reference(prompt, n, **kw):
@@ -46,36 +67,38 @@ def _spec_stream(srv, slot, n):
     return out[:n]
 
 
-@pytest.mark.parametrize("draft,label", [(DRAFT_SAME, "self"),
-                                         (DRAFT_OTHER, "other")])
-def test_spec_matches_greedy(draft, label):
-    prompt = _prompt(3, 13)
-    want = _greedy_reference(prompt, 12)
-    srv = _mk(draft, gamma=3)
+@pytest.mark.parametrize("family", FAMILIES)
+@pytest.mark.parametrize("draft", ["same", "other"])
+def test_spec_matches_greedy(draft, family):
+    prompt = _prompt(3, 13, family)
+    want = _greedy_reference(prompt, 12, family=family)
+    srv = _mk(draft, family, gamma=3)
     slot = srv.admit(prompt)
     assert _spec_stream(srv, slot, 12) == want
 
 
-def test_self_draft_accepts_full_blocks():
+@pytest.mark.parametrize("family", FAMILIES)
+def test_self_draft_accepts_full_blocks(family):
     """draft == target: EVERY round must emit gamma+1 tokens — not
     just the first. (Regression: the g-step draft loop never wrote the
     last proposal's KV, so each fully-accepted round left a draft-KV
     hole at base+gamma and acceptance collapsed from round 2 on.)"""
-    srv = _mk(DRAFT_SAME, gamma=3)
-    slot = srv.admit(_prompt(4, 9))
+    srv = _mk("same", family, gamma=3)
+    slot = srv.admit(_prompt(4, 9, family))
     for round_i in range(4):
         out = srv.step()
         assert len(out[slot]) == 4, (round_i, out)     # gamma + 1
 
 
-def test_per_slot_ragged_acceptance():
+@pytest.mark.parametrize("family", FAMILIES)
+def test_per_slot_ragged_acceptance(family):
     """Two slots advance independently (the dense loop's lockstep min
     is gone): each slot's flattened stream equals its solo greedy run
     even when their acceptance counts differ per round."""
-    p1, p2 = _prompt(5, 11), _prompt(6, 7)
-    want1 = _greedy_reference(p1, 10)
-    want2 = _greedy_reference(p2, 10)
-    srv = _mk(DRAFT_OTHER, gamma=3)
+    p1, p2 = _prompt(5, 11, family), _prompt(6, 7, family)
+    want1 = _greedy_reference(p1, 10, family=family)
+    want2 = _greedy_reference(p2, 10, family=family)
+    srv = _mk("other", family, gamma=3)
     s1, s2 = srv.admit(p1), srv.admit(p2)
     got1, got2 = [int(srv.last_token[s1, 0])], [int(srv.last_token[s2, 0])]
     while len(got1) < 10 or len(got2) < 10:
@@ -86,12 +109,13 @@ def test_per_slot_ragged_acceptance():
     assert got2[:10] == want2
 
 
-def test_spec_with_prefix_cache():
-    shared = _prompt(7, 8)
-    p1 = jnp.concatenate([shared, _prompt(8, 3)])
-    p2 = jnp.concatenate([shared, _prompt(9, 5)])
-    want = _greedy_reference(p2, 8, prefix_cache=True)
-    srv = _mk(DRAFT_OTHER, gamma=3, prefix_cache=True)
+@pytest.mark.parametrize("family", FAMILIES)
+def test_spec_with_prefix_cache(family):
+    shared = _prompt(7, 8, family)
+    p1 = jnp.concatenate([shared, _prompt(8, 3, family)])
+    p2 = jnp.concatenate([shared, _prompt(9, 5, family)])
+    want = _greedy_reference(p2, 8, family=family, prefix_cache=True)
+    srv = _mk("other", family, gamma=3, prefix_cache=True)
     srv.admit(p1)
     s2 = srv.admit(p2)
     assert srv.last_cached_len == 8           # shared blocks hit
@@ -106,21 +130,22 @@ def test_spec_with_int8_pools():
     assert _spec_stream(srv, slot, 10) == want
 
 
-def test_spec_capacity_deactivates_cleanly():
+@pytest.mark.parametrize("family", FAMILIES)
+def test_spec_capacity_deactivates_cleanly(family):
     """Acceptance clamps at slot capacity; the slot retires exactly
     like the non-speculative server (no KV past the last block — the
     trash-routing guard) and with the same tokens."""
     kw = dict(n_slots=1, n_blocks=8, block_size=4,
               max_blocks_per_slot=5)        # capacity 20
-    prompt = _prompt(11, 9)
-    ref = _mk(None, **kw)
+    prompt = _prompt(11, 9, family)
+    ref = _mk(None, family, **kw)
     s0 = ref.admit(prompt)
     want = [int(ref.last_token[s0, 0])]
     while ref.active[s0]:
         out = ref.step()
         if s0 in out:
             want.append(out[s0])
-    srv = _mk(DRAFT_SAME, gamma=3, **kw)
+    srv = _mk("same", family, gamma=3, **kw)
     slot = srv.admit(prompt)
     got = [int(srv.last_token[slot, 0])]
     while srv.active[slot]:
@@ -204,19 +229,16 @@ def test_spec_mlora_rejects_geometry_mismatch():
         _mk(draft, multi_lora=bank)
 
 
-def test_quantized_self_draft():
+@pytest.mark.parametrize("family", FAMILIES)
+def test_quantized_self_draft(family):
     """Quantized self-speculation: the int8 rounding of the target as
     the draft — still bit-exact greedy output, and acceptance is high
     (the draft is the target's own rounding)."""
-    from tpushare.models import quant
-    prompt = _prompt(12, 13)
-    want = _greedy_reference(prompt, 12)
-    qdraft = quant.quantize_params(PARAMS, CFG)
-    srv = PagedSlotServer(PARAMS, CFG, n_slots=2, n_blocks=32,
-                          block_size=4,
-                          speculative_draft=(qdraft, CFG),
-                          draft_layers_hook=quant.dequant_hook(CFG),
-                          gamma=3)
+    prompt = _prompt(12, 13, family)
+    want = _greedy_reference(prompt, 12, family=family)
+    params, cfg = FAMILY[family][:2]
+    srv = _mk((quant.quantize_params(params, cfg), cfg), family,
+              draft_layers_hook=quant.dequant_hook(cfg), gamma=3)
     slot = srv.admit(prompt)
     rounds = 0
     out = [int(srv.last_token[slot, 0])]
@@ -349,12 +371,13 @@ class TestStochasticPagedSpeculation:
         assert a != c                   # astronomically unlikely equal
         assert all(0 <= t < CFG.vocab_size for t in a)
 
-    def test_stochastic_capacity_clamp(self):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_stochastic_capacity_clamp(self, family):
         """Capacity clamp at temperature>0: the slot retires without
         device lengths ever exceeding capacity."""
-        srv = _mk(DRAFT_SAME, gamma=3, temperature=1.0, n_slots=1,
+        srv = _mk("same", family, gamma=3, temperature=1.0, n_slots=1,
                   n_blocks=8, block_size=4, max_blocks_per_slot=5)
-        slot = srv.admit(_prompt(22, 9))
+        slot = srv.admit(_prompt(22, 9, family))
         while srv.active[slot]:
             srv.step()
         assert int(srv.cache.lengths[slot]) <= srv.slot_capacity

@@ -3,9 +3,9 @@ speculative path rides the same draft-propose / verify-accept cores
 and the same round driver.
 
 Pinned here:
-- GREEDY BIT-EXACTNESS for all six family shapes — dense
+- GREEDY BIT-EXACTNESS for all five family shapes — dense
   (generate-level loop), dense-kvq (paged dense LM with int8 KV
-  pools), paged, paged-prefix, paged-moe, moe-rows — at horizon 1 AND
+  pools), paged, paged-prefix, paged-moe — at horizon 1 AND
   at a multi-token horizon k>1: the draft and the horizon affect
   speed, never output.
 - STOCHASTIC MoE speculation (the old third copy rejected
@@ -59,7 +59,7 @@ def _greedy_oracle(mk_server, prompt, n):
 
 
 # ---------------------------------------------------------------------------
-# Greedy bit-exactness: six family shapes × horizons {1, 2}
+# Greedy bit-exactness: five family shapes × horizons {1, 2}
 # ---------------------------------------------------------------------------
 
 def _paged(spec_draft=None, horizon=1, **kw):
@@ -71,17 +71,6 @@ def _paged(spec_draft=None, horizon=1, **kw):
         kw.setdefault("forward_fn", moe.paged_forward)
     return PagedSlotServer(params, cfg, speculative_draft=spec_draft,
                            spec_horizon=horizon, gamma=2, **kw)
-
-
-def _moe_rows(spec_draft=None, horizon=1, **kw):
-    kw.setdefault("n_slots", 2)
-    kw.setdefault("max_len", 64)
-    extra = {}
-    if spec_draft is not None:
-        extra = dict(speculative_draft=spec_draft, gamma=2,
-                     spec_horizon=horizon,
-                     draft_layers_hook=quant.dequant_hook(MOE_CFG))
-    return moe.MoESlotServer(MOE_PARAMS, MOE_CFG, **extra, **kw)
 
 
 SHAPES = {
@@ -103,10 +92,6 @@ SHAPES = {
         lambda h: _paged((MOE_QDRAFT, MOE_CFG), h,
                          model=(MOE_PARAMS, MOE_CFG),
                          draft_layers_hook=quant.dequant_hook(MOE_CFG)),
-        9),
-    "moe-rows": (
-        lambda: _moe_rows(),
-        lambda h: _moe_rows((MOE_QDRAFT, MOE_CFG), h),
         9),
 }
 
@@ -130,7 +115,7 @@ def test_greedy_bit_exact_per_shape_and_horizon(shape, horizon):
 
 @pytest.mark.parametrize("horizon", [1, 2])
 def test_greedy_bit_exact_dense_loop(horizon):
-    """The sixth shape: the generate-level dense loop
+    """The fifth shape: the generate-level dense loop
     (speculative_generate) — exactly greedy at any horizon, for a
     draft that disagrees with the target."""
     from tpushare.models.generate import generate
@@ -193,11 +178,12 @@ def test_seam_accounting():
 
 def _mk_moe_stoch(**kw):
     kw.setdefault("n_slots", 2)
-    kw.setdefault("max_len", 64)
+    kw.setdefault("n_blocks", 64)
+    kw.setdefault("block_size", 4)
     kw.setdefault("temperature", 1.0)
     kw.setdefault("gamma", 3)
-    return moe.MoESlotServer(
-        MOE_PARAMS, MOE_CFG,
+    return PagedSlotServer(
+        MOE_PARAMS, MOE_CFG, forward_fn=moe.paged_forward,
         speculative_draft=kw.pop("draft", (MOE_PARAMS, MOE_CFG)), **kw)
 
 
@@ -290,7 +276,7 @@ class TestStochasticMoESpeculation:
     def test_perfect_draft_always_accepts(self):
         """draft == target at temperature>0: p/q == 1 pointwise, so
         every round must emit gamma+1 tokens — pins the q bookkeeping
-        through the MoE hooks."""
+        through the sparse family's forward."""
         srv = _mk_moe_stoch(seed=5)
         slot = srv.admit(_prompt(22, 9, MOE_CFG.vocab_size))
         for round_i in range(4):
@@ -321,17 +307,6 @@ class TestStochasticMoESpeculation:
         slot = srv.admit(_prompt(24, 9, MOE_CFG.vocab_size))
         out = srv.step()
         assert len(out[slot]) == 5          # 2*2 + 1, p/q == 1
-
-    def test_max_len_clamp_stochastic(self):
-        """Near max_len the server falls back to plain ticks (the
-        room guard covers the whole gamma*K block) and retires
-        without device lengths ever exceeding max_len."""
-        srv = _mk_moe_stoch(n_slots=1, max_len=16, gamma=2,
-                            spec_horizon=2)
-        slot = srv.admit(_prompt(25, 8, MOE_CFG.vocab_size))
-        while srv.active[slot]:
-            srv.step()
-        assert int(jax.device_get(srv.lengths)[slot]) <= srv.max_len
 
 
 # ---------------------------------------------------------------------------

@@ -15,13 +15,34 @@ import pytest
 from tpushare.models import moe, quant
 from tpushare.models import transformer as tf
 from tpushare.models.paged import PagedSlotServer
-from tpushare.models.serving import SlotServer
 
 MOE_CFG = moe.tiny(remat=False)
 MOE_PARAMS = moe.init_params(jax.random.PRNGKey(0), MOE_CFG)
 MOE_QDRAFT = quant.quantize_params(MOE_PARAMS, MOE_CFG)
 TF_CFG = tf.tiny(remat=False)
 TF_PARAMS = tf.init_params(jax.random.PRNGKey(0), TF_CFG)
+
+FAMILIES = ("dense", "moe")
+CFGS = {"dense": TF_CFG, "moe": MOE_CFG}
+
+
+def _server(family, *, spec=False, **kw):
+    """The one slot server under either family's forward function
+    (``spec``: the dense family drafts with itself, the sparse one
+    with its own int8 rounding)."""
+    kw.setdefault("n_slots", 2)
+    kw.setdefault("n_blocks", 64)
+    kw.setdefault("block_size", 4)
+    if family == "moe":
+        if spec:
+            kw.setdefault("speculative_draft", (MOE_QDRAFT, MOE_CFG))
+            kw.setdefault("draft_layers_hook",
+                          quant.dequant_hook(MOE_CFG))
+        return PagedSlotServer(MOE_PARAMS, MOE_CFG,
+                               forward_fn=moe.paged_forward, **kw)
+    if spec:
+        kw.setdefault("speculative_draft", (TF_PARAMS, TF_CFG))
+    return PagedSlotServer(TF_PARAMS, TF_CFG, **kw)
 
 
 def _prompt(seed, n, vocab):
@@ -66,130 +87,70 @@ def _assert_one_transfer_per_tick(srv, ticks=3):
     assert counts == [1] * ticks, counts
 
 
+@pytest.mark.parametrize("family", FAMILIES)
 class TestOneTransferPerTick:
     """The regression the host-mirror refactor is held to: pre-fix,
-    MoESlotServer's spec guard device_get lengths every tick (2
-    transfers/round) and PagedSlotServer._grow_active np.asarray'd the
-    device lengths AND block table every tick (3 transfers/tick)."""
+    the spec guard device_get lengths every tick (2 transfers/round)
+    and PagedSlotServer._grow_active np.asarray'd the device lengths
+    AND block table every tick (3 transfers/tick). Held under both
+    forward functions the server runs."""
 
-    def test_moe_plain(self):
-        srv = moe.MoESlotServer(MOE_PARAMS, MOE_CFG, n_slots=2,
-                                max_len=64)
-        srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
-        srv.admit(_prompt(2, 4, MOE_CFG.vocab_size))
+    def test_plain(self, family):
+        srv = _server(family)
+        vocab = CFGS[family].vocab_size
+        srv.admit(_prompt(1, 6, vocab))
+        srv.admit(_prompt(2, 4, vocab))
         _assert_one_transfer_per_tick(srv)
 
-    def test_moe_speculative(self):
-        srv = moe.MoESlotServer(
-            MOE_PARAMS, MOE_CFG, n_slots=2, max_len=64,
-            speculative_draft=(MOE_QDRAFT, MOE_CFG), gamma=3,
-            draft_layers_hook=quant.dequant_hook(MOE_CFG))
-        srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
+    def test_speculative(self, family):
+        srv = _server(family, spec=True, gamma=3)
+        srv.admit(_prompt(1, 6, CFGS[family].vocab_size))
         _assert_one_transfer_per_tick(srv)
 
     @pytest.mark.parametrize("horizon", [2, 4])
-    def test_moe_speculative_horizon(self, horizon):
+    def test_speculative_horizon(self, family, horizon):
         """Multi-token horizons change the block length, never the
         sync count: a gamma*K round is still ONE fetch."""
-        srv = moe.MoESlotServer(
-            MOE_PARAMS, MOE_CFG, n_slots=2, max_len=128,
-            speculative_draft=(MOE_QDRAFT, MOE_CFG), gamma=2,
-            spec_horizon=horizon,
-            draft_layers_hook=quant.dequant_hook(MOE_CFG))
-        srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
+        srv = _server(family, spec=True, n_blocks=128, gamma=2,
+                      spec_horizon=horizon)
+        srv.admit(_prompt(1, 6, CFGS[family].vocab_size))
         _assert_one_transfer_per_tick(srv)
 
-    def test_moe_speculative_stochastic_one_transfer(self):
-        """temperature>0 MoE speculation (new on the unified seam):
-        the stochastic accept cores sample on-device off the
-        sampler's key stream — still exactly one fetch per round."""
-        srv = moe.MoESlotServer(
-            MOE_PARAMS, MOE_CFG, n_slots=2, max_len=64,
-            temperature=0.9, seed=3,
-            speculative_draft=(MOE_QDRAFT, MOE_CFG), gamma=3,
-            draft_layers_hook=quant.dequant_hook(MOE_CFG))
-        srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
+    def test_speculative_stochastic_horizon_one_transfer(self, family):
+        """temperature>0 speculation: the stochastic accept cores
+        sample on-device off the sampler's key stream — still exactly
+        one fetch per round."""
+        srv = _server(family, spec=True, n_blocks=128,
+                      temperature=0.8, seed=2, gamma=2, spec_horizon=2)
+        srv.admit(_prompt(1, 6, CFGS[family].vocab_size))
         _assert_one_transfer_per_tick(srv)
 
-    def test_paged_plain(self):
-        srv = PagedSlotServer(TF_PARAMS, TF_CFG, n_slots=2,
-                              n_blocks=32, block_size=4)
-        srv.admit(_prompt(1, 6, TF_CFG.vocab_size))
-        srv.admit(_prompt(2, 4, TF_CFG.vocab_size))
-        _assert_one_transfer_per_tick(srv)
-
-    def test_paged_speculative(self):
-        srv = PagedSlotServer(TF_PARAMS, TF_CFG, n_slots=2,
-                              n_blocks=64, block_size=4,
-                              speculative_draft=(TF_PARAMS, TF_CFG),
-                              gamma=3)
-        srv.admit(_prompt(1, 6, TF_CFG.vocab_size))
-        _assert_one_transfer_per_tick(srv)
-
-    @pytest.mark.parametrize("horizon", [2, 4])
-    def test_paged_speculative_horizon(self, horizon):
-        srv = PagedSlotServer(TF_PARAMS, TF_CFG, n_slots=2,
-                              n_blocks=128, block_size=4,
-                              speculative_draft=(TF_PARAMS, TF_CFG),
-                              gamma=2, spec_horizon=horizon)
-        srv.admit(_prompt(1, 6, TF_CFG.vocab_size))
-        _assert_one_transfer_per_tick(srv)
-
-    def test_paged_speculative_stochastic_horizon_one_transfer(self):
-        srv = PagedSlotServer(TF_PARAMS, TF_CFG, n_slots=2,
-                              n_blocks=128, block_size=4,
-                              temperature=0.8, seed=2,
-                              speculative_draft=(TF_PARAMS, TF_CFG),
-                              gamma=2, spec_horizon=2)
-        srv.admit(_prompt(1, 6, TF_CFG.vocab_size))
-        _assert_one_transfer_per_tick(srv)
-
-    def test_dense_slot_server(self):
-        srv = SlotServer(TF_PARAMS, TF_CFG, n_slots=2, max_len=64)
-        srv.admit(_prompt(1, 6, TF_CFG.vocab_size))
-        _assert_one_transfer_per_tick(srv)
-
-    def test_paged_moe(self):
-        srv = PagedSlotServer(MOE_PARAMS, MOE_CFG, n_slots=2,
-                              n_blocks=32, block_size=4,
-                              forward_fn=moe.paged_forward)
-        srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
-        _assert_one_transfer_per_tick(srv)
-
-    def test_retirement_still_exact_from_host_mirror(self):
-        """max_len retirement now reads the host mirror — it must fire
-        on exactly the same tick the device lengths reach the cap."""
-        srv = moe.MoESlotServer(MOE_PARAMS, MOE_CFG, n_slots=1,
-                                max_len=8)
-        s = srv.admit(_prompt(3, 6, MOE_CFG.vocab_size))
+    def test_retirement_still_exact_from_host_mirror(self, family):
+        """Capacity retirement reads the host mirror — it must fire
+        on exactly the tick the device lengths reach the slot's
+        capacity (2 blocks of 4 here)."""
+        srv = _server(family, n_slots=1, max_blocks_per_slot=2)
+        assert srv.slot_capacity == 8
+        s = srv.admit(_prompt(3, 6, CFGS[family].vocab_size))
         srv.step()                                   # 7
         out = srv.step()                             # 8 -> retires
         assert s in out and not srv.active[s]
-        assert int(jax.device_get(srv.lengths)[s]) == 8
-        assert int(srv._lengths_np[s]) == 8
+        assert int(jax.device_get(srv.cache.lengths)[s]) == 8
+        assert int(srv.cache.host_lengths()[s]) == 8
 
-    @pytest.mark.parametrize("family", ("paged", "dense-rows", "moe-rows"))
     def test_device_mask_is_a_copy_of_the_host_mirror(self, family):
         """On the CPU backend ``jnp.asarray`` aliases a numpy buffer
         that happens to be 64-byte aligned (half of all allocations),
-        and the servers flip ``active`` in place while a dispatch that
+        and the server flips ``active`` in place while a dispatch that
         reads the device mask may still be in flight: the retirement
         test above and the overlapped tick's bit-exactness failed now
         and then for it. The mirror is uploaded by copy; pinned here on
         a host mirror that IS aligned."""
-        if family == "paged":
-            srv = PagedSlotServer(TF_PARAMS, TF_CFG, n_slots=2,
-                                  n_blocks=32, block_size=4)
-        elif family == "dense-rows":
-            srv = SlotServer(TF_PARAMS, TF_CFG, n_slots=2, max_len=64)
-        else:
-            srv = moe.MoESlotServer(MOE_PARAMS, MOE_CFG, n_slots=2,
-                                    max_len=64)
+        srv = _server(family)
         raw = np.zeros(srv.active.size + 64, np.uint8)
         off = -raw.ctypes.data % 64
         srv.active = raw[off:off + srv.active.size].view(bool)
-        vocab = (MOE_CFG if family == "moe-rows" else TF_CFG).vocab_size
-        slot = srv.admit(_prompt(1, 6, vocab))
+        slot = srv.admit(_prompt(1, 6, CFGS[family].vocab_size))
         dev = srv._active_dev
         assert bool(dev[slot])
         srv.active[slot] = False            # what retirement does
@@ -202,18 +163,10 @@ class TestOneTransferPerTick:
 class TestFusedKernelPathSyncFree:
     """ISSUE 12: the fused int8 expert path (quant.fused_expert_hook
     -> ops/q8_expert) must not change the tick's sync discipline —
-    phase-timer-OFF engines keep exactly one fetch per tick on every
-    fused-path family, and phase-timer-ON is measurement mode:
+    phase-timer-OFF engines keep exactly one fetch per tick on the
+    fused path, and phase-timer-ON is measurement mode:
     instrumented, eager, deliberately sync-heavy, and excluded from
     the serving CLI path."""
-
-    def test_moe_rows_fused(self):
-        srv = moe.MoESlotServer(
-            MOE_QDRAFT, MOE_CFG, n_slots=2, max_len=64,
-            layers_hook=quant.fused_expert_hook(MOE_CFG))
-        srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
-        srv.admit(_prompt(2, 4, MOE_CFG.vocab_size))
-        _assert_one_transfer_per_tick(srv)
 
     def test_paged_moe_fused(self):
         srv = PagedSlotServer(MOE_QDRAFT, MOE_CFG, n_slots=2,
@@ -222,12 +175,13 @@ class TestFusedKernelPathSyncFree:
                               layers_hook=quant.fused_expert_hook(
                                   MOE_CFG))
         srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
+        srv.admit(_prompt(2, 4, MOE_CFG.vocab_size))
         _assert_one_transfer_per_tick(srv)
 
-    def test_moe_rows_real_kernel_in_tick(self, monkeypatch):
+    def test_real_kernel_in_tick(self, monkeypatch):
         # The REAL kernel (pallas interpreter, kernel-eligible
         # d_model=128 config) inside the jitted tick: still exactly
-        # one fetch. The tiny-config tests above cover the reference
+        # one fetch. The tiny-config test above covers the reference
         # fallback half of the dispatch gate.
         from tpushare.ops import q8_expert
         monkeypatch.setenv(q8_expert.Q8_EXPERT_KERNEL_ENV,
@@ -235,8 +189,9 @@ class TestFusedKernelPathSyncFree:
         cfg128 = moe.tiny(d_model=128, remat=False)
         qp128 = quant.quantize_params(
             moe.init_params(jax.random.PRNGKey(0), cfg128), cfg128)
-        srv = moe.MoESlotServer(
-            qp128, cfg128, n_slots=2, max_len=64,
+        srv = PagedSlotServer(
+            qp128, cfg128, n_slots=2, n_blocks=32, block_size=4,
+            forward_fn=moe.paged_forward,
             layers_hook=quant.fused_expert_hook(cfg128))
         srv.admit(_prompt(1, 6, cfg128.vocab_size))
         _assert_one_transfer_per_tick(srv)
@@ -245,27 +200,21 @@ class TestFusedKernelPathSyncFree:
     def test_spec_horizon_fused_draft(self, horizon):
         # int8-self draft through the FUSED hook: a gamma*K round is
         # still exactly one fetch.
-        srv = moe.MoESlotServer(
-            MOE_PARAMS, MOE_CFG, n_slots=2, max_len=128,
-            speculative_draft=(MOE_QDRAFT, MOE_CFG), gamma=2,
-            spec_horizon=horizon,
-            draft_layers_hook=quant.fused_expert_hook(MOE_CFG))
+        srv = _server("moe", spec=True, n_blocks=128, gamma=2,
+                      spec_horizon=horizon,
+                      draft_layers_hook=quant.fused_expert_hook(MOE_CFG))
         srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
         _assert_one_transfer_per_tick(srv)
 
     def test_phase_timer_on_is_not_sync_free(self, monkeypatch):
-        # The seam is real: a phase-timer server drains the device
+        # The seam is real: a phase-timer forward drains the device
         # queue (block_until_ready) at EVERY phase boundary — many
-        # barriers per tick on top of the token fetch. That is
-        # precisely why it must never reach the hot loop.
+        # barriers a step where a tick's whole budget is one fetch.
+        # That is precisely why it must never reach the hot loop.
         from tpushare.utils.profiling import PhaseTimer
         pt = PhaseTimer()
-        srv = moe.MoESlotServer(
-            MOE_QDRAFT, MOE_CFG, n_slots=1, max_len=64,
-            layers_hook=quant.fused_expert_hook(MOE_CFG),
-            phase_timer=pt)
-        srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
-        srv.step()                                  # warm
+        cache = moe.init_cache(MOE_CFG, 1, 16)
+        tok = _prompt(1, 1, MOE_CFG.vocab_size)[None, :]
         barriers = [0]
         orig = jax.block_until_ready
 
@@ -273,7 +222,11 @@ class TestFusedKernelPathSyncFree:
             barriers[0] += 1
             return orig(x)
         monkeypatch.setattr(jax, "block_until_ready", spy)
-        srv.step()
+        pt.start()
+        moe.forward(MOE_QDRAFT, tok, MOE_CFG, cache=cache,
+                    pos_offset=jnp.zeros((1,), jnp.int32),
+                    layers_hook=quant.fused_expert_hook(MOE_CFG),
+                    phase_timer=pt)
         # One barrier per phase mark per layer — a plain tick's sync
         # budget is 1 (the token fetch), so > 1 proves measurement
         # mode is the opposite of sync-free.
@@ -293,7 +246,8 @@ class TestFusedKernelPathSyncFree:
         def run(hook):
             eng = serve_mod.ServeEngine(
                 MOE_QDRAFT, MOE_CFG, model_family="moe", n_slots=2,
-                max_len=64, layers_hook=hook, idle_sleep_s=0.0)
+                n_blocks=32, block_size=4, layers_hook=hook,
+                idle_sleep_s=0.0)
             reqs = [serve_mod._Request(list(p), 6, None)
                     for p in prompts]
             for r in reqs:
@@ -324,6 +278,7 @@ class TestFusedKernelPathSyncFree:
         assert "phase_timer" not in inspect.getsource(serve_mod)
 
 
+@pytest.mark.parametrize("family", FAMILIES)
 class TestFusedTickOneTransfer:
     """The PR-2 invariant extended to the fused engine tick: a tick
     that carries an admission chunk alongside the decode batch is
@@ -344,45 +299,17 @@ class TestFusedTickOneTransfer:
                 done = slot in out
         assert counts == [1] * len(counts), counts
 
-    def test_dense(self):
-        srv = SlotServer(TF_PARAMS, TF_CFG, n_slots=2, max_len=64)
-        srv.admit(_prompt(1, 6, TF_CFG.vocab_size))
-        self._assert_fused(srv, _prompt(4, 21, TF_CFG.vocab_size))
+    def test_plain(self, family):
+        vocab = CFGS[family].vocab_size
+        srv = _server(family, n_blocks=32)
+        srv.admit(_prompt(1, 6, vocab))
+        self._assert_fused(srv, _prompt(4, 21, vocab))
 
-    def test_paged(self):
-        srv = PagedSlotServer(TF_PARAMS, TF_CFG, n_slots=2,
-                              n_blocks=32, block_size=4)
-        srv.admit(_prompt(1, 6, TF_CFG.vocab_size))
-        self._assert_fused(srv, _prompt(4, 21, TF_CFG.vocab_size))
-
-    def test_paged_speculative(self):
-        srv = PagedSlotServer(TF_PARAMS, TF_CFG, n_slots=2,
-                              n_blocks=64, block_size=4,
-                              speculative_draft=(TF_PARAMS, TF_CFG),
-                              gamma=3)
-        srv.admit(_prompt(1, 6, TF_CFG.vocab_size))
-        self._assert_fused(srv, _prompt(4, 21, TF_CFG.vocab_size))
-
-    def test_paged_moe(self):
-        srv = PagedSlotServer(MOE_PARAMS, MOE_CFG, n_slots=2,
-                              n_blocks=32, block_size=4,
-                              forward_fn=moe.paged_forward)
-        srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
-        self._assert_fused(srv, _prompt(4, 21, MOE_CFG.vocab_size))
-
-    def test_moe(self):
-        srv = moe.MoESlotServer(MOE_PARAMS, MOE_CFG, n_slots=2,
-                                max_len=64)
-        srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
-        self._assert_fused(srv, _prompt(4, 21, MOE_CFG.vocab_size))
-
-    def test_moe_speculative(self):
-        srv = moe.MoESlotServer(
-            MOE_PARAMS, MOE_CFG, n_slots=2, max_len=64,
-            speculative_draft=(MOE_QDRAFT, MOE_CFG), gamma=3,
-            draft_layers_hook=quant.dequant_hook(MOE_CFG))
-        srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
-        self._assert_fused(srv, _prompt(4, 21, MOE_CFG.vocab_size))
+    def test_speculative(self, family):
+        vocab = CFGS[family].vocab_size
+        srv = _server(family, spec=True, gamma=3)
+        srv.admit(_prompt(1, 6, vocab))
+        self._assert_fused(srv, _prompt(4, 21, vocab))
 
 
 class TestShardedOneTransfer:
@@ -427,12 +354,6 @@ class TestShardedOneTransfer:
         srv.admit(_prompt(1, 6, TF_CFG.vocab_size))
         _assert_one_transfer_per_tick(srv)
 
-    def test_moe_rows_eptp(self):
-        srv = moe.MoESlotServer(MOE_PARAMS, MOE_CFG, n_slots=2,
-                                max_len=64, mesh=self._mesh(4))
-        srv.admit(_prompt(1, 6, MOE_CFG.vocab_size))
-        _assert_one_transfer_per_tick(srv)
-
     def test_fused_tick_sharded_still_one_transfer(self):
         srv = PagedSlotServer(TF_PARAMS, TF_CFG, n_slots=2,
                               n_blocks=64, block_size=4,
@@ -469,47 +390,39 @@ class TestShardedOneTransfer:
         assert srv.device_fetches - f0 == counts[0] == 3
 
 
+@pytest.mark.parametrize("family", FAMILIES)
 class TestChunkedDraftPrefill:
-    """Chunked admission must bound the DRAFT prefill too: pre-fix,
-    _finish_admit cold-prefilled the whole draft prompt in one
-    forward, reintroducing the long-prompt stall for the draft's
-    weight stream."""
+    """Chunked admission must bound the DRAFT prefill too: a draft
+    prompt cold-prefilled in one forward would reintroduce the
+    long-prompt stall for the draft's weight stream."""
 
     GAMMA = 3
     CHUNK = 4
 
-    def _spec_server(self, **kw):
-        kw.setdefault("n_slots", 2)
-        kw.setdefault("max_len", 64)
-        return moe.MoESlotServer(
-            MOE_PARAMS, MOE_CFG, speculative_draft=(MOE_QDRAFT, MOE_CFG),
-            gamma=self.GAMMA,
-            draft_layers_hook=quant.dequant_hook(MOE_CFG), **kw)
-
-    def test_no_draft_forward_exceeds_chunk(self):
-        srv = self._spec_server()
+    def test_no_draft_forward_exceeds_chunk(self, family):
+        srv = _server(family, spec=True, gamma=self.GAMMA)
         widths = []
-        orig = srv._dfwd_prefill
+        orig = srv._draft_prefill
 
-        def spy(p, toks, **kw):
+        def spy(p, toks, *a, **kw):
             widths.append(int(toks.shape[1]))
-            return orig(p, toks, **kw)
+            return orig(p, toks, *a, **kw)
 
-        srv._dfwd_prefill = spy
-        slot = srv.admit_start(_prompt(5, 11, MOE_CFG.vocab_size),
+        srv._draft_prefill = spy
+        slot = srv.admit_start(_prompt(5, 13, CFGS[family].vocab_size),
                                chunk_tokens=self.CHUNK)
         while srv.admit_step(slot) is None:
             pass
         assert widths, "draft never prefilled"
         assert max(widths) <= self.CHUNK, widths
-        # The whole prompt was covered: ceil(11 / 4) chunks.
-        assert len(widths) == 3
+        # The whole prompt was covered: ceil(13 / 4) chunks.
+        assert len(widths) == 4
 
-    def test_chunked_spec_admission_matches_whole(self):
-        prompt = _prompt(7, 10, MOE_CFG.vocab_size)
+    def test_chunked_spec_admission_matches_whole(self, family):
+        prompt = _prompt(7, 10, CFGS[family].vocab_size)
 
         def run(chunked):
-            srv = self._spec_server()
+            srv = _server(family, spec=True, gamma=self.GAMMA)
             if chunked:
                 slot = srv.admit_start(prompt, chunk_tokens=self.CHUNK)
                 while srv.admit_step(slot) is None:
@@ -559,8 +472,7 @@ class TestPagedMoE:
         first_a = int(srv.last_token[a, 0])
         srv.evict(a)
         b = srv.admit(prompt)
-        # (S-1)//bs = 12//4 = 3 full blocks reused — the block-granular
-        # sharing the dense-row MoE cache could not do.
+        # (S-1)//bs = 12//4 = 3 full blocks reused.
         assert srv.last_cached_len == 12
         assert int(srv.last_token[b, 0]) == first_a
 
@@ -596,36 +508,42 @@ class TestPagedMoE:
 
 
 class TestEngineStatsSchema:
-    """/stats must tag the family/KV layout and never report a
-    nonexistent pool as exhausted (free_blocks=0) — null counters for
-    dense rows, real ones once --kv paged lands."""
+    """/stats tags the family and the one KV layout, with real pool
+    counters for every family; the removed layout is refused by
+    name."""
 
-    def test_dense_rows_report_null_pool(self):
+    @pytest.mark.parametrize("kv", (None, "paged"))
+    def test_moe_reports_real_pool(self, kv):
         from tpushare.cli import serve as serve_mod
         eng = serve_mod.ServeEngine(MOE_PARAMS, MOE_CFG,
-                                    model_family="moe", n_slots=1,
-                                    max_len=16)
-        st = eng.stats()
-        assert st["model_family"] == "moe" and st["kv"] == "rows"
-        assert st["free_blocks"] is None
-        assert st["reclaimable_blocks"] is None
-        assert st["live_blocks"] is None
-
-    def test_paged_moe_reports_real_pool(self):
-        from tpushare.cli import serve as serve_mod
-        eng = serve_mod.ServeEngine(MOE_PARAMS, MOE_CFG,
-                                    model_family="moe", kv="paged",
+                                    model_family="moe", kv=kv,
                                     n_slots=1, n_blocks=16,
                                     block_size=4)
+        assert type(eng.srv) is PagedSlotServer
         st = eng.stats()
         assert st["model_family"] == "moe" and st["kv"] == "paged"
         assert st["free_blocks"] == 15
         assert st["live_blocks"] == 0
 
-    def test_dense_family_rejects_rows(self):
+    @pytest.mark.parametrize("family", FAMILIES)
+    def test_kv_rows_refused_by_name(self, family):
         from tpushare.cli import serve as serve_mod
-        with pytest.raises(ValueError, match="paged pool"):
-            serve_mod.ServeEngine(TF_PARAMS, TF_CFG, kv="rows")
+        params = MOE_PARAMS if family == "moe" else TF_PARAMS
+        with pytest.raises(ValueError, match="removed in PR 30"):
+            serve_mod.ServeEngine(params, CFGS[family],
+                                  model_family=family, kv="rows")
+
+    def test_cli_moe_default_is_the_paged_server(self):
+        """``tpushare-serve --model-family moe`` with no further flag:
+        the path the chip measures, with a pool behind ``/stats``."""
+        from tpushare.cli import serve as serve_mod
+        eng = serve_mod.build_engine(serve_mod.build_parser().parse_args(
+            ["--model-family", "moe", "--platform", "cpu"]))
+        assert type(eng.srv) is PagedSlotServer
+        assert eng.srv._forward_fn is moe.paged_forward
+        st = eng.stats()
+        assert st["model_family"] == "moe" and st["kv"] == "paged"
+        assert st["free_blocks"] == 255 and st["live_blocks"] == 0
 
 
 class TestCliFlagGuards:
@@ -644,22 +562,22 @@ class TestCliFlagGuards:
                            match="bit-identical"):
             main()
 
-    def test_kv_rows_rejects_pool_flags(self, monkeypatch):
-        main = self._main_argv(monkeypatch, "--model-family", "moe",
-                               "--n-blocks", "64")
-        with pytest.raises(SystemExit, match="paged-pool"):
+    @pytest.mark.parametrize("argv", (
+        ("--model-family", "moe", "--kv", "rows"),
+        ("--kv=paged",),
+        ("--model-family", "moe", "--max-len", "128")))
+    def test_removed_flags_refused_by_name(self, monkeypatch, argv):
+        main = self._main_argv(monkeypatch, *argv)
+        with pytest.raises(SystemExit, match="removed in PR 30"):
             main()
 
-    def test_kv_paged_rejects_max_len(self, monkeypatch):
-        main = self._main_argv(monkeypatch, "--model-family", "moe",
-                               "--kv", "paged", "--max-len", "128")
-        with pytest.raises(SystemExit, match="--kv rows flag"):
-            main()
-
-    def test_dense_family_rejects_kv_rows(self, monkeypatch):
-        main = self._main_argv(monkeypatch, "--kv", "rows")
-        with pytest.raises(SystemExit, match="moe option"):
-            main()
+    def test_help_lists_neither_removed_flag(self):
+        from tpushare.cli import serve as serve_mod
+        flags = {s for a in serve_mod.build_parser()._actions
+                 for s in a.option_strings}
+        assert not {"--kv", "--max-len"} & flags
+        assert {"--kv-quant", "--n-blocks", "--block-size"} <= flags
+        assert len(flags - {"-h", "--help"}) == 38
 
 
 # ---------------------------------------------------------------------------

@@ -135,8 +135,9 @@ _READER_RE = re.compile(r"#\s*tpushare:\s*reader\b")
 OWNERSHIP_REGISTRY_NAME = "TPUSHARE_OWNERSHIP"
 
 #: attr names duck-typed onto the *SlotServer family when __init__
-#: gives no assignment to resolve them (the ServeEngine/_MoEServerAdapter
-#: seams: self.srv / self._inner hold whichever server the config chose)
+#: gives no assignment to resolve them (ServeEngine's self.srv holds
+#: whichever server the config chose; _inner / inner / server are the
+#: names a wrapper round one would use)
 DUCK_SERVER_ATTRS = {"srv", "_inner", "inner", "server"}
 DUCK_CLASS_SUFFIX = "SlotServer"
 

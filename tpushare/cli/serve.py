@@ -55,8 +55,7 @@ this framework is model-plumbing, not a tokenizer registry):
                            me"; the split is the contract now
   GET /prefixes         -> prefix-cache gossip: the hex chain keys
                            this replica's pool currently holds (the
-                           router's affinity key); null keys for
-                           dense-row families (no block pool)
+                           router's affinity key)
   GET /stats            -> slots / pool / prefix-cache / recovery counters
   POST /drain           -> stop accepting new work (the co-located
                            plugin's device-health churn hook POSTs
@@ -300,124 +299,6 @@ class _Request:
             self.cond.notify_all()
 
 
-class _DenseRowCacheStats:
-    """The cache-shaped attribute for a server with dense KV rows
-    (MoESlotServer): no block pool exists. /stats must NOT render its
-    absence as ``free_blocks=0`` — autoscaling keyed on pool
-    exhaustion would read an idle dense-row server as permanently
-    exhausted — so the engine emits null pool counters plus the
-    ``kv: "rows"`` tag for this surface (stats() branches on this
-    class)."""
-
-    def __init__(self, n_slots: int):
-        self.n_slots = n_slots
-
-
-class _MoEServerAdapter:
-    """MoESlotServer behind the slice of the PagedSlotServer surface
-    ServeEngine drives (admit/step/evict, active, last_token, stats
-    counters). Paged-only concepts report their identity values; the
-    engine's preemption path never triggers (dense rows are reserved
-    whole at admit, so step() cannot run out of pool mid-flight)."""
-
-    def __init__(self, inner):
-        self._inner = inner
-        self.cfg = inner.cfg
-        self.cache = _DenseRowCacheStats(inner.n_slots)
-
-    @property
-    def speculative(self):
-        return self._inner.speculative
-
-    @property
-    def gamma(self):
-        return self._inner.gamma
-
-    @property
-    def spec_horizon(self):
-        return self._inner.spec_horizon
-
-    @property
-    def spec_rounds(self):
-        return self._inner.spec_rounds
-
-    def spec_accept_rate(self):
-        return self._inner.spec_accept_rate()
-
-    @property
-    def last_cached_len(self):
-        return self._inner.last_cached_len
-
-    @property
-    def prefix_hit_tokens(self):
-        return self._inner.prefix_hit_tokens
-
-    @property
-    def prefix_prompt_tokens(self):
-        return self._inner.prefix_prompt_tokens
-
-    @property
-    def active(self):
-        return self._inner.active
-
-    @property
-    def last_token(self):
-        return self._inner.last_token
-
-    @property
-    def admitting_count(self):
-        return self._inner.admitting_count
-
-    @property
-    def admission_slots(self):
-        return self._inner.admission_slots
-
-    @property
-    def mesh(self):
-        return self._inner.mesh
-
-    @property
-    def device_fetches(self):
-        return self._inner.device_fetches
-
-    @staticmethod
-    def _check_adapter(adapter):
-        if adapter not in (-1, None):   # -1 = base model (the default)
-            raise ValueError("MoE serving has no adapter bank "
-                             "(multi-LoRA is a dense-server feature)")
-
-    def admit(self, prompt, adapter: int = -1):
-        self._check_adapter(adapter)
-        return self._inner.admit(prompt)
-
-    def admit_start(self, prompt, adapter: int = -1,
-                    chunk_tokens=None):
-        self._check_adapter(adapter)
-        if chunk_tokens is None:
-            # Unreachable from the engine (it always passes its
-            # clamped --prefill-chunk); default to the enforced
-            # break-even floor rather than a size the daemon itself
-            # calls a measured 2x regression.
-            chunk_tokens = PREFILL_CHUNK_FLOOR
-        return self._inner.admit_start(prompt,
-                                       chunk_tokens=chunk_tokens)
-
-    def admit_step(self, slot: int, max_chunk_tokens=None):
-        return self._inner.admit_step(slot,
-                                      max_chunk_tokens=max_chunk_tokens)
-
-    def step(self, prefill_work=None, max_chunk_tokens=None):
-        return self._inner.step(prefill_work=prefill_work,
-                                max_chunk_tokens=max_chunk_tokens)
-
-    def step_async(self, prefill_work=None, max_chunk_tokens=None):
-        return self._inner.step_async(prefill_work=prefill_work,
-                                      max_chunk_tokens=max_chunk_tokens)
-
-    def evict(self, slot: int) -> None:
-        self._inner.evict(slot)
-
-
 class _PendingTick:
     """One in-flight overlapped dispatch: the PendingStep whose fetch
     is deferred to the NEXT tick, stamped with the engine generation
@@ -447,17 +328,15 @@ class _PendingTick:
 
 
 class ServeEngine:
-    """Single-threaded engine loop around a PagedSlotServer — or,
-    with ``model_family="moe"``, around the MoE LM: ``kv="rows"``
-    (default) wraps an MoESlotServer (dense KV rows; chunked prefill,
-    a row-level prefix cache, and per-slot speculative decoding —
-    greedy or stochastic, on the shared seam — in the dense-row
-    idiom), ``kv="paged"`` serves MoE over the SAME
-    PagedSlotServer block pool via moe.paged_forward — block-granular
-    admission, chain-keyed prefix sharing, and a real free_blocks
-    pressure signal. Features with no MoE analog — kv_quant,
-    multi-LoRA — are rejected loudly rather than silently ignored;
-    int8 EXPERT weights ride ``layers_hook``."""
+    """Single-threaded engine loop around the one slot server,
+    PagedSlotServer: ``model_family="dense"`` over
+    transformer.forward, ``"moe"`` over the SAME block pool via
+    moe.paged_forward, ``"latent"`` through its subclass
+    LatentSlotServer. Every family gets block-granular admission,
+    chain-keyed prefix sharing and a real free_blocks pressure
+    signal. Features with no MoE analog — kv_quant, multi-LoRA — are
+    rejected loudly rather than silently ignored; int8 EXPERT weights
+    ride ``layers_hook``."""
 
     def __init__(self, params, cfg, *, n_slots: int = 8,
                  n_blocks: int = 256, block_size: int = 16,
@@ -475,7 +354,6 @@ class ServeEngine:
                  draft_layers_hook=None,
                  model_family: str = "dense",
                  kv: Optional[str] = None,
-                 max_len: int = 4096,
                  layers_hook=None,
                  chaos_spec: Optional[str] = None,
                  tick_deadline_ms: Optional[float] = None,
@@ -505,8 +383,14 @@ class ServeEngine:
         # fetch per host per tick. ``param_specs``/``draft_param_specs``
         # override the family default for int8 weight trees
         # (quant.quant_param_specs / quant_moe_param_specs).
-        if kv not in (None, "rows", "paged"):
-            raise ValueError(f"unknown kv {kv!r}; 'rows' or 'paged'")
+        # ``kv`` has one legal layout since PR 30 (the dense-row slot
+        # servers are gone); the keyword stays for the callers that
+        # still name it.
+        if kv not in (None, "paged"):
+            raise ValueError(
+                f"kv={kv!r}: 'paged' is the one KV layout ('rows' was "
+                f"removed in PR 30 with the dense-row slot servers; "
+                f"drop kv= or pass kv='paged')")
         # Spec-round granule math vs the tick budget: a speculative
         # round is UNSPLITTABLE — acceptance is decided on device, so
         # one slot's round emits up to gamma×horizon+1 tokens in its
@@ -529,15 +413,8 @@ class ServeEngine:
                 f"per-tick bound it promises. Raise the budget or "
                 f"lower --gamma/--spec-horizon")
         # Per-tenant KV-block quotas (tpushare.slo.quota) layer on the
-        # paged pool's counters; dense KV rows have no block pool to
-        # meter, so quotas there are a loud error, not a silent no-op.
+        # paged pool's counters.
         self._kv_quota = KvQuota(tenant_quotas) if tenant_quotas else None
-        if self._kv_quota is not None and (model_family == "moe"
-                                           and (kv or "rows") == "rows"):
-            raise ValueError(
-                "tenant_quotas meter paged KV-pool blocks; "
-                "model_family='moe' with kv='rows' has no block pool "
-                "(serve --kv paged for quota-aware MoE)")
         # The server construction is a FACTORY, not inline: the mesh
         # failure domain (ISSUE 13) rebuilds the slot server on a
         # degraded (or regrown) mesh mid-life, and two hand-synced
@@ -545,107 +422,44 @@ class ServeEngine:
         # contracts drift. The factory closes over every build-time
         # flag; only (params, draft, mesh, kv_quota) vary per rebuild.
         use_prefix = True if prefix_cache is None else prefix_cache
-        if model_family == "moe" and kv == "paged":
+        family_kw: Dict[str, Any] = {}
+        if model_family == "latent":
+            # Latent attention with a key selector and windowed layers
+            # (models/latent.py): the paged pool, block tables, prefix
+            # cache and tick of the dense family, its own two pools and
+            # programs underneath. kv_quant, multi_lora, layers_hook,
+            # a draft and a mesh are refused there, loudly.
+            from tpushare.models.latent import LatentSlotServer as server
+        elif model_family == "moe":
             from tpushare.models.moe import paged_forward
-            from tpushare.models.paged import PagedSlotServer
+            from tpushare.models.paged import PagedSlotServer as server
             if kv_quant or multi_lora is not None:
                 raise ValueError(
                     "model_family='moe' does not support kv_quant/"
                     "multi_lora (dense-LM features; pass layers_hook="
                     "quant.dequant_hook(cfg) for int8 expert weights)")
-
-            def factory(f_params, f_draft, f_mesh, f_quota):
-                return PagedSlotServer(
-                    f_params, cfg, n_slots=n_slots, n_blocks=n_blocks,
-                    block_size=block_size,
-                    max_blocks_per_slot=max_blocks_per_slot,
-                    prefix_cache=use_prefix,
-                    temperature=temperature, top_k=top_k, top_p=top_p,
-                    seed=seed, layers_hook=layers_hook,
-                    speculative_draft=f_draft, gamma=gamma,
-                    spec_horizon=spec_horizon,
-                    draft_layers_hook=draft_layers_hook,
-                    forward_fn=paged_forward,
-                    mesh=f_mesh, param_specs=param_specs,
-                    draft_param_specs=draft_param_specs,
-                    kv_quota=f_quota)
-        elif model_family == "moe":
-            unsupported = {
-                "kv_quant": kv_quant,
-                "max_blocks_per_slot": max_blocks_per_slot is not None,
-                "multi_lora": multi_lora is not None,
-            }
-            bad = [k for k, v in unsupported.items() if v]
-            if bad:
-                raise ValueError(
-                    f"model_family='moe' does not support {bad} "
-                    f"(moe.MoESlotServer docstring; pass "
-                    f"layers_hook=quant.dequant_hook(cfg) for int8 "
-                    f"expert weights instead)")
-            from tpushare.models.moe import MoESlotServer
-
-            # prefix_cache=None is "unset": both families default it
-            # on (MoE's is the row-level variant — one retained row,
-            # longest-common-prefix reuse on whole admits).
-            def factory(f_params, f_draft, f_mesh, f_quota):
-                return _MoEServerAdapter(MoESlotServer(
-                    f_params, cfg, n_slots=n_slots, max_len=max_len,
-                    temperature=temperature, top_k=top_k, top_p=top_p,
-                    seed=seed, layers_hook=layers_hook,
-                    prefix_cache=use_prefix,
-                    speculative_draft=f_draft, gamma=gamma,
-                    spec_horizon=spec_horizon,
-                    draft_layers_hook=draft_layers_hook,
-                    mesh=f_mesh, param_specs=param_specs,
-                    draft_param_specs=draft_param_specs))
-        elif model_family == "latent":
-            # Latent attention with a key selector and windowed layers
-            # (models/latent.py): the paged pool, block tables, prefix
-            # cache and tick of the dense family, its own two pools and
-            # programs underneath.
-            if kv == "rows":
-                raise ValueError("model_family='latent' serves over the "
-                                 "paged pool (kv='paged' is its only "
-                                 "KV layout)")
-            from tpushare.models.latent import LatentSlotServer
-
-            def factory(f_params, f_draft, f_mesh, f_quota):
-                return LatentSlotServer(
-                    f_params, cfg, n_slots=n_slots, n_blocks=n_blocks,
-                    block_size=block_size,
-                    max_blocks_per_slot=max_blocks_per_slot,
-                    prefix_cache=use_prefix,
-                    temperature=temperature, top_k=top_k, top_p=top_p,
-                    seed=seed, kv_quota=f_quota,
-                    # refused there, loudly, where given
-                    kv_quant=kv_quant, multi_lora=multi_lora,
-                    layers_hook=layers_hook, speculative_draft=f_draft,
-                    mesh=f_mesh)
-        elif model_family != "dense":
-            raise ValueError(f"unknown model_family {model_family!r}")
+            family_kw["forward_fn"] = paged_forward
+        elif model_family == "dense":
+            from tpushare.models.paged import PagedSlotServer as server
         else:
-            if kv == "rows":
-                raise ValueError("model_family='dense' serves over the "
-                                 "paged pool (kv='paged' is its only "
-                                 "KV layout)")
-            from tpushare.models.paged import PagedSlotServer
+            raise ValueError(f"unknown model_family {model_family!r}")
 
-            def factory(f_params, f_draft, f_mesh, f_quota):
-                return PagedSlotServer(
-                    f_params, cfg, n_slots=n_slots, n_blocks=n_blocks,
-                    block_size=block_size,
-                    max_blocks_per_slot=max_blocks_per_slot,
-                    prefix_cache=use_prefix,
-                    kv_quant=kv_quant,
-                    multi_lora=multi_lora, mlora_scale=mlora_scale,
-                    temperature=temperature, top_k=top_k, top_p=top_p,
-                    seed=seed, layers_hook=layers_hook,
-                    speculative_draft=f_draft, gamma=gamma,
-                    spec_horizon=spec_horizon,
-                    draft_layers_hook=draft_layers_hook,
-                    mesh=f_mesh, param_specs=param_specs,
-                    draft_param_specs=draft_param_specs,
-                    kv_quota=f_quota)
+        def factory(f_params, f_draft, f_mesh, f_quota):
+            return server(
+                f_params, cfg, n_slots=n_slots, n_blocks=n_blocks,
+                block_size=block_size,
+                max_blocks_per_slot=max_blocks_per_slot,
+                prefix_cache=use_prefix,
+                kv_quant=kv_quant,
+                multi_lora=multi_lora, mlora_scale=mlora_scale,
+                temperature=temperature, top_k=top_k, top_p=top_p,
+                seed=seed, layers_hook=layers_hook,
+                speculative_draft=f_draft, gamma=gamma,
+                spec_horizon=spec_horizon,
+                draft_layers_hook=draft_layers_hook,
+                mesh=f_mesh, param_specs=param_specs,
+                draft_param_specs=draft_param_specs,
+                kv_quota=f_quota, **family_kw)
         self._server_factory = factory
         # Mesh failure domain (ISSUE 13): the configured mesh is the
         # operator's sized shape; the CURRENT mesh lives on srv (it
@@ -722,9 +536,7 @@ class ServeEngine:
         self.srv = factory(params, speculative_draft, mesh,
                            self._kv_quota)
         self.model_family = model_family
-        self._has_pool = not isinstance(self.srv.cache,
-                                        _DenseRowCacheStats)
-        self.kv = "paged" if self._has_pool else "rows"
+        self.kv = "paged"
         # Bounded queue: a request flood gets an immediate 429 instead
         # of an unbounded queue + one parked handler thread per request.
         self._max_queue = max(1, max_queue)
@@ -849,11 +661,6 @@ class ServeEngine:
         # POST /kv/migrate. 0 = no tier (exactly the pre-r18 engine).
         self._host_tier = None
         if host_kv_bytes:
-            if not self._has_pool:
-                raise ValueError(
-                    "host_kv_bytes needs the paged KV pool (dense "
-                    "MoE rows have no blocks to demote; serve "
-                    "--kv paged)")
             if not use_prefix:
                 raise ValueError(
                     "host_kv_bytes needs prefix_cache: demoted "
@@ -1576,18 +1383,12 @@ class ServeEngine:
         """Prefix-cache gossip for the front door: the hex chain keys
         this replica's pool currently holds (published OR live — a
         referenced block's chain is just as hittable on a follow-up
-        admit as a parked one). Dense-row families have no block pool:
-        ``keys`` is null there, NOT [] — the same null-not-zero
-        contract as the pool counters, so the router reads "no prefix
-        plane" instead of "empty prefix plane" and skips affinity for
-        that replica rather than starving it.
+        admit as a parked one).
 
         Reading the index from a handler thread races the engine's
         mutations; the dict is small and insertion-only between
         evictions, so a snapshot retry is enough (a momentarily stale
         gossip only costs one routing hit)."""
-        if not self._has_pool:
-            return {"kv": self.kv, "block_size": None, "keys": None}
         cache = self.srv.cache
         for _ in range(3):
             try:
@@ -1625,8 +1426,6 @@ class ServeEngine:
         import base64
 
         import numpy as np
-        if not self._has_pool:
-            return {"block_size": None, "blocks": {}}
         from tpushare.models.paged import read_block
         out: Dict[str, Any] = {}
         for kh in keys_hex:
@@ -2002,35 +1801,24 @@ class ServeEngine:
                                      if self._host_tier is not None
                                      else None),
         })
-        if self._has_pool:
-            # Pool-GLOBAL under sharding, not per-shard: the pool's
-            # block axis is never sharded (only kv heads split over
-            # tp), so the host free list counts whole cross-shard
-            # blocks and the ROADMAP-2 autoscaler reads true
-            # exhaustion whatever the mesh shape.
-            n_total = int(srv.cache.pool_k.shape[1])    # static shape
-            allocatable = len(srv.cache.free) + len(srv.cache.lru)
-            out.update({
-                "free_blocks": len(srv.cache.free),
-                "reclaimable_blocks": len(srv.cache.lru),
-                "live_blocks": srv.cache.live_blocks(),
-                # Fraction of the pool an admission could claim right
-                # now (free + zero-ref reclaimable over total): the
-                # router's pool-pressure signal and the /scale
-                # advisory's exhaustion input.
-                "pool_free_frac": (round(allocatable / n_total, 3)
-                                   if n_total else None),
-            })
-        else:
-            # Dense KV rows: no pool exists. Null (not 0!) so an
-            # autoscaler keyed on pool exhaustion never reads an idle
-            # dense-row server as permanently exhausted — and the
-            # router's load metric reads null pool_free_frac as
-            # neutral pressure, never as "exhausted".
-            out.update({"free_blocks": None,
-                        "reclaimable_blocks": None,
-                        "live_blocks": None,
-                        "pool_free_frac": None})
+        # Pool-GLOBAL under sharding, not per-shard: the pool's
+        # block axis is never sharded (only kv heads split over
+        # tp), so the host free list counts whole cross-shard
+        # blocks and the ROADMAP-2 autoscaler reads true
+        # exhaustion whatever the mesh shape.
+        n_total = int(srv.cache.pool_k.shape[1])    # static shape
+        allocatable = len(srv.cache.free) + len(srv.cache.lru)
+        out.update({
+            "free_blocks": len(srv.cache.free),
+            "reclaimable_blocks": len(srv.cache.lru),
+            "live_blocks": srv.cache.live_blocks(),
+            # Fraction of the pool an admission could claim right
+            # now (free + zero-ref reclaimable over total): the
+            # router's pool-pressure signal and the /scale
+            # advisory's exhaustion input.
+            "pool_free_frac": (round(allocatable / n_total, 3)
+                               if n_total else None),
+        })
         # What only the latent family counts (models/latent.py
         # family_stats): keys the selector saw and kept, latent rows the
         # slots hold by layer kind and those behind every window to
@@ -2184,18 +1972,17 @@ class ServeEngine:
         chunked = (self._prefill_chunk is not None
                    and len(req.prompt) > self._prefill_chunk)
         self._fault_admit()
-        # The tenant rides into the paged server's quota ledger; the
-        # dense-row families have no block pool (and no tenant param).
-        tkw = {"tenant": req.tenant} if self._has_pool else {}
         try:
             if chunked:
                 slot = srv.admit_start(
                     jnp.asarray(req.prompt, jnp.int32),
                     adapter=req.adapter,
-                    chunk_tokens=self._prefill_chunk, **tkw)
+                    chunk_tokens=self._prefill_chunk,
+                    tenant=req.tenant)
             else:
                 slot = srv.admit(jnp.asarray(req.prompt, jnp.int32),
-                                 adapter=req.adapter, **tkw)
+                                 adapter=req.adapter,
+                                 tenant=req.tenant)
         except ValueError as e:         # permanently invalid (prompt
             req.error = str(e)          # exceeds capacity, bad adapter
             req.status = 400
@@ -3104,10 +2891,9 @@ class ServeEngine:
         and retry next tick rather than 503ing every in-flight
         request. SlotCapacityExceeded — ONE slot's block table is
         full, a per-slot ceiling and not a device fault: retire
-        exactly that request at its tokens-so-far (the paged analog of
-        dense max_len retirement); preempting or quarantining the
-        batch over one sequence's ceiling would punish the
-        innocents."""
+        exactly that request at its tokens-so-far; preempting or
+        quarantining the batch over one sequence's ceiling would
+        punish the innocents."""
         if isinstance(e, self._slot_cap_exceeded):
             req = self._active.pop(e.slot, None)
             self._safe_evict(e.slot)
@@ -3256,8 +3042,8 @@ class ServeEngine:
         self._schedule_and_dispatch(gen, finalized)
 
     def _prereap_retired(self) -> None:
-        """Dispatch-side capacity retirement (dense max_len, paged
-        slot ceiling) frees the server's slot while its final token is
+        """Dispatch-side capacity retirement (the slot's block
+        ceiling) frees the server's slot while its final token is
         still in flight. Move those rows out of ``_active`` — and
         reclaim their server-side state — BEFORE the admission drain
         can hand the slot to a new request; their tokens are emitted
@@ -3858,26 +3644,11 @@ def build_parser() -> argparse.ArgumentParser:
                     choices=["tiny", "gemma_2b", "llama3_8b"])
     ap.add_argument("--model-family", default="dense",
                     choices=["dense", "moe"],
-                    help="moe: serve the MoE LM via MoESlotServer "
-                         "(dense KV rows at --max-len; --preset tiny "
-                         "maps to moe.tiny; paged-only flags are "
-                         "rejected). Converted Mixtral checkpoints "
-                         "serve through the same engine via the API "
-                         "(convert.moe_from_hf)")
-    ap.add_argument("--max-len", type=int, default=None,
-                    help="per-slot context length for --model-family "
-                         "moe with --kv rows (default 2048; dense KV "
-                         "rows reserve it at admit). Rejected "
-                         "elsewhere — paged context is --n-blocks x "
-                         "--block-size")
-    ap.add_argument("--kv", default=None, choices=["rows", "paged"],
-                    help="KV layout for --model-family moe: 'rows' "
-                         "(default; dense [n_slots, max_len] rows) or "
-                         "'paged' (the dense family's block pool via "
-                         "moe.paged_forward — block-granular "
-                         "admission, chain-keyed prefix sharing, real "
-                         "free_blocks pressure in /stats). The dense "
-                         "family is always paged")
+                    help="moe: serve the MoE LM over the same "
+                         "paged pool (moe.paged_forward; --preset "
+                         "tiny maps to moe.tiny). Converted Mixtral "
+                         "checkpoints serve through the same engine "
+                         "via the API (convert.moe_from_hf)")
     ap.add_argument("--int8-experts", action="store_true",
                     help="moe only: serve an int8 quantize_params "
                          "tree (expert weights at half the bf16 "
@@ -4112,6 +3883,12 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main() -> int:
+    for flag in ("--kv", "--max-len"):
+        if any(a.split("=")[0] == flag for a in sys.argv[1:]):
+            raise SystemExit(
+                f"{flag} was removed in PR 30 with the dense-row slot "
+                f"servers: every family serves over the paged pool "
+                f"(context is --n-blocks x --block-size)")
     args = build_parser().parse_args()
     engine = build_engine(args)
     httpd = serve(engine, args.host, args.port, daemon_threads=False)
@@ -4305,7 +4082,6 @@ def build_engine(args) -> ServeEngine:
         enable_compile_cache()
     if args.model_family == "moe":
         from tpushare.models import moe
-        moe_kv = args.kv or "rows"
         if args.preset != "tiny":
             raise SystemExit("--model-family moe serves --preset tiny "
                              "(load real Mixtral trees via the API: "
@@ -4330,19 +4106,6 @@ def build_engine(args) -> ServeEngine:
             raise SystemExit("--kv-quant is a dense-family flag "
                              "(int8 KV pools); --model-family moe "
                              "serves full-precision KV")
-        if moe_kv == "rows":
-            paged_only = {"--n-blocks": args.n_blocks is not None,
-                          "--block-size": args.block_size is not None}
-            bad = [k for k, v in paged_only.items() if v]
-            if bad:
-                raise SystemExit(f"{bad} are paged-pool flags; "
-                                 f"--model-family moe --kv rows uses "
-                                 f"dense KV rows at --max-len (pass "
-                                 f"--kv paged for the block pool)")
-        elif args.max_len is not None:
-            raise SystemExit("--max-len is a --kv rows flag; paged "
-                             "MoE context is --n-blocks x "
-                             "--block-size")
         cfg = moe.tiny(remat=False)
         params = moe.init_params(jax.random.PRNGKey(args.seed), cfg)
         mhook, mspec, mdhook = None, None, None
@@ -4372,11 +4135,9 @@ def build_engine(args) -> ServeEngine:
                 if mesh is not None and args.draft_preset == "int8-self"
                 else None)
         engine = ServeEngine(params, cfg, model_family="moe",
-                             kv=moe_kv,
                              n_slots=args.n_slots,
                              n_blocks=args.n_blocks or 256,
                              block_size=args.block_size or 16,
-                             max_len=args.max_len or 2048,
                              prefix_cache=not args.no_prefix_cache,
                              prefill_chunk=args.prefill_chunk or None,
                              tick_token_budget=args.tick_token_budget,
@@ -4422,13 +4183,6 @@ def build_engine(args) -> ServeEngine:
         if args.int8_expert_hook:
             raise SystemExit("--int8-expert-hook is a moe flag "
                              "(pairs with --int8-experts)")
-        if args.kv == "rows":
-            raise SystemExit("--kv rows is a moe option; the dense "
-                             "family always serves over the paged "
-                             "pool")
-        if args.max_len is not None:
-            raise SystemExit("--max-len is a moe flag; dense context "
-                             "is --n-blocks x --block-size")
         from tpushare.models import transformer as tf
         cfg = {"tiny": tf.tiny, "gemma_2b": tf.gemma_2b,
                "llama3_8b": tf.llama3_8b}[args.preset]()

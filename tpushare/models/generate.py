@@ -27,7 +27,7 @@ def sample_logits(logits: jnp.ndarray, key: jax.Array, *,
                   top_p: Optional[float] = None) -> jnp.ndarray:
     """One sampling step on [B, V] logits -> [B] token ids; the ONE
     greedy/sample dispatch (temperature <= 0 is argmax) shared by
-    generate() and SlotServer.
+    generate() and the slot server's TokenSampler.
 
     Filters compose in the standard order: temperature scaling, top-k
     truncation (static k — lax.top_k keeps shapes known to XLA), then

@@ -30,13 +30,10 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from tpushare.ops import apply_rotary, attention, rms_norm, rotary_embedding
-from tpushare.models.spec import SpecDecodeMixin
 from tpushare.models.transformer import ParallelCtx, _act
-from tpushare.parallel.multihost import addressable_fetch, host_scalar
 from tpushare.parallel.ring_attention import ring_attention
 
 
@@ -1021,699 +1018,6 @@ def paged_forward(params, tokens: jnp.ndarray, cfg: MoEConfig, *,
         return out[0], None
     logits, _aux, new_cache = out
     return logits, new_cache
-
-
-class MoESlotServer(SpecDecodeMixin):
-    """Continuous batching for the MoE LM — the SlotServer surface
-    (admit/step/evict, ragged decode over one static-shaped cache) on
-    moe.forward, so MoE models serve under the same engine pattern as
-    the dense LM (serving.SlotServer docstring for the design).
-
-    Deliberately simpler than the dense servers: no paged pools or
-    multi-LoRA — expert weights dominate MoE memory, so dense KV rows
-    at max_len are the right first serving shape and the paged
-    machinery's win is proportionally smaller. ``prefix_cache`` is
-    the row-level variant (one retained row, longest-common-prefix
-    reuse; whole and chunked admits both consult it). Routing needs
-    no slot state (re-decided per token from the hidden state), which
-    is why admit/step are pure cache plumbing. ``layers_hook=
-    quant.fused_expert_hook(cfg)`` serves an int8 quantize_params
-    tree through the fused dequant×GEMM kernel (ops/q8_expert) —
-    expert weights (the dominant MoE memory AND decode-bandwidth
-    cost) store at 1/2 the bf16 bytes and stream from HBM as int8
-    with no materialized wide copy; ``quant.dequant_hook(cfg)`` is
-    the legacy per-layer widening hook, kept as the A/B oracle."""
-
-    def __init__(self, params, cfg: MoEConfig, *, n_slots: int,
-                 max_len: int, temperature: float = 0.0,
-                 top_k: Optional[int] = None, top_p: Optional[float] = None,
-                 seed: int = 0, attn_impl: str = "auto",
-                 layers_hook=None, prefix_cache: bool = False,
-                 speculative_draft=None, gamma: int = 4,
-                 spec_horizon: int = 1,
-                 draft_layers_hook=None,
-                 mesh=None, param_specs=None, draft_param_specs=None,
-                 phase_timer=None):
-        from tpushare.models.serving import (TokenSampler,
-                                             make_placement,
-                                             mesh_attn_impl)
-        attn_impl = mesh_attn_impl(mesh, attn_impl)
-        # mesh: span a jax.sharding Mesh — expert stacks over ep,
-        # per-expert GEMMs and attention heads over tp (param_specs;
-        # int8 expert trees need quant.quant_moe_param_specs), dense
-        # KV rows split on the kv-head axis. The one jitted forward
-        # compiles SPMD from placement alone (no pctx/shard_map), so
-        # every tick/admission/speculation path runs unchanged.
-        self.mesh = mesh
-        self._placement = make_placement(mesh, cfg, param_specs)
-        if self._placement is not None:
-            params = self._placement.place_params(params)
-        self.params = params
-        self.cfg = cfg
-        self.n_slots = n_slots
-        self.max_len = max_len
-        # Per-slot speculative decoding on the shared seam
-        # (models/spec.py SpecDecodeMixin): a draft LM proposes
-        # gamma×horizon tokens per slot, ONE multi-token ragged verify
-        # (forward's S>1 ragged mode) scores every slot's block, and
-        # each slot accepts ITS OWN matched prefix — no lockstep min
-        # across slots (the dense generate-level loops' compromise).
-        # Draft KV rides a second dense cache; stale rows from
-        # rejected proposals are overwritten before they can be
-        # attended (the same write-before-attend argument as bucket
-        # padding). temperature>0 composes via the seam's stochastic
-        # rejection rule (spec.spec_accept_core) — the old greedy-only
-        # restriction was the third divergent spec copy's limitation,
-        # not the MoE family's.
-        self.speculative = speculative_draft is not None
-        self.gamma = gamma
-        self.spec_horizon = spec_horizon
-        if self.speculative:
-            self._spec_init(gamma=gamma, spec_horizon=spec_horizon,
-                            temperature=temperature, top_k=top_k,
-                            top_p=top_p, cap=max_len)
-            self.draft_params, self.draft_cfg = speculative_draft
-            if self.draft_cfg.vocab_size != cfg.vocab_size:
-                raise ValueError("draft and target must share a "
-                                 "vocabulary")
-            self._dfwd = jax.jit(functools.partial(
-                forward, cfg=self.draft_cfg, attn_impl=attn_impl,
-                layers_hook=draft_layers_hook))
-            # Prefill variant: the draft prefill needs NO logits —
-            # last_logit_only skips the [1, S, V] unembed (forward's
-            # own docstring calls it the dominant prefill HBM spike).
-            self._dfwd_prefill = jax.jit(functools.partial(
-                forward, cfg=self.draft_cfg, attn_impl=attn_impl,
-                layers_hook=draft_layers_hook, last_logit_only=True))
-            self.dcache = init_cache(self.draft_cfg, n_slots, max_len)
-            if self._placement is not None:
-                dplace = make_placement(mesh, self.draft_cfg,
-                                        draft_param_specs, role="draft")
-                self.draft_params = dplace.place_params(self.draft_params)
-                self.dcache = dplace.place_kv(self.dcache)
-        self.cache = init_cache(cfg, n_slots, max_len)
-        if self._placement is not None:
-            self.cache = self._placement.place_kv(self.cache)
-        # Device->host transfers made by the tick paths — the /stats
-        # observability counter for the one-fetch-per-host invariant.
-        self.device_fetches = 0
-        self.lengths = jnp.zeros((n_slots,), jnp.int32)
-        # Host mirror of the per-slot lengths: admit sets S, a plain
-        # tick adds 1 per active slot, a speculative round adds the
-        # fetched a+1 — so the spec-round guard, max_len retirement,
-        # and evict all read host state and step() performs exactly
-        # ONE device->host transfer (the token fetch).
-        self._lengths_np = np.zeros((n_slots,), np.int64)
-        self.last_token = jnp.zeros((n_slots, 1), jnp.int32)
-        self.active = np.zeros(n_slots, dtype=bool)       # host truth
-        self._active_dev = jnp.zeros((n_slots,), bool)    # device mirror
-        # Uploaded by copy, never aliased (see PagedSlotServer.__init__).
-        self._admissions: Dict[int, Dict[str, Any]] = {}  # chunked
-        # Row-level prefix cache: the dense-row idiom of the paged
-        # server's block prefix cache. ONE retained (prompt, row)
-        # from the most recent whole admit; a new admit copies the
-        # longest common prefix's KV (jnp rows are immutable, so the
-        # "copy" is a reference) and prefills only the suffix.
-        # Deliberately a 1-entry registry: the win it targets is the
-        # shared-system-prompt pattern, and expert weights — not KV
-        # rows — dominate MoE serving memory.
-        self.prefix_cache = prefix_cache
-        self._prefix: Optional[Tuple[np.ndarray, Dict[str, Any]]] = None
-        self.last_cached_len = 0
-        self.prefix_hit_tokens = 0
-        self.prefix_prompt_tokens = 0
-        self._sampler = TokenSampler(temperature, top_k, top_p, seed)
-        # MEASUREMENT MODE (phase_timer set): the forward runs EAGER
-        # and phase-instrumented — per-phase block_until_ready marks
-        # are exactly the syncs the hot loop bans, so this server
-        # shape exists for benches/diagnostics only and is asserted
-        # excluded from the serving CLI (tests/test_sync_free.py).
-        # Default None: ONE jitted forward — prefill ([1, P], scalar
-        # offset) and decode ([n_slots, 1], ragged offsets) are just
-        # different shapes in its compile cache.
-        self.phase_timer = phase_timer
-        if phase_timer is not None:
-            self._fwd = functools.partial(
-                forward, cfg=cfg, attn_impl=attn_impl,
-                layers_hook=layers_hook, phase_timer=phase_timer)
-        else:
-            self._fwd = jax.jit(functools.partial(
-                forward, cfg=cfg, attn_impl=attn_impl,
-                layers_hook=layers_hook))
-
-    @property
-    def admitting_count(self) -> int:
-        return len(self._admissions)
-
-    @property
-    def admission_slots(self):
-        """Slots with an in-flight chunked admission (the engine's
-        quarantine path reaps untracked ones)."""
-        return list(self._admissions)
-
-    def _claim_slot(self, prompt: jnp.ndarray) -> int:
-        """Shared admit validation + slot pick (mid-chunked-admission
-        slots have active=False but are NOT free)."""
-        if prompt.ndim != 1:
-            raise ValueError("admit takes a single unbatched prompt")
-        S = int(prompt.shape[0])
-        if S >= self.max_len:
-            raise ValueError(f"prompt length {S} >= max_len "
-                             f"{self.max_len}")
-        for slot in range(self.n_slots):
-            if not self.active[slot] and slot not in self._admissions:
-                return slot
-        # Typed transient pressure (see paged.PoolExhausted): the
-        # engine holds the request instead of quarantining it.
-        from tpushare.models.paged import PoolExhausted
-        raise PoolExhausted("no free slots")
-
-    def _finish_admit(self, slot: int, row, last_logits,
-                      S: int, prompt: Optional[jnp.ndarray] = None,
-                      drow=None, din_cache: bool = False) -> None:
-        """Install a prefilled [1, max_len] row into the shared cache
-        and activate the slot with its first sampled token. ``row``
-        None means the admission already lives in the shared cache
-        (fused chunks wrote it in place — nothing to install). With
-        speculation, the draft cache installs here too: ``drow`` is a
-        chunked admission's already-prefilled draft row (admit_step
-        chunks the draft alongside the target so chunked admission
-        bounds ALL prefill latency) and ``din_cache`` marks a draft
-        that fused chunks already wrote into dcache; a whole admit
-        leaves both unset and cold-prefills the whole prompt (draft
-        KV never rides the target's prefix registry — int8-self
-        drafts stream half the weights, so the unshared prefill is
-        cheap relative to the bookkeeping of a second registry)."""
-        if row is not None:
-            self.cache = {kk: self.cache[kk].at[:, slot].set(row[kk][:, 0])
-                          for kk in self.cache}
-        if self.speculative and not din_cache:
-            if drow is None:
-                from tpushare.models.serving import bucket_len
-                assert prompt is not None
-                padded = jnp.zeros((min(bucket_len(S), self.max_len),),
-                                   jnp.int32).at[:S].set(prompt[:S])
-                drow = init_cache(self.draft_cfg, 1, self.max_len)
-                _, _, drow = self._dfwd_prefill(
-                    self.draft_params, padded[None, :], cache=drow,
-                    pos_offset=0)
-            self.dcache = {kk: self.dcache[kk].at[:, slot].set(
-                drow[kk][:, 0]) for kk in self.dcache}
-        self.lengths = self.lengths.at[slot].set(S)
-        self._lengths_np[slot] = S
-        nxt = self._sampler.pick(last_logits)[0].astype(jnp.int32)
-        self.last_token = self.last_token.at[slot, 0].set(nxt)
-        self.active[slot] = True
-        self._active_dev = jnp.array(self.active)
-
-    def _cached_prefix_len(self, prompt_np: np.ndarray) -> int:
-        """Longest usable cached-prefix length: common prefix with the
-        retained prompt, capped at S-1 (the admit must still forward
-        at least the final token to produce the logits it samples
-        from)."""
-        if self._prefix is None:
-            return 0
-        cp, _ = self._prefix
-        m = min(len(cp), len(prompt_np) - 1)
-        if m <= 0:
-            return 0
-        neq = np.nonzero(cp[:m] != prompt_np[:m])[0]
-        return int(neq[0]) if neq.size else m
-
-    def admit(self, prompt: jnp.ndarray) -> int:
-        """Prefill ``prompt`` [S] into a free slot; returns the slot.
-        Prompts zero-pad to a power-of-two bucket (one compile per
-        bucket); junk rows past S are never attended (length mask).
-        With ``prefix_cache`` the longest common prefix with the
-        retained row is reused and only the suffix prefills —
-        bit-identical to a cold admit (KV is causal: a prefix's rows
-        do not depend on what follows)."""
-        from tpushare.models.serving import bucket_len
-        slot = self._claim_slot(prompt)
-        S = int(prompt.shape[0])
-        prompt = jnp.asarray(prompt, jnp.int32)
-        prompt_np = np.asarray(prompt)
-        p = (self._cached_prefix_len(prompt_np)
-             if self.prefix_cache else 0)
-        if p > 0:
-            # The suffix keeps its power-of-two width (compile
-            # variants stay O(log max_len)); when the padded end would
-            # spill past max_len, REUSE LESS (shrink p to fit) rather
-            # than compiling a fresh width per distinct prefix length.
-            # S < max_len guarantees S - p' <= width after shrinking.
-            width = bucket_len(S - p)
-            if p + width > self.max_len:
-                p = max(0, self.max_len - width)
-        if p > 0:
-            row = self._prefix[1]        # immutable jnp rows: no copy
-            toks = jnp.zeros((1, width), jnp.int32).at[
-                0, :S - p].set(prompt[p:])
-            logits, _, row = self._fwd(self.params, toks, cache=row,
-                                       pos_offset=p)
-            last = logits[:1, S - 1 - p]
-        else:
-            padded = jnp.zeros((min(bucket_len(S), self.max_len),),
-                               prompt.dtype).at[:S].set(prompt)
-            row = init_cache(self.cfg, 1, self.max_len)
-            logits, _, row = self._fwd(self.params, padded[None, :],
-                                       cache=row, pos_offset=0)
-            last = logits[:1, S - 1]
-        self.last_cached_len = p
-        if self.prefix_cache:
-            self.prefix_hit_tokens += p
-            self.prefix_prompt_tokens += S
-            self._prefix = (prompt_np, row)
-        self._finish_admit(slot, row, last, S, prompt=prompt)
-        return slot
-
-    def admit_start(self, prompt: jnp.ndarray,
-                    chunk_tokens: int = 256) -> int:
-        """Begin a chunked admission: reserve a slot, prefill nothing;
-        drive with admit_step() (one chunk per call). Dense rows make
-        the MoE version of chunked prefill trivial next to the paged
-        one: each chunk is a prefill continuation into the slot's own
-        [1, max_len] row (forward's scalar-pos_offset mode), so
-        chunked and whole admission are bit-identical by construction
-        and there is nothing to re-gather between chunks."""
-        slot = self._claim_slot(prompt)
-        if chunk_tokens < 1:
-            raise ValueError("chunk_tokens must be >= 1")
-        prompt = jnp.asarray(prompt, jnp.int32)
-        prompt_np = np.asarray(prompt)
-        S = int(prompt.shape[0])
-        # Chunked admits consult the prefix cache like whole admits:
-        # the reused prefix simply counts as already-done chunks.
-        p = (self._cached_prefix_len(prompt_np)
-             if self.prefix_cache else 0)
-        self.last_cached_len = p
-        if self.prefix_cache:
-            self.prefix_hit_tokens += p
-            self.prefix_prompt_tokens += S
-        st = {
-            "prompt": prompt, "prompt_np": prompt_np,
-            "S": S, "done": p,
-            "chunk": int(chunk_tokens),
-            "row": (self._prefix[1] if p > 0
-                    else init_cache(self.cfg, 1, self.max_len)),
-            "in_cache": False,          # fused chunks write the shared
-            "din_cache": False,         # cache/dcache rows in place
-        }
-        if self.speculative:
-            # The draft prefills in chunks too — from position 0
-            # (draft KV never rides the target's prefix registry), so
-            # a prefix-hit target may finish before the draft; the
-            # admission completes only when BOTH rows are full.
-            st["drow"] = init_cache(self.draft_cfg, 1, self.max_len)
-            st["ddone"] = 0
-        self._admissions[slot] = st
-        return slot
-
-    def _chunk_forward(self, fwd, params, prompt, row, done: int,
-                       S: int, chunk: int, want_last: bool = True):
-        """One bounded prefill chunk [done, end) into ``row`` — shared
-        by the target and draft sides of a chunked admission, so no
-        single forward on EITHER weight stream exceeds the admission
-        chunk. The final (ragged) chunk zero-pads to a power-of-two
-        bucket capped at ``chunk`` (compile variants stay O(log chunk));
-        when the padded end would spill past max_len — where the
-        clamped dynamic_update_slice would corrupt earlier rows — it
-        falls back to the exact residual shape. Returns (last-position
-        logits [1, V] on the final chunk when ``want_last`` else None,
-        row, end)."""
-        from tpushare.models.serving import bucket_len
-        end = min(S, done + chunk)
-        width = end - done
-        if end >= S:                      # final chunk: bucket-pad
-            width = min(bucket_len(end - done), chunk)
-            if done + width > self.max_len:
-                width = end - done
-        toks = jnp.zeros((1, width), jnp.int32).at[0, :end - done].set(
-            prompt[done:end])
-        logits, _, row = fwd(params, toks, cache=row, pos_offset=done)
-        last = (logits[:1, S - 1 - done]
-                if want_last and end >= S else None)
-        return last, row, end
-
-    def admit_step(self, slot: int,
-                   max_chunk_tokens: Optional[int] = None
-                   ) -> Optional[int]:
-        """Prefill the next chunk of a started admission — one target
-        chunk AND (with speculation) one draft chunk per call, so
-        chunked admission bounds the latency of BOTH prefills: the old
-        whole-prompt draft prefill in _finish_admit reintroduced
-        exactly the long-prompt stall chunked prefill exists to
-        remove. Returns None while chunks remain on either side; the
-        final call installs the rows, samples the first token,
-        activates the slot, and returns that token."""
-        st = self._admissions.get(slot)
-        if st is None:
-            raise ValueError(
-                f"slot {slot} has no in-flight admission (already "
-                f"completed, evicted, or admitted whole)")
-        S, chunk = st["S"], st["chunk"]
-        if max_chunk_tokens is not None:
-            # The engine's tick budget bounds serial chunks too (the
-            # admission-only half of the budget alternation).
-            chunk = max(1, min(chunk, max_chunk_tokens))
-        if st["done"] < S:
-            if st["in_cache"]:
-                # Fused chunks moved this admission into the shared
-                # cache; serial chunks then operate on the slot's own
-                # cache row (view in, scatter back).
-                row = {kk: self.cache[kk][:, slot:slot + 1]
-                       for kk in self.cache}
-                last, row, st["done"] = self._chunk_forward(
-                    self._fwd, self.params, st["prompt"], row,
-                    st["done"], S, chunk)
-                self.cache = {kk: self.cache[kk].at[:, slot].set(
-                    row[kk][:, 0]) for kk in self.cache}
-                self._track_admit_frontier(slot, st)
-            else:
-                last, st["row"], st["done"] = self._chunk_forward(
-                    self._fwd, self.params, st["prompt"], st["row"],
-                    st["done"], S, chunk)
-            if last is not None:
-                st["last"] = last
-        if self.speculative and st["ddone"] < S:
-            if st["din_cache"]:
-                drow = {kk: self.dcache[kk][:, slot:slot + 1]
-                        for kk in self.dcache}
-                _, drow, st["ddone"] = self._chunk_forward(
-                    self._dfwd_prefill, self.draft_params, st["prompt"],
-                    drow, st["ddone"], S, chunk, want_last=False)
-                self.dcache = {kk: self.dcache[kk].at[:, slot].set(
-                    drow[kk][:, 0]) for kk in self.dcache}
-            else:
-                _, st["drow"], st["ddone"] = self._chunk_forward(
-                    self._dfwd_prefill, self.draft_params, st["prompt"],
-                    st["drow"], st["ddone"], S, chunk, want_last=False)
-        if st["done"] < S or (self.speculative and st["ddone"] < S):
-            return None
-        del self._admissions[slot]
-        if self.prefix_cache:
-            self._prefix = (st["prompt_np"],
-                            ({kk: self.cache[kk][:, slot:slot + 1]
-                              for kk in self.cache} if st["in_cache"]
-                             else st["row"]))
-        self._finish_admit(slot,
-                           None if st["in_cache"] else st["row"],
-                           st["last"], S, prompt=st["prompt"],
-                           drow=st.get("drow"),
-                           din_cache=st["din_cache"])
-        self.device_fetches += 1
-        return int(host_scalar(self.last_token[slot, 0]))
-
-    def _track_admit_frontier(self, slot: int, st) -> None:
-        """An in-cache admission keeps lengths[slot] at its target
-        write frontier: plain ticks and spec rounds write a junk KV
-        row for every inactive slot at lengths[slot], and ``done`` is
-        the one position the next chunk overwrites before attending —
-        a stale 0 there would clobber the admission's real KV."""
-        self.lengths = self.lengths.at[slot].set(st["done"])
-        self._lengths_np[slot] = st["done"]
-
-    def step(self, prefill_work: Optional[int] = None,
-             max_chunk_tokens: Optional[int] = None):
-        """One engine tick for every active slot -> {slot: token} (or
-        {slot: [tokens...]} on a speculative round). Inactive slots
-        compute garbage rows that are ignored (static shapes beat
-        dynamic batching on TPU); a slot reaching max_len retires.
-        A speculative server runs a spec round whenever every active
-        slot has room for gamma+1 rows; near capacity it falls back
-        to plain single-token ticks (a clamped scatter past max_len
-        would corrupt earlier rows).
-
-        ``prefill_work``: a slot with an in-flight chunked admission —
-        its next chunk rides the SAME jitted forward as the decode
-        rows (forward's ragged multi-token mode), capped at
-        ``max_chunk_tokens``. A tick carrying a fused chunk is always
-        a plain tick (spec rounds skip it; the draft side mirrors the
-        decode tokens AND advances its own chunk in one draft
-        forward). When the chunk completes the admission, the
-        returned dict also carries that slot's first sampled token."""
-        return self.step_async(prefill_work, max_chunk_tokens).finalize()
-
-    def step_async(self, prefill_work: Optional[int] = None,
-                   max_chunk_tokens: Optional[int] = None):
-        """step() with the token fetch deferred (serving.PendingStep
-        contract): all device work dispatches here; finalize()
-        performs the ONE device->host fetch and builds the out
-        dict."""
-        from tpushare.models.serving import PendingStep
-        if self.phase_timer is not None:
-            # Measurement mode: open the chain so the instrumented
-            # forward's marks attribute this tick's phases.
-            self.phase_timer.start()
-        if prefill_work is not None:
-            if prefill_work not in self._admissions:
-                raise ValueError(f"slot {prefill_work} has no "
-                                 f"in-flight admission")
-            return self._fused_tick_async(prefill_work, max_chunk_tokens)
-        if not self.active.any():
-            return PendingStep.done({})
-        if self.speculative:
-            # Spec-vs-plain decided from the HOST lengths mirror — the
-            # old per-tick device_get here stalled the pipeline before
-            # the round even started. The room check covers the whole
-            # gamma×horizon block (spec_block_len): a clamped scatter
-            # past max_len would corrupt earlier rows.
-            if (self._lengths_np[self.active] + self.spec_block_len + 1
-                    <= self.max_len).all():
-                return self._spec_step_async()
-            # Plain fallback on a speculative server still mirrors
-            # the token into the draft cache: a skipped draft write
-            # would leave a permanent zero row every later draft
-            # query attends (the draft-cache-hole review catch).
-            _, _, self.dcache = self._dfwd_prefill(
-                self.draft_params, self.last_token, cache=self.dcache,
-                pos_offset=self.lengths)
-        logits, _, self.cache = self._fwd(
-            self.params, self.last_token, cache=self.cache,
-            pos_offset=self.lengths)
-        nxt = self._sampler.pick(logits[:, 0]).astype(jnp.int32)
-        self.lengths = self.lengths + self._active_dev.astype(jnp.int32)
-        self.last_token = jnp.where(self._active_dev[:, None],
-                                    nxt[:, None], self.last_token)
-        # Host mirror advances by the same +1 per active slot; the
-        # tick's ONE transfer is the token fetch itself.
-        self._lengths_np[self.active] += 1
-        slots = [int(s) for s in np.nonzero(self.active)[0]]
-        retired = False
-        for slot in slots:
-            if int(self._lengths_np[slot]) >= self.max_len:
-                self.active[slot] = False   # next write would be OOB
-                retired = True
-        if retired:
-            self._active_dev = jnp.array(self.active)
-
-        def _finalize(invalid):
-            self.device_fetches += 1
-            nxt_np = addressable_fetch(nxt)
-            return {s: int(nxt_np[s]) for s in slots
-                    if s not in invalid}
-
-        return PendingStep(_finalize, slots=slots)
-
-    def _fused_tick(self, slot: int,
-                    max_chunk_tokens: Optional[int]) -> Dict[int, int]:
-        """One fused engine tick: every active decode slot contributes
-        1 token and admission ``slot`` contributes its next chunk, in
-        ONE forward per weight stream (target always; with speculation
-        the draft's decode-token mirror and its own admission chunk
-        share one draft forward too). Spec rounds never run on a tick
-        carrying a fused chunk — the plain-tick fallback semantics.
-        Sync discipline unchanged: exactly one device->host transfer
-        (the token fetch; the admission's first token rides it)."""
-        return self._fused_tick_async(slot, max_chunk_tokens).finalize()
-
-    def _fused_tick_async(self, slot: int,
-                          max_chunk_tokens: Optional[int]):
-        from tpushare.models.serving import (PendingStep,
-                                             fused_chunk_span,
-                                             fused_token_batch)
-        st = self._admissions[slot]
-        if not self.active.any():
-            # No decode batch to fuse into: serial admission is the
-            # fast path (and the bit-exactness oracle); the tick
-            # budget still caps its chunk. Its fetch cannot be
-            # deferred (the chunk loop needs the completion signal).
-            tok = self.admit_step(slot,
-                                  max_chunk_tokens=max_chunk_tokens)
-            return PendingStep.done({} if tok is None else {slot: tok})
-        S, chunk = st["S"], st["chunk"]
-        done = st["done"]
-        t_end = t_width = 0
-        if done < S:
-            t_end, t_width = fused_chunk_span(done, S, chunk,
-                                              max_chunk_tokens)
-        d_end = d_width = 0
-        if self.speculative and st["ddone"] < S:
-            d_end, d_width = fused_chunk_span(st["ddone"], S, chunk,
-                                              max_chunk_tokens)
-        if t_width == 0 and d_width == 0:
-            return self.step_async()    # budget left no chunk room
-        if t_width:
-            if not st["in_cache"]:
-                # First fused chunk: the admission's [0, done) KV
-                # moves from the serial row into the shared cache
-                # row, where fused forwards read and extend it.
-                self.cache = {kk: self.cache[kk].at[:, slot].set(
-                    st["row"][kk][:, 0]) for kk in self.cache}
-                st["row"] = None
-                st["in_cache"] = True
-            toks = fused_token_batch(self.last_token, st["prompt"],
-                                     done, t_end, t_width, slot)
-            pos = self.lengths.at[slot].set(done)
-            logits, _, self.cache = self._fwd(
-                self.params, toks, cache=self.cache, pos_offset=pos)
-            st["done"] = t_end
-            if t_end >= S:
-                st["last"] = logits[slot:slot + 1, S - 1 - done]
-        else:
-            # Target side already fully prefilled (prefix hit) while
-            # the draft still chunks: plain decode forward.
-            logits, _, self.cache = self._fwd(
-                self.params, self.last_token, cache=self.cache,
-                pos_offset=self.lengths)
-        if self.speculative:
-            if d_width:
-                if not st["din_cache"]:
-                    self.dcache = {kk: self.dcache[kk].at[:, slot].set(
-                        st["drow"][kk][:, 0]) for kk in self.dcache}
-                    st["drow"] = None
-                    st["din_cache"] = True
-                dtoks = fused_token_batch(self.last_token, st["prompt"],
-                                          st["ddone"], d_end, d_width,
-                                          slot)
-                dpos = self.lengths.at[slot].set(st["ddone"])
-                _, _, self.dcache = self._dfwd_prefill(
-                    self.draft_params, dtoks, cache=self.dcache,
-                    pos_offset=dpos)
-                st["ddone"] = d_end
-            else:
-                # Draft mirror of the plain tick: a skipped draft
-                # write would leave a permanent zero row every later
-                # draft query attends (the draft-cache-hole catch).
-                _, _, self.dcache = self._dfwd_prefill(
-                    self.draft_params, self.last_token,
-                    cache=self.dcache, pos_offset=self.lengths)
-        final = (st["done"] >= S
-                 and (not self.speculative or st["ddone"] >= S))
-        if final:
-            # Admission pick before the decode pick: matches the
-            # serial engine order on the sampler's key stream.
-            first = self._sampler.pick(st["last"]).astype(jnp.int32)
-        nxt = self._sampler.pick(logits[:, 0]).astype(jnp.int32)
-        self.lengths = self.lengths + self._active_dev.astype(jnp.int32)
-        self.last_token = jnp.where(self._active_dev[:, None],
-                                    nxt[:, None], self.last_token)
-        self._lengths_np[self.active] += 1
-        decode_slots = [int(s) for s in np.nonzero(self.active)[0]]
-        for s in decode_slots:
-            if int(self._lengths_np[s]) >= self.max_len:
-                self.active[s] = False
-        if final:
-            del self._admissions[slot]
-            # A side that never ran a fused chunk still holds its KV
-            # in the admission row — install it (the draft can finish
-            # on a fused draft chunk while the target completed
-            # serially, and vice versa).
-            if not st["in_cache"] and st["row"] is not None:
-                self.cache = {kk: self.cache[kk].at[:, slot].set(
-                    st["row"][kk][:, 0]) for kk in self.cache}
-            if (self.speculative and not st["din_cache"]
-                    and st.get("drow") is not None):
-                self.dcache = {kk: self.dcache[kk].at[:, slot].set(
-                    st["drow"][kk][:, 0]) for kk in self.dcache}
-            if self.prefix_cache:
-                self._prefix = (st["prompt_np"],
-                                {kk: self.cache[kk][:, slot:slot + 1]
-                                 for kk in self.cache})
-            # Activation is dispatch-side device work: the slot's
-            # first token stays on device (first[0] indexes the
-            # device array, no fetch) until finalize.
-            self.lengths = self.lengths.at[slot].set(S)
-            self._lengths_np[slot] = S
-            self.last_token = self.last_token.at[slot, 0].set(first[0])
-            self.active[slot] = True
-        elif st["in_cache"]:
-            self._track_admit_frontier(slot, st)
-        self._active_dev = jnp.array(self.active)
-        out_slots = decode_slots + ([slot] if final else [])
-
-        def _finalize(invalid):
-            self.device_fetches += 1
-            if final:
-                nxt_np, first_np = addressable_fetch((nxt, first))
-            else:
-                nxt_np = addressable_fetch(nxt)
-            out: Dict[int, int] = {}
-            for s in decode_slots:
-                if s not in invalid:
-                    out[s] = int(nxt_np[s])
-            if final and slot not in invalid:
-                out[slot] = int(first_np[0])
-            return out
-
-        return PendingStep(_finalize, slots=out_slots)
-
-    # -- speculation hooks (models/spec.py SpecDecodeMixin owns the
-    # round driver; these supply the dense-row MoE mechanics) ---------
-
-    def _spec_begin(self, h: int):
-        """Dense rows need no capacity prep: the step() room guard
-        (host mirror) already ensured every active slot holds the
-        whole h+1 block below max_len."""
-        del h
-        return self.lengths
-
-    def _spec_draft_step(self, tok, base, j: int):
-        """One draft decode, all slots batched (the draft cache
-        mirrors the target's positions)."""
-        dl, _, self.dcache = self._dfwd(
-            self.draft_params, tok, cache=self.dcache,
-            pos_offset=base + j)
-        return dl[:, 0]
-
-    def _spec_draft_catchup(self, block, tok, base, h: int):
-        """One multi-token write of the SAME block fills position
-        base+h (the proposal loop only wrote inputs last..d_{h-1}) —
-        without it, a fully-accepted round leaves a permanent
-        draft-cache hole there, degrading every later proposal exactly
-        in the high-acceptance regime speculation exists for. Rewrites
-        of [base, base+h) are idempotent (same inputs, same
-        positions)."""
-        del tok, h
-        _, _, self.dcache = self._dfwd_prefill(
-            self.draft_params, block, cache=self.dcache,
-            pos_offset=base)
-        return self.dcache
-
-    def _spec_verify(self, block, base):
-        """ONE multi-token ragged verify for the whole batch."""
-        tl, _, self.cache = self._fwd(self.params, block,
-                                      cache=self.cache,
-                                      pos_offset=base)
-        return tl
-
-    def _spec_commit(self, a_b, correction, active) -> None:
-        self.lengths = self.lengths + (a_b + 1) * active.astype(
-            jnp.int32)
-        self.last_token = jnp.where(active[:, None], correction,
-                                    self.last_token)
-
-    def _spec_host_lengths(self):
-        return self._lengths_np
-
-    def _spec_capacity(self) -> int:
-        return self.max_len
-
-    def evict(self, slot: int) -> None:
-        self._admissions.pop(slot, None)   # cancel mid-chunked admit
-        self.active[slot] = False
-        self._active_dev = jnp.array(self.active)
-        self.lengths = self.lengths.at[slot].set(0)
-        self._lengths_np[slot] = 0
 
 
 def lm_loss(params, tokens: jnp.ndarray, cfg: MoEConfig, *,

@@ -945,9 +945,9 @@ def _program(name: str, fn, **kw):
 
 class PagedSlotServer(SpecDecodeMixin):
     """Continuous batching over the paged pool — the integration the
-    block cache exists for. SlotServer semantics (admit/step/evict),
-    but KV storage scales with live tokens instead of slots×max_len,
-    so a tenant fits more concurrent sequences into its HBM share.
+    block cache exists for: admit/step/evict over a fixed slot array,
+    with KV storage that scales with live tokens instead of
+    slots×max_len, so a tenant fits more concurrent sequences into its HBM share.
 
     Host/device split: the host owns the free list, the active bitmap,
     and exact mirrors of the block table and per-slot lengths

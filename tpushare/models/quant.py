@@ -239,8 +239,7 @@ def init_cache_q8(cfg: TransformerConfig, batch: int, max_len: int,
                   n_kv_heads: int = None) -> Dict[str, jnp.ndarray]:
     """Int8 KV cache: {"k","v"} int8 [L,B,M,Hkv,Dh] +
     {"k_scale","v_scale"} f32 [L,B,M,Hkv]. Drop-in for
-    transformer.init_cache on the single-device forward/SlotServer
-    paths (``n_kv_heads`` overrides for tp-local caches, matching
+    transformer.init_cache on the single-device forward path (``n_kv_heads`` overrides for tp-local caches, matching
     init_cache's signature). The tp shard_map serving factories
     (serving.make_tp_decoder / cache_specs) do not yet carry the scale
     leaves — that composition is a documented seam, like kvq+paged."""

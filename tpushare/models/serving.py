@@ -23,11 +23,8 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from jax import shard_map
 
 from tpushare.models.generate import sample_logits
-from tpushare.models.paged import PoolExhausted
-from tpushare.parallel.multihost import addressable_fetch, host_scalar
 from tpushare.utils.profiling import span
 from tpushare.models.transformer import (
-    _chunked_prefill_loop,
     ParallelCtx, TransformerConfig, forward, init_cache, param_specs,
 )
 
@@ -162,7 +159,7 @@ def make_moe_decoder(cfg, mesh: Mesh, *, quantized: bool = False):
         # shard_map path is the dryrun parity oracle whose banked
         # MULTICHIP rows were measured against it, and the fused
         # kernel's per-shard dispatch is validated on the placement
-        # (jit-SPMD) serving path (MoESlotServer mesh= + quant specs).
+        # (jit-SPMD) serving path (PagedSlotServer mesh= + quant specs).
         from tpushare.models.quant import (
             dequant_hook, quant_moe_param_specs,
         )
@@ -552,436 +549,3 @@ class PendingStep:
         # for the device (every slot server's deferred fetch).
         with span("slot.fetch"):
             return fn(frozenset(invalid))
-
-
-class SlotServer:
-    """Continuous batching over a fixed slot array (host-side control).
-
-    One static-shaped cache of ``n_slots`` rows; sequences at different
-    lengths decode together via the ragged pos_offset path
-    (transformer.forward with per-sequence offsets — no recompiles as
-    slots come and go). admit() prefills a free slot, step() advances
-    every active slot one token, evict() frees a slot. This is the
-    serving-side building block for the mixed bin-pack BASELINE config
-    (a serving pod sharing its chip with small tenants wants stable,
-    static shapes).
-    """
-
-    def __init__(self, params, cfg: TransformerConfig, *, n_slots: int,
-                 max_len: int, attn_impl: str = "auto",
-                 layers_hook=None,
-                 temperature: float = 0.0,
-                 top_k=None, top_p=None, seed: int = 0,
-                 prefill_chunk: int = 0,
-                 kv_quant: bool = False,
-                 multi_lora=None, mlora_scale: float = 1.0,
-                 mesh=None, param_specs=None):
-        # multi_lora: an adapter bank from lora.stack_adapters — each
-        # slot picks its adapter at admit(prompt, adapter=i) and rows
-        # apply their own low-rank delta on the activation path inside
-        # ONE batched decode (adapter -1 = base model). The bank rides
-        # the layer scan; weights stay shared.
-        if multi_lora is not None:
-            from tpushare.models.lora import multi_lora_params
-            params = multi_lora_params(params, multi_lora)
-        self._ml = MultiLoraSlots(multi_lora, n_slots)
-        # mesh: span a jax.sharding Mesh — weights per param_specs
-        # (default: the family's full-precision tree; int8 trees need
-        # the quant specs), KV rows split on the kv-head axis over tp
-        # (MeshPlacement docstring). Every tick method runs unchanged:
-        # placement alone makes the jitted forwards compile SPMD.
-        self.mesh = mesh
-        if mesh is not None and (kv_quant or multi_lora is not None):
-            raise ValueError(
-                "mesh sharding does not compose with kv_quant/"
-                "multi_lora yet (the int8 scale pools' padded-head "
-                "layout and the adapter bank have no sharded "
-                "placement contract — documented seams)")
-        self._placement = make_placement(mesh, cfg, param_specs)
-        if self._placement is not None:
-            params = self._placement.place_params(params)
-        self.params = params
-        self.cfg = cfg
-        self.n_slots = n_slots
-        self.max_len = max_len
-        # kv_quant: int8 KV rows + per-(pos, head) scales
-        # (quant.init_cache_q8) — the resident cache shrinks ~2x (bf16)
-        # so the same tpu-mem grant holds ~2x the concurrent tokens;
-        # rows quantize on write inside forward, requant-idempotent.
-        if kv_quant:
-            from tpushare.models.quant import init_cache_q8
-            self._init_cache = init_cache_q8
-        else:
-            self._init_cache = init_cache
-        self.cache = self._init_cache(cfg, n_slots, max_len)
-        if self._placement is not None:
-            self.cache = self._placement.place_kv(self.cache)
-        # Device->host transfers made by the tick paths (step/
-        # _fused_tick/admit_step completions) — the /stats
-        # observability counter for the one-fetch-per-host invariant.
-        self.device_fetches = 0
-        self.lengths = jnp.zeros((n_slots,), jnp.int32)
-        # Host mirror of the per-slot lengths (admit sets S, each tick
-        # adds 1 per active slot): retirement reads it, so step()'s
-        # ONE device->host transfer is the token fetch itself.
-        self._lengths_np = np.zeros((n_slots,), np.int64)
-        self.last_token = jnp.zeros((n_slots, 1), jnp.int32)
-        self.active = np.zeros(n_slots, dtype=bool)       # host truth
-        self._active_dev = jnp.zeros((n_slots,), bool)    # device mirror
-        # Uploaded by copy, never aliased (see PagedSlotServer.__init__).
-        self._admissions: Dict[int, Dict[str, Any]] = {}  # chunked
-        # Sampling config (temperature 0 = greedy, the default).
-        self._sampler = TokenSampler(temperature, top_k, top_p, seed)
-        # prefill_chunk > 0: admit long prompts through fixed-size
-        # chunks (transformer.chunked_prefill semantics) — peak score
-        # footprint O(chunk x max_len) and one compile per chunk size
-        # instead of per bucket.
-        self._prefill_chunk = prefill_chunk
-
-        # layers_hook: the model API's per-layer transform seam (e.g.
-        # quant.dequant_hook(cfg) for an int8 params tree).
-        fwd_kw = dict(cfg=cfg, attn_impl=attn_impl,
-                      layers_hook=layers_hook, mlora_scale=mlora_scale)
-        self._prefill = jax.jit(functools.partial(forward, **fwd_kw),
-                                static_argnames=())
-        # Head-free chunks for chunked admit (one vocab row per piece).
-        self._prefill_last = jax.jit(functools.partial(
-            forward, last_logit_only=True, **fwd_kw))
-        self._decode = jax.jit(functools.partial(forward, **fwd_kw))
-
-    def _pick(self, logits: jnp.ndarray) -> jnp.ndarray:
-        return self._sampler.pick(logits)
-
-    # One bucketing policy for every slot server (MoESlotServer too).
-    _bucket = staticmethod(lambda n: bucket_len(n))
-
-    def admit(self, prompt: jnp.ndarray, adapter: int = -1) -> int:
-        """Prefill ``prompt`` [S] into a free slot; returns the slot.
-        ``adapter``: this slot's index into the multi-LoRA bank
-        (-1 = base model); only meaningful with multi_lora set."""
-        self._ml.validate(adapter)
-        slot = self._claim_slot(prompt)
-        S = prompt.shape[0]
-        row_cache = self._init_cache(self.cfg, 1, self.max_len)
-        if self._ml.enabled:
-            self._ml.set(slot, adapter)
-        prefill = self._ml.wrap_prefill(self._prefill, adapter)
-        prefill_last = self._ml.wrap_prefill(self._prefill_last, adapter)
-        chunk = self._prefill_chunk
-        if chunk and S > chunk:
-            # Pad to a multiple of chunk (NOT the power-of-two bucket:
-            # fixed-size pieces already bound compiles to one, and
-            # bucket padding would prefill up to ~2x dead positions).
-            n_pad = min(-(-S // chunk) * chunk, self.max_len)
-            padded = jnp.zeros((n_pad,), prompt.dtype).at[:S].set(prompt)
-            last_row, row_cache = _chunked_prefill_loop(
-                prefill_last, prefill, self.params,
-                padded[None, :], row_cache, chunk, S - 1)
-            last_logits = last_row[0]
-        else:
-            # Zero-pad to the bucket: positions >= S produce junk cache
-            # rows, but the ragged decode path masks by length so they
-            # are never attended; causality keeps positions < S exact.
-            padded = jnp.zeros((min(self._bucket(S), self.max_len),),
-                               prompt.dtype).at[:S].set(prompt)
-            logits, row_cache = prefill(self.params, padded[None, :],
-                                        cache=row_cache, pos_offset=0)
-            last_logits = logits[0, S - 1]
-        self.cache = {kk: self.cache[kk].at[:, slot].set(row_cache[kk][:, 0])
-                      for kk in self.cache}
-        self.lengths = self.lengths.at[slot].set(S)
-        self._lengths_np[slot] = S
-        nxt = self._pick(last_logits[None, :])[0].astype(jnp.int32)
-        self.last_token = self.last_token.at[slot, 0].set(nxt)
-        self.active[slot] = True
-        self._active_dev = jnp.array(self.active)
-        return slot
-
-    def _claim_slot(self, prompt: jnp.ndarray) -> int:
-        """Shared admit validation + slot pick (mid-chunked-admission
-        slots have active=False but are NOT free)."""
-        if prompt.ndim != 1:
-            raise ValueError("admit takes a single unbatched prompt")
-        S = int(prompt.shape[0])
-        if S >= self.max_len:
-            raise ValueError(f"prompt length {S} >= max_len "
-                             f"{self.max_len}")
-        for slot in range(self.n_slots):
-            if not self.active[slot] and slot not in self._admissions:
-                return slot
-        # Typed: transient slot pressure (the engine holds and
-        # retries), never to be mistaken for a device/runtime error.
-        raise PoolExhausted("no free slots")
-
-    @property
-    def admitting_count(self) -> int:
-        return len(self._admissions)
-
-    @property
-    def admission_slots(self):
-        """Slots with an in-flight chunked admission (the engine's
-        quarantine path reaps untracked ones)."""
-        return list(self._admissions)
-
-    def admit_start(self, prompt: jnp.ndarray, adapter: int = -1,
-                    chunk_tokens: Optional[int] = None) -> int:
-        """Begin a chunked admission: reserve a slot, prefill nothing;
-        drive with admit_step() (one chunk per call — the serial
-        oracle) or step(prefill_work=slot) (the fused tick). Each
-        chunk is a prefill continuation into the slot's row, so
-        chunked, whole, and fused admission are bit-identical by
-        construction under greedy sampling."""
-        self._ml.validate(adapter)
-        slot = self._claim_slot(prompt)
-        chunk = int(chunk_tokens or self._prefill_chunk
-                    or prompt.shape[0])
-        if chunk < 1:
-            raise ValueError("chunk_tokens must be >= 1")
-        if self._ml.enabled:
-            self._ml.set(slot, adapter)
-        prompt = jnp.asarray(prompt, jnp.int32)
-        self._admissions[slot] = {
-            "prompt": prompt, "S": int(prompt.shape[0]), "done": 0,
-            "chunk": chunk,
-            "row": self._init_cache(self.cfg, 1, self.max_len),
-            "in_cache": False,
-            "prefill_fn": self._ml.wrap_prefill(self._prefill, adapter),
-        }
-        return slot
-
-    def _chunk_forward(self, st, row, max_chunk_tokens=None):
-        """One bounded serial prefill chunk [done, end) into ``row``,
-        optionally capped at ``max_chunk_tokens`` (the engine's tick
-        budget). The final (ragged) chunk zero-pads to a power-of-two
-        bucket capped at the chunk size; when the padded end would
-        spill past max_len — where the clamped dynamic_update_slice
-        would corrupt earlier rows — it falls back to the exact
-        residual shape. Returns (last-position logits [1, V] on the
-        final chunk else None, row, end)."""
-        S, done, chunk = st["S"], st["done"], st["chunk"]
-        if max_chunk_tokens is not None:
-            chunk = max(1, min(chunk, max_chunk_tokens))
-        end = min(S, done + chunk)
-        width = end - done
-        if end >= S:
-            width = min(bucket_len(end - done), chunk)
-            if done + width > self.max_len:
-                width = end - done
-        toks = jnp.zeros((1, width), jnp.int32).at[0, :end - done].set(
-            st["prompt"][done:end])
-        logits, row = st["prefill_fn"](self.params, toks, cache=row,
-                                       pos_offset=done)
-        last = logits[:1, S - 1 - done] if end >= S else None
-        return last, row, end
-
-    def admit_step(self, slot: int,
-                   max_chunk_tokens: Optional[int] = None
-                   ) -> Optional[int]:
-        """Prefill the next chunk of a started admission, optionally
-        capped at ``max_chunk_tokens`` (the engine's tick budget).
-        Returns None while chunks remain; the final call installs the
-        row, samples the first token, activates the slot, and returns
-        that token. An admission that has run fused chunks
-        (step(prefill_work=)) already lives in the shared cache;
-        serial chunks then operate on the slot's cache row directly."""
-        st = self._admissions.get(slot)
-        if st is None:
-            raise ValueError(
-                f"slot {slot} has no in-flight admission (already "
-                f"completed, evicted, or admitted whole)")
-        if st["in_cache"]:
-            row = {kk: self.cache[kk][:, slot:slot + 1]
-                   for kk in self.cache}
-        else:
-            row = st["row"]
-        last, row, end = self._chunk_forward(st, row, max_chunk_tokens)
-        if st["in_cache"]:
-            self.cache = {kk: self.cache[kk].at[:, slot].set(row[kk][:, 0])
-                          for kk in self.cache}
-        else:
-            st["row"] = row
-        st["done"] = end
-        if end < st["S"]:
-            if st["in_cache"]:
-                # The admission lives in the shared cache: keep the
-                # slot's length at the write frontier so a plain
-                # tick's junk write for this inactive row lands at
-                # `done` (overwritten by the next chunk), never at 0
-                # (the admission's real KV).
-                self.lengths = self.lengths.at[slot].set(end)
-                self._lengths_np[slot] = end
-            return None
-        del self._admissions[slot]
-        if not st["in_cache"]:
-            self.cache = {kk: self.cache[kk].at[:, slot].set(row[kk][:, 0])
-                          for kk in self.cache}
-        S = st["S"]
-        self.lengths = self.lengths.at[slot].set(S)
-        self._lengths_np[slot] = S
-        nxt = self._pick(last)[0].astype(jnp.int32)
-        self.last_token = self.last_token.at[slot, 0].set(nxt)
-        self.active[slot] = True
-        self._active_dev = jnp.array(self.active)
-        self.device_fetches += 1
-        return int(host_scalar(nxt))
-
-    def step(self, prefill_work: Optional[int] = None,
-             max_chunk_tokens: Optional[int] = None) -> Dict[int, int]:
-        """One greedy decode step for every active slot; returns
-        {slot: new_token}. Inactive slots compute garbage rows that are
-        simply ignored (static shapes beat dynamic batching on TPU).
-        Host cost per step: one device->host read (the tokens; lengths
-        are host-mirrored); the active mask lives on device and
-        changes only on admit/evict/completion.
-
-        ``prefill_work``: a slot with an in-flight chunked admission —
-        its next chunk rides the SAME jitted forward as the decode
-        rows (one weight stream per tick instead of two), capped at
-        ``max_chunk_tokens`` chunk tokens. When the chunk completes
-        the admission, the returned dict also carries that slot's
-        first sampled token."""
-        return self.step_async(prefill_work, max_chunk_tokens).finalize()
-
-    def step_async(self, prefill_work: Optional[int] = None,
-                   max_chunk_tokens: Optional[int] = None) -> PendingStep:
-        """step() with the token fetch deferred: enqueue all of this
-        tick's device work (forward, pick, cache/length/last_token
-        rebinds, retirement on the host length mirror) and return a
-        PendingStep whose finalize() does the ONE device->host fetch
-        and builds the {slot: token} dict. Slot state after
-        step_async() is identical to after step() — only the tokens
-        are still on device."""
-        if prefill_work is not None:
-            return self._fused_tick_async(prefill_work, max_chunk_tokens)
-        if not self.active.any():
-            return PendingStep.done({})
-        mkw = ({"mlora_idx": self._ml.dev} if self._ml.enabled else {})
-        logits, self.cache = self._decode(
-            self.params, self.last_token, cache=self.cache,
-            pos_offset=self.lengths, **mkw)
-        nxt = self._pick(logits[:, 0]).astype(jnp.int32)
-        self.lengths = self.lengths + self._active_dev.astype(jnp.int32)
-        self.last_token = jnp.where(self._active_dev[:, None],
-                                    nxt[:, None], self.last_token)
-        self._lengths_np[self.active] += 1
-        slots = [int(s) for s in np.nonzero(self.active)[0]]
-        # Retirement reads only the host mirror — decided at dispatch,
-        # exactly the serial tick's criterion.
-        hit_cap = False
-        for slot in slots:
-            if int(self._lengths_np[slot]) >= self.max_len:
-                self.active[slot] = False
-                hit_cap = True
-        if hit_cap:
-            self._active_dev = jnp.array(self.active)
-
-        def _finalize(invalid):
-            self.device_fetches += 1
-            nxt_np = addressable_fetch(nxt)
-            return {s: int(nxt_np[s]) for s in slots
-                    if s not in invalid}
-
-        return PendingStep(_finalize, slots=slots)
-
-    def _fused_tick(self, slot: int,
-                    max_chunk_tokens: Optional[int]) -> Dict[int, int]:
-        """One fused engine tick: every active decode slot contributes
-        1 token and admission ``slot`` contributes its next chunk, in
-        ONE jitted forward (the ragged multi-token dense branch). Same
-        sync discipline as step(): exactly one device->host transfer —
-        the token fetch (the admission's first token, when the chunk
-        completes it, rides the same fetch)."""
-        return self._fused_tick_async(slot, max_chunk_tokens).finalize()
-
-    def _fused_tick_async(self, slot: int,
-                          max_chunk_tokens: Optional[int]) -> PendingStep:
-        st = self._admissions.get(slot)
-        if st is None:
-            raise ValueError(f"slot {slot} has no in-flight admission")
-        if not self.active.any():
-            # No decode batch to fuse into: serial admission is the
-            # fast path (and the bit-exactness oracle); the tick
-            # budget still caps its chunk. Its fetch cannot be
-            # deferred (the chunk loop needs the completion signal),
-            # so the PendingStep comes back already finalized.
-            tok = self.admit_step(slot,
-                                  max_chunk_tokens=max_chunk_tokens)
-            return PendingStep.done({} if tok is None else {slot: tok})
-        done, S = st["done"], st["S"]
-        end, width = fused_chunk_span(done, S, st["chunk"],
-                                      max_chunk_tokens)
-        if width == 0:
-            return self.step_async()    # budget left no chunk room
-        if not st["in_cache"]:
-            # First fused chunk: the admission's [0, done) KV moves
-            # from the serial row into the shared cache row, where
-            # the fused forward reads and extends it.
-            self.cache = {kk: self.cache[kk].at[:, slot].set(
-                st["row"][kk][:, 0]) for kk in self.cache}
-            st["row"] = None
-            st["in_cache"] = True
-        toks = fused_token_batch(self.last_token, st["prompt"],
-                                 done, end, width, slot)
-        pos = self.lengths.at[slot].set(done)
-        mkw = ({"mlora_idx": self._ml.dev} if self._ml.enabled else {})
-        logits, self.cache = self._decode(
-            self.params, toks, cache=self.cache, pos_offset=pos, **mkw)
-        st["done"] = end
-        final = end >= S
-        if not final:
-            # Keep the in-cache admission's length at its write
-            # frontier (see admit_step): a plain tick's junk write for
-            # this row must land where the next chunk overwrites it.
-            self.lengths = self.lengths.at[slot].set(end)
-            self._lengths_np[slot] = end
-        if final:
-            # Admission pick before the decode pick: matches the
-            # serial engine order (advance-admissions, then step) on
-            # the sampler's key stream.
-            first = self._pick(logits[slot:slot + 1, S - 1 - done]
-                               ).astype(jnp.int32)
-        nxt = self._pick(logits[:, 0]).astype(jnp.int32)
-        self.lengths = self.lengths + self._active_dev.astype(jnp.int32)
-        self.last_token = jnp.where(self._active_dev[:, None],
-                                    nxt[:, None], self.last_token)
-        self._lengths_np[self.active] += 1
-        decode_slots = [int(s) for s in np.nonzero(self.active)[0]]
-        for s in decode_slots:
-            if int(self._lengths_np[s]) >= self.max_len:
-                self.active[s] = False
-        if final:
-            # Activation is dispatch-side device work: the slot's
-            # first token stays on device (first[0] indexes the
-            # device array, no fetch) until finalize.
-            del self._admissions[slot]
-            self.lengths = self.lengths.at[slot].set(S)
-            self._lengths_np[slot] = S
-            self.last_token = self.last_token.at[slot, 0].set(first[0])
-            self.active[slot] = True
-        self._active_dev = jnp.array(self.active)
-        out_slots = decode_slots + ([slot] if final else [])
-
-        def _finalize(invalid):
-            self.device_fetches += 1
-            if final:
-                nxt_np, first_np = addressable_fetch((nxt, first))
-            else:
-                nxt_np = addressable_fetch(nxt)
-            out: Dict[int, int] = {}
-            for s in decode_slots:
-                if s not in invalid:
-                    out[s] = int(nxt_np[s])
-            if final and slot not in invalid:
-                out[slot] = int(first_np[0])
-            return out
-
-        return PendingStep(_finalize, slots=out_slots)
-
-    def evict(self, slot: int) -> None:
-        self._admissions.pop(slot, None)   # cancel mid-chunked admit
-        self.active[slot] = False
-        self._active_dev = jnp.array(self.active)
-        self.lengths = self.lengths.at[slot].set(0)
-        self._lengths_np[slot] = 0
-        if self._ml.enabled:
-            self._ml.reset(slot)

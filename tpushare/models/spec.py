@@ -12,8 +12,8 @@ rotted twice (ROADMAP item 5). This module is the single home now:
   ``greedy_accept_core`` (longest matched prefix + capacity clamp),
   ``draft_sample_core`` (one filtered draft proposal + its law) and
   ``spec_accept_core`` (the Leviathan/Chen stochastic rejection rule,
-  per-slot or lockstep). The generate-level loops, the paged slot
-  server, and the MoE slot server all call exactly these.
+  per-slot or lockstep). The generate-level loops and the paged slot
+  server call exactly these.
 - **The round driver** (``SpecDecodeMixin._spec_step``): the one
   implementation of a speculative round — h = gamma × horizon draft
   proposals, the draft-KV catch-up write, ONE multi-token target
@@ -21,9 +21,8 @@ rotted twice (ROADMAP item 5). This module is the single home now:
   round's single device→host fetch (tokens + accepted counts) —
   parameterized by a small per-family hook surface
   (``_spec_draft_step`` / ``_spec_draft_catchup`` / ``_spec_verify``
-  / ``_spec_commit`` + state accessors). PagedSlotServer and
-  MoESlotServer implement the hooks; their ``_spec_step`` IS this
-  method.
+  / ``_spec_commit`` + state accessors). PagedSlotServer implements
+  the hooks; its ``_spec_step`` IS this method.
 
 Draft horizons (the longer-horizon mode): ``spec_horizon=K`` scales
 the drafted block to ``gamma*K`` tokens per round — one target weight

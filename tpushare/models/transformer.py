@@ -749,9 +749,8 @@ def _chunk_prefill_fwd(cfg: "TransformerConfig", attn_impl: str,
 
 def _chunked_prefill_loop(fwd_light, fwd_full, params, tokens, cache,
                           chunk: int, last_pos: int):
-    """THE chunked-prefill loop (one copy — serving.SlotServer.admit
-    shares it): run ``tokens`` [B, S] through fixed ``chunk`` slices,
-    returning (logit row at ``last_pos`` [B, V], cache).
+    """THE chunked-prefill loop (one copy): run ``tokens`` [B, S]
+    through fixed ``chunk`` slices, returning (logit row at ``last_pos`` [B, V], cache).
 
     Only the piece CONTAINING ``last_pos`` runs ``fwd_full`` (full
     per-position logits, [B, chunk, V] once); every other piece runs
