@@ -699,7 +699,9 @@ def kernels_child(preset: str, platform: str) -> int:
         c = srv.cache
         n_dc = srv._decode.lower(
             params, srv.last_token, c.pool_k, c.pool_v, c.block_table,
-            c.lengths, srv._active_dev, pool_k_scale=None,
+            c.lengths, srv._active_dev,
+            np.full((c.n_slots, 1), -1, np.int32),  # the tick's growth
+            pool_k_scale=None,
             pool_v_scale=None).as_text().count("tpu_custom_call")
         res["mosaic_calls"] = {"prefill": n_pf, "decode": n_dc}
         say(f"Mosaic custom calls in the server's lowered programs: "

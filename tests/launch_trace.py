@@ -1,0 +1,117 @@
+"""What a slot server's tick makes the device do before its launch,
+read off a CPU ``jax.profiler`` session the way the benchmark reads
+``slot.programs_per_tick`` off the chip's: every program the host hands
+the runtime is an event on the calling thread, whether it went through
+Python or through jit's C++ fast path, which no patch of a Python
+function sees (an eager ``x.at[i].set(v)`` is eight such programs).
+
+    with launch_trace.Session() as ticks:
+        with ticks.tick("crossing"):
+            srv.step()
+    ticks["crossing"] == {"programs": ["paged_decode"], "uploads": 0,
+                          "arguments": 1}
+
+A tick's reading covers its ``tick`` span's start to the end of the
+``tpushare.slot.launch`` span inside it:
+
+- ``programs``: the names of the programs executed, in order
+  (``PjitFunction(<name>)`` calls that reached the runtime);
+- ``uploads``: explicit host-to-device copies made outside any program
+  call (``jnp.asarray`` and friends of a numpy array);
+- ``arguments``: host values uploaded inside a program's call (its
+  numpy arguments): on the chip each is a copy the launch waits for.
+
+Run every shape once before the session: a first call compiles, and
+its cache miss runs programs of its own.
+"""
+
+import glob
+import tempfile
+
+import jax
+import numpy as np
+
+#: one a program the CPU client runs
+_EXECUTE = "PjRtCpuExecutable::Execute"
+#: jit's C++ entry, named after the jitted function
+_CALL = "PjitFunction("
+#: an explicit device_put
+_UPLOAD = "DevicePutWithSharding"
+#: a host value among a call's arguments
+_ARGUMENT = "DevicePut"
+_TICK = "launch_trace.tick:"
+_LAUNCH = "tpushare.slot.launch"
+
+
+class Session(dict):
+    """{label: {"programs": [...], "uploads": n, "arguments": n}},
+    filled on exit."""
+
+    def __enter__(self):
+        self._labels = []
+        self._tmp = tempfile.TemporaryDirectory()
+        opts = jax.profiler.ProfileOptions()
+        opts.python_tracer_level = 0
+        opts.host_tracer_level = 2
+        jax.profiler.start_trace(self._tmp.name, profiler_options=opts)
+        return self
+
+    def tick(self, label: str):
+        """The span one tick runs under; a label once a session."""
+        assert label not in self._labels, label
+        self._labels.append(label)
+        return jax.profiler.TraceAnnotation(_TICK + label)
+
+    def __exit__(self, exc_type, exc, tb):
+        try:
+            jax.profiler.stop_trace()
+            if exc_type is None:
+                self._read()
+        finally:
+            self._tmp.cleanup()
+
+    def _read(self):
+        path = sorted(glob.glob(
+            f"{self._tmp.name}/plugins/profile/*/*.xplane.pb"))[-1]
+        data = jax.profiler.ProfileData.from_file(path)
+        for plane in data.planes:
+            if not plane.name.startswith("/host:"):
+                continue
+            for line in plane.lines:
+                evs = [(e.name, e.start_ns, e.start_ns + e.duration_ns)
+                       for e in line.events]
+                for name, t0, t1 in evs:
+                    if name.startswith(_TICK):
+                        self[name[len(_TICK):]] = _reading(
+                            [e for e in evs if t0 <= e[1] < t1])
+        assert sorted(self) == sorted(self._labels), (
+            sorted(self), self._labels)
+
+
+def _reading(inside):
+    launches = [e for e in inside if e[0] == _LAUNCH]
+    assert len(launches) == 1, launches
+    upto = launches[0][2]
+    calls = sorted((e for e in inside
+                    if e[0].startswith(_CALL) and e[1] < upto),
+                   key=lambda e: e[1])
+    programs = []
+    for _, s, _e in sorted((e for e in inside
+                            if e[0] == _EXECUTE and e[1] < upto),
+                           key=lambda e: e[1]):
+        # the innermost call around an execution names its program
+        around = [c for c in calls if c[1] <= s < c[2]]
+        programs.append(around[-1][0][len(_CALL):-1] if around else "?")
+    return {"programs": programs,
+            "uploads": sum(e[0] == _UPLOAD and e[1] < upto for e in inside),
+            "arguments": sum(e[0] == _ARGUMENT and e[1] < upto
+                             for e in inside)}
+
+
+def tables_agree(srv):
+    """The device block table and lengths against the host mirrors:
+    what every tick of these tests must leave true."""
+    np.testing.assert_array_equal(np.asarray(srv.cache.block_table),
+                                  srv.cache.host_table())
+    np.testing.assert_array_equal(np.asarray(srv.cache.lengths),
+                                  srv.cache.host_lengths())

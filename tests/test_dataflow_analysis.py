@@ -215,8 +215,8 @@ def test_dn_real_paged_tree_donates_and_stays_clean():
                 and node.name == "PagedSlotServer"):
             handles = dataflow.class_jit_handles(node)
     donating = {n for n, i in handles.items() if i.donates}
-    assert donating == {"_decode", "_verify",
-                        "_draft_decode", "_draft_verify"}, handles
+    assert donating == {"_decode", "_fused", "_verify",
+                        "_draft_decode", "_draft_fused"}, handles
     assert all(handles[n].donate_idx == frozenset({2, 3})
                for n in donating)
     assert analyze_file(path, CONFIG, rules=rules_of("DN"),

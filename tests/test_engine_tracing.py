@@ -304,15 +304,21 @@ def test_the_paged_programs_carry_stable_names(draft, family):
     toks = jnp.zeros((2, 1), jnp.int32)
     active = jnp.ones((2,), bool)
     decode = srv._draft_decode if draft else srv._decode
-    fused = srv._draft_verify if draft else srv._verify
+    fused = srv._draft_fused if draft else srv._fused
     prefill = srv._draft_prefill if draft else srv._prefill
     text = decode.lower(params, toks, c.pool_k, c.pool_v, c.block_table,
                         c.lengths, active).as_text()
     assert f"module @jit_{pre}paged_decode " in text
-    text = fused.lower(params, jnp.zeros((2, 8), jnp.int32), c.pool_k,
-                       c.pool_v, c.block_table, c.lengths,
-                       active).as_text()
+    text = fused.lower(params, toks, c.pool_k, c.pool_v, c.block_table,
+                       c.lengths, active, np.full((2, 1), -1, np.int32),
+                       np.zeros((8,), np.int32), np.int32(1), np.int32(0),
+                       np.int32(8)).as_text()
     assert f"module @jit_{pre}paged_fused " in text
+    if not draft:       # a speculative round's verify: the same forward
+        text = srv._verify.lower(params, jnp.zeros((2, 8), jnp.int32),
+                                 c.pool_k, c.pool_v, c.block_table,
+                                 c.lengths, active).as_text()
+        assert "module @jit_paged_fused " in text
     row = init_cache(cfg, 1, 16)
     text = prefill.lower(params, jnp.zeros((1, 16), jnp.int32), cache=row,
                          pos_offset=0).as_text()

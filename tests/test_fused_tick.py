@@ -170,9 +170,9 @@ class TestDispatchCount:
         return counts
 
     @pytest.mark.parametrize("family,fwd_names", [
-        (family, ("_decode", "_prefill", "_verify")) for family in
-        ("paged", "paged_kvq", "paged_spec", "paged_moe",
-         "paged_moe_spec")])
+        (family, ("_decode", "_prefill", "_verify", "_fused"))
+        for family in ("paged", "paged_kvq", "paged_spec", "paged_moe",
+                       "paged_moe_spec")])
     def test_one_forward_per_fused_tick(self, family, fwd_names):
         srv = FAMILIES[family]()
         srv.admit(_prompt(1, 6, (MOE_CFG if "moe" in family

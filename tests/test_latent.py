@@ -18,6 +18,7 @@ from tpubench.families import latent as fam
 from tpubench.references import latent as ref
 from tpushare.models import latent
 from tpushare.models.latent import LatentSlotServer
+from tests.launch_trace import Session, tables_agree
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 TOL = 2e-5          # float32 on both sides
@@ -367,6 +368,123 @@ def test_counters_follow_what_was_served(toy):
     assert srv.family_stats()["expert_tokens"] - before == cfg.n_moe
     srv.evict(slot)
     assert srv.family_stats()["latent_rows_live"] == {"full": 0, "sliding": 0}
+
+
+def _growth_scenario(srv, tick):
+    """Lengths 31, 30, 29 on blocks of 16: a tick where no slot crosses
+    a block boundary, one where one does, a fused tick beside a
+    crossing, then 31, 31, 31 for a tick where every slot crosses."""
+    slots = [srv.admit(jnp.asarray(prompt_of(n, seed=n), jnp.int32))
+             for n in (31, 30, 29)]
+    tick("none")                                # 31 30 29
+    tick("one")                                 # 32 31 30
+    a = srv.admit_start(jnp.asarray(prompt_of(100, seed=7), jnp.int32),
+                        chunk_tokens=48)
+    tick("fused", a)                            # 33 32 31
+    for s in slots + [a]:
+        srv.evict(s)
+    for seed in (1, 2, 3):
+        srv.admit(jnp.asarray(prompt_of(31, seed=seed), jnp.int32))
+    tick("full")                                # 31 31 31
+    tick("every")                               # 32 32 32
+    for s in range(srv.cache.n_slots):
+        srv.evict(s)
+
+
+@pytest.fixture(scope="module")
+def launch_readings(toy):
+    """One traced session of the scenario on a server that has run it
+    once already (every shape compiled)."""
+    _, cfg, params = toy
+    srv = LatentSlotServer(params, cfg, n_slots=4, n_blocks=160,
+                           block_size=16, max_blocks_per_slot=24)
+    _growth_scenario(srv, lambda label, work=None:
+                     srv.step(prefill_work=work))
+    grown = {}
+    with Session() as ticks:
+        def tick(label, work=None):
+            before = (srv.growth_ticks, srv.blocks_grown)
+            with ticks.tick(label):
+                srv.step(prefill_work=work)
+            grown[label] = (srv.growth_ticks - before[0],
+                            srv.blocks_grown - before[1])
+            tables_agree(srv)
+        _growth_scenario(srv, tick)
+    return ticks, grown
+
+
+@pytest.mark.parametrize("label,program,blocks", [
+    ("none", "paged_decode", 0), ("one", "paged_decode", 1),
+    ("every", "paged_decode", 3), ("fused", "paged_fused", 1)])
+def test_a_tick_runs_no_eager_operation_ahead_of_its_launch(
+        launch_readings, label, program, blocks):
+    """Block growth, the chunk and its three scalars ride the family's
+    own decode and fused programs (ISSUE 31)."""
+    ticks, grown = launch_readings
+    # a tick that grows nothing uploads nothing; the others their
+    # growth array, and a fused tick its chunk and three scalars too
+    arguments = {"none": 0, "fused": 5}.get(label, 1)
+    assert ticks[label] == {"programs": [program], "uploads": 0,
+                            "arguments": arguments}
+    assert grown[label] == (int(blocks > 0), blocks)
+
+
+def test_the_device_table_is_the_host_mirror_after_every_tick(toy):
+    """Whole, chunked and fused admissions, evictions and a re-admission
+    into the freed slot between ticks; every stream's last decode step
+    reads the reference's logits."""
+    config, cfg, params = toy
+    srv, seen = server(cfg, params, n_slots=3)
+    prompts, toks = {}, {}
+    pending = None
+
+    def admit(seed, n, chunked=False):
+        nonlocal pending
+        p = prompt_of(n, seed=seed)
+        if chunked:
+            slot = pending = srv.admit_start(jnp.asarray(p, jnp.int32),
+                                             chunk_tokens=48)
+            toks[slot] = []
+        else:
+            slot = srv.admit(jnp.asarray(p, jnp.int32))
+            toks[slot] = [int(srv.last_token[slot, 0])]
+        prompts[slot] = p
+        return slot
+
+    def tick():
+        nonlocal pending
+        out = srv.step(prefill_work=pending)
+        tables_agree(srv)
+        for slot, tok in out.items():
+            toks[slot].append(tok)
+        if pending in out:
+            pending = None
+
+    def close(slot):
+        """The slot's last decode pick against the reference, then
+        evict."""
+        tick()
+        want, _ = fam.forward_with_margins(
+            params, prompts[slot] + toks[slot][:-1], config)
+        assert reference.relative_error(seen[-1][slot], want[-1]) < TOL
+        srv.evict(slot)
+        toks.pop(slot)
+        tables_agree(srv)
+
+    a, b = admit(1, 45), admit(2, 30)
+    for _ in range(4):                          # b crosses at 32, a at 48
+        tick()
+    c = admit(3, 120, chunked=True)             # fused beside a and b
+    while pending is not None:
+        tick()
+    close(a)
+    d = admit(4, 61)                            # a's slot again
+    assert d == a
+    for _ in range(5):                          # d crosses at 64
+        tick()
+    close(b), close(c), close(d)
+    assert srv.cache.live_blocks() == 0
+    assert srv.growth_ticks >= 3 and srv.blocks_grown >= srv.growth_ticks
 
 
 def test_what_the_family_does_not_serve_is_refused(toy):

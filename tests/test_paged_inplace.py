@@ -52,19 +52,25 @@ def _family(sparse: bool):
 
 
 def _lowered(srv, program: str):
-    """The server's own jitted decode or fused program, lowered at its
-    own shapes."""
+    """The server's own jitted program, lowered at its own shapes: a
+    plain tick's (block growth riding it), a fused tick's, or a
+    speculative round's verify."""
     c = srv.cache
-    fn, width = ((srv._decode, 1) if program == "decode"
-                 else (srv._verify, 4))
+    grow = np.full((c.n_slots, 1), -1, np.int32)
+    fn, width, tail = {
+        "decode": (srv._decode, 1, (grow,)),
+        "fused": (srv._fused, 1, (grow, np.zeros((4,), np.int32),
+                                  np.int32(0), np.int32(0), np.int32(4))),
+        "verify": (srv._verify, 4, ()),
+    }[program]
     return fn.lower(srv.params, jnp.zeros((c.n_slots, width), jnp.int32),
                     c.pool_k, c.pool_v, c.block_table, c.lengths,
-                    jnp.ones((c.n_slots,), bool),
+                    jnp.ones((c.n_slots,), bool), *tail,
                     pool_k_scale=c.pool_k_scale,
                     pool_v_scale=c.pool_v_scale)
 
 
-@pytest.mark.parametrize("program", ("decode", "verify"))
+@pytest.mark.parametrize("program", ("decode", "fused", "verify"))
 @pytest.mark.parametrize("family,kv_quant", (
     ("dense", False), ("dense", True), ("sparse", False)),
     ids=("dense-fp", "dense-kvq", "sparse-fp"))
@@ -143,9 +149,9 @@ def pool_sized_moves(hlo: str, layer_elems: int, n_layers: int):
                                   "mixtral8x7b-l4.chat-batch"))
 def test_the_chat_decode_program_moves_no_pool_on_a_v5e(
         cell, one_chip, no_compile_cache, monkeypatch):
-    """The decode step of both chat cells at their real widths and
-    pools, compiled by the chip's own compiler with the paged kernel on
-    its path (the dispatch asks the backend, which is the CPU here: the
+    """The decode step of both chat cells (block growth riding it) at
+    their real widths and pools, compiled by the chip's own compiler
+    with the paged kernel on its path (the dispatch asks the backend, which is the CPU here: the
     test answers for it)."""
     sparse = cell.startswith("mixtral")
     kw = dict(vocab_size=32000, d_model=4096, n_heads=32, n_kv_heads=8,
@@ -168,12 +174,12 @@ def test_the_chat_decode_program_moves_no_pool_on_a_v5e(
                        jax.random.PRNGKey(0)))
     pool = sds((cfg.n_layers, n_blocks, 16, 8 * 128), jnp.bfloat16)
     step = jax.jit(paged._program(
-        "paged_decode", paged.decode_core, cfg=cfg, block_size=16,
+        "paged_decode", paged.tick_decode, cfg=cfg, block_size=16,
         forward_fn=fwd), donate_argnums=(2, 3))
     compiled = step.lower(
         params, sds((slots, 1), jnp.int32), pool, pool,
         sds((slots, 128), jnp.int32), sds((slots,), jnp.int32),
-        sds((slots,), jnp.bool_)).compile()
+        sds((slots,), jnp.bool_), sds((slots, 1), jnp.int32)).compile()
     hlo = compiled.as_text()
     assert hlo.count("tpu_custom_call") >= 1
     one_layer = n_blocks * 16 * 8 * 128

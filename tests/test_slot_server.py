@@ -18,6 +18,7 @@ from tpushare.models import moe, quant
 from tpushare.models import transformer as tf
 from tpushare.models.generate import generate
 from tpushare.models.paged import PagedSlotServer
+from tests.launch_trace import Session, tables_agree
 
 TF_CFG = tf.tiny(remat=False)
 MOE_CFG = moe.tiny(remat=False)
@@ -359,3 +360,267 @@ class TestInt8Weights:
         for s, p in ((s0, p0), (s1, p1)):
             assert got[s] == _reference(family, p, 7, params=qp,
                                         layers_hook=hook), s
+
+
+class TestATickLaunchesOneProgram:
+    """Block growth and the fused tick's batch ride the step's own
+    program (ISSUE 31): between a tick's entry and its launch the host
+    runs no device operation, the device table follows the host mirror
+    through admissions, evictions and re-admissions, and a failure on
+    either side of the host half leaves both consistent."""
+
+    @staticmethod
+    def _scenario(srv, family, tick):
+        """Lengths 7, 6, 5 on blocks of 4: a tick where no slot crosses
+        a block boundary, one where one does, a fused tick beside a
+        crossing, then 7, 7, 7 for a tick where every slot crosses."""
+        slots = [srv.admit(_prompt(family, 20 + n, n)) for n in (7, 6, 5)]
+        tick("none")                            # 7 6 5: blocks all there
+        tick("one")                             # 8 7 6: the first crosses
+        a = srv.admit_start(_prompt(family, 30, 21), chunk_tokens=8)
+        tick("fused", a)                        # 9 8 7: the second, fused
+        for s in slots + [a]:
+            srv.evict(s)
+        for seed in (41, 42, 43):
+            srv.admit(_prompt(family, seed, 7))
+        tick("full")                            # 7 7 7
+        tick("every")                           # 8 8 8: all three cross
+        for s in range(srv.cache.n_slots):
+            srv.evict(s)
+
+    _READINGS = {}          # family -> (ticks, grown): one session each
+
+    @classmethod
+    def _readings(cls, family):
+        if family not in cls._READINGS:
+            cls._READINGS[family] = cls._traced(family)
+        return cls._READINGS[family]
+
+    @classmethod
+    def _traced(cls, family):
+        srv = _mk(family, n_slots=4, n_blocks=64)
+        cls._scenario(srv, family,          # every shape once, untraced
+                      lambda label, work=None: srv.step(prefill_work=work))
+        grown = {}
+        with Session() as ticks:
+            def tick(label, work=None):
+                before = (srv.growth_ticks, srv.blocks_grown)
+                with ticks.tick(label):
+                    srv.step(prefill_work=work)
+                grown[label] = (srv.growth_ticks - before[0],
+                                srv.blocks_grown - before[1])
+                tables_agree(srv)
+            cls._scenario(srv, family, tick)
+        return ticks, grown
+
+    @pytest.mark.parametrize("label,program,blocks", [
+        ("none", "paged_decode", 0), ("one", "paged_decode", 1),
+        ("every", "paged_decode", 3), ("fused", "paged_fused", 1)])
+    def test_no_eager_operation_ahead_of_the_launch(
+            self, family, label, program, blocks):
+        ticks, grown = self._readings(family)
+        # a tick that grows nothing uploads nothing; the others their
+        # growth array, and a fused tick its chunk and three scalars too
+        arguments = {"none": 0, "fused": 5}.get(label, 1)
+        assert ticks[label] == {"programs": [program], "uploads": 0,
+                                "arguments": arguments}
+        assert grown[label] == (int(blocks > 0), blocks)
+
+    def test_the_trace_reader_sees_an_eager_operation(self, family):
+        """The control of the test above: the eager scatter the tick
+        used to run is programs the reader counts."""
+        from tpushare.utils.profiling import span
+        table = jnp.full((4, 8), -1, jnp.int32)
+        for _ in range(2):                      # the second run is warm
+            with Session() as ticks:
+                with ticks.tick("eager"):
+                    with span("slot.launch"):
+                        table.at[np.asarray([0, 1]), np.asarray([2, 3])].set(
+                            jnp.asarray(np.asarray([5, 6], np.int32)))
+        assert "scatter" in ticks["eager"]["programs"]
+        assert len(ticks["eager"]["programs"]) > 1
+        assert ticks["eager"]["uploads"] == 1
+        assert ticks["eager"]["arguments"] > 1  # its index arrays, a call each
+
+    @pytest.mark.parametrize("variant", ("plain", "int8-pool", "speculative"))
+    def test_the_device_table_is_the_host_mirror_after_every_tick(
+            self, family, variant):
+        """Admissions (whole, chunked and fused), evictions and
+        re-admissions between ticks; greedy streams are the family's
+        reference whatever rode the program."""
+        if variant == "int8-pool" and family == "moe":
+            pytest.skip("kv_quant lives in the dense LM's forward")
+        cfg, params, _, _ = FAMILY[family]
+        kw = {"plain": {}, "int8-pool": {"kv_quant": True},
+              "speculative": {"speculative_draft": (params, cfg),
+                              "gamma": 3}}[variant]
+        srv = _mk(family, n_slots=3, n_blocks=48, **kw)
+        prompts, got = {}, {}
+        pending = None
+
+        def admit(seed, n, chunked=False):
+            nonlocal pending
+            p = _prompt(family, seed, n)
+            if chunked:
+                slot = pending = srv.admit_start(p, chunk_tokens=8)
+            else:
+                slot = srv.admit(p)
+                got[slot] = [int(srv.last_token[slot, 0])]
+            prompts[slot] = p
+            return slot
+
+        def tick():
+            nonlocal pending
+            out = srv.step(prefill_work=pending)
+            tables_agree(srv)
+            for slot, toks in out.items():
+                got.setdefault(slot, []).extend(
+                    toks if isinstance(toks, list) else [toks])
+            if pending in out:
+                pending = None
+
+        def close(slot):
+            if variant != "int8-pool":          # int8 KV is not the
+                n = len(got[slot])              # reference's arithmetic
+                assert got.pop(slot) == _reference(
+                    family, prompts[slot], n), slot
+            srv.evict(slot)
+            tables_agree(srv)
+
+        a, b = admit(1, 7), admit(2, 5)
+        for _ in range(3):
+            tick()
+        c = admit(3, 19, chunked=True)          # fused beside a and b
+        while pending is not None:
+            tick()
+        close(a)
+        d = admit(4, 6)                         # a's slot again
+        assert d == a
+        for _ in range(6):
+            tick()
+        close(b), close(c)
+        e = admit(5, 11, chunked=True)          # fused beside d alone
+        while pending is not None:
+            tick()
+        for _ in range(4):
+            tick()
+        close(d), close(e)
+        assert srv.cache.live_blocks() == 0
+        assert srv.blocks_grown > 0 and srv.growth_ticks > 0
+
+    def test_a_slot_grows_into_its_last_block_and_retires(self, family):
+        srv = _mk(family, n_slots=1, n_blocks=8, max_blocks_per_slot=2)
+        p = _prompt(family, 51, 3)              # one block; capacity 8
+        s = srv.admit(p)
+        got = [int(srv.last_token[s, 0])]
+        while srv.active[s]:
+            got.append(srv.step()[s])
+            tables_agree(srv)
+        assert int(srv.cache.host_lengths()[s]) == 8
+        assert (srv.cache.host_table()[s] >= 0).all()
+        assert srv.blocks_grown == 1
+        assert got == _reference(family, p, len(got))
+
+    def test_capacity_is_refused_with_the_host_state_intact(self, family):
+        """A slot past its last block raises before anything is popped,
+        charged or launched, though another slot wanted a block in the
+        same tick."""
+        from tpushare.models.paged import SlotCapacityExceeded
+        from tpushare.slo.quota import KvQuota
+        quota = KvQuota()
+        srv = _mk(family, n_slots=2, n_blocks=16, max_blocks_per_slot=2,
+                  kv_quota=quota)
+        full = srv.admit(_prompt(family, 52, 6), tenant="acme")
+        srv.step()                              # 7 of 8
+        p = _prompt(family, 53, 3)
+        other = srv.admit(p, tenant="beta")
+        got = [int(srv.last_token[other, 0]), srv.step()[other]]
+        assert not srv.active[full]             # 8 of 8: retired
+        srv.active[full] = True                 # what retirement prevents
+        before = (list(srv.cache.free), dict(srv.cache.refs),
+                  dict(quota.used), srv.cache.host_table().copy(),
+                  srv.growth_ticks)
+        with pytest.raises(SlotCapacityExceeded):
+            srv.step()                          # `other` is at 4: crossing
+        assert (list(srv.cache.free), dict(srv.cache.refs),
+                dict(quota.used)) == before[:3]
+        np.testing.assert_array_equal(srv.cache.host_table(), before[3])
+        assert srv.growth_ticks == before[4]
+        tables_agree(srv)
+        srv.active[full] = False
+        got += [srv.step()[other] for _ in range(2)]
+        assert quota.used["beta"] == 2          # the growth, charged once
+        assert got == _reference(family, p, 4)
+
+    def test_an_exhausted_pool_is_refused_with_the_host_state_intact(
+            self, family):
+        from tpushare.models.paged import PoolExhausted
+        srv = _mk(family, n_slots=2, n_blocks=5)    # four usable blocks
+        a, b = srv.admit(_prompt(family, 54, 7)), srv.admit(
+            _prompt(family, 55, 7))
+        srv.step()                                  # 8 and 8: both cross
+        before = (list(srv.cache.free), srv.cache.host_table().copy())
+        assert before[0] == []
+        with pytest.raises(PoolExhausted):
+            srv.step()
+        assert list(srv.cache.free) == before[0]
+        np.testing.assert_array_equal(srv.cache.host_table(), before[1])
+        tables_agree(srv)
+        srv.evict(b)
+        assert a in srv.step()
+        tables_agree(srv)
+
+    @pytest.mark.parametrize("fused", (False, True), ids=("plain", "fused"))
+    def test_a_failed_dispatch_rebuilds_the_table_from_the_mirror(
+            self, family, fused):
+        """The host half ran (block popped, mirror written) and the
+        launch raised: the device table is uploaded from the mirror and
+        the stream goes on as if the tick had only been late."""
+        srv = _mk(family, n_slots=2)
+        p = _prompt(family, 56, 7)
+        s = srv.admit(p)
+        got = [int(srv.last_token[s, 0]), srv.step()[s]]    # length 8
+        work = (srv.admit_start(_prompt(family, 57, 13), chunk_tokens=8)
+                if fused else None)
+        name = "_fused" if fused else "_decode"
+        real = getattr(srv, name)
+
+        def broken(*a, **kw):
+            raise RuntimeError("injected after the host half")
+
+        setattr(srv, name, broken)
+        free0 = len(srv.cache.free)
+        with pytest.raises(RuntimeError, match="injected"):
+            srv.step(prefill_work=work)
+        setattr(srv, name, real)
+        assert len(srv.cache.free) == free0 - 1     # the host half stands
+        assert srv.cache.host_table()[s, 2] >= 0
+        tables_agree(srv)                          # and the device has it
+        for _ in range(4):
+            got.append(srv.step(prefill_work=work
+                                if work in srv.admission_slots
+                                else None)[s])
+            tables_agree(srv)
+        assert got == _reference(family, p, len(got))
+
+    def test_a_speculative_round_grows_across_two_blocks(self, family):
+        """gamma 6 on blocks of 4: a round at length 7 writes through
+        position 13, blocks 2 and 3 of the slot, in one growth program
+        of fixed width ahead of the round's own."""
+        from tpushare.models.paged import growth_width
+        cfg, params, _, _ = FAMILY[family]
+        assert growth_width(6, BS) == 3 and growth_width(0, BS) == 1
+        srv = _mk(family, n_slots=2, n_blocks=48, gamma=6,
+                  speculative_draft=(params, cfg))
+        p = _prompt(family, 58, 7)
+        s = srv.admit(p)
+        got = [int(srv.last_token[s, 0])]
+        assert (srv.cache.host_table()[s] >= 0).sum() == 2
+        got += srv.step()[s]                        # the draft is the target
+        assert srv.blocks_grown == 2 and srv.growth_ticks == 1
+        assert (srv.cache.host_table()[s] >= 0).sum() == 4
+        tables_agree(srv)
+        while len(got) < 24:
+            got += srv.step()[s]
+            tables_agree(srv)
+        assert got == _reference(family, p, len(got))

@@ -1658,6 +1658,11 @@ class ServeEngine:
             "uptime_s": round(time.monotonic() - self._engine_t0, 1),
             "prefix_hit_tokens": srv.prefix_hit_tokens,
             "prefix_prompt_tokens": srv.prefix_prompt_tokens,
+            # Ticks whose program carried at least one new block id
+            # (a slot crossed a block boundary) and the blocks: the
+            # slot server's own counters, beside work_ticks.
+            "growth_ticks": srv.growth_ticks,
+            "blocks_grown": srv.blocks_grown,
             # Target-weight-stream forwards per engine tick that did
             # work: 1.0 is the fused-tick invariant (pre-fusion, a
             # tick advancing an admission beside its decode batch

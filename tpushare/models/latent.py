@@ -53,7 +53,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpushare.models.paged import PagedSlotServer, _program
+from tpushare.models.paged import PagedSlotServer, _program, apply_growth
 from tpushare.ops.norms import layer_norm
 from tpushare.ops.rotary import apply_rotary, rotary_embedding
 from tpushare.utils.profiling import span
@@ -681,27 +681,34 @@ class _Paged:
 
 
 def decode_tick(params, tokens, pool_k, pool_v, pool_x, table, lengths,
-                active, counts, *, cfg: LatentConfig):
-    """One token a slot. tokens [B, 1]. Returns (logits [B, 1, V],
-    pool_k, pool_v, pool_x, lengths advanced for active slots, counts),
-    and under a ``select_log`` the key positions each full layer kept
+                active, grow, counts, *, cfg: LatentConfig):
+    """One token a slot, over the table with this tick's new blocks
+    written in (``paged.apply_growth``; ``grow`` None: none). tokens
+    [B, 1]. Returns (logits [B, 1, V], pool_k, pool_v, pool_x, lengths
+    advanced for active slots, the table, counts), and under a
+    ``select_log`` the key positions each full layer kept
     [n_full, B, K]."""
+    table = apply_growth(table, lengths, grow, pool_k.shape[2])
     att = _Paged(cfg, pool_k, pool_v, pool_x, table, lengths, active)
     x, c = _run_layers(params, cfg, tokens[:, 0], lengths, active, att)
     out = (_logits(params, cfg, x)[:, None], att.pools[FULL],
            att.pools[SLIDING], att.pools["x"],
-           lengths + active.astype(jnp.int32), bump(counts, c))
+           lengths + active.astype(jnp.int32), table, bump(counts, c))
     return out if cfg.select_log is None else (*out, jnp.stack(att.kept))
 
 
 def fused_tick(params, last_token, chunk_tokens, pool_k, pool_v, pool_x,
-               table, lengths, active, slot, done, n_valid, counts, *,
+               table, lengths, active, grow, slot, done, n_valid, counts, *,
                cfg: LatentConfig, n_kblk: int):
     """A decode step for the active slots and ``chunk_tokens`` [P] of
     slot ``slot``'s prompt at positions done.. in ONE pass over the
-    weights: B + P tokens, not B x P. The chunk attends its slot's first
-    ``n_kblk`` blocks. Returns (decode logits [B, V], the logits after
-    the chunk's last real token [1, V], pool_k, pool_v, pool_x, counts)."""
+    weights: B + P tokens, not B x P, over the table with this tick's
+    new blocks written in (``grow``, as decode_tick's). The chunk
+    attends its slot's first ``n_kblk`` blocks. Returns (decode logits
+    [B, V], the logits after the chunk's last real token [1, V], pool_k,
+    pool_v, pool_x, lengths advanced for the decode rows, the table,
+    counts)."""
+    table = apply_growth(table, lengths, grow, pool_k.shape[2])
     B, P = last_token.shape[0], chunk_tokens.shape[0]
     cpos = done + jnp.arange(P)
     clive = jnp.arange(P) < n_valid
@@ -714,7 +721,8 @@ def fused_tick(params, last_token, chunk_tokens, pool_k, pool_v, pool_x,
     last = jax.lax.dynamic_slice_in_dim(x, B + n_valid - 1, 1, axis=0)
     lg = _logits(params, cfg, jnp.concatenate([x[:B], last]))
     return (lg[:B], lg[B:], att.pools[FULL], att.pools[SLIDING],
-            att.pools["x"], bump(counts, c))
+            att.pools["x"], lengths + active.astype(jnp.int32), table,
+            bump(counts, c))
 
 
 def paged_forward(params, tokens, cfg: LatentConfig, *, cache=None,
@@ -847,17 +855,17 @@ class LatentSlotServer(PagedSlotServer):
     # -- programs -----------------------------------------------------
 
     def _decode_counted(self, params, tokens, pool_k, pool_v, table,
-                        lengths, active, pool_k_scale=None,
+                        lengths, active, grow=None, pool_k_scale=None,
                         pool_v_scale=None):
-        logits, pk, pv, px, new_lengths, self._counts, *kept = (
+        logits, pk, pv, px, new_lengths, table, self._counts, *kept = (
             self._decode_prog(params, tokens, pool_k, pool_v,
                               self.cache.pool_x, table, lengths, active,
-                              self._counts))
+                              grow, self._counts))
         # the parent rebinds the two pools it knows; the third here
         self.cache = dataclasses.replace(self.cache, pool_x=px)
         if kept:
             self.cfg.select_log.step = (lengths, active, tokens, kept[0])
-        return logits, pk, pv, None, None, new_lengths
+        return logits, pk, pv, None, None, new_lengths, table
 
     #: the width a fused chunk shorter than the server's chunk runs at:
     #: a prompt's tail is padded up to it, so a daemon builds two fused
@@ -870,7 +878,7 @@ class LatentSlotServer(PagedSlotServer):
     #: and a partial prefix hit would build programs inside a window
     lean_admission = True
 
-    def _fused_forward(self, slot, st, done, end, width, final):
+    def _fused_forward(self, slot, st, done, end, width, final, grow):
         bs = self.cache.block_size
         width = (self.FUSED_TAIL if width <= self.FUSED_TAIL
                  else max(width, st["chunk"]))
@@ -880,15 +888,18 @@ class LatentSlotServer(PagedSlotServer):
         # tokens at 16 a block): a program a step, not a key length
         n_kblk = min(self.cache.max_blocks,
                      -(-(done + width) // (256 * bs)) * 256)
-        nxt, first, pk, pv, px, self._counts = self._pools_dispatch(
-            self._fused_prog, self.params, self.last_token,
-            jnp.asarray(chunk), self.cache.pool_k, self.cache.pool_v,
-            self.cache.pool_x, self.cache.block_table, self.cache.lengths,
-            self._active_dev, jnp.int32(slot), jnp.int32(done),
-            jnp.int32(end - done), self._counts, n_kblk=n_kblk)
-        lengths = self.cache.lengths + self._active_dev.astype(jnp.int32)
-        self.cache = dataclasses.replace(self.cache, pool_k=pk, pool_v=pv,
-                                         pool_x=px, lengths=lengths)
+        # the chunk, the scalars and the growth array are host values:
+        # the call uploads them, and no eager operation runs ahead of it
+        nxt, first, pk, pv, px, lengths, table, self._counts = (
+            self._pools_dispatch(
+                self._fused_prog, self.params, self.last_token, chunk,
+                self.cache.pool_k, self.cache.pool_v, self.cache.pool_x,
+                self.cache.block_table, self.cache.lengths,
+                self._active_dev, grow, np.int32(slot), np.int32(done),
+                np.int32(end - done), self._counts, n_kblk=n_kblk))
+        self.cache = dataclasses.replace(
+            self.cache, pool_k=pk, pool_v=pv, pool_x=px,
+            block_table=table, lengths=lengths)
         return nxt, (first if final else None)
 
     def admit_step(self, slot: int, max_chunk_tokens: Optional[int] = None):

@@ -680,6 +680,72 @@ def verify_core(params, tokens, pool_k, pool_v, table, lengths, active,
             new_cache.get("pool_k_scale"), new_cache.get("pool_v_scale"))
 
 
+def growth_width(extra: int, block_size: int) -> int:
+    """How many blocks a slot can newly need to write positions
+    length .. length+extra: the columns of ``_grow_active``'s array (1
+    for a plain tick)."""
+    return -(-extra // block_size) + 1
+
+
+def apply_growth(table, lengths, grow, block_size: int):
+    """The block table with a tick's new blocks written in, inside the
+    tick's own program: ``grow`` [n_slots, w] int32 holds, a slot, the
+    ids of blocks ``lengths[slot] // block_size`` + 0..w-1 that the
+    host just allocated (-1 = none: the block is there already, or the
+    slot is not growing). The host half is
+    ``PagedSlotServer._grow_active``, which wrote the same ids into the
+    host mirror; ``None`` leaves a table someone else grew as it is."""
+    if grow is None:
+        return table
+    n, mb = table.shape
+    col = (lengths // block_size)[:, None] + jnp.arange(grow.shape[1])
+    # -1 entries aim past the row and are dropped
+    return table.at[jnp.arange(n)[:, None],
+                    jnp.where(grow >= 0, col, mb)].set(grow, mode="drop")
+
+
+def tick_decode(params, tokens, pool_k, pool_v, table, lengths, active,
+                grow=None, *, pool_k_scale=None, pool_v_scale=None,
+                mlora_idx=None, **static):
+    """The plain tick as ONE program: this tick's block growth
+    (``apply_growth``), then ``decode_core`` (whose keywords ``static``
+    carries) over the grown table. Returns decode_core's six and the
+    table."""
+    table = apply_growth(table, lengths, grow, pool_k.shape[2])
+    return (*decode_core(params, tokens, pool_k, pool_v, table, lengths,
+                         active, pool_k_scale=pool_k_scale,
+                         pool_v_scale=pool_v_scale, mlora_idx=mlora_idx,
+                         **static), table)
+
+
+def tick_fused(params, last_token, pool_k, pool_v, table, lengths, active,
+               grow, chunk, slot, done, n_valid, *, pool_k_scale=None,
+               pool_v_scale=None, mlora_idx=None, **static):
+    """The fused tick as ONE program. Everything the forward needs is
+    built here, from host values, not ahead of the launch: the grown
+    table, the [B, width] token batch (``serving.fused_token_batch``: ``chunk`` [width] is
+    prompt[done:done+n_valid] of the admitting ``slot``, zero-padded on
+    the host), the positions (the cache lengths, ``done`` for the
+    admitting slot), the write mask (the decode rows and the admitting
+    slot, whose table row is reserved; every other row writes to the
+    trash block) and the decode rows' length advance; ``static``
+    carries verify_core's keywords. Returns (decode logits [B, V], the
+    logits after the chunk's last real token [1, V], verify_core's four
+    pools, lengths, table)."""
+    from tpushare.models.serving import fused_token_batch
+    width = chunk.shape[0]
+    table = apply_growth(table, lengths, grow, pool_k.shape[2])
+    toks = fused_token_batch(last_token, chunk, 0, width, width, slot)
+    logits, pk, pv, pks, pvs = verify_core(
+        params, toks, pool_k, pool_v, table, lengths.at[slot].set(done),
+        active.at[slot].set(True), pool_k_scale=pool_k_scale,
+        pool_v_scale=pool_v_scale, mlora_idx=mlora_idx, **static)
+    first = jax.lax.dynamic_slice(
+        logits, (slot, n_valid - 1, 0), (1, 1, logits.shape[2]))[:, 0]
+    return (logits[:, 0], first, pk, pv, pks, pvs,
+            lengths + active.astype(jnp.int32), table)
+
+
 # The speculation cores moved to models/spec.py — the ONE seam every
 # family (dense loops, paged slots, MoE slots) shares. draft_sample/
 # spec_accept stay re-exported here because they were this module's
@@ -1088,9 +1154,18 @@ class PagedSlotServer(SpecDecodeMixin):
         # and nothing else holds a pool reference — DN601/DN602 police
         # exactly this surface); a PagedCache snapshot from before a
         # tick was already invalidated by the host-mirror contract.
+        #
+        # A tick launches ONE program: what it changes on the device
+        # ahead of its forward (the new block ids, the fused tick's
+        # token batch, positions and write mask) is an argument built
+        # on the host in numpy at a fixed shape and applied inside the
+        # program (tick_decode, tick_fused), which hands back the
+        # grown table beside the pools. The table is not donated: it
+        # is small, and snapshots of it (the draft's view, the prefix
+        # cache's) stay valid.
         self._decode = jax.jit(_program(
             "paged_decode",
-            decode_core, cfg=cfg, block_size=block_size,
+            tick_decode, cfg=cfg, block_size=block_size,
             attn_impl=attn_impl, layers_hook=layers_hook,
             mlora_scale=mlora_scale, forward_fn=forward_fn),
             donate_argnums=(2, 3),
@@ -1099,9 +1174,17 @@ class PagedSlotServer(SpecDecodeMixin):
             "paged_prefill",
             base_fwd, cfg=cfg, attn_impl=attn_impl,
             layers_hook=layers_hook, mlora_scale=mlora_scale))
-        # The multi-token paged forward (verify_core) is also the
-        # fused engine tick's dispatch: decode rows contribute 1 token
-        # each, the admitting slot its next chunk — one weight stream.
+        # The fused engine tick: decode rows contribute 1 token each,
+        # the admitting slot its next chunk — one weight stream.
+        self._fused = jax.jit(_program(
+            "paged_fused",
+            tick_fused, cfg=cfg, attn_impl=attn_impl,
+            layers_hook=layers_hook, mlora_scale=mlora_scale,
+            forward_fn=forward_fn),
+            donate_argnums=(2, 3),
+            donate_argnames=("pool_k_scale", "pool_v_scale"))
+        # The multi-token paged forward a speculative round verifies
+        # with (the fused tick's forward under the fused tick's name).
         self._verify = jax.jit(_program(
             "paged_fused",
             verify_core, cfg=cfg, attn_impl=attn_impl,
@@ -1109,6 +1192,23 @@ class PagedSlotServer(SpecDecodeMixin):
             forward_fn=forward_fn),
             donate_argnums=(2, 3),
             donate_argnames=("pool_k_scale", "pool_v_scale"))
+        # A speculative round's draft and verify programs run several
+        # times over one table, so its growth is a program of its own
+        # at the round's start (_spec_begin).
+        self._grow = jax.jit(_program(
+            "paged_grow", apply_growth, block_size=block_size))
+        # Ticks whose program carried at least one new block, and the
+        # blocks: /stats growth_ticks / blocks_grown.
+        self.growth_ticks = 0
+        self.blocks_grown = 0
+        # What rides a tick that grows nothing, a width of the growth
+        # array: the same all -1 argument already on the device, so
+        # such a tick uploads nothing (an upload inside the call is
+        # some 0.4 ms ahead of the launch on a v5e: PERF.md, PR 31).
+        widths = {1} | ({growth_width(gamma * spec_horizon, block_size)}
+                        if speculative_draft is not None else set())
+        self._no_growth = {w: jnp.full((n_slots, w), -1, jnp.int32)
+                           for w in widths}
         # Speculative decoding over the paged pools: a draft LM drafts
         # gamma tokens per slot, the target verifies the whole block in
         # ONE weight stream — and unlike the dense speculative loop
@@ -1190,9 +1290,9 @@ class PagedSlotServer(SpecDecodeMixin):
             # forward mirrors the decode tokens' draft KV AND writes
             # the admission chunk's draft KV (same batch as the
             # target's fused forward — logits discarded).
-            self._draft_verify = jax.jit(_program(
+            self._draft_fused = jax.jit(_program(
                 "draft_paged_fused",
-                verify_core, cfg=draft_cfg, attn_impl=attn_impl,
+                tick_fused, cfg=draft_cfg, attn_impl=attn_impl,
                 layers_hook=draft_layers_hook, mlora_scale=mlora_scale,
                 forward_fn=dfwd_fn),
                 donate_argnums=(2, 3),
@@ -1230,7 +1330,11 @@ class PagedSlotServer(SpecDecodeMixin):
         deleted, turning the engine's quarantine-and-replay recovery
         (PR 4 contract) into an unrecoverable 'Array has been
         deleted' loop. On failure the pools are rebuilt before the
-        exception propagates, so recovery proceeds normally."""
+        exception propagates, so recovery proceeds normally. So does
+        every dispatch that carries block growth (a speculative
+        round's growth program donates nothing): the failure leaves
+        the device table behind the host mirror, and the same recovery
+        uploads it again."""
         try:
             return fn(*args, **kw)
         except Exception:
@@ -1247,7 +1351,13 @@ class PagedSlotServer(SpecDecodeMixin):
         pools, and a later admit hitting a zeroed block would be
         silent corruption (zero-ref LRU blocks return to the free
         list; referenced published blocks lose their chain so release
-        frees them instead of parking garbage on the LRU)."""
+        frees them instead of parking garbage on the LRU).
+
+        The block table too: a tick's growth reaches the device table
+        inside the tick's program, so a dispatch that raised after
+        ``_grow_active``'s host half left the device table behind the
+        host mirror. The mirror is the truth; the table is uploaded
+        from it (by copy: the mirror is mutated in place)."""
         c = self.cache
         repl = {}
         for pf, _ in _row_pairs(c):
@@ -1263,7 +1373,8 @@ class PagedSlotServer(SpecDecodeMixin):
             c.lru.clear()
             c.index.clear()
             c.chains.clear()
-            self.cache = dataclasses.replace(c, **repl)
+        self.cache = dataclasses.replace(
+            c, block_table=jnp.array(c.host_table(), jnp.int32), **repl)
         if self.speculative:
             for attr in ("_dpk", "_dpv"):
                 arr = getattr(self, attr)
@@ -1573,24 +1684,33 @@ class PagedSlotServer(SpecDecodeMixin):
         tier.clear_staged(keep=staged)
         return len(staged)
 
-    def _grow_active(self, extra: int = 0) -> None:
+    def _grow_active(self, extra: int = 0):
         """Allocate next blocks for active slots whose current length
-        crosses a block boundary — batched: host-mirror reads only (no
-        device sync), one device scatter, free-list pops on the host.
+        crosses a block boundary — the HOST half of block growth:
+        mirror reads only (no device sync), free-list pops, ``refs``,
+        quota charges and the host table, all before anything is in
+        flight, so a shortfall raises with nothing to undo on the
+        device. Returns what rides the tick's program as an argument
+        (``apply_growth`` is the device half, inside that program):
+        [n_slots, growth_width(extra)] int32, a slot's new block ids
+        from block ``length // block_size`` on, -1 where there is none;
+        a numpy array that the call uploads, or where no slot grows the
+        all -1 array the device already holds.
         ``extra``: additionally cover positions through length+extra
         (a speculative round writes gamma+1 tokens ahead), clamped at
         slot capacity — the acceptance clamp keeps lengths in range,
         and writes past the last allocated block land in the trash
         block by construction."""
+        bs = self.cache.block_size
         lengths = self.cache.host_lengths()
         table = self.cache.host_table()
         slots, bis = [], []
         for slot in np.nonzero(self.active)[0]:
-            lo = int(lengths[slot]) // self.cache.block_size
+            lo = int(lengths[slot]) // bs
             if lo >= self.cache.max_blocks:
                 raise SlotCapacityExceeded(
                     int(slot), f"slot {slot} exceeded max_blocks")
-            hi = min((int(lengths[slot]) + extra) // self.cache.block_size,
+            hi = min((int(lengths[slot]) + extra) // bs,
                      self.cache.max_blocks - 1)
             for bi in range(lo, hi + 1):
                 if table[slot, bi] >= 0:
@@ -1616,12 +1736,16 @@ class PagedSlotServer(SpecDecodeMixin):
                 self.kv_quota.charge(t, 1)
                 self._slot_charge[int(slot)] = (
                     self._slot_charge.get(int(slot), 0) + 1)
-        if slots:
-            table[np.asarray(slots), np.asarray(bis)] = ids
-            bt = self.cache.block_table.at[
-                np.asarray(slots), np.asarray(bis)].set(
-                jnp.asarray(ids, jnp.int32))
-            self.cache = dataclasses.replace(self.cache, block_table=bt)
+        width = growth_width(extra, bs)
+        if not slots:
+            return self._no_growth[width]
+        grow = np.full((self.cache.n_slots, width), -1, np.int32)
+        rows, at = np.asarray(slots), np.asarray(bis)
+        table[rows, at] = ids
+        grow[rows, at - lengths[rows] // bs] = ids
+        self.growth_ticks += 1
+        self.blocks_grown += len(ids)
+        return grow
 
     def step(self, prefill_work: Optional[int] = None,
              max_chunk_tokens: Optional[int] = None) -> Dict[int, int]:
@@ -1648,7 +1772,15 @@ class PagedSlotServer(SpecDecodeMixin):
         dispatch — so pool-pressure errors (PoolExhausted,
         SlotCapacityExceeded) raise host-side before anything is in
         flight. finalize() performs the ONE device->host fetch and
-        builds the out dict."""
+        builds the out dict.
+
+        Between this method's entry and the launch the host runs no
+        device operation: the tick's new block ids ride the step's own
+        program as a numpy argument (``_grow_active`` /
+        ``apply_growth``), which hands back the grown table with the
+        pools and the advanced lengths. The device is idle when a
+        tick is entered (the engine fetched the last one first), so
+        whatever ran here was tick time nothing overlapped."""
         from tpushare.models.serving import PendingStep
         if prefill_work is not None:
             if prefill_work not in self._admissions:
@@ -1660,25 +1792,27 @@ class PagedSlotServer(SpecDecodeMixin):
         if not self.active.any():
             return PendingStep.done({})
         with span("slot.grow"):
-            self._grow_active()
+            grow = self._grow_active()
         with span("slot.launch"):
             mkw = ({"mlora_idx": self._ml.dev} if self._ml.enabled
                    else {})
-            logits, pool_k, pool_v, pks, pvs, lengths = \
+            logits, pool_k, pool_v, pks, pvs, lengths, table = \
                 self._pools_dispatch(
                     self._decode,
                     self.params, self.last_token, self.cache.pool_k,
                     self.cache.pool_v, self.cache.block_table,
-                    self.cache.lengths, self._active_dev,
+                    self.cache.lengths, self._active_dev, grow,
                     pool_k_scale=self.cache.pool_k_scale,
                     pool_v_scale=self.cache.pool_v_scale, **mkw)
             # Rebind the donated pools IMMEDIATELY: between the
             # dispatch and this replace, self.cache.pool_k/pool_v name
             # deleted buffers (donate_argnums), and any raise in that
-            # window would leave the server holding them.
+            # window would leave the server holding them. The grown
+            # table comes back with them.
             self.cache = dataclasses.replace(
                 self.cache, pool_k=pool_k, pool_v=pool_v,
-                lengths=lengths, pool_k_scale=pks, pool_v_scale=pvs)
+                block_table=table, lengths=lengths,
+                pool_k_scale=pks, pool_v_scale=pvs)
         with span("slot.sample"):
             nxt = self._sampler.pick(logits[:, 0]).astype(jnp.int32)
             self.last_token = jnp.where(self._active_dev[:, None],
@@ -1743,11 +1877,11 @@ class PagedSlotServer(SpecDecodeMixin):
         if width == 0:
             return self.step_async()    # budget left no chunk room
         with span("slot.grow"):
-            self._grow_active()
+            grow = self._grow_active()
         final = end >= S
         with span("slot.launch"):
             nxt_logits, first_logits = self._fused_forward(
-                slot, st, done, end, width, final)
+                slot, st, done, end, width, final, grow)
         st["done"] = end
         st["row_stale"] = True
         with span("slot.sample"):
@@ -1796,50 +1930,48 @@ class PagedSlotServer(SpecDecodeMixin):
         return PendingStep(_finalize, slots=out_slots)
 
     def _fused_forward(self, slot: int, st, done: int, end: int,
-                       width: int, final: bool):
+                       width: int, final: bool, grow):
         """The fused tick's one forward: the decode rows' pending tokens
         and prompt[done:end) of the admitting slot through the pools,
-        which are rebound here. Returns (the decode rows' logits [B, V],
-        the logits after the prompt's last token [1, V] where the chunk
-        completes it, else None). The seam a family with its own fused
-        program overrides (latent.LatentSlotServer)."""
-        from tpushare.models.serving import fused_token_batch
-        S = int(st["prompt_np"].shape[0])
-        toks = fused_token_batch(self.last_token, st["prompt"],
-                                 done, end, width, slot)
-        pos = self.cache.lengths.at[slot].set(done)
-        # The admitting slot must WRITE (its table row is
-        # reserved); decode rows write their one real token;
-        # everything else routes to the trash block.
-        wmask = self._active_dev.at[slot].set(True)
+        which are rebound here with the grown table and the advanced
+        lengths. Everything the program needs of this tick rides it as
+        a host argument: ``grow`` (``_grow_active``), the chunk as a
+        numpy row at the program's width, and ``slot``, ``done`` and
+        the chunk's real length as numpy scalars; the token batch, the
+        positions, the write mask and the length advance are computed
+        inside it (``tick_fused``). Returns (the decode rows' logits
+        [B, V], the logits after the prompt's last token [1, V] where
+        the chunk completes it, else None). The seam a family with its
+        own fused program overrides (latent.LatentSlotServer)."""
+        chunk = np.zeros((width,), np.int32)
+        chunk[:end - done] = st["prompt_np"][done:end]
+        host = (chunk, np.int32(slot), np.int32(done), np.int32(end - done))
         mkw = ({"mlora_idx": self._ml.dev} if self._ml.enabled
                else {})
-        logits, pk, pv, pks, pvs = self._pools_dispatch(
-            self._verify,
-            self.params, toks, self.cache.pool_k, self.cache.pool_v,
-            self.cache.block_table, pos, wmask,
+        lengths0 = self.cache.lengths
+        nxt, first, pk, pv, pks, pvs, lengths, table = self._pools_dispatch(
+            self._fused,
+            self.params, self.last_token, self.cache.pool_k,
+            self.cache.pool_v, self.cache.block_table, lengths0,
+            self._active_dev, grow, *host,
             pool_k_scale=self.cache.pool_k_scale,
             pool_v_scale=self.cache.pool_v_scale, **mkw)
-        # Rebind donated pools immediately (see step()); lengths
-        # are not donated, so computing the advance after the
-        # replace is identical.
-        lengths = (self.cache.lengths
-                   + self._active_dev.astype(jnp.int32))
+        # Rebind donated pools immediately (see step()).
         self.cache = dataclasses.replace(
-            self.cache, pool_k=pk, pool_v=pv, lengths=lengths,
-            pool_k_scale=pks, pool_v_scale=pvs)
+            self.cache, pool_k=pk, pool_v=pv, block_table=table,
+            lengths=lengths, pool_k_scale=pks, pool_v_scale=pvs)
         if self.speculative:
             # One draft forward: decode rows mirror their pending
             # token's draft KV (a skipped write would leave a hole
             # every later draft step attends), the admitting row
-            # advances the draft chunk — same batch, logits
-            # dropped.
-            _, self._dpk, self._dpv, _, _ = self._pools_dispatch(
-                self._draft_verify,
-                self.draft_params, toks, self._dpk, self._dpv,
-                self.cache.block_table, pos, wmask, **mkw)
-        return (logits[:, 0],
-                logits[slot:slot + 1, S - 1 - done] if final else None)
+            # advances the draft chunk — same batch over the table the
+            # target's program just grew, at the lengths it started
+            # from; logits dropped.
+            _, _, self._dpk, self._dpv, *_ = self._pools_dispatch(
+                self._draft_fused,
+                self.draft_params, self.last_token, self._dpk, self._dpv,
+                table, lengths0, self._active_dev, None, *host, **mkw)
+        return nxt, (first if final else None)
 
     # -- speculation hooks (models/spec.py SpecDecodeMixin owns the
     # round driver; these supply the paged mechanics) -----------------
@@ -1847,8 +1979,15 @@ class PagedSlotServer(SpecDecodeMixin):
     def _spec_begin(self, h: int):
         """Blocks through position length+h (the round's last write:
         both the verify block's final token and the extra draft write
-        land at length+h), clamped at capacity."""
-        self._grow_active(extra=h)
+        land at length+h), clamped at capacity. The round's draft and
+        verify programs all read one table, so its growth is one
+        program of fixed shape ahead of them (``apply_growth``, the
+        function a plain tick runs inside its step)."""
+        grow = self._grow_active(extra=h)
+        self.cache = dataclasses.replace(
+            self.cache, block_table=self._pools_dispatch(
+                self._grow, self.cache.block_table, self.cache.lengths,
+                grow))
         return self.cache.lengths
 
     def _spec_mkw(self):

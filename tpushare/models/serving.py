@@ -396,7 +396,12 @@ def fused_token_batch(last_token: jnp.ndarray, prompt: jnp.ndarray,
     that; their columns >= 1 are junk whose KV the length masks keep
     unattended until real writes overwrite it), and the admitting row
     carries prompt[done:end] zero-padded to ``width``. One batch, one
-    forward, one weight stream for decode AND admission."""
+    forward, one weight stream for decode AND admission.
+
+    Traced inside the fused tick's program (paged.tick_fused), never
+    run eagerly ahead of it: there ``prompt`` is the chunk the host
+    padded to ``width`` (done 0, end ``width``) and ``slot`` a traced
+    scalar; ``done``, ``end`` and ``width`` are static."""
     B = last_token.shape[0]
     toks = jnp.zeros((B, width), jnp.int32).at[:, 0].set(last_token[:, 0])
     row = jnp.zeros((width,), jnp.int32).at[:end - done].set(
