@@ -1,7 +1,11 @@
 """The one slot server (PagedSlotServer) under both forward functions
 it runs: ``family`` is the dense LM (transformer.forward) or the sparse
 one (moe.paged_forward through the ``forward_fn`` seam), and the
-reference is that family's own row-cache ``generate``. Held for both:
+reference is that family's own row-cache ``generate``. The retention
+family (its subclass RetentionSlotServer: a recurrent state a slot, the
+blocks a token budget) is a third ``family`` of the two classes whose
+cases have meaning without blocks, against the greedy argmax of its
+plain reference. Held for both:
 streams equal independent generation, slots recycle, a slot retires at
 its capacity, sampled decode is reproducible, chunked admission equals
 whole admission and interleaves with decode, an evict cancels an
@@ -14,7 +18,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpushare.models import moe, quant
+from tpubench.references import retention as retention_reference
+from tpushare.models import moe, quant, retention
 from tpushare.models import transformer as tf
 from tpushare.models.generate import generate
 from tpushare.models.paged import PagedSlotServer
@@ -22,24 +27,51 @@ from tests.launch_trace import Session, tables_agree
 
 TF_CFG = tf.tiny(remat=False)
 MOE_CFG = moe.tiny(remat=False)
-# family -> (cfg, params, the family's reference generate, server kwargs)
+RET_CFG = retention.tiny()
+
+
+def _retention_generate(params, prompts, cfg, max_new_tokens):
+    """Greedy continuation by the plain reference's full forward: the
+    quadratic form is causal, so every step runs at one padded length
+    and reads the logits of the last real position."""
+    config = {"num_attention_heads": cfg.n_heads,
+              "num_key_value_heads": cfg.n_kv_heads,
+              "head_dim": cfg.head_dim, "rms_norm_eps": cfg.norm_eps,
+              "rope_theta": cfg.rope_base}
+    toks = [int(t) for t in prompts[0]]
+    for _ in range(max_new_tokens):
+        padded = toks + [0] * (48 - len(toks))
+        logits = retention_reference.forward(params, padded, config)
+        toks.append(int(jnp.argmax(logits[len(toks) - 1])))
+    return jnp.asarray([toks])
+
+
+# family -> (cfg, params, the family's reference generate, server kwargs,
+# the server)
 FAMILY = {
     "dense": (TF_CFG, tf.init_params(jax.random.PRNGKey(0), TF_CFG),
-              generate, {}),
+              generate, {}, PagedSlotServer),
     "moe": (MOE_CFG, moe.init_params(jax.random.PRNGKey(0), MOE_CFG),
-            moe.generate, {"forward_fn": moe.paged_forward}),
+            moe.generate, {"forward_fn": moe.paged_forward},
+            PagedSlotServer),
+    "retention": (RET_CFG,
+                  retention.init_params(jax.random.PRNGKey(0), RET_CFG),
+                  _retention_generate, {}, retention.RetentionSlotServer),
 }
 BS = 4
 
-pytestmark = pytest.mark.parametrize("family", sorted(FAMILY))
+#: the families that keep blocks of keys and values: every class; the
+#: retention family joins where a case has meaning without them
+paged_families = pytest.mark.parametrize("family", ["dense", "moe"])
+every_family = pytest.mark.parametrize("family", sorted(FAMILY))
 
 
 def _mk(family, **kw):
-    cfg, params, _, fkw = FAMILY[family]
+    cfg, params, _, fkw, server = FAMILY[family]
     kw.setdefault("n_slots", 2)
     kw.setdefault("n_blocks", 32)
     kw.setdefault("block_size", BS)
-    return PagedSlotServer(params, cfg, **fkw, **kw)
+    return server(params, cfg, **fkw, **kw)
 
 
 def _prompt(family, seed, n):
@@ -49,7 +81,7 @@ def _prompt(family, seed, n):
 
 
 def _reference(family, prompt, n, **kw):
-    cfg, params, gen, _ = FAMILY[family]
+    cfg, params, gen = FAMILY[family][:3]
     out = gen(kw.pop("params", params), prompt[None, :], cfg,
               max_new_tokens=n, **kw)
     return [int(t) for t in np.asarray(out[0, prompt.shape[0]:])]
@@ -62,6 +94,7 @@ def _stream(srv, slot, n):
     return out
 
 
+@every_family
 class TestStreamsAndSlots:
     def test_mixed_length_slots_match_independent_generation(self, family):
         srv = _mk(family, n_slots=4)
@@ -159,6 +192,7 @@ class TestStreamsAndSlots:
         assert len(srv.cache.free) == 1         # nothing leaked
 
 
+@every_family
 class TestChunkedAdmission:
     """vLLM-style chunked prefill: admit_start/admit_step must produce
     bit-identical KV and tokens to a whole-prompt admit."""
@@ -234,6 +268,7 @@ class TestChunkedAdmission:
         assert srv.admit(_prompt(family, 8, 2)) == slot
 
 
+@paged_families
 class TestPrefixCache:
     """A prefix hit is bit-identical KV reuse by whole blocks: sharing
     reduces unique pool blocks, retention survives eviction, and pool
@@ -340,11 +375,12 @@ class TestPrefixCache:
             before, np.asarray(srv.cache.pool_k[:, shared]))
 
 
+@paged_families
 class TestInt8Weights:
     def test_int8_weights_match_int8_generate(self, family):
         # The server must be bit-exact vs generate ON THE SAME int8
         # params: the serving path itself adds zero error.
-        cfg, params, _, _ = FAMILY[family]
+        cfg, params = FAMILY[family][:2]
         qp = quant.quantize_params(params, cfg)
         hook = quant.dequant_hook(cfg)
         srv = PagedSlotServer(qp, cfg, n_slots=3, n_blocks=32,
@@ -362,6 +398,7 @@ class TestInt8Weights:
                                         layers_hook=hook), s
 
 
+@paged_families
 class TestATickLaunchesOneProgram:
     """Block growth and the fused tick's batch ride the step's own
     program (ISSUE 31): between a tick's entry and its launch the host
@@ -450,7 +487,7 @@ class TestATickLaunchesOneProgram:
         reference whatever rode the program."""
         if variant == "int8-pool" and family == "moe":
             pytest.skip("kv_quant lives in the dense LM's forward")
-        cfg, params, _, _ = FAMILY[family]
+        cfg, params = FAMILY[family][:2]
         kw = {"plain": {}, "int8-pool": {"kv_quant": True},
               "speculative": {"speculative_draft": (params, cfg),
                               "gamma": 3}}[variant]
@@ -608,7 +645,7 @@ class TestATickLaunchesOneProgram:
         position 13, blocks 2 and 3 of the slot, in one growth program
         of fixed width ahead of the round's own."""
         from tpushare.models.paged import growth_width
-        cfg, params, _, _ = FAMILY[family]
+        cfg, params = FAMILY[family][:2]
         assert growth_width(6, BS) == 3 and growth_width(0, BS) == 1
         srv = _mk(family, n_slots=2, n_blocks=48, gamma=6,
                   speculative_draft=(params, cfg))

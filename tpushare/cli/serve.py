@@ -332,9 +332,11 @@ class ServeEngine:
     PagedSlotServer: ``model_family="dense"`` over
     transformer.forward, ``"moe"`` over the SAME block pool via
     moe.paged_forward, ``"latent"`` through its subclass
-    LatentSlotServer. Every family gets block-granular admission,
-    chain-keyed prefix sharing and a real free_blocks pressure
-    signal. Features with no MoE analog — kv_quant, multi-LoRA — are
+    LatentSlotServer, ``"retention"`` through RetentionSlotServer (a
+    recurrent state a slot where the others keep blocks of keys and
+    values: no prefix sharing, the pool a token budget). Every other
+    family gets block-granular admission, chain-keyed prefix sharing
+    and a real free_blocks pressure signal. Features with no MoE analog — kv_quant, multi-LoRA — are
     rejected loudly rather than silently ignored; int8 EXPERT weights
     ride ``layers_hook``."""
 
@@ -423,7 +425,18 @@ class ServeEngine:
         # flag; only (params, draft, mesh, kv_quota) vary per rebuild.
         use_prefix = True if prefix_cache is None else prefix_cache
         family_kw: Dict[str, Any] = {}
-        if model_family == "latent":
+        if model_family == "retention":
+            # Power retention (models/retention.py): a stream's past is
+            # one recurrent state, so there are no blocks to share:
+            # prefix_cache=None means off here, True is refused by the
+            # server by name, as are kv_quant, multi_lora, layers_hook,
+            # a draft and a mesh; the host KV tier below refuses itself
+            # (it needs the prefix cache). A preempted stream is
+            # replayed from its tokens, as wherever blocks are gone.
+            from tpushare.models.retention import \
+                RetentionSlotServer as server
+            use_prefix = bool(prefix_cache)
+        elif model_family == "latent":
             # Latent attention with a key selector and windowed layers
             # (models/latent.py): the paged pool, block tables, prefix
             # cache and tick of the dense family, its own two pools and
@@ -1824,8 +1837,8 @@ class ServeEngine:
             "pool_free_frac": (round(allocatable / n_total, 3)
                                if n_total else None),
         })
-        # What only the latent family counts (models/latent.py
-        # family_stats): keys the selector saw and kept, latent rows the
+        # What only one family counts (family_stats). The latent
+        # family's (models/latent.py): keys the selector saw and kept, latent rows the
         # slots hold by layer kind and those behind every window to
         # come, assignments that reached the held experts. Null for the
         # other families (null-not-zero: they have no selector, window
@@ -1841,6 +1854,18 @@ class ServeEngine:
             "expert_tokens": fam.get("expert_tokens"),
             "expert_load": fam.get("expert_load"),
             "expert_load_max": fam.get("expert_load_max"),
+            # The retention family's (models/retention.py): bytes of
+            # recurrent state the engine holds, those of active slots,
+            # those decode and fused ticks read and wrote and how many
+            # such ticks ran (counted on the host off the active mask),
+            # admission chunks run.
+            "retention_state_bytes": fam.get("retention_state_bytes"),
+            "retention_state_bytes_live":
+                fam.get("retention_state_bytes_live"),
+            "retention_state_bytes_moved":
+                fam.get("retention_state_bytes_moved"),
+            "retention_ticks": fam.get("retention_ticks"),
+            "retention_chunks": fam.get("retention_chunks"),
         })
         if srv.speculative:
             # Mean tokens per (slot, round) in [1, gamma×horizon+1] is

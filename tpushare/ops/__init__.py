@@ -17,6 +17,7 @@ from tpushare.ops.q8_expert import (
     q8_expert_dispatch, q8_expert_eligible, q8_expert_ffn,
     q8_expert_ffn_reference,
 )
+from tpushare.ops.retention import phi, retention_step
 from tpushare.ops.rotary import apply_rotary, rotary_embedding
 
 __all__ = [
@@ -24,5 +25,5 @@ __all__ = [
     "flash_attention_partial", "flash_eligible", "partial_reference",
     "layer_norm", "rms_norm", "apply_rotary", "rotary_embedding",
     "q8_expert_dispatch", "q8_expert_eligible", "q8_expert_ffn",
-    "q8_expert_ffn_reference",
+    "q8_expert_ffn_reference", "phi", "retention_step",
 ]
