@@ -21,6 +21,23 @@ A tick's reading covers its ``tick`` span's start to the end of the
 - ``arguments``: host values uploaded inside a program's call (its
   numpy arguments): on the chip each is a copy the launch waits for.
 
+Where the tick is a pass of the engine (``eng._loop_once()``: it holds a
+``tpushare.engine.dispatch`` span) the reading also says where the
+pass's device-to-host transfer lies:
+
+- ``fetches_in_dispatch``: transfers to the host between the START of
+  the launch and the END of the ``dispatch`` stage (the sampler and the
+  mirror run there): each would make the launch of tick N+1 wait for
+  the device again;
+- ``fetch_spans``: the ``tpushare.slot.fetch`` spans (the deferred
+  fetch of the oldest owed tick) that start [before the launch, after
+  the dispatch stage's end]: an engine that runs ahead reads [0, 1].
+
+A transfer is seen where it goes through ``jax.device_get`` (the slot
+servers' ``addressable_fetch``) or a scalar conversion (``int(x)``); a
+bare ``np.asarray(x)`` of a device array leaves no event of its own, and
+is what ``test_sync_free.count_transfers`` counts by patching it.
+
 Run every shape once before the session: a first call compiles, and
 its cache miss runs programs of its own.
 """
@@ -39,8 +56,13 @@ _CALL = "PjitFunction("
 _UPLOAD = "DevicePutWithSharding"
 #: a host value among a call's arguments
 _ARGUMENT = "DevicePut"
+#: a device-to-host transfer on the calling thread (jax.device_get is
+#: the first, then the second; int(x) and np.asarray(x) the second)
+_FETCHES = ("ArrayImpl.copy_to_host_async", "np.asarray(jax.Array)")
 _TICK = "launch_trace.tick:"
 _LAUNCH = "tpushare.slot.launch"
+_DISPATCH = "tpushare.engine.dispatch"
+_FETCH_SPAN = "tpushare.slot.fetch"
 
 
 class Session(dict):
@@ -102,10 +124,22 @@ def _reading(inside):
         # the innermost call around an execution names its program
         around = [c for c in calls if c[1] <= s < c[2]]
         programs.append(around[-1][0][len(_CALL):-1] if around else "?")
-    return {"programs": programs,
-            "uploads": sum(e[0] == _UPLOAD and e[1] < upto for e in inside),
-            "arguments": sum(e[0] == _ARGUMENT and e[1] < upto
-                             for e in inside)}
+    reading = {"programs": programs,
+               "uploads": sum(e[0] == _UPLOAD and e[1] < upto
+                              for e in inside),
+               "arguments": sum(e[0] == _ARGUMENT and e[1] < upto
+                                for e in inside)}
+    stages = [e for e in inside if e[0] == _DISPATCH]
+    if stages:
+        assert len(stages) == 1, stages
+        launched, dispatched = launches[0][1], stages[0][2]
+        spans = [e[1] for e in inside if e[0] == _FETCH_SPAN]
+        reading["fetches_in_dispatch"] = sum(
+            e[0] in _FETCHES and launched <= e[1] < dispatched
+            for e in inside)
+        reading["fetch_spans"] = [sum(t < launched for t in spans),
+                                  sum(t >= dispatched for t in spans)]
+    return reading
 
 
 def tables_agree(srv):

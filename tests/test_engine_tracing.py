@@ -205,6 +205,29 @@ def test_stage_clocks_sum_to_the_threads_wall_clock(traced):
         assert seen.get(k, 0) == n1[k] - n0[k], k
 
 
+def test_the_ahead_counters_say_how_often_the_engine_ran_ahead(traced):
+    """/stats ``ahead_ticks`` (work ticks dispatched with an older tick
+    still owed) and ``ahead_dropped_tokens`` (rows such a tick computed
+    for a stream that had ended), beside ``work_ticks``; and on the
+    trace, a tick that ran ahead is a ``dispatch`` span with the
+    ``finalize`` of the older tick behind it."""
+    b, a = traced["before"], traced["after"]
+    ahead = a["ahead_ticks"] - b["ahead_ticks"]
+    dropped = a["ahead_dropped_tokens"] - b["ahead_dropped_tokens"]
+    # admissions of what arrived during the dispatch go in between
+    names = [n for n, *_ in _stages(traced) if n != "engine.admit"]
+    behind = sum(x == "engine.dispatch" and y == "engine.finalize"
+                 for x, y in zip(names, names[1:]))
+    if traced["mode"] == "serial":
+        assert a["ahead_ticks"] == 0 and a["ahead_dropped_tokens"] == 0
+        assert behind == 0
+        return
+    assert 0 < ahead <= a["work_ticks"] - b["work_ticks"]
+    assert behind == ahead
+    # a request that ends leaves at most one row in the tick ahead
+    assert 0 <= dropped <= a["completed"] - b["completed"] == 4
+
+
 def test_queue_wait_and_admission_time_count_each_admission_once(traced):
     b, a = traced["before"], traced["after"]
     assert a["queue_wait_n"] - b["queue_wait_n"] == 4

@@ -301,30 +301,48 @@ class _Request:
 
 class _PendingTick:
     """One in-flight overlapped dispatch: the PendingStep whose fetch
-    is deferred to the NEXT tick, stamped with the engine generation
-    and tick id it was dispatched under so a fault in the overlap
-    window quarantines exactly the dispatched tick's slots, plus the
-    slot->request identity map at dispatch time (a slot recycled while
-    the tick was in flight must not receive the old dispatch's token).
-    ``dispatch_fetches`` is the device-fetch delta the dispatch itself
-    paid (normally zero; the eager monkeypatch fallback pays its fetch
-    up front), so /stats fetch accounting stays exact either way."""
+    is still owed, stamped with the engine generation and tick id it
+    was dispatched under so a fault in the overlap window quarantines
+    exactly the dispatched tick's slots, plus the slot->request
+    identity map at dispatch time with each request's placement stamp
+    (``_Request.seq``, new at every admission): a slot recycled while
+    the tick was in flight must not receive the old dispatch's token,
+    not even where a replay put the SAME request object back into the
+    SAME slot. ``dispatch_fetches`` is the device-fetch delta the
+    dispatch itself paid (normally zero; the eager monkeypatch
+    fallback pays its fetch up front), so /stats fetch accounting
+    stays exact either way. ``ahead``: dispatched while an older tick
+    was still owed, so some of its rows may belong to streams that
+    tick ended."""
 
-    __slots__ = ("step", "engine_gen", "tick_id", "slot_reqs", "work",
-                 "dispatch_fetches", "retired")
+    __slots__ = ("step", "engine_gen", "tick_id", "slot_reqs", "seqs",
+                 "work", "landed", "dispatch_fetches", "ahead", "retired")
 
     def __init__(self, step, *, engine_gen, tick_id, slot_reqs, work,
-                 dispatch_fetches):
+                 dispatch_fetches, ahead=False):
         self.step = step
         self.engine_gen = engine_gen
         self.tick_id = tick_id
         self.slot_reqs = dict(slot_reqs)
+        self.seqs = {s: r.seq for s, r in self.slot_reqs.items()}
         self.work = work
+        # The slot whose admission this tick's fused chunk completed
+        # at dispatch (the slot server activated it there); the engine
+        # moves its request to _active only when the tick is applied.
+        self.landed = (work if work is not None and work in step.slots
+                       else None)
         self.dispatch_fetches = int(dispatch_fetches)
+        self.ahead = bool(ahead)
         # {slot: request} capacity-retired rows pre-reaped out of the
         # engine's _active while this tick was in flight (their final
         # tokens are emitted at finalize).
         self.retired: Dict[int, "_Request"] = {}
+
+    def carries(self, slot: int, req: Optional["_Request"]) -> bool:
+        """Is the row this tick computed for ``slot`` still owed to
+        ``req``, in the placement it was dispatched for?"""
+        return (req is not None and self.slot_reqs.get(slot) is req
+                and self.seqs[slot] == req.seq)
 
 
 class ServeEngine:
@@ -600,6 +618,12 @@ class ServeEngine:
                        "preempted": 0, "chunked_admits": 0, "steps": 0,
                        "fused_ticks": 0, "model_forwards": 0,
                        "work_ticks": 0, "device_fetches": 0,
+                       # Work ticks dispatched while an older tick's
+                       # fetch was still owed, and the rows such a
+                       # tick computed for a request that no longer
+                       # held the slot when they came home (a stream
+                       # the older tick ended): never emitted.
+                       "ahead_ticks": 0, "ahead_dropped_tokens": 0,
                        "tokens_out": 0, "slot_rounds": 0,
                        "engine_errors": 0, "last_error": None,
                        "quarantines": 0, "replays": 0,
@@ -750,21 +774,26 @@ class ServeEngine:
         # wedged thread aborts at its next seam instead of ever
         # touching the (already quarantined-and-replayed) state again.
         self._tick_wedge_ms = tick_wedge_ms or None
-        # Overlapped tick pipeline (ISSUE 17): while tick N's dispatch
-        # is in flight, tick N+1 runs its host-side work (journal
-        # fsync, admission drain, scheduling) and only then finalizes
-        # tick N's one deferred device fetch — the host gap hides
-        # behind the device window. _pending_tick holds the in-flight
-        # dispatch (None = pipeline empty); every abandon path counts
-        # a pipeline_flush. Engine-thread-owned, like _active.
+        # Overlapped tick pipeline (ISSUE 17, 33): the engine keeps up
+        # to TWO ticks in flight. With tick N's fetch still owed a
+        # pass dispatches tick N+1 first and only then fetches N, so
+        # the device has its next program queued before a token comes
+        # home and the host's whole pass (fetch latency, emission,
+        # scheduling, the launch) hides behind it. _pending_ticks
+        # holds the dispatches whose fetch is owed, oldest first
+        # (empty = pipeline empty; one between passes, two only inside
+        # a pass); every abandon path counts a pipeline_flush a tick.
+        # Engine-thread-owned, like _active.
         self._overlap_tick = bool(overlap_tick)
-        self._pending_tick: Optional[_PendingTick] = None  # tpushare: owner[engine]
+        self._pending_ticks: collections.deque = collections.deque()  # tpushare: owner[engine]
         self._pipeline_flushes = 0
-        # Host-gap ring (overlap mode only): wall-clock from one
-        # dispatch's launch to the next — the host-side span the
-        # overlap is hiding. Bounded like the tier-stats rings.
+        # Host-gap ring (overlap mode only): wall-clock from the end
+        # of one dispatch to the end of the next, less the wait inside
+        # the finalize between them: the host's own part of a tick.
+        # Bounded like the tier-stats rings.
         self._host_gap_ms: List[float] = []     # tpushare: owner[engine]
         self._gap_anchor: Optional[float] = None
+        self._gap_waited = 0.0          # s inside finalize since anchor
         self._dispatch_seq = 0          # tick-generation stamp source
         # Next-tick pick plan, precomputed in the overlap window off a
         # quota-ledger snapshot (pure host work; committed or
@@ -995,7 +1024,7 @@ class ServeEngine:
                 and not self._active and not self._admitting \
                 and not self._sched.backlog() \
                 and not self._quota_parked and self._pending.empty() \
-                and self._pending_tick is None:
+                and not self._pending_ticks:
             self._journal_checkpoint()
 
     def _journal_checkpoint(self) -> None:
@@ -1119,7 +1148,7 @@ class ServeEngine:
                         and not self._quota_parked
                         and self._popped is None
                         and self._pending.empty()
-                        and self._pending_tick is None)
+                        and not self._pending_ticks)
             if idle:
                 return True
             time.sleep(0.05)
@@ -1992,7 +2021,7 @@ class ServeEngine:
                 self._popped = None
 
     def _admit_popped(self, req: _Request, sp) -> bool:
-        import jax.numpy as jnp
+        import numpy as np
         from tpushare.utils.profiling import span
         srv = self.srv
         if req.cancelled:               # client gave up while queued
@@ -2002,16 +2031,21 @@ class ServeEngine:
         chunked = (self._prefill_chunk is not None
                    and len(req.prompt) > self._prefill_chunk)
         self._fault_admit()
+        # The prompt goes in as a HOST array: the slot server hashes it
+        # for the prefix cache and slices its chunks on the host, and
+        # an array uploaded here would be read straight back there, a
+        # round trip that waits for every program in flight (two,
+        # since the engine runs ahead) and leaves the device idle
+        # behind them.
+        prompt = np.asarray(req.prompt, np.int32)
         try:
             if chunked:
                 slot = srv.admit_start(
-                    jnp.asarray(req.prompt, jnp.int32),
-                    adapter=req.adapter,
+                    prompt, adapter=req.adapter,
                     chunk_tokens=self._prefill_chunk,
                     tenant=req.tenant)
             else:
-                slot = srv.admit(jnp.asarray(req.prompt, jnp.int32),
-                                 adapter=req.adapter,
+                slot = srv.admit(prompt, adapter=req.adapter,
                                  tenant=req.tenant)
         except ValueError as e:         # permanently invalid (prompt
             req.error = str(e)          # exceeds capacity, bad adapter
@@ -2686,11 +2720,31 @@ class ServeEngine:
         would have left — the plan only moves the host arithmetic
         into the device window."""
         self._reap_cancelled_admissions()
+        admitting = self._open_admissions()
         plan, self._next_pick_plan = self._next_pick_plan, None
         if plan is not None and plan["admitting"] == tuple(sorted(
-                (s, r.seq) for s, r in self._admitting.items())):
+                (s, r.seq) for s, r in admitting.items())):
             return self._sched.commit_admission(plan["choice"])
-        return self._sched.pick_admission(self._admitting)
+        return self._sched.pick_admission(admitting)
+
+    def _landed_admissions(self) -> Dict[int, "_Request"]:
+        """Admissions a still-owed fused tick completed at dispatch:
+        the slot server decodes them from the next program on, while
+        their request stays in ``_admitting`` until that tick is
+        applied (its first token starts the stream). A tick dispatched
+        in between carries them as decode rows, never as work."""
+        return {p.landed: p.slot_reqs[p.landed]
+                for p in self._pending_ticks
+                if p.landed is not None
+                and p.carries(p.landed, self._admitting.get(p.landed))}
+
+    def _open_admissions(self) -> Dict[int, "_Request"]:
+        """The admissions that still have chunks to run."""
+        landed = self._landed_admissions()
+        if not landed:
+            return self._admitting
+        return {s: r for s, r in self._admitting.items()
+                if s not in landed}
 
     def _plan_next_pick(self) -> None:
         """Precompute the NEXT tick's scheduling decisions inside this
@@ -2703,13 +2757,14 @@ class ServeEngine:
         consistent ledger; the authoritative charge still lands
         dispatch-side, against the live ledger, when the admission
         actually allocates (slo/quota.py ledger_view)."""
-        choice = self._sched.peek_admission(self._admitting)
+        admitting = self._open_admissions()
+        choice = self._sched.peek_admission(admitting)
         quota = getattr(self.srv, "kv_quota", None)
         head = self._sched.peek()
         self._next_pick_plan = {
             "choice": choice,
             "admitting": tuple(sorted(
-                (s, r.seq) for s, r in self._admitting.items())),
+                (s, r.seq) for s, r in admitting.items())),
             "head": head,
             "ledger": (quota.ledger_view()
                        if quota is not None else None),
@@ -2848,8 +2903,13 @@ class ServeEngine:
         return True
 
     def _schedule(self, finalized: bool):
-        """What this tick dispatches, decided on serial-equivalent
-        state (the previous tick is fully applied), for both ticks:
+        """What this tick dispatches, for both ticks. Decided on
+        serial-equivalent state (the previous tick fully applied)
+        where the fetch came first; where the overlapped tick runs
+        ahead, on that state less the owed tick's outcome: a stream it
+        ended still rides this dispatch (its row is dropped when it
+        comes home), and an admission it completed rides as a decode
+        row (``_landed_admissions``).
         ``("admit", slot, None)`` a serial admission chunk with its
         own forward — the no-active-decodes fast path, and the
         decode-starved half of the token-budget alternation;
@@ -2874,7 +2934,8 @@ class ServeEngine:
             return None
         room = None
         if work is not None and self._tick_token_budget:
-            room = self._tick_token_budget - len(self._active)
+            room = (self._tick_token_budget - len(self._active)
+                    - len(self._landed_admissions()))
             if room < self._chunk_gran:
                 # No chunk fits beside this decode batch: decode-only
                 # and admission-only ticks take turns so neither side
@@ -2962,37 +3023,39 @@ class ServeEngine:
         for s in bad:
             out.pop(s)
             self._stats["last_error"] = f"NaN token from slot {s}"
-            if s in self._active:
-                self._quarantine_slot(s, self._active,
-                                      "NaN token (poisoned logits)")
-            elif s in self._admitting:
-                self._quarantine_slot(s, self._admitting,
-                                      "NaN token (poisoned logits)")
-            elif retired and s in retired:
+            if retired and s in retired:
                 # Quarantine minus the evict (the pre-reap already
-                # returned the slot): suspect tokens never reach the
-                # stream; the request replays or 503s like any other
+                # returned the slot, and whoever holds it now is not
+                # this row's): suspect tokens never reach the stream;
+                # the request replays or 503s like any other
                 # quarantined row.
                 done = retired.pop(s)
                 self._stats["quarantines"] += 1
                 self._tier_stats.bump(done.tier, "quarantined")
                 self._unpark_tenant(done.tenant)
                 self._replay_or_503(done, "NaN token (poisoned logits)")
+            elif s in self._active:
+                self._quarantine_slot(s, self._active,
+                                      "NaN token (poisoned logits)")
+            elif s in self._admitting:
+                self._quarantine_slot(s, self._admitting,
+                                      "NaN token (poisoned logits)")
         for slot, toks in out.items():
+            done = retired.pop(slot, None) if retired else None
+            if done is not None:
+                # Capacity-retired mid-flight: this row is the retired
+                # stream's whoever holds the slot by now (the drain may
+                # have handed it on). Emit its final tokens, then
+                # complete it at tokens-so-far — the serial reap's
+                # outcome, one stage later.
+                self._stats["slot_rounds"] += 1
+                for tok in (toks if isinstance(toks, list)
+                            else [toks]):
+                    self._emit(done, tok)
+                    self._stats["tokens_out"] += 1
+                self._finish_completed(done)
+                continue
             req = self._active.get(slot)
-            if req is None and retired:
-                done = retired.pop(slot, None)
-                if done is not None:
-                    # Capacity-retired mid-flight: emit its final
-                    # tokens, then complete it at tokens-so-far —
-                    # the serial reap's outcome, one stage later.
-                    self._stats["slot_rounds"] += 1
-                    for tok in (toks if isinstance(toks, list)
-                                else [toks]):
-                        self._emit(done, tok)
-                        self._stats["tokens_out"] += 1
-                    self._finish_completed(done)
-                    continue
             if req is None:
                 continue
             # One (slot, step) emission — the per-slot denominator the
@@ -3020,56 +3083,128 @@ class ServeEngine:
         if retired:
             for req in retired.values():
                 self._finish_completed(req)
-        # A slot step() deactivated at capacity without our evict:
-        for slot in [s for s in self._active
-                     if not self.srv.active[s]]:
+        # A slot step() deactivated at capacity without our evict
+        # (unless a younger tick still owes that row its last token:
+        # the pre-reap of the pass that fetches it completes it):
+        for slot in [s for s, r in self._active.items()
+                     if not self.srv.active[s]
+                     and not any(p.carries(s, r)
+                                 for p in self._pending_ticks)]:
             req = self._active.pop(slot)
             self._safe_evict(slot)          # reclaim blocks (counted
             self._finish_completed(req)     # on failure, never raised
                                             # past the finished request
 
-    # -- overlapped tick pipeline (ISSUE 17) --------------------------
+    # -- overlapped tick pipeline (ISSUE 17, 33) ----------------------
     def _tick_overlap(self, gen: Optional[int] = None) -> None:
-        """Two-stage pipelined tick: finalize (fetch) the PREVIOUS
-        tick's in-flight dispatch, then schedule and dispatch this
-        one — so this tick's host scheduling and the previous tick's
-        journal fsync ride the device window of the dispatch in
-        flight, and the one device fetch lands one tick late
-        (fetches_per_tick stays <= 1.0). Stage order:
+        """Pipelined tick, up to two dispatches in flight: with tick
+        N's fetch still owed, a pass dispatches tick N+1 FIRST and
+        only then fetches and applies N. The device has its next
+        program queued before N's tokens come home, so the fetch's
+        latency, the emission, the scheduling and the launch all ride
+        a device window, and the period of a plain tick is the larger
+        of the program and the host's pass, not their sum. Still one
+        forward and at most one device fetch a pass
+        (fetches_per_tick <= 1.0). Stage order:
 
           1. preamble    — chip chaos + proactive mesh degrade (a mesh
                            fault FLUSHES the pipeline: never fetch
                            from a suspect dispatch)
           2. admit drain — the same pre-dispatch point as the serial
-                           tick, so admission timing matches serial
-                           exactly; a pre-reap first returns any
+                           tick; a pre-reap first returns any
                            capacity-retired in-flight slots before the
-                           drain can hand them to new requests
-          3. finalize    — the ONE deferred device fetch, applied
-                           through the exact serial post-step block
-                           (NaN scan, emit, fused completion, reap)
-          4. schedule    — pure pick: the overlap-window plan is
+                           drain can hand them to new requests. The
+                           slots the last pass's finalize freed are
+                           refilled here
+          3. schedule    — pure pick: the overlap-window plan is
                            committed when still valid, else recomputed
-          5. dispatch    — step_async, stash the generation-stamped
-                           _PendingTick, then precompute the next
-                           pick inside the freshly opened window
-        """
+          4. dispatch    — step_async (N+1), queued behind N on the
+                           device; the generation-stamped _PendingTick
+                           joins the queue. Then a second admit drain:
+                           what arrived meanwhile goes in ahead of the
+                           wait below, not a tick behind it
+          5. finalize    — the ONE device fetch, of the OLDEST owed
+                           tick (N), applied through the exact serial
+                           post-step block (NaN scan, emit, fused
+                           completion, reap). A row N+1 computed for a
+                           stream N ended is dropped when N+1 comes
+                           home (the identity guard), never emitted
+          6. plan        — the next pass's pick, precomputed
+
+        Where the next dispatch needs this fetch the order is the
+        older one, 5 (then a refill drain) before 3 and 4, and one
+        tick is in flight (``_runs_ahead``): the same code with the
+        older tick finalized first."""
+        if not self._pending_ticks:
+            self._gap_anchor = None     # no sample across an empty pipe
         if not self._preamble_and_admit():
             return
-        q0 = self._stats["quarantines"]
-        finalized = self._finalize_pending()
-        if finalized and self._stats["quarantines"] == q0:
-            # Completions in the finalize freed server slots; refill
-            # them NOW, like the serial tick's drain (which runs after
-            # the previous tick is fully applied) — otherwise every
-            # completion opens a one-tick admission bubble the serial
-            # engine does not have. Skipped when the finalize
-            # quarantined: a replayed request re-admits at the NEXT
-            # tick's drain, keeping the recovery tick itself at the
-            # one transfer the sync-free invariant allows.
-            if not self._drain_admissions():
+        ahead = fetched = self._runs_ahead()
+        if self._pending_ticks and not ahead:
+            q0 = self._stats["quarantines"]
+            fetched = self._finalize_pending()
+            if fetched and self._stats["quarantines"] == q0:
+                # Completions in the finalize freed server slots;
+                # refill them NOW, like the serial tick's drain (which
+                # runs after the previous tick is fully applied) —
+                # otherwise every completion opens a one-tick admission
+                # bubble the serial engine does not have. Skipped when
+                # the finalize quarantined: a replayed request
+                # re-admits at the NEXT tick's drain, keeping the
+                # recovery tick itself at the one transfer the
+                # sync-free invariant allows.
+                if not self._drain_admissions():
+                    return
+        dispatched = self._schedule_and_dispatch(gen, finalized=fetched,
+                                                 ahead=ahead)
+        if ahead:
+            # The fetch blocks until program N ends. Whatever arrived
+            # while N+1 was being dispatched is admitted BEFORE that
+            # wait, as the fetch-first order admits it before its own
+            # (stage 2): a request that comes in just behind the drain
+            # must not sit out a whole tick, which may be a fused one.
+            # Not where this dispatch retired a row at capacity: its
+            # slot is the retired stream's until the next pass's
+            # pre-reap (two ticks may still owe it tokens).
+            if (all(self.srv.active[s] for s in self._active)
+                    and not self._drain_admissions()):
                 return
-        self._schedule_and_dispatch(gen, finalized)
+            self._finalize_pending()
+        if dispatched:
+            with self._clock.stage("plan"):
+                self._plan_next_pick()
+
+    def _runs_ahead(self) -> bool:
+        """Does this pass dispatch before it fetches? Decided from what
+        the engine can observe, never from a flag. It does not where
+        the owed tick's outcome shapes the next dispatch: a speculative
+        server (the accepted counts decide the next lengths, so the
+        host mirrors cannot advance without the fetch), a server with
+        no ``step_async`` or an instance-patched ``step`` (the eager
+        branch: its fetch is paid at dispatch), the first tick after a
+        flush (nothing owed), and a batch whose every stream is on its
+        last token by count (``max_tokens``): a draining engine runs no
+        wasted program. A plan that turns out to need the serial
+        admission path waits one tick (``_schedule`` with
+        ``finalized``), as it always did behind a fetch."""
+        if len(self._pending_ticks) != 1 or self._eager_step():
+            return False
+        if getattr(self.srv, "speculative", False):
+            return False
+        pend = self._pending_ticks[0]
+        live = list(self._active.items())
+        live += self._landed_admissions().items()
+        return any(not r.cancelled
+                   and len(r.tokens) + pend.carries(s, r) < r.max_tokens
+                   for s, r in live)
+
+    def _eager_step(self) -> bool:
+        """Instance-level step overrides (chaos/unit tests monkeypatch
+        eng.srv.step) see exactly the serial call — eagerly, with
+        exceptions raising at dispatch — and their output rides the
+        pipeline pre-fetched."""
+        return ("step" in vars(self.srv)
+                or not hasattr(self.srv, "step_async"))
 
     def _prereap_retired(self) -> None:
         """Dispatch-side capacity retirement (the slot's block
@@ -3078,41 +3213,43 @@ class ServeEngine:
         reclaim their server-side state — BEFORE the admission drain
         can hand the slot to a new request; their tokens are emitted
         at finalize from the pending tick's own identity map, so the
-        stream still ends exactly where the serial engine's would."""
-        pend = self._pending_tick
-        if pend is None:
-            return
-        for slot, req in list(pend.slot_reqs.items()):
-            if (self._active.get(slot) is req
-                    and not self.srv.active[slot]):
-                del self._active[slot]
-                self._safe_evict(slot)
-                pend.retired[slot] = req
+        stream still ends exactly where the serial engine's would.
+        Runs between passes, where at most one tick is owed: the one
+        that retired the row."""
+        for pend in self._pending_ticks:
+            for slot, req in list(pend.slot_reqs.items()):
+                if (pend.carries(slot, self._active.get(slot))
+                        and not self.srv.active[slot]):
+                    del self._active[slot]
+                    self._safe_evict(slot)
+                    pend.retired[slot] = req
 
     def _finalize_pending(self) -> bool:
-        """Stage 3: the one deferred device fetch. Slots whose request
-        changed while the tick was in flight (preempted, quarantined,
+        """The one deferred device fetch, of the oldest owed tick.
+        Slots whose request changed while the tick was in flight
+        (ended by the tick before it, preempted, quarantined,
         completed-and-recycled) are invalidated — the generation-
         stamped identity map decides, so a recycled slot can never
         receive the old dispatch's token. Returns True when a pending
         tick was actually fetched (the caller then defers any serial
         admission forward to keep one fetch per tick)."""
-        pend, self._pending_tick = self._pending_tick, None
-        if pend is None:
+        if not self._pending_ticks:
             return False
+        pend = self._pending_ticks.popleft()
         if pend.engine_gen != self._engine_gen:
             # Stamped under a previous engine generation: its device
             # work answers for state that was quarantined and replayed
             # — drop it unfetched.
-            self._pipeline_flushes += 1
+            self._abandon(pend, "engine generation superseded")
             return False
         with self._clock.stage("finalize"):
             stale = frozenset(
                 s for s, req in pend.slot_reqs.items()
-                if (self._active.get(s) is not req
-                    and self._admitting.get(s) is not req
+                if (not pend.carries(s, self._active.get(s))
+                    and not pend.carries(s, self._admitting.get(s))
                     and s not in pend.retired))
             f1 = self.srv.device_fetches
+            t0 = time.monotonic()
             try:
                 out = pend.step.finalize(stale)
             except BaseException:
@@ -3120,16 +3257,15 @@ class ServeEngine:
                 # fault. Pre-reaped retired rows live in no store the
                 # quarantine sweep can see — replay them here, then
                 # let the fault take the normal quarantine path for
-                # everyone else.
-                for req in pend.retired.values():
-                    self._stats["quarantines"] += 1
-                    self._tier_stats.bump(req.tier, "quarantined")
-                    self._unpark_tenant(req.tenant)
-                    self._replay_or_503(
-                        req, "device fault at pipeline finalize")
+                # everyone else (it flushes the younger tick unfetched).
+                self._replay_retired(
+                    pend, "device fault at pipeline finalize")
                 raise
+            self._gap_waited += time.monotonic() - t0
         with self._clock.stage("apply"):
             self._stats["steps"] += 1
+            if pend.ahead:
+                self._stats["ahead_dropped_tokens"] += len(stale)
             # Fetch accounting joins the two halves of the split tick:
             # the dispatch-side delta (zero on the async path; the
             # eager monkeypatch fallback pays there) plus the finalize
@@ -3138,34 +3274,29 @@ class ServeEngine:
             self._stats["device_fetches"] += (
                 pend.dispatch_fetches + (self.srv.device_fetches - f1))
             self._apply_step_output(out, pend.work, retired=pend.retired)
-        self._gap_anchor = time.monotonic()
         return True
 
-    def _schedule_and_dispatch(self, gen: Optional[int],
-                               finalized: bool) -> None:
-        """Stages 4+5: the pick (_schedule), then the dispatch with
-        its fetch left owing, then the next tick's pick precomputed
-        inside the freshly opened device window."""
+    def _schedule_and_dispatch(self, gen: Optional[int], *,
+                               finalized: bool, ahead: bool) -> bool:
+        """Stages 3+4: the pick (_schedule), then the dispatch with
+        its fetch left owing. ``finalized``: this pass pays a fetch
+        (before or after this call), so a serial admission forward
+        waits a tick. True when a tick joined the queue."""
         with self._clock.stage("schedule"):
             plan = self._schedule(finalized)
         if plan is None or self._run_unbatched(plan, gen):
-            return
+            return False
         _, work, room = plan
         with self._clock.stage("dispatch"):
             self._fault_forward()   # chaos: this tick's model forward
             self._check_superseded(gen)  # wedge hang fired above: abort
             slot_reqs = dict(self._active)
+            slot_reqs.update(self._landed_admissions())
             if work is not None:
                 slot_reqs[work] = self._admitting[work]
             f0 = self.srv.device_fetches
-            # Instance-level step overrides (chaos/unit tests
-            # monkeypatch eng.srv.step) see exactly the serial call —
-            # eagerly, with exceptions raising at dispatch — and their
-            # output rides the pipeline pre-fetched.
-            eager = ("step" in vars(self.srv)
-                     or not hasattr(self.srv, "step_async"))
             try:
-                if eager:
+                if self._eager_step():
                     from tpushare.models.serving import PendingStep
                     out = (self.srv.step(prefill_work=work,
                                          max_chunk_tokens=room)
@@ -3181,46 +3312,70 @@ class ServeEngine:
                 # holds nothing suspect.
                 if not self._shed_at_dispatch(e):
                     raise
-                return
+                return False
             self._dispatch_seq += 1
-            self._pending_tick = _PendingTick(
+            self._pending_ticks.append(_PendingTick(
                 pstep, engine_gen=self._engine_gen,
                 tick_id=self._dispatch_seq, slot_reqs=slot_reqs,
-                work=work, dispatch_fetches=self.srv.device_fetches - f0)
+                work=work, dispatch_fetches=self.srv.device_fetches - f0,
+                ahead=ahead))
             self._stats["model_forwards"] += 1
             self._stats["work_ticks"] += 1
             if work is not None:
                 self._stats["fused_ticks"] += 1
+            if ahead:
+                self._stats["ahead_ticks"] += 1
         self._record_host_gap()
-        with self._clock.stage("plan"):
-            self._plan_next_pick()
+        return True
 
     def _flush_pipeline(self) -> None:
-        """Abandon the in-flight dispatch WITHOUT its fetch: its
+        """Abandon every in-flight dispatch WITHOUT its fetch: its
         tokens are never observed (quarantine replay regenerates them
         token-exactly), so a reshard/quarantine path never blocks on —
-        or trusts — a suspect device computation. Counted on the
-        /stats ``pipeline_flushes`` surface."""
-        if self._pending_tick is None:
+        or trusts — a suspect device computation. Counted, a tick, on
+        the /stats ``pipeline_flushes`` surface."""
+        if not self._pending_ticks:
             return
-        self._pending_tick = None
         self._next_pick_plan = None
+        while self._pending_ticks:
+            self._abandon(self._pending_ticks.popleft(),
+                          "pipeline flushed")
+
+    def _abandon(self, pend: _PendingTick, msg: str) -> None:
         self._pipeline_flushes += 1
+        self._replay_retired(pend, msg)
+
+    def _replay_retired(self, pend: _PendingTick, msg: str) -> None:
+        """A tick that will never be applied still owes its pre-reaped
+        retired rows an ending: they live in no store the quarantine
+        sweep can see, so replay them from here."""
+        while pend.retired:
+            _, req = pend.retired.popitem()
+            self._stats["quarantines"] += 1
+            self._tier_stats.bump(req.tier, "quarantined")
+            self._unpark_tenant(req.tenant)
+            self._replay_or_503(req, msg)
 
     def _record_host_gap(self) -> None:
-        """One host-gap sample: the previous tick's finalize applied
-        -> this tick's dispatch call RETURNED. So a sample holds the
-        ``schedule`` stage and the whole ``dispatch`` stage (block
-        growth, the launch, the eager sampler), not only the
-        scheduling the overlap hides; the stage clocks give the two
-        apart. Plain monotonic deltas into a bounded ring (no
-        PhaseTimer — its barriers are the syncs the hot loop must
-        never make)."""
-        anchor, self._gap_anchor = self._gap_anchor, None
+        """One host-gap sample: host wall-clock between the end of one
+        dispatch and the end of the next, less the wait inside the
+        ``finalize`` between them (the fetch, where the host only
+        waits for the device): whatever else the engine's thread did
+        to get from one launch to the next — apply, admissions,
+        journal, preamble, schedule and the whole ``dispatch`` stage
+        (block growth, the launch, the eager sampler); the stage
+        clocks give them apart. No sample spans an empty pipeline
+        (``_tick_overlap`` drops the anchor). Plain monotonic deltas
+        into a bounded ring (no PhaseTimer — its barriers are the
+        syncs the hot loop must never make)."""
+        now = time.monotonic()
+        anchor, self._gap_anchor = self._gap_anchor, now
+        waited, self._gap_waited = self._gap_waited, 0.0
         if anchor is None:
             return
         from tpushare.utils.profiling import HOST_GAP_CAP
-        self._host_gap_ms.append((time.monotonic() - anchor) * 1e3)
+        self._host_gap_ms.append(
+            max(0.0, now - anchor - waited) * 1e3)
         if len(self._host_gap_ms) > HOST_GAP_CAP:
             del self._host_gap_ms[
                 :len(self._host_gap_ms) - HOST_GAP_CAP]

@@ -38,6 +38,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpushare.models.serving import upload_mirror
 from tpushare.models.transformer import TransformerConfig, forward
 from tpushare.parallel.multihost import addressable_fetch, host_scalar
 from tpushare.router.chainkeys import chain_keys
@@ -1374,7 +1375,7 @@ class PagedSlotServer(SpecDecodeMixin):
             c.index.clear()
             c.chains.clear()
         self.cache = dataclasses.replace(
-            c, block_table=jnp.array(c.host_table(), jnp.int32), **repl)
+            c, block_table=upload_mirror(c.host_table()), **repl)
         if self.speculative:
             for attr in ("_dpk", "_dpv"):
                 arr = getattr(self, attr)
@@ -1635,7 +1636,7 @@ class PagedSlotServer(SpecDecodeMixin):
                 last_logits[None, :])[0].astype(jnp.int32)
             self.last_token = self.last_token.at[slot, 0].set(nxt)
         self.active[slot] = True
-        self._active_dev = jnp.array(self.active)
+        self._active_dev = upload_mirror(self.active)
         self.device_fetches += 1
         with span("slot.admit.first_token"):
             tok = int(host_scalar(nxt))
@@ -1778,9 +1779,17 @@ class PagedSlotServer(SpecDecodeMixin):
         device operation: the tick's new block ids ride the step's own
         program as a numpy argument (``_grow_active`` /
         ``apply_growth``), which hands back the grown table with the
-        pools and the advanced lengths. The device is idle when a
-        tick is entered (the engine fetched the last one first), so
-        whatever ran here was tick time nothing overlapped."""
+        pools and the advanced lengths. The engine enters a tick with
+        the previous one still owed (it fetches tick N only after it
+        has launched N+1), so what runs here rides the device window of
+        the program in flight, and a tick chains to the next on the
+        device alone: ``last_token``, ``lengths``, the table and the
+        pools are device arrays rebound here, growth and retirement
+        read host mirrors that advance here, and each PendingStep
+        closes over its own ``nxt`` and ``slots``. A row computed for a
+        stream the owed tick turns out to have ended is the engine's to
+        drop; it lands in the slot's private blocks, which ``evict``
+        releases, ahead of anything a later admission launches."""
         from tpushare.models.serving import PendingStep
         if prefill_work is not None:
             if prefill_work not in self._admissions:
@@ -1832,7 +1841,7 @@ class PagedSlotServer(SpecDecodeMixin):
                     self.active[slot] = False
                     hit_cap = True
             if hit_cap:
-                self._active_dev = jnp.array(self.active)
+                self._active_dev = upload_mirror(self.active)
 
         def _finalize(invalid):
             self.device_fetches += 1
@@ -1910,7 +1919,7 @@ class PagedSlotServer(SpecDecodeMixin):
                 self.last_token = self.last_token.at[slot, 0].set(
                     first[0])
                 self.active[slot] = True
-            self._active_dev = jnp.array(self.active)
+            self._active_dev = upload_mirror(self.active)
         out_slots = decode_slots + ([slot] if final else [])
 
         def _finalize(invalid):
@@ -2085,7 +2094,7 @@ class PagedSlotServer(SpecDecodeMixin):
         prefix bookkeeping exists). Safe mid-admission: the chunk
         state is dropped with the blocks."""
         self.active[slot] = False
-        self._active_dev = jnp.array(self.active)
+        self._active_dev = upload_mirror(self.active)
         self._admissions.pop(slot, None)
         if self._ml.enabled:
             self._ml.reset(slot)

@@ -46,7 +46,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpushare.models.paged import PagedSlotServer, _program
-from tpushare.models.serving import bucket_len
+from tpushare.models.serving import bucket_len, upload_mirror
 from tpushare.ops.norms import rms_norm
 from tpushare.ops.retention import n_features, phi, retention_step
 from tpushare.ops.rotary import apply_rotary, rotary_embedding
@@ -468,7 +468,7 @@ class RetentionSlotServer(PagedSlotServer):
             nxt = self._sampler.pick(last_logits[None, :])[0].astype(jnp.int32)
             self.last_token = self.last_token.at[slot, 0].set(nxt)
         self.active[slot] = True
-        self._active_dev = jnp.array(self.active)
+        self._active_dev = upload_mirror(self.active)
         self.device_fetches += 1
         with span("slot.admit.first_token"):
             # the admission's one fetch, as PagedSlotServer.admit_step's

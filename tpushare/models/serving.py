@@ -357,6 +357,20 @@ class MeshPlacement:
         return jax.device_put(pool, self.pool)
 
 
+def upload_mirror(mirror: np.ndarray) -> jnp.ndarray:
+    """The device copy of a host mirror that is mutated in place
+    afterwards (the active mask, the block table, the adapter ids).
+    An upload reads its argument when the transfer RUNS, not when it
+    is called: the CPU client aliases the numpy buffer and copies it
+    in a program of its own, a chip's may read it until the transfer
+    completes. So a mutation right after the call reached programs
+    launched BEFORE it, and since the engine fetches tick N only after
+    it has launched N+1 the next mutation (an evict, an activation)
+    is microseconds away. The mirror is copied on the host first; the
+    copy is nobody else's to mutate."""
+    return jnp.asarray(mirror.copy())
+
+
 def bucket_len(n: int, floor: int = 16) -> int:
     """Next power of two >= n (floor 16): admits compile once per
     bucket, not once per distinct prompt length — the ONE bucketing
@@ -497,7 +511,7 @@ class MultiLoraSlots:
 
     def set(self, slot: int, adapter: int) -> None:
         self._host[slot] = adapter
-        self.dev = jnp.array(self._host)
+        self.dev = upload_mirror(self._host)
 
     def reset(self, slot: int) -> None:
         self.set(slot, -1)
@@ -517,8 +531,10 @@ class PendingStep:
     and ``finalize()`` performs the deferred fetch and builds the
     ``{slot: token}`` dict. ``step() == step_async().finalize()`` —
     the serial engine keeps exact one-transfer-per-tick semantics,
-    while the overlapped engine holds the PendingStep across its next
-    tick's host work so the fetch lands one tick late.
+    while the overlapped engine holds the PendingStep until it has
+    dispatched the NEXT tick, so at most two are owed and the fetch
+    returns when this tick's program ends with the next one already
+    queued behind it.
 
     ``finalize(invalid=...)`` skips slots whose request changed while
     the tick was in flight (evicted, or evicted-and-readmitted): their

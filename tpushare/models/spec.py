@@ -62,6 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpushare.models.serving import upload_mirror
 from tpushare.parallel.multihost import addressable_fetch
 
 
@@ -391,7 +392,7 @@ class SpecDecodeMixin:
                     self.active[slot] = False
                     retired = True
             if retired:
-                self._active_dev = jnp.array(self.active)
+                self._active_dev = upload_mirror(self.active)
             return out
 
         return PendingStep(_finalize, slots=slots)
