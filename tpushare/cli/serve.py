@@ -459,7 +459,10 @@ class ServeEngine:
             # (models/latent.py): the paged pool, block tables, prefix
             # cache and tick of the dense family, its own two pools and
             # programs underneath. kv_quant, multi_lora, layers_hook,
-            # a draft and a mesh are refused there, loudly.
+            # a second model as draft and a mesh are refused there,
+            # loudly. A configuration that carries a multi-token-
+            # prediction module drafts with it (no flag: the server
+            # sets ``speculative`` itself).
             from tpushare.models.latent import LatentSlotServer as server
         elif model_family == "moe":
             from tpushare.models.moe import paged_forward
@@ -606,6 +609,18 @@ class ServeEngine:
         # beside the decode batch, the engine alternates decode-only
         # and admission-only ticks so neither side starves.
         self._tick_token_budget = int(tick_token_budget or 0)
+        # Positions a stream runs through the weights in a plain tick: a
+        # latent server that drafts with its own module verifies two
+        # (the last token and the draft), and its round cannot be split
+        # either, so the budget counts both and must hold one round.
+        self._stream_positions = 2 if getattr(self.srv, "drafting",
+                                              False) else 1
+        if 0 < self._tick_token_budget < self._stream_positions:
+            raise ValueError(
+                f"tick_token_budget={tick_token_budget} is below the two "
+                f"positions a stream's self-drafting round verifies "
+                f"(the configuration carries a multi-token-prediction "
+                f"module); a round cannot be split: raise the budget")
         self._admit_turn = False
         self._chunk_gran = getattr(self.srv.cache, "block_size", 1)
         self._admitting: Dict[int, _Request] = {}   # tpushare: owner[engine]
@@ -1895,6 +1910,17 @@ class ServeEngine:
                 fam.get("retention_state_bytes_moved"),
             "retention_ticks": fam.get("retention_ticks"),
             "retention_chunks": fam.get("retention_chunks"),
+            # A latent server that drafts with its own multi-token-
+            # prediction module: rounds run, drafts proposed (one a
+            # stream a round) and accepted, tokens those rounds emitted
+            # (the seam's counters under the module's names; the
+            # ``speculative`` group below has the rates), and the
+            # cached latent rows the rounds' attention had to read.
+            "mtp_rounds": fam.get("mtp_rounds"),
+            "mtp_proposed": fam.get("mtp_proposed"),
+            "mtp_accepted": fam.get("mtp_accepted"),
+            "mtp_emitted": fam.get("mtp_emitted"),
+            "latent_rows_read": fam.get("latent_rows_read"),
         })
         if srv.speculative:
             # Mean tokens per (slot, round) in [1, gamma×horizon+1] is
@@ -2934,7 +2960,8 @@ class ServeEngine:
             return None
         room = None
         if work is not None and self._tick_token_budget:
-            room = (self._tick_token_budget - len(self._active)
+            room = (self._tick_token_budget
+                    - self._stream_positions * len(self._active)
                     - len(self._landed_admissions()))
             if room < self._chunk_gran:
                 # No chunk fits beside this decode batch: decode-only

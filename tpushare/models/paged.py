@@ -31,6 +31,7 @@ from __future__ import annotations
 import collections
 import dataclasses
 import functools
+import math
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -989,7 +990,8 @@ def _prefill_chunk(params, prompt: jnp.ndarray, cfg: TransformerConfig,
         for pf, rk_ in _row_pairs(cache):
             r = row[rk_][:, 0, start_blk * bs:end_blk * bs]
             # a page: [bs, Hkv*Dh]; a leaf has its own count of layers
-            r = r.reshape(r.shape[0], n_fresh, bs, -1)
+            # (spelt out, not -1: a family may have a pool with no layer)
+            r = r.reshape(r.shape[0], n_fresh, bs, math.prod(r.shape[2:]))
             if pf.endswith("_scale"):
                 from tpushare.models.quant import scales_to_pool_layout
                 r = scales_to_pool_layout(r)    # -> [L, fb, Hkv_pad, bs]
@@ -1219,10 +1221,16 @@ class PagedSlotServer(SpecDecodeMixin):
         # The draft keeps its own KV pools indexed by the SAME block
         # table (shared prefix blocks carry draft KV written by their
         # publisher — identical values for identical tokens).
-        self.speculative = speculative_draft is not None
+        # ``speculative`` is what the engine reads (a step returns a
+        # list a slot, /stats has a ``speculative`` group); ``_draft_lm``
+        # says the draft is a second model with pools of its own, which
+        # the admission and tick paths below feed. A family whose draft
+        # is a module of the target itself (latent.LatentSlotServer)
+        # sets the first and not the second.
+        self.speculative = self._draft_lm = speculative_draft is not None
         self.gamma = gamma
         self.spec_horizon = spec_horizon
-        if self.speculative:
+        if self._draft_lm:
             # The shared seam owns the round driver, acceptance cores,
             # horizon semantics, and the gamma/horizon validation.
             self._spec_init(gamma=gamma, spec_horizon=spec_horizon,
@@ -1376,7 +1384,7 @@ class PagedSlotServer(SpecDecodeMixin):
             c.chains.clear()
         self.cache = dataclasses.replace(
             c, block_table=upload_mirror(c.host_table()), **repl)
-        if self.speculative:
+        if self._draft_lm:
             for attr in ("_dpk", "_dpv"):
                 arr = getattr(self, attr)
                 if arr.is_deleted():
@@ -1537,7 +1545,7 @@ class PagedSlotServer(SpecDecodeMixin):
             # chunk (admit_step checks this flag).
             "row_stale": self.lean_admission,
         }
-        if self.speculative:
+        if self._draft_lm:
             # The draft's admission row shares the block table; its
             # prefix gather (draft KV written by the publisher) also
             # happens once per admission. Its prefill pins the slot's
@@ -1594,7 +1602,7 @@ class PagedSlotServer(SpecDecodeMixin):
             with span("slot.admit.row"):
                 st["row"], st["comp_len"], _ = _admission_row(
                     self.cfg, self.cache, slot, S, st["done"])
-                if self.speculative:
+                if self._draft_lm:
                     st["drow"], st["dcomp_len"], _ = _admission_row(
                         self.draft_cfg, self._draft_view(), slot, S,
                         st["done"])
@@ -1612,7 +1620,7 @@ class PagedSlotServer(SpecDecodeMixin):
             st["row"], st["done"], end, st["n_blk"], st["comp_len"],
             chunk, prefill_fn=st["prefill_fn"],
             inplace=self.lean_admission)
-        if self.speculative:
+        if self._draft_lm:
             # The draft needs prompt KV too, chunked the same way.
             _, dview, st["drow"] = _prefill_chunk(
                 self.draft_params, st["prompt"], self.draft_cfg,
@@ -1969,7 +1977,7 @@ class PagedSlotServer(SpecDecodeMixin):
         self.cache = dataclasses.replace(
             self.cache, pool_k=pk, pool_v=pv, block_table=table,
             lengths=lengths, pool_k_scale=pks, pool_v_scale=pvs)
-        if self.speculative:
+        if self._draft_lm:
             # One draft forward: decode rows mirror their pending
             # token's draft KV (a skipped write would leave a hole
             # every later draft step attends), the admitting row

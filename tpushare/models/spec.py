@@ -361,6 +361,21 @@ class SpecDecodeMixin:
         else:
             a_b, correction = self._greedy_accept(tl, drafts_arr, base)
         self._spec_commit(a_b, correction, active)
+        return self._spec_pending(drafts_arr, correction, a_b)
+
+    def _spec_pending(self, drafts_arr, correction, a_b, first=None):
+        """The round's deferred half, as a PendingStep: everything after
+        the device-side commit. Where a family's draft is a module of
+        the target itself (latent.LatentSlotServer: draft, verify,
+        accept and commit are ONE program of its own, so none of the
+        dispatch hooks above applies), it joins the seam here, with
+        drafts_arr [B, h], correction [B, 1] and a_b [B] as its program
+        left them on the device. ``first`` = (slot, token [1] on the
+        device): an admission the same program completed; its first
+        token rides the round's one fetch."""
+        from tpushare.models.serving import PendingStep
+        timer = self._spec_timer
+        h = drafts_arr.shape[1]
         cap = self._spec_capacity()
         slots = [int(s) for s in np.nonzero(self.active)[0]]
         self.spec_rounds += 1
@@ -373,8 +388,9 @@ class SpecDecodeMixin:
             # per recorded slot, skipping slots whose request changed
             # in flight (their mirror was reset by evict/re-admit).
             self.device_fetches += 1
-            drafts_np, corr_np, a_np = addressable_fetch(
-                (drafts_arr, correction, a_b))
+            drafts_np, corr_np, a_np, *first_np = addressable_fetch(
+                (drafts_arr, correction, a_b)
+                + (() if first is None else (first[1],)))
             if timer is not None:
                 timer.mark("accept_fold")
             lnp = self._spec_host_lengths()
@@ -393,6 +409,9 @@ class SpecDecodeMixin:
                     retired = True
             if retired:
                 self._active_dev = upload_mirror(self.active)
+            if first is not None and first[0] not in invalid:
+                out[first[0]] = int(first_np[0][0])
             return out
 
-        return PendingStep(_finalize, slots=slots)
+        return PendingStep(_finalize, slots=slots + (
+            [] if first is None else [first[0]]))
