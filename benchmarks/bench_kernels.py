@@ -1,8 +1,9 @@
 """On-chip pallas kernel validation + timing.
 
 Runs every pallas kernel (resident flash, streaming flash, partial
-flash, ragged decode, paged decode bf16/int8, paged verify, the flash
-kernel under shard_map, the fused int8 expert FFN) on the real TPU,
+flash, ragged decode, paged decode bf16/int8, paged verify, the paged
+latent decode, the flash kernel under shard_map, the fused int8 expert
+FFN) on the real TPU,
 compiled by Mosaic, checks numerical parity against the XLA reference,
 and times kernel vs reference. Prints one JSON line per kernel:
 
@@ -29,6 +30,7 @@ Timing methodology:
 
 from __future__ import annotations
 
+import functools
 import json
 import os
 import sys
@@ -330,6 +332,56 @@ def bench_paged_verify():
                                 pool_v, table, pos))
 
 
+def _timeit_latent_chained(fn, q, pool, table, pos, *, layer: int,
+                           iters: int = 5):
+    """(ms, credible), paged latent rows: the stacked pool in the carry,
+    one row per slot scattered through the block table each step."""
+    B, mb = table.shape
+    bs, C = pool.shape[2:]
+
+    def body(carry, table0, q0):
+        qc, pc, at = carry
+        o = fn(qc, pc, table0, at)                  # [B, Q, H, rank]
+        nxt = jnp.minimum(at + 1, bs * mb - 1)
+        blk = jnp.take_along_axis(table0, nxt[:, :1] // bs, 1)[:, 0]
+        row = jnp.concatenate([o[:, 0, 0], o[:, 0, 1]], -1)[:, :C]
+        return (q0 + (o[..., :1] * 1e-3).astype(q0.dtype),
+                pc.at[layer, blk, nxt[:, 0] % bs].set(row.astype(pc.dtype)),
+                nxt)
+    return _timeit_scan(body, (q, pool, pos), table, q, iters=iters)
+
+
+def _bench_latent_paged(live_rows: int):
+    """The paged latent decode kernel at the pangu cell's shapes (16
+    slots, two queries of 128 heads, rows of 640 of which 512 are the
+    latent, a table of 1,046 pages of 16) with every slot at
+    ``live_rows``, against the gathered ``jnp`` form."""
+    from tpushare.models import latent
+    from tpushare.ops.latent_decode import latent_paged_decode
+    B, Q, H, bs, mb, L, li = 16, 2, 128, 16, 1046, 2, 1
+    dims = latent.AttnDims(H, 1536, 512, 128, 64, 128, 25.6e6)
+    C, nb = dims.key_dim, B * mb + 1
+    q, pool = _mk(11, (B, Q, H, C), (L, nb, bs, C))
+    table = jnp.asarray(1 + np.arange(B)[:, None] * mb
+                        + np.arange(mb)[None, :], jnp.int32)
+    pos = jnp.asarray(live_rows - 2 + np.zeros((B, 1), np.int32)
+                      + np.arange(Q)[None, :], jnp.int32)
+    live = jnp.ones((B, Q), bool)
+    fl = jax.jit(lambda q, pool, t, pos: latent_paged_decode(
+        q, pool, t, pos, live, layer=li, kv_rank=dims.kv_rank,
+        scale=(dims.nope + dims.rope) ** -0.5))
+    rf = jax.jit(lambda q, pool, t, pos: latent._gather_attend(
+        pool, li, t, pos, q, dims))
+    timer = functools.partial(_timeit_latent_chained, layer=li)
+    return _report(f"latent_paged_decode_{live_rows // 1024}k",
+                   fl(q, pool, table, pos), rf(q, pool, table, pos),
+                   *_timed_pair(timer, fl, rf, q, pool, table, pos))
+
+
+def bench_latent_paged():
+    return all([_bench_latent_paged(n) for n in (8192, 12288, 16384)])
+
+
 def bench_ring_shardmap():
     """Ring attention's REAL flash inner loop lowered inside a
     vma-tagged shard_map on the actual Mosaic toolchain — the half of
@@ -400,7 +452,7 @@ def main():
     for bench in (bench_resident, bench_resident_window_softcap,
                   bench_streaming, bench_partial, bench_decode,
                   bench_paged, bench_paged_q8, bench_paged_verify,
-                  bench_ring_shardmap, bench_q8_expert):
+                  bench_latent_paged, bench_ring_shardmap, bench_q8_expert):
         try:
             results.append(bench())
         except Exception as e:      # noqa: BLE001 — a kernel Mosaic

@@ -270,13 +270,18 @@ def test_counters_follow_what_was_served(toy):
     assert (st["mtp_rounds"], st["mtp_proposed"]) == (5, 5)
     assert st["mtp_emitted"] == emitted == 5 + st["mtp_accepted"]
     assert st["latent_rows_read"] == rows
+    # the CPU gathers: no paged-kernel call is counted; where the kernel
+    # is the rounds' choice the count is one a cached layer a round
+    assert st["latent_decode_calls"] == 0
     assert st["latent_rows_live"]["full"] == (cfg.n_full + 1) * (n + emitted)
     assert st["latent_row_bytes"]["full"] == 4 * cfg.full.key_dim
     # the module's expert layer counts as one more sparse layer
     assert len(st["expert_load"]) == (cfg.n_moe + 1) * cfg.experts_held
     assert sum(st["expert_load"]) == st["expert_assign_local"]
     t0 = st["expert_tokens"]
+    srv._decode_calls_a_round = cfg.n_cached_full
     srv.step()
+    assert srv.family_stats()["latent_decode_calls"] == cfg.n_full + 1
     # two positions a sparse layer, and the module's one (or two) pending
     assert srv.family_stats()["expert_tokens"] - t0 in (
         2 * cfg.n_moe + 1, 2 * cfg.n_moe + 2)

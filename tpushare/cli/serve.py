@@ -1915,12 +1915,15 @@ class ServeEngine:
             # stream a round) and accepted, tokens those rounds emitted
             # (the seam's counters under the module's names; the
             # ``speculative`` group below has the rates), and the
-            # cached latent rows the rounds' attention had to read.
+            # cached latent rows the rounds' attention had to read and
+            # the paged-kernel calls that read them (0 where the rounds
+            # gather: ops/latent_decode.latent_decode_eligible).
             "mtp_rounds": fam.get("mtp_rounds"),
             "mtp_proposed": fam.get("mtp_proposed"),
             "mtp_accepted": fam.get("mtp_accepted"),
             "mtp_emitted": fam.get("mtp_emitted"),
             "latent_rows_read": fam.get("latent_rows_read"),
+            "latent_decode_calls": fam.get("latent_decode_calls"),
         })
         if srv.speculative:
             # Mean tokens per (slot, round) in [1, gamma×horizon+1] is

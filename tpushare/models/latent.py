@@ -76,6 +76,8 @@ from tpushare.models.paged import (PagedSlotServer, _admission_row, _program,
 from tpushare.models.serving import upload_mirror
 from tpushare.models.spec import (draft_sample_core, greedy_accept_core,
                                   spec_accept_core)
+from tpushare.ops.latent_decode import (latent_decode_eligible,
+                                        latent_paged_decode)
 from tpushare.ops.norms import layer_norm
 from tpushare.ops.rotary import apply_rotary, rotary_embedding
 from tpushare.utils.profiling import span
@@ -588,18 +590,46 @@ def _decode_full(pool, xpool, li: int, tb, pos, pr, cfg: LatentConfig):
     return o, jnp.where(kept, idx, -1)
 
 
-def _decode_all(pool, li: int, tb, pos, q, cfg: LatentConfig):
+def decode_kernel_serves(cfg: LatentConfig, n_slots: int, n_q: int,
+                         pool) -> bool:
+    """Whether ``_decode_all`` hands ``n_q`` queries a slot to the paged
+    kernel (``ops/latent_decode``): by the backend and the shapes the
+    call will see, nothing else."""
+    a = cfg.full
+    q = jax.ShapeDtypeStruct((n_slots, n_q, a.n_heads, a.key_dim), cfg.dtype)
+    return latent_decode_eligible(q, pool, a.kv_rank)
+
+
+def _gather_attend(pool, li: int, tb, pos, q, a: AttnDims):
+    """``_decode_all`` in plain ``jnp``: every slot's whole table width
+    gathered into a dense [B, keys a table can hold, row] copy, masked
+    past each query's position."""
+    B, mb = tb.shape
+    rows = pool[li, tb].reshape(B, mb * pool.shape[2], -1)
+    keep = jnp.arange(rows.shape[1])[None, None, :] <= pos[:, :, None]
+    return _attend(q, rows, keep, a)
+
+
+def _decode_all(pool, li: int, tb, pos, live, q, cfg: LatentConfig):
     """``q`` [B, Q, H, C+R]: Q queries a slot at ``pos`` [B, Q] against
     every cached row of the slot, read through the block table (its own
     rows already written): the full layer of a model with no selector.
     A row past a query's position is masked, so what a rejected draft
     left there is never attended. Returns the latent output
-    [B, Q, H, C]."""
-    B, mb = tb.shape
+    [B, Q, H, C]; what a query that is not ``live`` [B, Q] gets is
+    nobody's to read.
+
+    Where the backend and the shapes allow (``latent_decode_eligible``)
+    a paged kernel reads each slot's live blocks once; every other call
+    (the CPU, the float32 toys, odd widths) gathers, and that form is
+    the reference the kernel is tested against."""
+    a = cfg.full
     with jax.named_scope("latent_attend"):
-        rows = pool[li, tb].reshape(B, mb * pool.shape[2], -1)
-        keep = jnp.arange(rows.shape[1])[None, None, :] <= pos[:, :, None]
-        return _attend(q, rows, keep, cfg.full)
+        if latent_decode_eligible(q, pool, a.kv_rank):
+            return latent_paged_decode(
+                q, pool, tb, pos, live, layer=li, kv_rank=a.kv_rank,
+                scale=1.0 / math.sqrt(a.nope + a.rope))
+        return _gather_attend(pool, li, tb, pos, q, a)
 
 
 def _decode_swa(pool, li: int, tb, pos, pr, cfg: LatentConfig):
@@ -833,7 +863,8 @@ class _Paged:
                                         self.live[:, 0])
             elif kind == FULL:
                 q = dec["q"].reshape(*self.pos.shape, *dec["q"].shape[1:])
-                o = _decode_all(pool, li, self.tb, self.pos, q, cfg)
+                o = _decode_all(pool, li, self.tb, self.pos, self.live, q,
+                                cfg)
                 o = o.reshape(nd, *o.shape[2:])
             else:
                 o = _decode_swa(pool, li, self.tb, self.pos[:, 0], dec, cfg)
@@ -1207,6 +1238,13 @@ class LatentSlotServer(PagedSlotServer):
         #: arithmetic off the lengths mirror, every cached layer's rows
         #: up to the round's last write
         self.latent_rows_read = 0
+        #: paged-kernel calls the rounds' programs made: one a cached
+        #: layer a round where the kernel is ``_decode_all``'s choice at
+        #: this server's shapes, none where it is not
+        self.latent_decode_calls = 0
+        self._decode_calls_a_round = (
+            cfg.n_cached_full if decode_kernel_serves(
+                cfg, B, 2, self.cache.pool_k) else 0)
 
     # -- counters -----------------------------------------------------
 
@@ -1230,7 +1268,8 @@ class LatentSlotServer(PagedSlotServer):
                    # a slot's round emits what it accepted and one more
                    "mtp_emitted": (self.spec_draft_tokens
                                    + self.spec_accepted_tokens),
-                   "latent_rows_read": self.latent_rows_read}
+                   "latent_rows_read": self.latent_rows_read,
+                   "latent_decode_calls": self.latent_decode_calls}
         return {
             **out,
             "select_keys_kept": int(c[2]) if cfg.selector else None,
@@ -1323,6 +1362,7 @@ class LatentSlotServer(PagedSlotServer):
             self.latent_rows_read += int(
                 self.cfg.n_full * (lnp + 2).sum()
                 + self.cfg.n_mtp * lnp.sum())
+            self.latent_decode_calls += self._decode_calls_a_round
         with span("slot.launch"):
             key = (self._sampler.next_key() if self._spec_stochastic
                    else None)
