@@ -19,7 +19,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from tpushare.cli.serve import ENGINE_STAGES, ServeEngine, _Request, serve
+from tpushare.cli.serve import (ENGINE_STAGES, PASS_KINDS, ServeEngine,
+                                _Request, serve)
 from tpushare.models import transformer as tf
 from tpushare.models.paged import PagedSlotServer
 from tpushare.utils.profiling import SPAN_PREFIX, StageClock, span
@@ -83,6 +84,7 @@ def traced(request, tmp_path_factory):
     try:
         for n in (5, 13, 21):           # every program the run will use
             _post(port, _prompt(rng, n), 4, False)
+        time.sleep(0.05)                # the tick still owed lands
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
         opts.host_tracer_level = 2
@@ -118,6 +120,7 @@ def traced(request, tmp_path_factory):
                    if any(n.startswith("engine.") for n, *_ in evs)]
     assert len(engine_line) == 1, "engine spans on more than one thread"
     return {"mode": mode, "engine": engine_line[0], "lines": lines,
+            "trace_dir": str(tmp / "trace"),
             "rids": rids, "before": before, "after": after,
             "wall_ms": (t1_lo - t0_hi) * 1e3,       # at least
             "wall_max_ms": (t1_hi - t0_lo) * 1e3}   # and at most
@@ -203,6 +206,92 @@ def test_stage_clocks_sum_to_the_threads_wall_clock(traced):
         seen[n[len("engine."):]] = seen.get(n[len("engine."):], 0) + 1
     for k in ("admit", "dispatch", "apply", "finalize", "plan"):
         assert seen.get(k, 0) == n1[k] - n0[k], k
+
+
+def _pass_delta(traced):
+    b, a = traced["before"]["engine_pass"], traced["after"]["engine_pass"]
+    assert set(b) == set(a) == set(PASS_KINDS)
+    return {k: {f: a[k][f] - b[k][f] for f in a[k]} for k in a}
+
+
+def test_passes_tile_the_threads_wall_clock(traced):
+    """/stats ``engine_pass``: every pass of the engine's thread filed
+    under one kind, wall, fetch wait and CPU outside the fetch."""
+    d = _pass_delta(traced)
+    wall = sum(r["wall_ms"] for r in d.values())
+    # a pass is counted whole when it ends: the idle pass open at the
+    # first snapshot is in, the one open at the second is out
+    assert wall <= traced["wall_max_ms"] * 1.001 + 20
+    assert wall >= traced["wall_ms"] * 0.98             # passes tile it
+    for kind, r in d.items():
+        assert r["n"] >= 0 and r["wait_ms"] >= 0 and r["cpu_ms"] >= 0
+        # the reads nest (CPU inside wall), so only /stats' rounding
+        assert r["wait_ms"] + r["cpu_ms"] <= r["wall_ms"] + 0.01, kind
+    if traced["mode"] == "serial":      # no finalize: the fetch is in
+        assert all(r["wait_ms"] == 0       # dispatch, off the CPU
+                   for r in traced["after"]["engine_pass"].values())
+    else:
+        assert d["decode"]["wait_ms"] > 0
+    # a whole-prompt admission (5 tokens) files an admit pass; a chunked
+    # one's later chunks ride decode ticks (fused passes)
+    assert d["admit"]["n"] > 0 and d["fused"]["n"] > 0
+    assert d["decode"]["n"] > 0 and d["idle"]["n"] > 0
+    ticks = traced["after"]["work_ticks"] - traced["before"]["work_ticks"]
+    # a tick is dispatched in one pass and fetched in the same or the next
+    assert 0 < d["decode"]["n"] + d["fused"]["n"] <= 2 * ticks
+
+
+def test_each_pass_is_a_span_with_its_kind(traced):
+    passes = [(st["kind"], s, e) for n, s, e, st in traced["engine"]
+              if n == "pass"]
+    assert {k for k, _, _ in passes} <= set(PASS_KINDS)
+    # the session starts and ends on idle passes: the work passes of the
+    # session are the entries the ledger counted
+    d = _pass_delta(traced)
+    for kind in ("decode", "admit", "fused"):
+        assert sum(k == kind for k, _, _ in passes) == d[kind]["n"], kind
+    for (_, _, end), (_, start, _) in zip(passes, passes[1:]):
+        assert start >= end
+    # every stage lies inside one pass (but those of the passes open as
+    # the session started and stopped: their spans were not recorded)
+    lo, hi = passes[0][1], passes[-1][2]
+    for name, start, end, _ in _stages(traced):
+        if lo <= start and end <= hi:
+            assert any(s <= start and end <= e for _, s, e in passes), name
+    # and the benchmark's reader finds them by kind in the same file
+    from tpubench.readers import pass_trace, trace
+    seen = pass_trace.load(trace.find(traced["trace_dir"]))["passes"]
+    assert {k: len(v) for k, v in seen.items()} == {
+        k: sum(x == k for x, _, _ in passes) for k in seen}
+
+
+def test_thread_cpu_clocks_run_beside_the_wall_clocks(traced):
+    b, a = traced["before"], traced["after"]
+    assert set(a["engine_thread_cpu_ms"]) == set(ENGINE_STAGES)
+    for k in ENGINE_STAGES:
+        cpu = a["engine_thread_cpu_ms"][k] - b["engine_thread_cpu_ms"][k]
+        ms = a["engine_thread_ms"][k] - b["engine_thread_ms"][k]
+        assert 0 <= cpu <= ms + 0.01, k
+    # the sleep is off the CPU; the dispatch is mostly work
+    assert (a["engine_thread_cpu_ms"]["idle"]
+            - b["engine_thread_cpu_ms"]["idle"]) < 0.5 * (
+        a["engine_thread_ms"]["idle"] - b["engine_thread_ms"]["idle"])
+    assert (a["engine_thread_cpu_ms"]["dispatch"]
+            > b["engine_thread_cpu_ms"]["dispatch"])
+
+
+def test_handler_turns_are_counted_once_each(traced):
+    """/stats ``http_turns``: one a wake-up of a stream's handler that
+    wrote; a turn writes one event or more (a token and the done event
+    may share one), so the streams bound it from below and their events
+    from above."""
+    b, a = traced["before"], traced["after"]
+    turns = a["http_turns"] - b["http_turns"]
+    events = sum(n == "http.write" for evs in traced["lines"].values()
+                 for n, *_ in evs)
+    assert events == 4 * (8 + 1)
+    assert 4 <= turns <= events
+    assert a["http_turn_cpu_ms"] > b["http_turn_cpu_ms"]
 
 
 def test_the_ahead_counters_say_how_often_the_engine_ran_ahead(traced):
@@ -305,6 +394,32 @@ def test_stage_clock_and_span_cost_nothing_they_should_not():
         with span("x", a=1):
             pass
     assert (time.perf_counter() - t0) / 2000 < 50e-6
+    # and a stage, span and both clocks, not much more
+    t0 = time.perf_counter()
+    for _ in range(2000):
+        with clock.stage("a"):
+            pass
+    assert (time.perf_counter() - t0) / 2000 < 50e-6
+
+
+@pytest.mark.parametrize("spin", (False, True), ids=("sleep", "spin"))
+def test_a_stage_tells_waiting_from_work(spin):
+    """The thread-CPU clock beside the wall clock: a sleep is wall time
+    off the CPU, a spin is both."""
+    clock = StageClock("t", ("a",))
+    with clock.stage("a"):
+        c0 = time.thread_time()
+        if spin:
+            while time.thread_time() - c0 < 0.020:
+                pass
+        else:
+            time.sleep(0.020)
+    snap = clock.snapshot()
+    assert snap["ms"]["a"] >= 20.0
+    if spin:
+        assert 20.0 <= snap["cpu_ms"]["a"] <= snap["ms"]["a"]
+    else:
+        assert snap["cpu_ms"]["a"] < 2.0
 
 
 @pytest.mark.parametrize("family", ("dense", "moe"))

@@ -567,6 +567,9 @@ def test_streaming_is_event_driven():
         srv = _FakeSrv()
         max_tokens_cap = 4096
 
+        def note_http_turn(self, cpu_s):
+            pass
+
         def submit(self, req):
             req.cond = _CountingCondition()
             captured["req"] = req

@@ -160,7 +160,10 @@ PREFILL_CHUNK_FLOOR = 512
 #: The engine thread's loop as stages, in the overlapped tick's order;
 #: a moment of the thread is in at most one, and what is in none is the
 #: remainder against its wall clock. Each is a ``tpushare.engine.<name>``
-#: span in a profiler session and a clock in /stats ``engine_thread_ms``.
+#: span in a profiler session and two clocks in /stats: wall time in
+#: ``engine_thread_ms``, the thread's CPU time in ``engine_thread_cpu_ms``
+#: (wall less CPU is waiting: for the device, the interpreter's lock, or
+#: the OS).
 #:   preamble  chaos points, gang poll, capacity pre-reap
 #:   admit     one admission that popped a request (prefill included);
 #:             it waits for the device too: the eager pool scatter
@@ -178,6 +181,24 @@ PREFILL_CHUNK_FLOOR = 512
 #:   idle      the idle sleep
 ENGINE_STAGES = ("preamble", "admit", "finalize", "apply", "schedule",
                  "dispatch", "plan", "journal", "idle")
+
+#: What one pass of the engine's thread (one ``_loop_once``) is filed
+#: under in /stats ``engine_pass``, lowest precedence first: a pass takes
+#: the last of these it earned.
+#:   idle    none of the below
+#:   decode  dispatched or finalized a tick
+#:   admit   ran an admission (the ``admit`` stage, or a serial chunk)
+#:   fused   dispatched or finalized a tick that carried an admission chunk
+#: Each kind keeps ``n`` and, summed over its passes, ``wall_ms`` (the
+#: pass's monotonic span; passes tile the thread's time), ``wait_ms``
+#: (its ``finalize`` stage: the host waiting for the device) and
+#: ``cpu_ms`` (the thread's CPU time outside ``finalize``). The rest,
+#: wall - wait - cpu, is the thread off the CPU outside the fetch: the
+#: interpreter's lock held by a handler thread, a device sync inside
+#: ``admit`` or a serial ``dispatch``, the OS. The serial tick has no
+#: ``finalize``: its fetch is in ``dispatch``, so off the CPU there.
+#: Each pass is also a ``tpushare.pass`` span with its ``kind``.
+PASS_KINDS = ("idle", "decode", "admit", "fused")
 
 
 def _np_dtype(name: str):
@@ -678,8 +699,20 @@ class ServeEngine:
         # The engine thread's loop cut into stages (ENGINE_STAGES): a
         # span each when someone traces, a cumulative clock each
         # always. /stats engine_thread_ms / engine_thread_n.
-        from tpushare.utils.profiling import StageClock
+        from tpushare.utils.profiling import StageClock, span
         self._clock = StageClock("engine", ENGINE_STAGES)
+        self._span = span
+        # The pass ledger (PASS_KINDS): one writer, the engine's
+        # thread; stats() copies. _pass_rank is the running pass's
+        # kind so far, an index into PASS_KINDS.
+        self._pass = {k: {"n": 0, "wall_ms": 0.0, "wait_ms": 0.0,
+                          "cpu_ms": 0.0} for k in PASS_KINDS}
+        self._pass_rank = 0
+        # The handler threads' stream turns (make_handler): their CPU
+        # time, where the interpreter's other holders are.
+        self._turn_lock = threading.Lock()
+        self._http_turns = 0
+        self._http_turn_cpu_ms = 0.0
         # Typed transient-pressure exception (lazy-bound like every
         # other jax-adjacent import in this module): the admission and
         # preemption paths catch EXACTLY this — any other runtime
@@ -1673,6 +1706,13 @@ class ServeEngine:
         matches the scheduler's."""
         return self._sched.specs
 
+    def note_http_turn(self, cpu_s: float) -> None:
+        """One stream turn of a handler thread (``make_handler``):
+        ``cpu_s`` of that thread's CPU time."""
+        with self._turn_lock:
+            self._http_turns += 1
+            self._http_turn_cpu_ms += cpu_s * 1e3
+
     def stats(self) -> Dict[str, Any]:
         from tpushare.models.serving import mesh_axes as _mesh_axes
         from tpushare.utils.profiling import \
@@ -1850,6 +1890,22 @@ class ServeEngine:
             # above.
             "engine_thread_ms": clock["ms"],
             "engine_thread_n": clock["n"],
+            # The same stages on the thread's CPU clock: a stage's
+            # wall less its CPU is waiting (device, interpreter's
+            # lock, OS), so the stages but ``finalize`` and ``idle``
+            # on this clock bound the host's work from below.
+            "engine_thread_cpu_ms": clock["cpu_ms"],
+            # The pass ledger (PASS_KINDS), cumulative.
+            "engine_pass": {k: {"n": r["n"],
+                                "wall_ms": round(r["wall_ms"], 3),
+                                "wait_ms": round(r["wait_ms"], 3),
+                                "cpu_ms": round(r["cpu_ms"], 3)}
+                            for k, r in self._pass.items()},
+            # The handler threads' stream turns (make_handler): one
+            # wake-up's drain, encode, write and flush, on each
+            # thread's CPU clock.
+            "http_turns": self._http_turns,
+            "http_turn_cpu_ms": round(self._http_turn_cpu_ms, 3),
             # Host KV offload tier (ISSUE 18). Null-not-0 when no
             # tier is configured: an engine without a tier has no
             # offload plane, not an idle one — the router reads null
@@ -1982,6 +2038,7 @@ class ServeEngine:
             # container; _popped keeps drain()'s idle check honest
             # across the prefill (handoff atomic under _pop_lock).
             self._popped = req
+        self._file_pass("admit")
         with self._clock.stage(
                 "admit", rid=req.request_id,
                 prompt_tokens=len(req.prompt)) as sp:
@@ -2361,10 +2418,14 @@ class ServeEngine:
 
     def _loop_once(self, gen: Optional[int] = None) -> None:
         """One supervised engine iteration: tick, per-tick failure
-        recovery, deadline accounting. Split from _loop so tests can
-        drive the recovery machinery synchronously."""
+        recovery, deadline accounting, and the pass ledger
+        (PASS_KINDS). Split from _loop so tests can drive the recovery
+        machinery synchronously."""
         self._fire_kill_chaos()
-        t0 = time.monotonic()
+        t0, c0 = time.monotonic(), time.thread_time()
+        wait0 = self._clock.ms["finalize"]
+        wait_cpu0 = self._clock.cpu_ms["finalize"]
+        self._pass_rank = 0
         self._stats["ticks"] += 1
         # Published BEFORE the tick runs: a genuinely wedged tick
         # never reaches the post-hoc breach accounting below, so
@@ -2372,46 +2433,63 @@ class ServeEngine:
         # handler thread) is the only live signal of the wedge — and
         # the wedge watchdog's escalation trigger.
         self._tick_started = t0
-        try:
-            self._tick(gen)
-        except _EngineSuperseded:
-            # Escalated away mid-wedge: the new generation owns every
-            # piece of state now — touch nothing, not even the
-            # accounting, and let _loop's generation check exit.
-            return
-        except Exception as e:              # noqa: BLE001 — the engine
-            # must survive anything step()/admit() can raise: the
-            # tick is the failure domain, so every in-flight
-            # slot's device state is suspect — quarantine them all
-            # and REPLAY their requests (token-exact re-admission)
-            # instead of 503ing work a transient fault never
-            # corrupted. A dead engine thread with a happy
-            # /healthz is the one unacceptable state (lethal
-            # BaseExceptions escape to the supervisor, which
-            # restarts the thread).
-            self._stats["engine_errors"] += 1
-            self._stats["last_error"] = str(e)
-            if self._is_mesh_fault(e):
-                # Sharded dispatch death / flagged chip loss: the
-                # MESH is the failure domain — degrade-and-replay
-                # (quarantine rides inside) instead of replaying onto
-                # the same broken placement until replays exhaust.
-                self._reshard(f"mesh fault: {e}")
-            else:
-                self._quarantine_inflight(f"engine error: {e}")
-        finally:
-            if gen is None or gen == self._engine_gen:
-                # A superseded thread must not clobber the NEW
-                # generation's in-flight timestamp or flush its
-                # half-batched journal records.
-                self._tick_started = None
-                if self._journal is not None:
-                    with self._clock.stage("journal"):
-                        self._journal_tick_end()
-            if self._tick_deadline_ms is not None:
-                dt_ms = (time.monotonic() - t0) * 1e3
-                if dt_ms > self._tick_deadline_ms:
-                    self._stats["deadline_breaches"] += 1
+        with self._span("pass") as sp:
+            try:
+                self._tick(gen)
+            except _EngineSuperseded:
+                # Escalated away mid-wedge: the new generation owns
+                # every piece of state now — touch nothing, not even
+                # the accounting, and let _loop's generation check
+                # exit.
+                return
+            except Exception as e:          # noqa: BLE001 — the engine
+                # must survive anything step()/admit() can raise: the
+                # tick is the failure domain, so every in-flight
+                # slot's device state is suspect — quarantine them all
+                # and REPLAY their requests (token-exact re-admission)
+                # instead of 503ing work a transient fault never
+                # corrupted. A dead engine thread with a happy
+                # /healthz is the one unacceptable state (lethal
+                # BaseExceptions escape to the supervisor, which
+                # restarts the thread).
+                self._stats["engine_errors"] += 1
+                self._stats["last_error"] = str(e)
+                if self._is_mesh_fault(e):
+                    # Sharded dispatch death / flagged chip loss: the
+                    # MESH is the failure domain — degrade-and-replay
+                    # (quarantine rides inside) instead of replaying
+                    # onto the same broken placement until replays
+                    # exhaust.
+                    self._reshard(f"mesh fault: {e}")
+                else:
+                    self._quarantine_inflight(f"engine error: {e}")
+            finally:
+                if gen is None or gen == self._engine_gen:
+                    # A superseded thread must not clobber the NEW
+                    # generation's in-flight timestamp or flush its
+                    # half-batched journal records.
+                    self._tick_started = None
+                    if self._journal is not None:
+                        with self._clock.stage("journal"):
+                            self._journal_tick_end()
+                    c1, t1 = time.thread_time(), time.monotonic()
+                    kind = PASS_KINDS[self._pass_rank]
+                    sp.set_metadata(kind=kind)
+                    rec = self._pass[kind]
+                    rec["n"] += 1
+                    rec["wall_ms"] += (t1 - t0) * 1e3
+                    rec["wait_ms"] += self._clock.ms["finalize"] - wait0
+                    rec["cpu_ms"] += (c1 - c0) * 1e3 - (
+                        self._clock.cpu_ms["finalize"] - wait_cpu0)
+                if self._tick_deadline_ms is not None:
+                    dt_ms = (time.monotonic() - t0) * 1e3
+                    if dt_ms > self._tick_deadline_ms:
+                        self._stats["deadline_breaches"] += 1
+
+    def _file_pass(self, kind: str) -> None:
+        """The running pass earned ``kind``; it keeps the highest it
+        earns (PASS_KINDS order)."""
+        self._pass_rank = max(self._pass_rank, PASS_KINDS.index(kind))
 
     # -- mesh failure domain (ISSUE 13) --------------------------------
     def _device_in_serving_mesh(self, device: int,
@@ -2831,6 +2909,7 @@ class ServeEngine:
         the token-budget alternation. The tick budget caps this chunk
         too (an admission-only tick must not smuggle a full unbounded
         chunk past the latency bound the budget promises)."""
+        self._file_pass("admit")
         with self._clock.stage("dispatch"):
             self._fault_forward()   # chaos: this tick's model forward
             self._check_superseded(gen)  # wedge hang fired above: abort
@@ -2888,6 +2967,7 @@ class ServeEngine:
             self._stats["work_ticks"] += 1
             if work is not None:
                 self._stats["fused_ticks"] += 1
+            self._file_pass("decode" if work is None else "fused")
         with self._clock.stage("apply"):
             self._apply_step_output(out, work)
 
@@ -3292,6 +3372,7 @@ class ServeEngine:
                     pend, "device fault at pipeline finalize")
                 raise
             self._gap_waited += time.monotonic() - t0
+        self._file_pass("decode" if pend.work is None else "fused")
         with self._clock.stage("apply"):
             self._stats["steps"] += 1
             if pend.ahead:
@@ -3355,6 +3436,7 @@ class ServeEngine:
                 self._stats["fused_ticks"] += 1
             if ahead:
                 self._stats["ahead_ticks"] += 1
+        self._file_pass("decode" if work is None else "fused")
         self._record_host_gap()
         return True
 
@@ -3501,31 +3583,40 @@ def make_handler(engine: ServeEngine, timeout_s: float):
                             lambda: len(req.tokens) > sent
                             or req.done.is_set(),
                             timeout=max(0.0, deadline - time.time()))
-                    # Sample done BEFORE draining: every push precedes
-                    # finish(), so done-then-drain sees all tokens; a
-                    # push landing after the drain wakes the next
-                    # iteration. (Drain-then-check could break on a
-                    # push+finish pair landing between the two.)
-                    done = req.done.is_set()
-                    toks = req.tokens        # drain outside the lock
-                    while sent < len(toks):
-                        event({"token": toks[sent]}, eid=sent + 1)
-                        sent += 1
-                    if done:
-                        break
-                    if time.time() > deadline:
-                        if can_cancel:
-                            req.cancelled = True
-                        event({"error": "generation timed out"})
-                        return
-                if req.error:
-                    event({"error": req.error})
-                elif resume:
-                    event({"done": True}, eid=sent)
-                else:
-                    event({"done": True,
-                           "cached_prefix": req.cached_prefix},
-                          eid=sent)
+                    # A turn: from the wake-up to the next wait, on
+                    # this thread's CPU clock (/stats http_turns,
+                    # http_turn_cpu_ms), counted as it ends so a
+                    # stream that outlives a window is counted in it.
+                    c0 = time.thread_time()
+                    try:
+                        # Sample done BEFORE draining: every push
+                        # precedes finish(), so done-then-drain sees
+                        # all tokens; a push landing after the drain
+                        # wakes the next iteration. (Drain-then-check
+                        # could break on a push+finish pair landing
+                        # between the two.)
+                        done = req.done.is_set()
+                        toks = req.tokens    # drain outside the lock
+                        while sent < len(toks):
+                            event({"token": toks[sent]}, eid=sent + 1)
+                            sent += 1
+                        if done:
+                            if req.error:
+                                event({"error": req.error})
+                            elif resume:
+                                event({"done": True}, eid=sent)
+                            else:
+                                event({"done": True,
+                                       "cached_prefix": req.cached_prefix},
+                                      eid=sent)
+                            return
+                        if time.time() > deadline:
+                            if can_cancel:
+                                req.cancelled = True
+                            event({"error": "generation timed out"})
+                            return
+                    finally:
+                        engine.note_http_turn(time.thread_time() - c0)
             except (BrokenPipeError, ConnectionResetError):
                 if can_cancel:
                     req.cancelled = True    # engine reaps the slot

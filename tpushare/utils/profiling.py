@@ -12,8 +12,10 @@ The serving path traces itself with two things from here. ``span``
 names a stretch of host code in whatever profiler session is running
 (``trace()`` above, or anyone's ``jax.profiler.start_trace``), on the
 device trace's clock; ``StageClock`` cuts one thread's loop into named
-stages, each a span plus an always-on cumulative clock that ``/stats``
-shows. Neither takes a flag: spans are on when someone traces, clocks
+stages, each a span plus an always-on cumulative wall and thread-CPU
+clock that ``/stats`` shows (the two apart say how much of a stage was
+work and how much waiting: for the device, the interpreter's lock or
+the OS). Neither takes a flag: spans are on when someone traces, clocks
 always.
 """
 
@@ -96,30 +98,38 @@ def span(name: str, **args):
 class StageClock:
     """One thread's loop cut into named stages that do not overlap:
     ``with clock.stage(name):`` is the span ``<prefix>.<name>`` plus
-    the elapsed ``time.monotonic()`` added to ``ms[name]`` and one to
-    ``n[name]``. Whatever the thread does under no stage shows as the
-    remainder against its wall clock. One writer (the owning thread)
-    and no lock: every stage is named up front so the dicts never
-    change size, and ``snapshot()`` hands other threads a copy."""
+    the elapsed ``time.monotonic()`` added to ``ms[name]``, the
+    elapsed ``time.thread_time()`` (the thread's own CPU time) to
+    ``cpu_ms[name]`` and one to ``n[name]``. Whatever the thread does
+    under no stage shows as the remainder against its wall clock. One
+    writer (the owning thread) and no lock: every stage is named up
+    front so the dicts never change size, and ``snapshot()`` hands
+    other threads a copy."""
 
     def __init__(self, prefix: str, stages):
         self._prefix = prefix + "."
         self.ms = {name: 0.0 for name in stages}
+        self.cpu_ms = {name: 0.0 for name in stages}
         self.n = {name: 0 for name in stages}
 
     @contextlib.contextmanager
     def stage(self, name: str, **args):
-        t0 = time.monotonic()
+        t0, c0 = time.monotonic(), time.thread_time()
         try:
             with span(self._prefix + name, **args) as sp:
                 yield sp
         finally:
+            # read in the reverse order: the CPU interval lies inside
+            # the wall one, so a stage's CPU never exceeds its wall
+            self.cpu_ms[name] += (time.thread_time() - c0) * 1e3
             self.ms[name] += (time.monotonic() - t0) * 1e3
             self.n[name] += 1
 
     def snapshot(self) -> dict:
-        """{"ms": {stage: cumulative ms}, "n": {stage: entries}}."""
+        """{"ms": {stage: cumulative wall ms}, "cpu_ms": {stage:
+        cumulative thread-CPU ms}, "n": {stage: entries}}."""
         return {"ms": {k: round(v, 3) for k, v in self.ms.items()},
+                "cpu_ms": {k: round(v, 3) for k, v in self.cpu_ms.items()},
                 "n": dict(self.n)}
 
 
@@ -180,18 +190,6 @@ def time_step_chained(body: Callable, init, *consts, k_lo: int = 16,
     credible = delta >= min_credible_delta_s
     return max(delta, 1e-9) / (k_hi - k_lo), credible
 
-
-#: PhaseTimer phase name for the host-side scheduling gap of an
-#: overlapped engine tick: finalize-of-tick-N-1 done -> tick N's
-#: dispatch call RETURNED, so a sample holds the dispatch itself
-#: (block growth, the launch, the eager sampler) as well as the
-#: scheduling before it; the ``schedule`` and ``dispatch`` stage clocks
-#: give the two apart. The serving loop itself never attaches a
-#: PhaseTimer (measurement mode only — see the class docstring); it
-#: records raw monotonic deltas and summarizes them with
-#: ``gap_percentiles`` below. Benches that DO attach a timer charge
-#: the same span to this row so the two spellings line up.
-HOST_GAP = "host_gap"
 
 #: newest host-gap samples kept by the engine's ring (matches the
 #: tier-latency SAMPLE_CAP in slo/stats.py).
