@@ -2,6 +2,8 @@
 with single-device execution (the critical check: vma-aware transpose
 must produce full replicated-param grads under expert parallelism)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -736,3 +738,36 @@ class TestMoERaggedMultiToken:
                                        np.asarray(lg[:, 0]),
                                        rtol=2e-5, atol=2e-5)
             lens = lens + 1
+
+
+class TestMoEHeadProj:
+    """The MoE block's attention projections go through
+    ``transformer.head_proj`` too (imported into ``moe``): in bf16 the
+    forward is the one the plain ``h @ w`` gives, bit for bit, for one
+    token a row at its own offset, for a block of tokens, and through
+    the gradient."""
+
+    CFG = dataclasses.replace(CFG, dtype=jnp.bfloat16)
+
+    def _run(self, case):
+        cfg = self.CFG
+        params = _params(cfg)
+        toks = _tokens(cfg, batch=2, seq=9)
+        if case == "decode":
+            cache = moe.init_cache(cfg, 2, 16)
+            _, _, cache = moe.forward(params, toks[:, :8], cfg, cache=cache,
+                                      pos_offset=0)
+            return moe.forward(params, toks[:, 8:], cfg, cache=cache,
+                               pos_offset=jnp.asarray([8, 5]))[0]
+        if case == "block":
+            return moe.forward(params, toks, cfg)[0]
+        return jax.grad(lambda p: moe.lm_loss(p, toks, cfg))(params)
+
+    @pytest.mark.parametrize("case", ["decode", "block", "grad"])
+    def test_matches_the_plain_product(self, case, monkeypatch):
+        got = self._run(case)
+        monkeypatch.setattr(moe, "head_proj", lambda h, w: h @ w)
+        want = self._run(case)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                          np.asarray(w, np.float32))

@@ -1,6 +1,8 @@
 """Transformer LM: forward shapes, cache/decode equivalence, parity of
 the shard_map SPMD path with single-device execution (8-dev CPU mesh)."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -346,3 +348,57 @@ class TestGemma2Features:
         logits, _ = tf.forward(params, _tokens(cfg), cfg)
         assert np.isfinite(np.asarray(logits)).all()
         assert 2e9 < tf.gemma2_2b().num_params() < 3.5e9
+
+
+def _plain_proj(h, w):
+    return h @ w
+
+
+def _random_bank(params, cfg, seed=3):
+    """A multi-LoRA bank of one adapter on every target whose B is not
+    zero (a fresh adapter's delta is exactly zero)."""
+    from tpushare.models import lora
+    ad = lora.init_lora(jax.random.PRNGKey(seed), cfg, rank=4,
+                        targets=lora.LORA_TARGETS)
+    keys = iter(jax.random.split(jax.random.PRNGKey(seed + 1), len(ad)))
+    ad = {n: {"a": t["a"], "b": 0.05 * jax.random.normal(
+        next(keys), t["b"].shape, t["b"].dtype)} for n, t in ad.items()}
+    return lora.multi_lora_params(params, lora.stack_adapters([ad]))
+
+
+class TestHeadProj:
+    """The attention projections go through ``tf.head_proj`` (the dot
+    held in its own layout, so that XLA on TPU does not copy the
+    layer's weight out of the scanned stack): in bf16, with an adapter
+    on every projection in one row and none in the other, the forward
+    is the one the plain ``h @ w`` gives, for one token a row at its own
+    offset, for a block of tokens, and through the gradient. The barrier
+    is the identity on values, so the two agree bit for bit."""
+
+    CFG = dataclasses.replace(CFG, dtype=jnp.bfloat16)
+
+    def _run(self, case):
+        cfg = self.CFG
+        params = _random_bank(_params(cfg), cfg)
+        idx = jnp.asarray([0, -1], jnp.int32)
+        toks = _tokens(cfg, batch=2, seq=9)
+        if case == "decode":
+            cache = tf.init_cache(cfg, 2, 16)
+            _, cache = tf.forward(params, toks[:, :8], cfg, cache=cache,
+                                  pos_offset=0, mlora_idx=idx)
+            return tf.forward(params, toks[:, 8:], cfg, cache=cache,
+                              pos_offset=jnp.asarray([8, 5]),
+                              mlora_idx=idx)[0]
+        if case == "block":
+            return tf.forward(params, toks, cfg, mlora_idx=idx)[0]
+        loss = lambda p: lm_loss(p, toks, cfg)
+        return jax.grad(loss)(params)["layers"]
+
+    @pytest.mark.parametrize("case", ["decode", "block", "grad"])
+    def test_matches_the_plain_product(self, case, monkeypatch):
+        got = self._run(case)
+        monkeypatch.setattr(tf, "head_proj", _plain_proj)
+        want = self._run(case)
+        for g, w in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                          np.asarray(w, np.float32))

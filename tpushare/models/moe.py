@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from tpushare.ops import apply_rotary, attention, rms_norm, rotary_embedding
-from tpushare.models.transformer import ParallelCtx, _act
+from tpushare.models.transformer import ParallelCtx, _act, head_proj
 from tpushare.parallel.ring_attention import ring_attention
 
 
@@ -750,9 +750,11 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray, cfg: MoEConfig, *,
         h = rms_norm(x, layer["ln1"], eps=cfg.norm_eps)
         H = layer["wq"].shape[-1] // Dh
         Hkv = layer["wk"].shape[-1] // Dh
-        q = apply_rotary((h @ layer["wq"]).reshape(B, S, H, Dh), cos, sin)
-        k = apply_rotary((h @ layer["wk"]).reshape(B, S, Hkv, Dh), cos, sin)
-        v = (h @ layer["wv"]).reshape(B, S, Hkv, Dh)
+        q = apply_rotary(head_proj(h, layer["wq"]).reshape(B, S, H, Dh),
+                         cos, sin)
+        k = apply_rotary(head_proj(h, layer["wk"]).reshape(B, S, Hkv, Dh),
+                         cos, sin)
+        v = head_proj(h, layer["wv"]).reshape(B, S, Hkv, Dh)
         if use_cache and paged:
             # Scatter the new KV through the block table (inactive or
             # out-of-range positions land in the sacrificial trash

@@ -235,6 +235,19 @@ def _act(name: str, x: jnp.ndarray) -> jnp.ndarray:
     raise ValueError(f"unknown activation {name!r}")
 
 
+def head_proj(h: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+    """``h @ w`` for a projection into attention heads (q, k, v), held
+    in the dot's own layout. Left free, XLA on TPU lays the product out
+    head-major for its consumers (the rotary's per-head halves, the
+    attention kernels' head folds) and computes it per head, which
+    reads the layer's weight out of the scanned stack through a
+    per-layer slice and a transposed copy of it (32 MB each for ``wq``
+    at Mistral's widths), every step. The barrier is the identity on
+    values; what is reordered is the activation, a few hundred KB at
+    decode."""
+    return jax.lax.optimization_barrier(h @ w)
+
+
 def forward(params: Dict[str, Any], tokens: jnp.ndarray,
             cfg: TransformerConfig, *,
             pctx: Optional[ParallelCtx] = None,
@@ -380,9 +393,12 @@ def forward(params: Dict[str, Any], tokens: jnp.ndarray,
                      offset=cfg.norm_offset)
         H = layer["wq"].shape[-1] // Dh                        # tp-local heads
         Hkv = layer["wk"].shape[-1] // Dh
-        q = (h @ layer["wq"] + _ml("wq", h)).reshape(B, S, H, Dh)
-        k = (h @ layer["wk"] + _ml("wk", h)).reshape(B, S, Hkv, Dh)
-        v = (h @ layer["wv"] + _ml("wv", h)).reshape(B, S, Hkv, Dh)
+        q = (head_proj(h, layer["wq"]) + _ml("wq", h)).reshape(
+            B, S, H, Dh)
+        k = (head_proj(h, layer["wk"]) + _ml("wk", h)).reshape(
+            B, S, Hkv, Dh)
+        v = (head_proj(h, layer["wv"]) + _ml("wv", h)).reshape(
+            B, S, Hkv, Dh)
         q = apply_rotary(q, cos, sin)
         k = apply_rotary(k, cos, sin)
 
