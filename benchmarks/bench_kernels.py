@@ -111,7 +111,10 @@ def _timeit_paged_chained(fn, q, pk, pv, table, pos, *,
     return _timeit_scan(body, (q, pk, pv, pos), table, q, iters=iters)
 
 
-def _report(name, out, ref, kernel_ms, kernel_cred, ref_ms, ref_cred):
+def _report(name, out, ref, kernel_ms, kernel_cred, ref_ms, ref_cred, *,
+            floor_ms=None):
+    """Print a kernel's row; ``floor_ms``, where given, is the least time
+    the call can take, and the row gains the kernel's share of it."""
     err = float(jnp.max(jnp.abs(out.astype(jnp.float32)
                                 - ref.astype(jnp.float32))))
     ok = err < 3e-2  # bf16 inputs, f32 softmax in both paths
@@ -122,6 +125,8 @@ def _report(name, out, ref, kernel_ms, kernel_cred, ref_ms, ref_cred):
             "kernel_ms": round(kernel_ms, 3), "ref_ms": round(ref_ms, 3),
             "speedup": round(ref_ms / kernel_ms, 2) if kernel_ms else None,
             "timing_credible": bool(kernel_cred and ref_cred)})
+        if floor_ms is not None and kernel_ms:
+            row["floor_pct"] = round(100.0 * floor_ms / kernel_ms, 1)
     print(json.dumps(row), flush=True)
     return ok
 
@@ -351,11 +356,28 @@ def _timeit_latent_chained(fn, q, pool, table, pos, *, layer: int,
     return _timeit_scan(body, (q, pool, pos), table, q, iters=iters)
 
 
+def _latent_floor_ms(n_rows: int, n_q: int) -> float:
+    """The least time a call over ``n_rows`` cached rows under ``n_q``
+    live queries a slot can take at the pangu cell's published widths:
+    the floor ``mla.decode_roofline_pct`` holds the kernel to in a trace
+    (``tpubench/readers/mla_decode_trace.call_floor_s``), so that the
+    kernel alone and the cell read on one yardstick."""
+    from tpubench import spec
+    from tpubench.peaks import peaks_for
+    from tpubench.readers.mla_decode_trace import call_floor_s
+    with open(os.path.join(spec.ROOT, "tpubench", "configs",
+                           "openpangu-ultra-l5-ep32.json")) as f:
+        config = json.load(f)
+    peaks = peaks_for(jax.devices()[0].device_kind)
+    return 1e3 * call_floor_s(config, n_rows, n_q, peaks)
+
+
 def _bench_latent_paged(live_rows: int):
     """The paged latent decode kernel at the pangu cell's shapes (16
     slots, two queries of 128 heads, rows of 640 of which 512 are the
     latent, a table of 1,046 pages of 16) with every slot at
-    ``live_rows``, against the gathered ``jnp`` form."""
+    ``live_rows``, against the gathered ``jnp`` form; its row gives the
+    kernel's share of the published widths' floor (``floor_pct``)."""
     from tpushare.models import latent
     from tpushare.ops.latent_decode import latent_paged_decode
     B, Q, H, bs, mb, L, li = 16, 2, 128, 16, 1046, 2, 1
@@ -375,7 +397,8 @@ def _bench_latent_paged(live_rows: int):
     timer = functools.partial(_timeit_latent_chained, layer=li)
     return _report(f"latent_paged_decode_{live_rows // 1024}k",
                    fl(q, pool, table, pos), rf(q, pool, table, pos),
-                   *_timed_pair(timer, fl, rf, q, pool, table, pos))
+                   *_timed_pair(timer, fl, rf, q, pool, table, pos),
+                   floor_ms=_latent_floor_ms(B * live_rows, Q))
 
 
 def bench_latent_paged():

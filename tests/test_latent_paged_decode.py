@@ -2,7 +2,13 @@
 Pallas interpreter against the ``jnp`` body of ``latent._decode_all``
 (what every call takes on the CPU): Q = 1 and 2, ragged lengths, a dead
 slot, a dead query beside a live one, stale rows past a position, a
-shared prefix block, and the eligibility predicate's truth table."""
+shared prefix block, whole groups past a slot's live pages, and the
+eligibility predicate's truth table.
+
+Under the interpreter a DMA semaphore counts elements in int16
+(``SEMAPHORE_MAX_VALUE``, 32,767) and the kernel waits once for a whole
+tile, so every tile here stays under that count: six pages of 16 rows of
+256 are 24,576 elements, a group of two such pages 8,192."""
 
 import dataclasses
 import math
@@ -85,14 +91,68 @@ def test_ragged_slots_agree_with_the_jnp_body(key_dim, n_q):
     _close(got, want, live)
 
 
-def test_the_loop_runs_in_groups_and_the_last_may_be_short(monkeypatch):
-    """A group of two pages over a slot of five live pages: three loop
-    steps, the last of one page beside a stale tile half."""
+def _unallocated_past_live(table, pos):
+    """-1 in every entry of a slot's table past its live pages."""
+    table = table.copy()
+    for b in range(table.shape[0]):
+        table[b, pos[b].max() // BS + 1:] = -1
+    return table
+
+
+GROUPS = [
+    # mb, lengths, -1 past the live pages
+    ("five live pages of six", 6, [70, 3, 95, 33], False),
+    ("a table of five pages, the last group past it", 5, [78, 3, 60, 33],
+     False),
+    ("live pages ending one page into a group", 6, [32, 33, 38, 46], False),
+    ("-1 past the live pages", 5, [78, 3, 40, 16], True),
+]
+
+
+@pytest.mark.parametrize("case", GROUPS, ids=[c[0] for c in GROUPS])
+def test_the_loop_runs_in_groups_and_the_last_may_be_short(case,
+                                                           monkeypatch):
+    """A group of two pages: a slot's last group may reach past its live
+    pages, past its table's width, or onto -1 entries. It is copied
+    whole all the same, its pages there the slot's last live page again,
+    whose rows lie past every query's position."""
+    _, mb, lengths, unallocated = case
     monkeypatch.setattr(latent_decode, "LATENT_GROUP_KEYS", 2 * BS)
     dims = _cfg(256)
-    q, pool, table, pos = _case(256, 2, [70, 3, 95, 33], seed=3)
+    q, pool, table, pos = _case(256, 2, lengths, mb=mb, seed=3)
+    if unallocated:
+        table = _unallocated_past_live(table, pos)
     live = np.ones(pos.shape, bool)
     got, want = _both(dims, q, pool, table, pos, live)
+    _close(got, want, live)
+
+
+@pytest.mark.parametrize("group_keys", [2 * BS, 512])
+@pytest.mark.parametrize("unallocated", [False, True])
+def test_a_page_past_the_live_ones_never_reads_a_foreign_block(
+        group_keys, unallocated, monkeypatch):
+    """NaN in block 0 and in every block no slot holds live (a slot's
+    own blocks past its live pages among them, or -1 entries there):
+    the clamp copies none of them, so every output is finite and what
+    the ``jnp`` body gives over the clean pool."""
+    monkeypatch.setattr(latent_decode, "LATENT_GROUP_KEYS", group_keys)
+    dims = _cfg(256)
+    q, pool, table, pos = _case(256, 2, [78, 3, 40, 16], mb=5, seed=7)
+    if unallocated:
+        table = _unallocated_past_live(table, pos)
+    live = np.ones(pos.shape, bool)
+    held = {int(table[b, p]) for b in range(table.shape[0])
+            for p in range(pos[b].max() // BS + 1)}
+    poisoned = np.asarray(pool, np.float32)
+    for blk in range(pool.shape[1]):
+        if blk not in held:
+            poisoned[:, blk] = np.nan
+    assert 0 not in held
+    clean, want = _both(dims, q, pool, table, pos, live)
+    got, _ = _both(dims, q, jnp.asarray(poisoned, jnp.bfloat16), table, pos,
+                   live)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
     _close(got, want, live)
 
 

@@ -11,7 +11,7 @@ AND, cut to its first ``kv_rank`` columns, the value of every head:
 ``latent_paged_decode`` is ``paged_flash_decode``'s scheme
 (``ops/flash_attention.py``) for that shape: grid ``(B,)``, one grid step
 a slot, inside it a loop over groups of the slot's LIVE pages, one DMA a
-page through the scalar-prefetched block table into one VMEM tile of a
+page through the scalar-prefetched page list into one VMEM tile of a
 double buffer, the next group (or the next live slot's first) in flight
 while this one is computed, the softmax online. What differs:
 
@@ -23,7 +23,11 @@ while this one is computed, the softmax online. What differs:
   (``_attend``'s precision, not ``_pv_exact``'s split);
 - slots with no live query are compacted out of the loop (the
   ``retention_step`` scheme): they cost no copy and no product, and
-  their output is zero.
+  their output is zero;
+- the loop step is one straight-line block: every group is whole (a
+  slot's page list is cut and clamped before the call, so a group's
+  copies need no branch and no arithmetic on the scalar core), the next
+  group's copies start at its top and one wait covers the whole tile.
 
 The stacked pool ``[L, n_blocks, bs, key_dim]`` stays in HBM whole and
 the layer is a scalar: no layer slice, no gathered view exists.
@@ -50,7 +54,8 @@ KERNEL_NAME = "latent_paged_decode"
 #: the pangu cell's shapes, 12k live rows a slot (PERF.md section 6,
 #: PR 36): 256 keys a step cost 1.01 ms a call, 512 cost 0.93; at 1,024
 #: the program halted on the chip (64 copies in flight on one
-#: semaphore, 1.3 MB; not looked into).
+#: semaphore, 1.3 MB: 40,960 granules of 32 bytes, past the 32,767 the
+#: interpreter's semaphores hold; not looked into on the chip).
 LATENT_GROUP_KEYS = 512
 
 
@@ -82,16 +87,17 @@ def latent_decode_eligible(q: jnp.ndarray, pool: jnp.ndarray,
             and (Q * H) % _sublanes(q.dtype) == 0)
 
 
-def _latent_decode_kernel(table_ref, qpos_ref, order_ref, n_ref, layer_ref,
+def _latent_decode_kernel(pages_ref, qpos_ref, order_ref, n_ref, layer_ref,
                           q_ref, pool_hbm, o_ref, buf, acc_ref, sem,
                           turn_ref, *, scale: float, n_q: int, n_heads: int,
-                          kv_rank: int, group: int, n_pages: int):
+                          kv_rank: int, group: int, n_pages: int, width: int):
     # Grid step i serves slot order[i]; the first n_ref[0] steps are the
-    # slots with a live query, in slot order. qpos [B * n_q] holds a
-    # query's position, -1 where it is not live. buf [2, group * bs,
-    # key_dim] is the double buffer, acc [n_q * n_heads, kv_rank] the
-    # output's float32 accumulator; the buffer's turn and its copy in
-    # flight pass from one grid step to the next.
+    # slots with a live query, in slot order. pages [B * width] is each
+    # slot's page list, whole groups wide (``_page_lists``); qpos
+    # [B * n_q] holds a query's position, -1 where it is not live. buf
+    # [2, group * bs, key_dim] is the double buffer, acc [n_q * n_heads,
+    # kv_rank] the output's float32 accumulator; the buffer's turn and
+    # its copy in flight pass from one grid step to the next.
     bs = buf.shape[1] // group
     T = group * bs
     rows = n_q * n_heads
@@ -100,53 +106,37 @@ def _latent_decode_kernel(table_ref, qpos_ref, order_ref, n_ref, layer_ref,
     layer = layer_ref[0]
 
     def span(step):
-        """(slot, end of its live pages, its groups) of a live step: the
-        pages up to the slot's largest live position."""
+        """(slot, its groups) of a live step: the pages up to the slot's
+        largest live position."""
         slot = order_ref[step]
         top = qpos_ref[slot * n_q]
         for j in range(1, n_q):
             top = jnp.maximum(top, qpos_ref[slot * n_q + j])
         hi = jnp.clip(top // bs + 1, 1, n_pages)
-        return slot, hi, (hi + group - 1) // group
+        return slot, (hi + group - 1) // group
 
-    def page_copies(slot, hi, g, half, start: bool):
-        """Start, or wait for, the copies of ``slot``'s live pages of
-        group ``g`` into ``half`` of the buffer: page j of the group
-        lands on rows j * bs of the tile."""
-        first = g * group
-        n = jnp.minimum(hi, first + group) - first
+    def start_group(slot, g, half):
+        """Start the copies of ``slot``'s group ``g`` into ``half`` of the
+        buffer, page j of the group on rows j * bs of the tile."""
+        at = slot * width + g * group
+        for j in range(group):
+            pltpu.make_async_copy(
+                pool_hbm.at[layer, pages_ref[at + j]],
+                buf.at[half, pl.ds(j * bs, bs)], sem.at[half]).start()
 
-        def one(j, _=None):
-            at = j * bs if isinstance(j, int) else pl.multiple_of(j * bs, bs)
-            blk = jnp.maximum(table_ref[slot, first + j], 0)
-            dma = pltpu.make_async_copy(
-                pool_hbm.at[layer, blk], buf.at[half, pl.ds(at, bs)],
-                sem.at[half])
-            dma.start() if start else dma.wait()
-
-        # a whole group (all but a slot's last) unrolled: in a rolled
-        # loop the scalar core's work a copy was a quarter of the call
-        # (1.22 -> 0.93 ms at 12k rows a slot, PERF.md section 6, PR 36)
-        @pl.when(n == group)
-        def _whole():
-            for j in range(group):
-                one(j)
-
-        @pl.when(n != group)
-        def _part():
-            jax.lax.fori_loop(0, n, one, None)
+    def wait_group(half):
+        """Wait for a whole group's copies into ``half``: one wait for
+        the tile's bytes (the descriptor is only its size)."""
+        pltpu.make_async_copy(buf.at[half], buf.at[half],
+                              sem.at[half]).wait()
 
     @pl.when(i == 0)
     def _first():
-        # Tile rows no copy ever writes are multiplied by p = 0: they
-        # must hold finite values (0 x NaN would poison the product).
-        buf[...] = jnp.zeros_like(buf)
         turn_ref[0] = 0
 
         @pl.when(n_live > 0)
         def _():
-            slot, hi, _ = span(0)
-            page_copies(slot, hi, 0, 0, start=True)
+            start_group(order_ref[0], 0, 0)
 
     @pl.when(i >= n_live)
     def _dead():
@@ -154,7 +144,10 @@ def _latent_decode_kernel(table_ref, qpos_ref, order_ref, n_ref, layer_ref,
 
     @pl.when(i < n_live)
     def _live():
-        slot, hi, n_g = span(i)
+        slot, n_g = span(i)
+        # in line after this slot's last group: the next live slot's
+        # first, and after the call's last, this slot's first again
+        nslot = order_ref[jnp.minimum(i + 1, n_live - 1)]
         row = jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0)
         row_pos = jnp.full((rows, 1), qpos_ref[slot * n_q], jnp.int32)
         for j in range(1, n_q):
@@ -163,21 +156,16 @@ def _latent_decode_kernel(table_ref, qpos_ref, order_ref, n_ref, layer_ref,
         lane = jax.lax.broadcasted_iota(jnp.int32, (rows, T), 1)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
+        # One straight-line block a step. The next group's copies start
+        # first: placed after the score product, the scheduler sinks them
+        # to the step's end and the next wait stalls (on a v5e at 12k
+        # rows a slot, 1.18 ms a call against 0.97; PERF.md section 6).
         def step(g, carry):
             m, l, half = carry
-            # Next in line: this slot's next group, else the next live
-            # slot's first (nothing after the last live slot's last).
             last = g == n_g - 1
-            nslot, nhi, _ = span(jnp.where(
-                last, jnp.minimum(i + 1, n_live - 1), i))
-
-            @pl.when(jnp.logical_not(jnp.logical_and(last,
-                                                     i == n_live - 1)))
-            def _prefetch():
-                page_copies(nslot, nhi, jnp.where(last, 0, g + 1),
-                            1 - half, start=True)
-
-            page_copies(slot, hi, g, half, start=False)
+            start_group(jnp.where(last, nslot, slot),
+                        jnp.where(last, 0, g + 1), 1 - half)
+            wait_group(half)
             tile = buf[half]                                # [T, key_dim]
             s = jax.lax.dot_general(
                 q_ref[0], tile, (((1,), (1,)), ((), ())),
@@ -198,10 +186,33 @@ def _latent_decode_kernel(table_ref, qpos_ref, order_ref, n_ref, layer_ref,
             (jnp.full((rows, 1), NEG_INF, jnp.float32),
              jnp.zeros((rows, 1), jnp.float32), turn_ref[0]))
         turn_ref[0] = half
+
+        @pl.when(i == n_live - 1)
+        def _drain():
+            # the call's last copies, which no step reads
+            wait_group(half)
+
         # a query that is not live (beside a live one) attended nothing
         o_ref[0] = jnp.where(row_pos >= 0,
                              acc_ref[...] / jnp.maximum(l, 1e-30),
                              0.0).astype(o_ref.dtype)
+
+
+def _page_lists(table, qpos, *, bs: int, group: int, n_blocks: int):
+    """Each slot's page list as the kernel's copies read it: [B * width]
+    int32, ``width`` the table's width rounded up to whole groups. Past
+    a slot's live pages (past its table, or a -1 entry) it repeats the
+    slot's last live page, whose rows there lie past every query's
+    position: a group is always whole and a slot reads no block but its
+    own. Every entry is a block of the pool, so no copy can leave it."""
+    B, mb = table.shape
+    width = -(-mb // group) * group
+    hi = jnp.clip(jnp.max(qpos, axis=1) // bs + 1, 1, mb)         # [B]
+    col = jnp.arange(width)[None, :]
+    padded = jnp.pad(table, ((0, 0), (0, width - mb)))
+    last = jnp.sum(jnp.where(col == hi[:, None] - 1, padded, 0), axis=1)
+    pages = jnp.where(col < hi[:, None], padded, last[:, None])
+    return jnp.clip(pages, 0, n_blocks - 1).reshape(B * width), width
 
 
 @functools.partial(jax.jit, static_argnames=("kv_rank", "scale", "interpret"))
@@ -215,29 +226,31 @@ def latent_paged_decode(q: jnp.ndarray, pool: jnp.ndarray,
 
     q [B, Q, H, key_dim]; pool the WHOLE stacked pool [L, n_blocks, bs,
     key_dim] with ``layer`` (a traced scalar is fine) the layer to read;
-    table [B, max_blocks] int32 pool indices (-1 = unallocated: clamped
-    to page 0 and masked by ``pos``); pos, live [B, Q]: query (b, j)
+    table [B, max_blocks] int32 pool indices (-1 = unallocated: never
+    read past a slot's live pages); pos, live [B, Q]: query (b, j)
     attends slot b's positions <= pos[b, j] through its table (its own
     row already written) and nothing where it is not live. Returns the
     latent output [B, Q, H, kv_rank], zero for a query that is not live.
 
-    A call costs the LIVE pages: a slot's loop runs up to its largest
-    live position, a page past a query's position is masked (what a
-    rejected draft left there is never attended), a slot with no live
-    query is skipped."""
+    A call costs the LIVE pages, in whole groups: a slot's loop runs up
+    to its largest live position, a page past a query's position is
+    masked (what a rejected draft left there is never attended), a slot
+    with no live query is skipped."""
     B, Q, H, C = q.shape
-    _, _, bs, C2 = pool.shape
+    L, n_blocks, bs, C2 = pool.shape
     assert C2 == C and 0 < kv_rank <= C, (q.shape, pool.shape, kv_rank)
     mb = table.shape[1]
     group = _latent_group_pages(bs, mb, C * pool.dtype.itemsize)
     qpos = jnp.where(live, pos, -1).astype(jnp.int32)            # [B, Q]
     alive = jnp.max(qpos, axis=1) >= 0
     order = jnp.argsort(jnp.logical_not(alive), stable=True)
+    pages, width = _page_lists(jnp.asarray(table, jnp.int32), qpos, bs=bs,
+                               group=group, n_blocks=n_blocks)
 
-    def at_slot(i, table_ref, qpos_ref, order_ref, n_ref, layer_ref):
+    def at_slot(i, pages_ref, qpos_ref, order_ref, n_ref, layer_ref):
         return (order_ref[i], 0, 0)
 
-    def at_live_slot(i, table_ref, qpos_ref, order_ref, n_ref, layer_ref):
+    def at_live_slot(i, pages_ref, qpos_ref, order_ref, n_ref, layer_ref):
         # a skipped step asks for the block it already holds: no copy
         return (order_ref[jnp.minimum(i, jnp.maximum(n_ref[0] - 1, 0))],
                 0, 0)
@@ -245,7 +258,7 @@ def latent_paged_decode(q: jnp.ndarray, pool: jnp.ndarray,
     out = pl.pallas_call(
         functools.partial(_latent_decode_kernel, scale=scale, n_q=Q,
                           n_heads=H, kv_rank=kv_rank, group=group,
-                          n_pages=mb),
+                          n_pages=mb, width=width),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(B,),
@@ -260,12 +273,18 @@ def latent_paged_decode(q: jnp.ndarray, pool: jnp.ndarray,
             ],
         ),
         # the double buffer's turn and its copy in flight pass from one
-        # slot's grid step to the next: the steps run in order
+        # slot's grid step to the next: the steps run in order. Every
+        # index the kernel reads by is in range by construction (the
+        # page lists and the layer are clipped into the pool here, the
+        # slot order and positions are the call's own), so Mosaic's
+        # run-time checks are off: on a v5e they cost the scalar core
+        # 0.38 us a group of copies (PERF.md section 6).
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",), disable_bounds_checks=True),
         out_shape=_sds((B, Q * H, kv_rank), q.dtype, q, pool),
         interpret=interpret, name=KERNEL_NAME,
-    )(jnp.asarray(table, jnp.int32), qpos.reshape(B * Q),
-      order.astype(jnp.int32), jnp.sum(alive).astype(jnp.int32).reshape(1),
-      jnp.asarray(layer, jnp.int32).reshape(1), q.reshape(B, Q * H, C), pool)
+    )(pages, qpos.reshape(B * Q), order.astype(jnp.int32),
+      jnp.sum(alive).astype(jnp.int32).reshape(1),
+      jnp.clip(jnp.asarray(layer, jnp.int32), 0, L - 1).reshape(1),
+      q.reshape(B, Q * H, C), pool)
     return out.reshape(B, Q, H, kv_rank)
