@@ -32,10 +32,12 @@ def test_the_metric_resolves_through_spec_as_the_other_readers_do():
     assert spec.reader(lm["reader"]) is reader
     entry = [m for m in spec.benchmark()["per_layer"]
              if m["name"] == "mla.decode_roofline_pct"]
+    assert len(entry) == 1
+    # a later cell on the same kernel may add itself to the list
+    assert CELL in entry[0].pop("workloads")
     assert entry == [{"name": "mla.decode_roofline_pct", "unit": "%",
                       "better": "higher", "source": "device_trace",
-                      "layer": "kernels", "moves": "itl_p50_ms",
-                      "workloads": [CELL]}]
+                      "layer": "kernels", "moves": "itl_p50_ms"}]
     assert "mla.decode_roofline_pct" in spec.load_cell(CELL).per_layer
     for other in ("dots3-note-prev-l5-ep8.longdoc", "mistral7b-l16.chat"):
         assert "mla.decode_roofline_pct" not in spec.load_cell(other).per_layer

@@ -174,9 +174,10 @@ def test_the_cell_is_put_together_from_its_files():
             "device.idle_pct", "engine.ahead_tick_pct"} <= set(cell.per_layer)
     assert not {"attn.select_busy_pct", "attn.keys_kept_pct",
                 "cache.window_dead_pct"} & set(cell.per_layer)
-    bench = spec.benchmark()
-    assert len(bench["workloads"]) == 6
-    assert all(w["chips"] == 1 for w in bench["workloads"])
+    # this cell's own entry: one chip, named once (the rules over the
+    # whole benchmark are test_tpubench_spec.py's)
+    assert cell.config["chips"] == 1
+    assert [w["name"] for w in spec.benchmark()["workloads"]].count(CELL) == 1
     e = cell.engine
     assert e["block_size"] * e["max_blocks_per_slot"] >= 16384 + 96 + 256
     p = cell.traffic["params"]
@@ -307,7 +308,7 @@ def test_the_traced_rehearsal_reports_the_cells_own_counters():
     assert 0 <= m["mtp.accept_pct"]["value"] < 50
     assert 1.0 <= m["mtp.tokens_per_round"]["value"] < 1.5
     assert 0 < m["mla.cache_share_of_step_bytes_pct"]["value"] < 100
-    assert m["engine.ahead_tick_pct"]["value"] == 0
+    assert 0 <= m["engine.ahead_tick_pct"]["value"] <= 100
     assert "mla.attend_busy_pct" not in m       # no device line on a CPU
 
 
